@@ -3,18 +3,21 @@
 The XLA-on-TPU trace annotates every device op with model_flops and
 bytes_accessed; this script aggregates them into the per-op and
 per-category tables committed in docs/PERF_RESNET.md, including each op's
-achieved HBM bandwidth / FLOP rate and its distance from the chip roofline
-(v5e: 197 TFLOP/s bf16, 819 GB/s HBM).
+achieved HBM bandwidth / FLOP rate and its distance from the chip roofline.
+The peaks come from the one table (telemetry/devstats.PEAK_TABLE) for the
+device_kind the trace was captured on — named on the command line, because
+a recorded trace is analysed on any host; an unknown kind is an error.
 
-Usage: python benchmark/roofline.py <trace.json.gz> [n_steps]
+Usage: python benchmark/roofline.py <trace.json.gz> <device_kind> [n_steps]
+  e.g. python benchmark/roofline.py trace.json.gz "TPU v5 lite" 3
 """
 import collections
 import gzip
 import json
+import os
 import sys
 
-PEAK_F = 197e12
-PEAK_B = 819e9
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def load_ops(path):
@@ -47,8 +50,10 @@ def category(e):
 
 
 def main():
+    from incubator_mxnet_tpu.telemetry import devstats
     path = sys.argv[1]
-    steps = int(sys.argv[2]) if len(sys.argv) > 2 else 5
+    PEAK_F, _peak_int8, PEAK_B = devstats.device_peaks(sys.argv[2])
+    steps = int(sys.argv[3]) if len(sys.argv) > 3 else 5
     ops = load_ops(path)
     per_op = {}
     per_cat = collections.defaultdict(lambda: dict(us=0.0, f=0, b=0))

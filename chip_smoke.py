@@ -1,0 +1,587 @@
+"""chip_smoke.py — the quickest proof that HEAD still starts on the chip.
+
+One process, no arguments: drives the system's main paths once, through the
+public package, at the full width of the models bench.py times — the Pallas
+flash-attention kernels against a reference, BERT and a long-context GPT
+through gluon.Trainer -> jit.TrainStep with those kernels, ResNet-50 training, ResNet-50 behind the HTTP
+server, the generative engine, and (on a host with >= 4 chips) the dp and
+dp x sp mesh steps — and checks what comes out. Weights are random from a
+seed; depth is the bench's. It measures nothing: the compile and step
+seconds it prints are for information.
+
+It fails (non-zero exit, no result line) when JAX's default backend is not
+a TPU, when the device_kind has no row in the peak table, when
+MXTPU_FLASH_INTERPRET is set, or when any phase fails; nothing here catches
+an exception to carry on. The last line of stdout on success is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+`--rehearse` runs the same phases at toy sizes on whatever backend JAX has
+(kernels interpreted) so that a change can be debugged on the CPU before
+chip time is spent; `--phases a,b` runs a subset. Neither prints the result
+line: they are not a pass.
+"""
+import argparse
+import gc
+import json
+import math
+import os
+import re
+import statistics
+import sys
+import threading
+import time
+import urllib.request
+
+PHASES = ("kernels", "bert", "gpt", "resnet", "serve", "generate",
+          "multichip")
+
+# the bench's widths (bench.py bench_transformer / bench_long_context / main)
+FULL = {
+    # (B, H, S, D), causal: S picks the block (512 as BERT, 1024 as GPT)
+    "kernels": [((2, 4, 512, 128), False), ((1, 4, 2048, 128), True)],
+    "bert": dict(B=64, S=512, V=32768, U=1024, L=12, H=8),
+    "gpt": dict(S=8192, V=32768, U=1024, L=4, H=8),
+    "resnet": dict(B=256, HW=224),
+    "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
+    "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
+}
+# --rehearse: same code paths, toy shapes (D stays 128 so the flash kernels
+# are legal and run interpreted)
+TOY = {
+    "kernels": [((1, 2, 128, 128), False), ((1, 2, 256, 128), True)],
+    "bert": dict(B=4, S=128, V=512, U=256, L=1, H=2),
+    "gpt": dict(S=256, V=512, U=256, L=1, H=2),
+    "resnet": dict(B=16, HW=64),
+    "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
+    "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
+}
+
+
+def log(msg):
+    print(msg, flush=True)
+
+
+# ------------------------------------------------------------------- kernels
+def phase_kernels(cases, on_chip, shared):
+    """flash_attention's output and its three gradients against a float32
+    jax.numpy reference, on the device, at the block sizes the BERT and
+    GPT phases use (the 1024-block causal backward is the kernel with the
+    largest VMEM footprint)."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops.attention import flash_attention
+
+    def reference(q, k, v, causal):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
+            / math.sqrt(q.shape[-1])
+        if causal:
+            n = q.shape[2]
+            s = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :],
+                          s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                          precision="highest")
+
+    compile_s, steady, worst = 0.0, [], 0.0
+    for shape, causal in cases:
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, w = (jax.random.normal(kk, shape, jnp.float32)
+                      .astype(jnp.bfloat16) for kk in keys)
+
+        def both(attn):
+            def f(q, k, v):
+                out = attn(q, k, v, causal)
+                return (out.astype(jnp.float32)
+                        * w.astype(jnp.float32)).sum(), out
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                              has_aux=True))
+
+        flash = both(flash_attention)
+        if on_chip and "tpu_custom_call" not in \
+                flash.lower(q, k, v).as_text():
+            raise RuntimeError("flash_attention%s lowered without the "
+                               "Pallas kernels" % (shape,))
+        t0 = time.perf_counter()
+        (_, out), grads = jax.block_until_ready(flash(q, k, v))
+        compile_s += time.perf_counter() - t0
+        t0 = time.perf_counter()
+        jax.block_until_ready(flash(q, k, v))
+        steady.append(time.perf_counter() - t0)
+        (_, ref_out), ref_grads = both(reference)(q, k, v)
+        for name, got, want in zip(("out", "dq", "dk", "dv"),
+                                   (out,) + grads, (ref_out,) + ref_grads):
+            got, want = (jnp.asarray(x, jnp.float32) for x in (got, want))
+            err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+            worst = max(worst, err)
+            # bf16 in and out: one rounding is 2^-8 of the value
+            if not err <= 2e-2:
+                raise RuntimeError("flash %s%s causal=%s: max error %.3g of "
+                                   "the reference's max" % (
+                                       name, shape, causal, err))
+    return compile_s, statistics.median(steady), \
+        "out/dq/dk/dv vs float32 reference at %s, worst error %.2g of max" \
+        % ([c[0] for c in cases], worst)
+
+
+# ------------------------------------------------------------------ training
+def run_steps(step, inputs, warm, steps):
+    """warm + steps calls on one fixed batch, every loss read back (the
+    readback is the sync). -> (losses, compile seconds = first call,
+    median seconds of the steady calls)."""
+    losses, times = [], []
+    for _ in range(warm + steps):
+        t0 = time.perf_counter()
+        loss = float(step(*inputs).mean().asscalar())
+        times.append(time.perf_counter() - t0)
+        losses.append(loss)
+    if not all(math.isfinite(v) for v in losses):
+        raise RuntimeError("non-finite loss: %r" % (losses,))
+    return losses, times[0], statistics.median(times[warm:])
+
+
+def mosaic_kernels_in_train_program(expect_at_least):
+    """Count tpu_custom_call (Mosaic) instructions in the ONE compiled
+    train program the AOT cache holds — the proof that attention did not
+    take the XLA composite (or the interpreter)."""
+    from incubator_mxnet_tpu import aot
+    entries = [aot.CACHE.peek(k) for k in aot.CACHE.keys()
+               if k.kind == "train"]
+    if len(entries) != 1:
+        raise RuntimeError("expected one live train program, found %d"
+                           % len(entries))
+    n = entries[0].fn.as_text().count("tpu_custom_call")
+    if n < expect_at_least:
+        raise RuntimeError(
+            "compiled train step holds %d tpu_custom_call(s), expected >= %d"
+            " (3 per attention layer: fwd, dK/dV, dQ) — attention did not "
+            "run as the Pallas kernels" % (n, expect_at_least))
+    return n
+
+
+def build_bert(cfg, attention="flash"):
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import models
+    mx.random.seed(0)
+    net = models.BERTModel(vocab_size=cfg["V"], units=cfg["U"],
+                           hidden_size=4 * cfg["U"], num_layers=cfg["L"],
+                           num_heads=cfg["H"], max_length=cfg["S"],
+                           dropout=0.0, attention=attention)
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    return net
+
+
+def adam_trainer(net):
+    from incubator_mxnet_tpu import gluon
+    return gluon.Trainer(net.collect_params(), "adam",
+                         {"learning_rate": 1e-4, "multi_precision": True})
+
+
+def fixed_tokens(cfg, batch):
+    import numpy as onp
+    from incubator_mxnet_tpu import nd
+    rng = onp.random.RandomState(0)
+    return nd.array(rng.randint(0, cfg["V"], (batch, cfg["S"]))
+                    .astype("int32"))
+
+
+def phase_bert(cfg, on_chip, shared):
+    from incubator_mxnet_tpu import gluon, jit
+    net = build_bert(cfg)
+    tokens = fixed_tokens(cfg, cfg["B"])
+    trainer = adam_trainer(net)
+    step = jit.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(), trainer)
+    losses, compile_s, steady_s = run_steps(step, (tokens, tokens), 2, 3)
+    if not losses[-1] < losses[0]:
+        raise RuntimeError("loss did not fall: %r" % (losses,))
+    kernels = (mosaic_kernels_in_train_program(3 * cfg["L"]) if on_chip
+               else "skipped (interpreted)")
+    shared["bert_first_loss"] = losses[0]
+    del step, trainer, net, tokens
+    gc.collect()
+    return compile_s, steady_s, "loss %.4f -> %.4f, mosaic kernels: %s" % (
+        losses[0], losses[-1], kernels)
+
+
+def phase_gpt(cfg, on_chip, shared):
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import jit, models
+    mx.random.seed(0)
+    net = models.GPTModel(vocab_size=cfg["V"], units=cfg["U"],
+                          num_layers=cfg["L"], num_heads=cfg["H"],
+                          max_length=cfg["S"], attention="flash")
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    tokens = fixed_tokens(cfg, 1)
+    view = models.FeaturesView(net)
+    trainer = adam_trainer(view)
+    step = jit.TrainStep(view, models.ChunkedLMLoss(net), trainer)
+    losses, compile_s, steady_s = run_steps(step, (tokens, tokens), 2, 2)
+    if not losses[-1] < losses[0]:
+        raise RuntimeError("loss did not fall: %r" % (losses,))
+    kernels = (mosaic_kernels_in_train_program(3 * cfg["L"]) if on_chip
+               else "skipped (interpreted)")
+    del step, trainer, view, net, tokens
+    gc.collect()
+    return compile_s, steady_s, "S=%d causal, loss %.4f -> %.4f, mosaic " \
+        "kernels: %s" % (cfg["S"], losses[0], losses[-1], kernels)
+
+
+def build_resnet():
+    import incubator_mxnet_tpu as mx
+    mx.random.seed(0)
+    net = mx.gluon.model_zoo.vision.resnet50_v1(classes=1000)
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    return net
+
+
+def phase_resnet(cfg, on_chip, shared):
+    import numpy as onp
+    from incubator_mxnet_tpu import gluon, jit, nd
+    net = build_resnet()
+    rng = onp.random.RandomState(0)
+    x = nd.array(rng.standard_normal((cfg["B"], 3, cfg["HW"], cfg["HW"]))
+                 .astype("float32")).astype("bfloat16")
+    y = nd.array(rng.randint(0, 1000, cfg["B"]).astype("float32"))
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9,
+                             "multi_precision": True})
+    step = jit.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(), trainer)
+    losses, compile_s, steady_s = run_steps(step, (x, y), 2, 3)
+    del step, trainer, net, x, y
+    gc.collect()
+    return compile_s, steady_s, "b%d, losses finite (%.3f .. %.3f)" % (
+        cfg["B"], losses[0], losses[-1])
+
+
+# ------------------------------------------------------------------- serving
+def http_json(url, payload=None, timeout=300.0):
+    data = None if payload is None else json.dumps(payload).encode()
+    req = urllib.request.Request(url, data=data)
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return resp.status, resp.read()
+
+
+def phase_serve(cfg, on_chip, shared):
+    import numpy as onp
+    from incubator_mxnet_tpu import jit, nd, serving
+    net = build_resnet()
+    hw, n = cfg["HW"], cfg["requests"]
+    rng = onp.random.RandomState(1)
+    # bf16-representable inputs, so the JSON round trip is exact
+    items = nd.array(rng.standard_normal((n, 3, hw, hw)).astype("float32")) \
+        .astype("bfloat16").asnumpy()
+    net(nd.array(items[:1]))        # finalize deferred parameter shapes
+    buckets = serving.default_buckets(cfg["max_batch"])
+    t0 = time.perf_counter()
+    server = serving.serve({"resnet50": net}, port=0,
+                           max_batch_size=cfg["max_batch"],
+                           warm_spec=[((3, hw, hw), "bfloat16")],
+                           prewarm=True)
+    compile_s = time.perf_counter() - t0       # the whole bucket ladder
+    base = "http://127.0.0.1:%d" % server.port
+    replies, lat, errors = [None] * n, [None] * n, []
+
+    def client(ids):
+        for i in ids:
+            t = time.perf_counter()
+            try:
+                status, body = http_json(
+                    base + "/v1/models/resnet50:predict",
+                    {"inputs": [items[i].astype("float32").tolist()],
+                     "dtype": "bfloat16"})
+            except Exception as e:  # noqa: BLE001 — reported below, fatal
+                errors.append("request %d: %r" % (i, e))
+                return
+            lat[i] = time.perf_counter() - t
+            replies[i] = (status, body)
+
+    threads = [threading.Thread(target=client, daemon=True,
+                                args=(range(c, n, cfg["clients"]),))
+               for c in range(cfg["clients"])]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(600.0)
+        if errors or any(t.is_alive() for t in threads):
+            raise RuntimeError("client failure: %s" % (errors or "hung",))
+        health, _ = http_json(base + "/healthz")
+        _, metrics = http_json(base + "/metrics")
+    finally:
+        server.stop()
+    if health != 200:
+        raise RuntimeError("/healthz answered %d" % health)
+    for metric, want in (("mxtpu_serving_ok_total", n),
+                         ("mxtpu_aot_prewarms_total", len(buckets))):
+        m = re.search(r'^%s\{model="resnet50"\} (\S+)$' % metric,
+                      metrics.decode(), re.M)
+        if m is None or float(m.group(1)) != want:
+            raise RuntimeError("/metrics %s is %s, expected %d"
+                               % (metric, m and m.group(1), want))
+
+    # reference: the same inputs straight through EvalStep (the bucket-8
+    # program the server just compiled, found again in the AOT cache)
+    direct = jit.EvalStep(net)
+    mb = cfg["max_batch"]
+    ref = onp.concatenate([
+        direct(nd.array(items[i:i + mb])).asnumpy().astype("float32")
+        for i in range(0, n, mb)])
+    exact = 0
+    for i, (status, body) in enumerate(replies):
+        if status != 200:
+            raise RuntimeError("request %d answered %d" % (i, status))
+        out = onp.asarray(json.loads(body)["outputs"][0], "float32").ravel()
+        if out.shape != (1000,) or not onp.isfinite(out).all():
+            raise RuntimeError("request %d: bad logits %r" % (i, out.shape))
+        # buckets 1/2/4/8 are four compiled programs; bf16 sums may differ
+        # in the last bits between them, so agree within bf16 noise and
+        # accept an arg-max swap only between logits that tie within it
+        tol = 0.05 * max(1.0, float(onp.abs(ref[i]).max()))
+        if onp.abs(out - ref[i]).max() > tol:
+            raise RuntimeError("request %d: logits differ from EvalStep by "
+                               "%.4g (> %.4g)" % (
+                                   i, onp.abs(out - ref[i]).max(), tol))
+        exact += int(out.argmax() == ref[i].argmax())
+        if ref[i][out.argmax()] < ref[i].max() - tol:
+            raise RuntimeError("request %d: arg-max %d vs EvalStep %d"
+                               % (i, out.argmax(), ref[i].argmax()))
+    del direct, net
+    gc.collect()
+    return compile_s, statistics.median(lat), \
+        "%d/%d HTTP 200 from %d threads, arg-max equal to EvalStep %d/%d " \
+        "(rest tie within bf16 noise), healthz 200, /metrics counts %d " \
+        "ok and buckets %s prewarmed" % (
+            n, n, cfg["clients"], exact, n, n, buckets)
+
+
+def phase_generate(cfg, on_chip, shared):
+    from incubator_mxnet_tpu.serving.generate import GenerativeEngine
+    engine = GenerativeEngine(prewarm=False)
+    try:
+        t0 = time.perf_counter()
+        engine.warm()
+        compile_s = time.perf_counter() - t0
+        prompts = [list(range(1, 1 + k)) for k in (3, 9, 17, 33, 5, 48)]
+        results, errors = [None] * len(prompts), []
+
+        def one(i):
+            t = time.perf_counter()
+            try:
+                toks, reason = engine.submit(
+                    prompts[i], max_new_tokens=24, seed=i).tokens(300.0)
+            except Exception as e:  # noqa: BLE001 — reported below, fatal
+                errors.append("stream %d: %r" % (i, e))
+                return
+            results[i] = (toks, reason, time.perf_counter() - t)
+
+        threads = [threading.Thread(target=one, args=(i,), daemon=True)
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(600.0)
+        if errors or any(t.is_alive() for t in threads):
+            raise RuntimeError("stream failure: %s" % (errors or "hung",))
+    finally:
+        engine.close()
+    if engine.alive:
+        raise RuntimeError("decode loop still alive after close()")
+    vocab = engine.model.VOCAB
+    for i, (toks, reason, _dt) in enumerate(results):
+        if reason not in ("eos", "max_tokens"):
+            raise RuntimeError("stream %d ended with %r" % (i, reason))
+        if not toks or not all(0 <= t < vocab for t in toks):
+            raise RuntimeError("stream %d: tokens out of range" % i)
+    n_tok = sum(len(r[0]) for r in results)
+    return compile_s, statistics.median(r[2] for r in results), \
+        "%d concurrent streams, %d tokens, all ended eos/max_tokens; at " \
+        "TinyLM's 64 wide this shows only that the prefill/decode/write " \
+        "programs compile on the device and the loop terminates" % (
+            len(prompts), n_tok)
+
+
+# ----------------------------------------------------------------- multichip
+def spread(arr, n_dev, what):
+    """Assert a jax.Array is partitioned on dim 0 over n_dev devices (not
+    sitting on one, not replicated)."""
+    if len(arr.sharding.device_set) != n_dev:
+        raise RuntimeError("%s lives on %d device(s), expected %d"
+                           % (what, len(arr.sharding.device_set), n_dev))
+    rows = {s.data.shape[0] for s in arr.addressable_shards}
+    if rows != {arr.shape[0] // n_dev}:
+        raise RuntimeError("%s: per-device rows %s, expected %d (dim 0 of "
+                           "%d over %d devices)" % (
+                               what, sorted(rows), arr.shape[0] // n_dev,
+                               arr.shape[0], n_dev))
+
+
+def phase_multichip(cfgs, on_chip, shared):
+    import jax
+    from incubator_mxnet_tpu import gluon, parallel
+    cfg, ring = cfgs["bert"], cfgs["ring"]
+    devices = jax.devices()[:4]
+
+    # -- dp=4, ZeRO-1: the BERT phase again, global batch unchanged
+    mesh = parallel.make_mesh({"dp": 4}, devices=devices)
+    net = build_bert(cfg)
+    tokens = fixed_tokens(cfg, cfg["B"])
+    trainer = adam_trainer(net)
+    step = parallel.DataParallelTrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), trainer, mesh=mesh,
+        zero=True)
+    t0 = time.perf_counter()
+    loss = step(tokens, tokens)
+    first = float(loss.mean().asscalar())
+    compile_s = time.perf_counter() - t0
+    # the per-example loss is computed from the batch inside the program:
+    # rows spread over four devices means the batch was
+    spread(loss._data, 4, "per-example loss (the batch dimension)")
+    n_state = 0
+    for state in trainer._states:
+        for leaf in jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+                lambda s: s._data, state)):
+            if leaf.ndim and leaf.shape[0] % 4 == 0:
+                spread(leaf, 4, "ZeRO optimizer state %s" % (leaf.shape,))
+                n_state += 1
+    if not n_state:
+        raise RuntimeError("no dp-sharded optimizer state found")
+    ref = shared.get("bert_first_loss")
+    if ref is None:
+        raise RuntimeError("the multichip phase needs the bert phase's "
+                           "first loss (run both)")
+    if abs(first - ref) > 2e-2 * abs(ref):
+        raise RuntimeError("dp=4 first loss %.5f vs single-chip %.5f"
+                           % (first, ref))
+    t0 = time.perf_counter()
+    second = float(step(tokens, tokens).mean().asscalar())
+    steady_s = time.perf_counter() - t0
+    del step, trainer, net, tokens, loss
+    gc.collect()
+
+    # -- dp=2 x sp=2: ring attention, the Pallas kernel inside shard_map
+    mesh = parallel.make_mesh({"dp": 2, "sp": 2}, devices=devices)
+    if on_chip:
+        import jax.numpy as jnp
+        q = jax.ShapeDtypeStruct(
+            (ring["B"], ring["H"], ring["S"], ring["U"] // ring["H"]),
+            jnp.bfloat16)
+        lowered = jax.jit(lambda q, k, v: parallel.ring_attention(
+            q, k, v, mesh=mesh, axis="sp")).lower(q, q, q).as_text()
+        if "tpu_custom_call" not in lowered:
+            raise RuntimeError("ring attention lowered without the Pallas "
+                               "kernel at local S=%d" % (ring["S"] // 2))
+    net = build_bert(ring, attention="ring")
+    tokens = fixed_tokens(ring, ring["B"])
+    trainer = adam_trainer(net)
+    step = parallel.DataParallelTrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), trainer, mesh=mesh)
+    ring_loss = float(step(tokens, tokens).mean().asscalar())
+    if not math.isfinite(ring_loss):
+        raise RuntimeError("ring step loss %r" % ring_loss)
+    del step, trainer, net, tokens
+    gc.collect()
+    parallel.set_current_mesh(None)
+    return compile_s, steady_s, \
+        "dp=4 zero=True first loss %.4f (single chip %.4f) then %.4f, " \
+        "batch and %d optimizer-state leaves spread over 4 devices; " \
+        "dp=2 x sp=2 ring step at S=%d loss %.4f%s" % (
+            first, ref, second, n_state, ring["S"], ring_loss,
+            ", Pallas kernel inside shard_map" if on_chip else "")
+
+
+# ---------------------------------------------------------------------- main
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy sizes, interpreted kernels, any backend: a "
+                         "debugging aid, NOT a pass")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="comma-separated subset of %s (a subset is NOT a "
+                         "pass)" % (PHASES,))
+    args = ap.parse_args()
+    phases = [p for p in args.phases.split(",") if p]
+    unknown = [p for p in phases if p not in PHASES]
+    if unknown:
+        ap.error("unknown phase(s) %s" % unknown)
+
+    if args.rehearse:
+        os.environ["MXTPU_FLASH_INTERPRET"] = "1"
+        if "xla_force_host_platform_device_count" not in \
+                os.environ.get("XLA_FLAGS", ""):
+            os.environ["XLA_FLAGS"] = (
+                "--xla_force_host_platform_device_count=4 "
+                + os.environ.get("XLA_FLAGS", ""))
+
+    import jax
+    import jaxlib
+    from incubator_mxnet_tpu import runtime  # the import places the cache
+
+    # ---- device gate ----------------------------------------------------
+    if args.rehearse:
+        d = jax.devices()[0]
+        device = {"platform": d.platform, "kind": d.device_kind,
+                  "count": len(jax.devices())}
+        log("REHEARSAL on %s — toy sizes, kernels interpreted; this is NOT "
+            "a chip pass" % device)
+    else:
+        device, (peak_bf16, _peak_int8, peak_bw) = runtime.require_tpu()
+        log("peaks for %r (devstats.PEAK_TABLE): %.0f TFLOP/s bf16, "
+            "%.0f GB/s HBM" % (device["kind"], peak_bf16 / 1e12,
+                               peak_bw / 1e9))
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "not installed"
+    log("device: platform=%s device_kind=%r count=%d" % (
+        device["platform"], device["kind"], device["count"]))
+    log("versions: python %s, jax %s, jaxlib %s, libtpu %s" % (
+        sys.version.split()[0], jax.__version__, jaxlib.__version__,
+        libtpu_version))
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if cache_dir is None:
+        log("compile cache: none (process pinned to the CPU backend)")
+    else:
+        log("compile cache: %s (%d entries at start%s)" % (
+            cache_dir,
+            len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0,
+            ", placed by JAX_COMPILATION_CACHE_DIR"
+            if os.environ.get("JAX_COMPILATION_CACHE_DIR") else ""))
+
+    cfgs = TOY if args.rehearse else FULL
+    on_chip = not args.rehearse
+    shared = {}
+    table = {"kernels": (phase_kernels, cfgs["kernels"]),
+             "bert": (phase_bert, cfgs["bert"]),
+             "gpt": (phase_gpt, cfgs["gpt"]),
+             "resnet": (phase_resnet, cfgs["resnet"]),
+             "serve": (phase_serve, cfgs["serve"]),
+             "generate": (phase_generate, None),
+             "multichip": (phase_multichip, cfgs)}
+    t_all = time.perf_counter()
+    for name in phases:
+        if name == "multichip" and device["count"] < 4:
+            log("multichip: skipped, %d device(s)" % device["count"])
+            continue
+        fn, cfg = table[name]
+        try:
+            compile_s, steady_s, detail = fn(cfg, on_chip, shared)
+        except BaseException:
+            log("%s: FAIL" % name)
+            raise
+        log("%s: ok compile %.1f s, steady %.4f s — %s"
+            % (name, compile_s, steady_s, detail))
+    log("total %.1f s" % (time.perf_counter() - t_all))
+
+    if args.rehearse or phases != list(PHASES):
+        log("NOT A PASS: %s" % ("rehearsal" if args.rehearse
+                                else "phases %s only" % phases))
+        return
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -178,7 +178,7 @@ has_stage() { local s; for s in "${STAGES[@]}"; do [ "$s" = "$1" ] && return 0; 
 
 if has_stage lint; then
   echo "=== lint: syntax walk + mxtpulint gate (two-phase) ==="
-  python -m compileall -q incubator_mxnet_tpu tests tools benchmark bench.py __graft_entry__.py
+  python -m compileall -q incubator_mxnet_tpu tests tools benchmark bench.py chip_smoke.py __graft_entry__.py
   # Per-file rules R001-R008 + R012-R013 over the runtime (tools/ and tests/ under
   # the relaxed R003/R005/R006 profile) + the whole-program passes
   # (R009-R011, interprocedural R001); exits nonzero on any finding that

@@ -30,6 +30,8 @@ if _config.get_env("MXTPU_NUM_PROC") > 1 and \
                                     _config.get_env("MXTPU_NUM_PROC"),
                                     _config.get_env("MXTPU_PROC_ID"))
 
+_config.place_compile_cache()
+
 if _config.get_env("MXTPU_MATMUL_PRECISION"):
     import jax as _jax
     _jax.config.update("jax_default_matmul_precision",

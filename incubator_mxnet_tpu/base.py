@@ -12,36 +12,19 @@ class MXNetError(RuntimeError):
 
 
 def distributed_is_initialized():
-    """``jax.distributed.is_initialized()`` resolved against the
-    installed jax: older releases never exposed the query — there, the
-    coordination client on ``jax._src.distributed.global_state`` is the
-    ground truth (None until ``initialize()`` ran). Callers use this so
-    double-initialization is avoided on every jax, not just current
-    ones."""
+    """True once ``jax.distributed.initialize`` ran in this process
+    (package import under tools/launch.py, or the user) — callers use it
+    to avoid double-initialization."""
     import jax
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src import distributed as _distributed
-        return _distributed.global_state.client is not None
-    except Exception:
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def enable_x64(enabled=True):
-    """``jax.enable_x64`` resolved against the installed jax.
-
-    Newer jax exposes the scoped 64-bit-dtype switch at top level; a
-    long range of releases only as ``jax.experimental.enable_x64``.
-    Every int64/float64 code path (ndarray dtype handling, kvstore
-    wide-dtype batching) resolves it HERE so the installed jax decides
-    once — not as an AttributeError inside an op."""
+    """Scoped 64-bit-dtype switch (``jax.enable_x64``) for the int64 /
+    float64 code paths (ndarray dtype handling, kvstore wide-dtype
+    batching)."""
     import jax
-    fn = getattr(jax, "enable_x64", None)
-    if fn is None:
-        from jax.experimental import enable_x64 as fn
-    return fn(enabled)
+    return jax.enable_x64(enabled)
 
 
 string_types = (str,)

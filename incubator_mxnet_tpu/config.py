@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["ENV_VARS", "get_env", "describe"]
+__all__ = ["ENV_VARS", "get_env", "describe", "place_compile_cache"]
 
 ENV_VARS = {
     # name: (type, default, doc)
@@ -749,6 +749,44 @@ def get_env(name):
     if typ is bool:
         return raw.strip().lower() not in ("0", "", "false", "no", "off")
     return typ(raw)
+
+
+def place_compile_cache():
+    """Give JAX's persistent compilation cache a home — the ONE place any
+    entry point (chip_smoke.py, bench.py, example/*, the server) gets it
+    from, called at package import. It only sets config values; no
+    backend is initialised. Returns the directory it set, or None.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: the cache was placed from outside,
+    JAX reads the variable itself and no directory is set here. Unset:
+    ``<checkout>/.jax_cache``, computed from this package's own location —
+    the directory is part of the cache key, so never a tempdir, a pid or
+    a timestamp. Either way every program is kept, not only those over
+    JAX's default 1 s of compile time: parameter initialisation, the
+    eager forward that settles deferred shapes and the generative
+    engine's buckets are hundreds of sub-second programs (with only the
+    >= 1 s ones kept, a warm ResNet-50 first step still took 41.5 s on
+    the chip against 79.3 s cold; 13.2 s with all — chip runs, PR 21).
+
+    A process pinned to the CPU backend (the test suite, DataLoader
+    workers, tools/launch.py local ranks) gets nothing from here:
+    XLA:CPU executables are tied to the build machine's feature list and
+    log an error-level line on every load.
+
+    This is XLA's executable cache; the StableHLO artifact layer under
+    MXTPU_AOT_CACHE_DIR (aot.py) is a different thing and still pays the
+    XLA compile after a load."""
+    import jax
+    if jax.config.jax_platforms == "cpu":
+        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def evict_to_bound(cache, on_evict=None):

@@ -188,6 +188,11 @@ class TrainStep:
         net, loss_fn = self.net, self.loss_fn
         optimizer = self.trainer._optimizer
         aux_box = []
+        # blocks whose kernels GSPMD cannot partition (models' flash
+        # attention) ask which mesh they are being traced for (imported
+        # here: parallel/ imports this module)
+        from .parallel.mesh import step_mesh_scope
+        mesh, data_axis = self.mesh, self.data_axis
 
         def inner(t_datas, f_datas, input_datas, key):
             saved_t = [a._data for a in t_arrs]
@@ -197,7 +202,8 @@ class TrainStep:
             for a, d in zip(f_arrs, f_datas):
                 a._data = d
             try:
-                with _functional.FunctionalScope(key) as st:
+                with step_mesh_scope(mesh, data_axis), \
+                        _functional.FunctionalScope(key) as st:
                     with autograd.pause(train_mode=True):
                         nd_inputs = [NDArray(d) for d in input_datas]
                         # bypass hybridize's own cache: trace the eager forward
@@ -275,22 +281,18 @@ class TrainStep:
         the XLA compile lands inside the train:build span instead of
         lazily inside the first dispatch, and the cache entry is an
         analyzable compiled program (devstats harvests its cost/memory
-        analysis at insert). A failed lower/compile degrades to the
-        classic lazy-jit behavior (debug-logged), never to a broken
-        step."""
+        analysis at insert). A failed lower/compile raises to the
+        caller: a lazy retry would compile the same program again, and
+        swallowing the first error is how a compiler refusal (a Mosaic
+        kernel over its VMEM budget, an HBM OOM) gets hidden."""
         jitted, trainable, frozen, t_arrs, f_arrs, aux_box = \
             self._build(None, n_inputs)
         if arg_specs is not None and self.mesh is None:
-            try:
-                # the trace swaps tracers into the live param NDArrays
-                # (inner's _data swap) — hold the net's trace lock for
-                # the whole window, exactly like the eval build
-                with self._trace_lock:
-                    jitted = jitted.lower(*arg_specs).compile()
-            except Exception:
-                _LOG.debug("train AOT lower/compile failed; program "
-                           "compiles lazily on first dispatch",
-                           exc_info=True)
+            # the trace swaps tracers into the live param NDArrays
+            # (inner's _data swap) — hold the net's trace lock for
+            # the whole window, exactly like the eval build
+            with self._trace_lock:
+                jitted = jitted.lower(*arg_specs).compile()
         return jitted, (trainable, frozen, t_arrs, f_arrs, aux_box), None
 
     def _arg_specs(self, arrs, key):

@@ -31,6 +31,7 @@ class MultiHeadAttention(HybridBlock):
         self._dropout = dropout
         self._attention = attention
         self._sp_axis = sp_axis
+        self._tp_axis = tp_axis
         self._causal = causal
         with self.name_scope():
             self.query = nn.Dense(units, flatten=False, in_units=units)
@@ -68,9 +69,21 @@ class MultiHeadAttention(HybridBlock):
                 qd, kd, vd, mesh=mesh, axis=self._sp_axis, causal=causal),
                 q, k, v)
         elif self._attention == "flash":
-            from ..ops.attention import flash_attention
-            out = _apply(lambda qd, kd, vd: flash_attention(qd, kd, vd, causal),
-                         q, k, v)
+            from ..ops.attention import (flash_attention,
+                                         flash_attention_on_mesh)
+            from ..parallel.mesh import step_mesh
+            step = step_mesh()
+            if step is not None:
+                # a mesh train step is tracing us: GSPMD cannot partition
+                # the Mosaic kernels, so they run under shard_map on each
+                # device's slice of the batch (and of the heads, under tp)
+                mesh, data_axis = step
+                out = _apply(lambda qd, kd, vd: flash_attention_on_mesh(
+                    qd, kd, vd, mesh, batch_axis=data_axis,
+                    head_axis=self._tp_axis, causal=causal), q, k, v)
+            else:
+                out = _apply(lambda qd, kd, vd: flash_attention(
+                    qd, kd, vd, causal), q, k, v)
         else:
             scale = 1.0 / math.sqrt(D)
             scores = nd.batch_dot(q.reshape((B * H, S, D)),

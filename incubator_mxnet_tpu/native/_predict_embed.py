@@ -32,14 +32,6 @@ class _PredState:
 
 def create(path):
     """Load a .mxtpu serving artifact → predictor state (≙ MXPredCreate)."""
-    import os
-    plats = os.environ.get("JAX_PLATFORMS")
-    if plats:
-        # The deployment env's sitecustomize may pin jax_platforms after
-        # reading the env var; re-assert the caller's choice explicitly so
-        # `JAX_PLATFORMS=cpu ./client model.mxtpu ...` behaves as written.
-        import jax
-        jax.config.update("jax_platforms", plats)
     from incubator_mxnet_tpu.contrib import serving
     return _PredState(serving.load(path))
 

@@ -1,7 +1,9 @@
 """ctypes bindings for the native library (built from src/*.cc).
 
-Build: ``python -m incubator_mxnet_tpu.native.build`` (or import-time
-auto-build). All users gate on ``available()`` and fall back to pure Python.
+Build: on first use (``_load`` calls ``build()``, one g++ command, mtime-
+gated), or by hand with ``python -c "from incubator_mxnet_tpu.native import
+lib; lib.build()"``. The .so files are build outputs, not in git. All users
+gate on ``available()`` and fall back to pure Python.
 """
 from __future__ import annotations
 

@@ -113,14 +113,6 @@ void init_python() {
     std::string code = "import sys; sys.path.insert(0, '" + quoted + "')";
     PyRun_SimpleString(code.c_str());
   }
-  // Pin the JAX platform from the caller's env BEFORE any framework import:
-  // the deployment env's sitecustomize may register accelerator plugins that
-  // would otherwise win during package import (backend init is first-touch).
-  PyRun_SimpleString(
-      "import os\n"
-      "if os.environ.get('JAX_PLATFORMS'):\n"
-      "    import jax\n"
-      "    jax.config.update('jax_platforms', os.environ['JAX_PLATFORMS'])\n");
   g_init_ok = true;
   // Drop the GIL acquired by initialization so PyGILState_Ensure works
   // from any caller thread (including this one).

@@ -12,7 +12,7 @@ re-expressed as SPMD sharding + XLA collectives over ICI/DCN).
 - moe:               expert parallel mixture-of-experts (NEW)
 - compression:       2-bit gradient compression analog (ref gradient_compression.h)
 """
-from .compat import shard_map, HAVE_SHARD_MAP, ShardMapUnavailable  # noqa
+from jax import shard_map  # noqa
 from .mesh import make_mesh, current_mesh, set_current_mesh, replicated, shard_spec  # noqa
 from .data_parallel import DataParallelTrainStep  # noqa
 from .tensor_parallel import ColParallelDense, RowParallelDense, shard_params  # noqa

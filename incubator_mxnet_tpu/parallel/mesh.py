@@ -6,15 +6,20 @@ XLA place collectives on it. DCN (multi-host) is just an outer mesh axis.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
+
 import numpy as onp
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["make_mesh", "current_mesh", "set_current_mesh", "replicated",
-           "shard_spec", "P", "NamedSharding", "Mesh"]
+           "shard_spec", "step_mesh_scope", "step_mesh", "P", "NamedSharding",
+           "Mesh"]
 
 _CURRENT = [None]
+_STEP = threading.local()
 
 
 def make_mesh(axes=None, devices=None):
@@ -50,6 +55,29 @@ def set_current_mesh(mesh):
 
 def current_mesh():
     return _CURRENT[0]
+
+
+@contextlib.contextmanager
+def step_mesh_scope(mesh, data_axis):
+    """Declared by a train step around the trace of its forward: the mesh
+    the program will be partitioned over and the axis its batch is split
+    on (nothing to declare for a step on one device). Unlike
+    ``current_mesh()`` — a process-wide default that outlives the step
+    that set it — this is true exactly while that step traces, so a block
+    can rely on it."""
+    prev = getattr(_STEP, "value", None)
+    _STEP.value = (mesh, data_axis) \
+        if mesh is not None and mesh.size > 1 else None
+    try:
+        yield
+    finally:
+        _STEP.value = prev
+
+
+def step_mesh():
+    """(mesh, data_axis) of the multi-device train step being traced on
+    this thread, or None (eager code, steps on one device)."""
+    return getattr(_STEP, "value", None)
 
 
 def replicated(mesh):

@@ -20,10 +20,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size, pcast
 from jax.sharding import PartitionSpec as P
-
-from .compat import axis_size, pcast, shard_map
 
 __all__ = ["PipelineParallel", "pipeline_spmd", "pipeline_1f1b_grads"]
 

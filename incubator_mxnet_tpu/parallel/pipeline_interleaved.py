@@ -28,10 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as onp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size, pcast
 from jax.sharding import PartitionSpec as P
-
-from .compat import axis_size, pcast, shard_map
 
 __all__ = ["interleaved_schedule", "schedule_stats",
            "pipeline_interleaved_grads", "schedule_1f1b", "schedule_gpipe"]

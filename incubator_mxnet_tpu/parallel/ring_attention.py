@@ -13,10 +13,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .compat import axis_size, shard_map
 
 __all__ = ["ring_attention", "local_attention"]
 

@@ -20,10 +20,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
-
-from .compat import axis_size, shard_map
 from .ring_attention import local_attention
 
 __all__ = ["ulysses_attention"]

@@ -68,27 +68,33 @@ __all__ = ["program_stats", "peaks", "observe_dispatch", "dispatch_context",
            "start", "stop", "running", "sample_now", "device_memory",
            "set_memory_source", "capture_profile", "ProfileCaptureBusy",
            "capture_in_progress", "dispatch_totals",
-           "PEAK_TABLE", "reset_peaks", "HBM_TABLE", "hbm_capacity"]
+           "PEAK_TABLE", "device_peaks", "reset_peaks", "HBM_TABLE",
+           "hbm_capacity"]
 
 _LOG = logging.getLogger(__name__)
 
-#: device_kind prefix -> (peak dense FLOP/s at the serving/bench compute
-#: dtype (bf16), peak HBM bytes/s). Sources: published TPU spec sheets —
-#: the same table bench.py anchored its hand-rolled MFU on, now owned
-#: here so every consumer divides by the same denominator.
+#: THE peak table: device_kind prefix -> (peak dense bf16 FLOP/s, peak
+#: int8 OP/s, peak HBM bytes/s) per chip. Source: Google Cloud TPU
+#: documentation, system-architecture pages per generation ("TPU v5e":
+#: 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s). Every rate the repo
+#: prints against a hardware peak (bench.py, chip_smoke.py,
+#: benchmark/roofline.py, the MFU gauges) divides by a row of this
+#: table; a device_kind with no row is an error for those callers
+#: (device_peaks), never a default.
 PEAK_TABLE = {
-    "TPU v5 lite": (197e12, 819e9),
-    "TPU v5e": (197e12, 819e9),
-    "TPU v4": (275e12, 1228e9),
-    "TPU v5p": (459e12, 2765e9),
-    "TPU v5": (459e12, 2765e9),
-    "TPU v6 lite": (918e12, 1640e9),
-    "TPU v6e": (918e12, 1640e9),
+    "TPU v5 lite": (197e12, 393e12, 819e9),
+    "TPU v5e": (197e12, 393e12, 819e9),
+    "TPU v4": (275e12, 275e12, 1228e9),
+    "TPU v5p": (459e12, 918e12, 2765e9),
+    "TPU v5": (459e12, 918e12, 2765e9),
+    "TPU v6 lite": (918e12, 1836e12, 1640e9),
+    "TPU v6e": (918e12, 1836e12, 1640e9),
 }
 
-#: report-only fallback for backends not in the table (CPU, unknown
-#: accelerators): utilization gauges stay live and internally consistent
-#: but are NOT meaningful against hardware peaks (peaks()[2] == "fallback")
+#: report-only stand-in (bf16 FLOP/s, HBM bytes/s) for backends with no
+#: PEAK_TABLE row (the CPU test backend): utilization gauges stay live
+#: and internally consistent but are NOT meaningful against hardware
+#: peaks (peaks()[2] == "fallback"); nothing prints a device rate from it
 _FALLBACK_PEAKS = (1e12, 100e9)
 
 #: device_kind prefix -> per-chip HBM CAPACITY in bytes (spec sheets —
@@ -116,22 +122,49 @@ def hbm_capacity():
     'table'), or (None, 'unknown') for backends the table doesn't know —
     callers that would otherwise guess (hlolint H004) must skip
     instead."""
-    kind = ""
+    row = _table_row(HBM_TABLE, _device_kind())
+    if row is None:
+        return None, "unknown"
+    return float(row), "table"
+
+
+def _device_kind():
+    """``device_kind`` of this process's first device ('' when no
+    backend can be initialised)."""
     try:
         import jax
-        kind = getattr(jax.devices()[0], "device_kind", "") or ""
-    except Exception:
-        pass
-    # longest prefix wins, so e.g. a v5e chip can never fall through to
-    # the broader "TPU v5" entry regardless of table ordering — and a
-    # prefix hit only counts at a word boundary: an unlisted sub-variant
-    # ("TPU v7x") must come back unknown (H004 skips), never inherit a
-    # bigger sibling's capacity and wave a predicted OOM through
-    for prefix in sorted(HBM_TABLE, key=len, reverse=True):
+        return jax.devices()[0].device_kind or ""
+    except RuntimeError:
+        return ""
+
+
+def _table_row(table, kind):
+    """The ``table`` row whose device_kind prefix matches ``kind``, or
+    None. Longest prefix wins, so e.g. a v5e chip can never fall through
+    to the broader "TPU v5" entry regardless of table ordering — and a
+    prefix hit only counts at a word boundary: an unlisted sub-variant
+    ("TPU v7x") must come back unknown, never inherit a sibling's row
+    (H004 would wave a predicted OOM through; an MFU would divide by the
+    wrong peak)."""
+    for prefix in sorted(table, key=len, reverse=True):
         if kind == prefix or (kind.startswith(prefix)
                               and not kind[len(prefix)].isalnum()):
-            return float(HBM_TABLE[prefix]), "table"
-    return None, "unknown"
+            return table[prefix]
+    return None
+
+
+def device_peaks(kind):
+    """(peak bf16 FLOP/s, peak int8 OP/s, peak HBM bytes/s) of one chip
+    of ``kind`` from PEAK_TABLE. Raises LookupError for a kind with no
+    row: whoever prints a rate against a peak (bench.py, chip_smoke.py)
+    must fail on an unknown device, not assume one."""
+    row = _table_row(PEAK_TABLE, kind)
+    if row is None:
+        raise LookupError(
+            "device_kind %r has no row in devstats.PEAK_TABLE (known: %s) "
+            "— add its published peaks with their source before "
+            "reporting a utilization on it" % (kind, sorted(PEAK_TABLE)))
+    return row
 
 
 # --------------------------------------------------------------- program facts
@@ -208,19 +241,12 @@ def peaks():
         from .. import config
         env_f = config.get_env("MXTPU_DEVICE_PEAK_FLOPS")
         env_b = config.get_env("MXTPU_DEVICE_PEAK_HBM_BPS")
-        kind = ""
-        try:
-            import jax
-            kind = getattr(jax.devices()[0], "device_kind", "") or ""
-        except Exception:
-            pass
-        table = None
-        for prefix, vals in PEAK_TABLE.items():
-            if kind.startswith(prefix):
-                table = vals
-                break
-        flops_p, bw_p = table if table is not None else _FALLBACK_PEAKS
-        base = "table" if table is not None else "fallback"
+        row = _table_row(PEAK_TABLE, _device_kind())
+        if row is not None:
+            flops_p, _int8, bw_p = row
+        else:
+            flops_p, bw_p = _FALLBACK_PEAKS
+        base = "table" if row is not None else "fallback"
         if env_f is not None and env_b is not None:
             source = "env"
         elif env_f is not None or env_b is not None:
@@ -680,32 +706,17 @@ def _trace_session(path):
     attribution layer only reads the XLA TraceMe events (host_tracer),
     which survive with the python tracer off, so off is the default;
     MXTPU_PROFILE_PYTHON_TRACER=1 re-enables python frames for
-    interactive debugging. Falls back to jax.profiler.start_trace when
-    the jaxlib session API is unavailable."""
+    interactive debugging."""
     from .. import config
     import jax
-    sess = None
-    try:
-        from jax._src.lib import xla_client
-        jax.devices()                    # backends must exist first
-        opts = xla_client.profiler.ProfileOptions()
-        opts.python_tracer_level = (
-            1 if config.get_env("MXTPU_PROFILE_PYTHON_TRACER") else 0)
-        sess = xla_client.profiler.ProfilerSession(opts)
-    except Exception:
-        _LOG.debug("low-overhead profiler session unavailable; falling "
-                   "back to jax.profiler.start_trace", exc_info=True)
-    if sess is None:
-        jax.profiler.start_trace(path)
-        try:
-            yield
-        finally:
-            jax.profiler.stop_trace()
-        return
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = (
+        1 if config.get_env("MXTPU_PROFILE_PYTHON_TRACER") else 0)
+    jax.profiler.start_trace(path, profiler_options=opts)
     try:
         yield
     finally:
-        sess.export(sess.stop(), path)
+        jax.profiler.stop_trace()
 
 
 def capture_profile(seconds=2.0, out_dir=None):

@@ -1,0 +1,134 @@
+"""Guards around chip_smoke.py that a CPU host can check in seconds: the
+script refuses to pass without a TPU, the flash kernels still lower to
+Mosaic at the smoke's shapes, and the compile-cache placement rule."""
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+from incubator_mxnet_tpu import config
+from incubator_mxnet_tpu.ops import attention as A
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_smoke_without_a_chip_fails_and_names_the_device():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       cwd=REPO, env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr and "platform='cpu'" in r.stderr, r.stderr
+    # no result line, and no model was built on the way to the refusal
+    assert '"ok"' not in r.stdout and ": ok" not in r.stdout, r.stdout
+
+
+def _bert_gpt_shapes():
+    b, g = chip_smoke.FULL["bert"], chip_smoke.FULL["gpt"]
+    return [((b["B"], b["H"], b["S"], b["U"] // b["H"]), False),
+            ((1, g["H"], g["S"], g["U"] // g["H"]), True)]
+
+
+@pytest.mark.parametrize("shape,causal", _bert_gpt_shapes())
+def test_flash_kernels_lower_to_mosaic_at_smoke_shapes(shape, causal,
+                                                       monkeypatch):
+    """forward + dK/dV + dQ lower for platform 'tpu' from a CPU host as
+    three tpu_custom_calls — not the XLA composite, not the interpreter."""
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    bq, bk = A._resolve_blocks(shape[2], None, None)
+    scale = shape[-1] ** -0.5
+
+    def fwd_bwd(q, k, v, do):
+        out, lse = A._fa_call(q, k, v, causal, scale, bq, bk)
+        return A._fa_bwd_call(q, k, v, out, lse, do, causal, scale, bq, bk)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = jax.jit(fwd_bwd).trace(x, x, x, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
+
+
+def test_compile_cache_placement_rule(monkeypatch):
+    set_calls = {}
+    monkeypatch.setattr(jax.config, "update", set_calls.__setitem__)
+    # a CPU-pinned process (this suite) gets no cache from the code
+    assert jax.config.jax_platforms == "cpu"
+    assert config.place_compile_cache() is None and not set_calls
+    # placed from outside: the code sets no directory
+    monkeypatch.setattr(type(jax.config), "jax_platforms",
+                        property(lambda self: "tpu,cpu"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert config.place_compile_cache() is None
+    assert "jax_compilation_cache_dir" not in set_calls
+    # otherwise: one fixed path inside the checkout
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(REPO, ".jax_cache")
+    assert config.place_compile_cache() == want
+    assert set_calls["jax_compilation_cache_dir"] == want
+
+
+def _lower_for_tpu(fn, *specs):
+    return jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_flash_on_a_mesh_is_wrapped_in_shard_map(monkeypatch):
+    """GSPMD refuses to partition a Mosaic kernel (what the first run on
+    four real chips died of); the models' mesh path and ring attention
+    must hand JAX the kernels inside a fully-manual shard_map instead.
+    Checked by lowering for 'tpu' over a CPU mesh — the interpreter the
+    numeric tests use never meets this rule."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as onp
+    from incubator_mxnet_tpu.parallel import ring_attention
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    monkeypatch.setattr(A, "flash_attention_supported", lambda *a, **k: True)
+    mesh = Mesh(onp.array(jax.devices()[:4]).reshape(2, 2), ("dp", "sp"))
+    x = jax.ShapeDtypeStruct((4, 2, 512, 128), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+
+    def grads(attn):
+        return jax.grad(lambda q, k, v: attn(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _lower_for_tpu(grads(lambda q, k, v: A.flash_attention(q, k, v)),
+                       x, x, x)
+    text = _lower_for_tpu(grads(lambda q, k, v: A.flash_attention_on_mesh(
+        q, k, v, mesh, batch_axis="dp")), x, x, x)
+    assert text.count("tpu_custom_call") == 3
+    text = _lower_for_tpu(grads(lambda q, k, v: ring_attention(
+        q, k, v, mesh=mesh, axis="sp")), x, x, x)
+    assert "tpu_custom_call" in text
+
+
+def test_mesh_train_step_hands_flash_its_mesh(monkeypatch):
+    """attention='flash' under DataParallelTrainStep (kernels interpreted
+    here) is told the step's mesh and takes the shard_map path; the
+    numbers are compared with the one-device step by chip_smoke.py."""
+    from incubator_mxnet_tpu import gluon, nd, parallel
+    from incubator_mxnet_tpu.models.bert import MultiHeadAttention
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    net = MultiHeadAttention(128, 1, attention="flash")
+    net.initialize()
+    x = nd.random.normal(shape=(4, 128, 128))
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 1e-2})
+    seen = []
+    monkeypatch.setattr(
+        A, "flash_attention_on_mesh",
+        lambda *a, _real=A.flash_attention_on_mesh, **k:
+        seen.append((a[3], k["batch_axis"])) or _real(*a, **k))
+    mesh = parallel.make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    try:
+        step = parallel.DataParallelTrainStep(net, gluon.loss.L2Loss(),
+                                              trainer, mesh=mesh)
+        loss = float(step(x, x).mean().asscalar())
+    finally:
+        parallel.set_current_mesh(None)
+    assert seen == [(mesh, "dp")], seen
+    assert loss == loss and parallel.mesh.step_mesh() is None

@@ -492,9 +492,8 @@ class _F64Servable:
         specs = [jax.ShapeDtypeStruct(tuple(x.shape), jnp.float32)]
 
         def build():
-            import jax.experimental
             from jax import export as jax_export
-            with jax.experimental.enable_x64():
+            with jax.enable_x64():
                 exported = jax_export.export(jax.jit(
                     lambda a: (a.astype(jnp.float64) * 2.0)
                     .astype(jnp.float32)))(*specs)

@@ -10,8 +10,8 @@ so the dp x tp topologies here are real multi-executable programs):
   gauge detached; /healthz degraded),
 - (bucket x replica) prewarm + hot reload with ZERO dropped requests and
   zero compile spans between swap-begin and drain-complete,
-- tp=2 MeshServable bit-for-bit vs the single-device model, through the
-  batcher,
+- tp=2 MeshServable vs the single-device model at float32 tolerance,
+  through the batcher,
 - a mini 1-vs-4-replica goodput-scaling smoke on a timer-bound servable
   (the hard-gated 1-vs-8 soak lives in ``ci/run.sh sharded``).
 """
@@ -330,7 +330,11 @@ def _col_parallel_net(seed=3):
     return net
 
 
-def test_tp2_mesh_servable_bit_for_bit_through_batcher():
+def test_tp2_mesh_servable_matches_unsharded_through_batcher():
+    """The tp=2 program splits each contraction over two devices, so its
+    float32 sums associate differently from the single-device forward: XLA
+    promises no bit-equality across partitionings (CPU or TPU). Agreement
+    is held to float32 rounding of these O(1) activations instead."""
     net = _col_parallel_net()
     rng = onp.random.RandomState(0)
     x = rng.randn(6, 8).astype("float32")
@@ -343,7 +347,8 @@ def test_tp2_mesh_servable_bit_for_bit_through_batcher():
     try:
         for i in range(6):
             out = reg.predict("tp2", x[i])
-            assert onp.array_equal(onp.asarray(out[0]), ref[i]), i
+            onp.testing.assert_allclose(onp.asarray(out[0]), ref[i],
+                                        rtol=1e-5, atol=1e-6)
     finally:
         reg.close()
 

@@ -134,7 +134,6 @@ def write_diff_canaries(out_dir):
     import jax.numpy as jnp
     from jax import export as jax_export
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def exp(fn, *specs):
         return jax_export.export(jax.jit(fn))(*specs)
@@ -179,14 +178,14 @@ def write_diff_canaries(out_dir):
 
     # D005: the candidate's partitioning grew an all_gather the base's
     # dispatch path never paid (1-device mesh still EXPORTS the
-    # collective op; check_rep=False keeps the replication checker out
+    # collective op; check_vma=False keeps the replication checker out
     # of the single-device canary)
     b, c = dirs("collective")
     _write_artifact(b, "decode", exp(lambda x: x + 1.0, f32_84))
     mesh = Mesh(onp.array(jax.devices()[:1]), ("x",))
-    gathered = shard_map(lambda x: jax.lax.all_gather(x, "x", tiled=True),
-                         mesh=mesh, in_specs=P("x"), out_specs=P(),
-                         check_rep=False)
+    gathered = jax.shard_map(
+        lambda x: jax.lax.all_gather(x, "x", tiled=True),
+        mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)
     _write_artifact(c, "decode", exp(gathered, f32_84))
     pairs["collective"] = (b, c, {"D005"})
 

@@ -1,10 +1,16 @@
 #!/usr/bin/env python
 """Distributed launcher (ref tools/launch.py + dmlc-tracker).
 
-TPU-native: multi-host SPMD uses jax.distributed — one process per host over
-DCN. This launcher starts N local worker processes with the coordinator env
-(COORD_ADDR/NUM_PROC/PROC_ID), the analog of DMLC_ROLE/DMLC_PS_ROOT_URI for
-the parameter-server design. Remote hosts: run the same command per host with
+TPU-native: multi-host SPMD uses jax.distributed — ONE process per host over
+DCN, and that process drives every chip of its host. This launcher starts N
+worker processes with the coordinator env (COORD_ADDR/NUM_PROC/PROC_ID), the
+analog of DMLC_ROLE/DMLC_PS_ROOT_URI for the parameter-server design.
+
+Local mode is CPU-only: N workers on one host would each claim every chip
+of that host (a chip belongs to one process), so local workers are started
+with JAX_PLATFORMS=cpu. It exists to exercise the multi-process control
+flow (tests/test_dist.py); on a TPU host, run one process and give it a
+mesh over the host's chips. Remote hosts: run the same command per host with
 PROC_ID set (ssh orchestration mirrors dmlc-tracker's ssh mode; exercised
 only manually — CI images ship no sshd). The reference's mpi/yarn/sge
 launchers are a documented cut: TPU pods are provisioned by the platform
@@ -35,6 +41,8 @@ def main():
                 "MXTPU_COORD_ADDR": args.coord_addr,
                 "MXTPU_NUM_PROC": str(args.num_workers),
                 "MXTPU_PROC_ID": str(rank),
+                # N processes on one host cannot share its chips
+                "JAX_PLATFORMS": "cpu",
                 # DMLC-compat aliases so reference-era scripts keep working
                 "DMLC_NUM_WORKER": str(args.num_workers),
                 "DMLC_RANK": str(rank),
