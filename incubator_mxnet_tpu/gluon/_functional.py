@@ -77,7 +77,11 @@ def make_pure_fn(block, train_mode):
         try:
             with FunctionalScope(key) as st:
                 with autograd.pause(train_mode=train_mode):
-                    out = block.forward(*[NDArray(d) for d in input_datas])
+                    # a direct forward call: name the root's scope here
+                    # (Block.__call__ names every child's)
+                    with jax.named_scope(block.name):
+                        out = block.forward(
+                            *[NDArray(d) for d in input_datas])
                 outs = out if isinstance(out, (list, tuple)) else [out]
                 out_datas = [o._data for o in outs]
                 aux_pairs = list(st.aux_updates)

@@ -217,7 +217,10 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args)
+        # every op traced in here carries the block's name (the one its
+        # parameters carry) in its HLO metadata; costs at trace time only
+        with jax.named_scope(self.name):
+            out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         if args and all(isinstance(a, NDArray) for a in args):

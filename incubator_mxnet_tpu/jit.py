@@ -68,7 +68,7 @@ def _net_trace_lock(net):
                 net._mxtpu_trace_lock = lock
     return lock
 
-__all__ = ["TrainStep", "EvalStep"]
+__all__ = ["TrainStep", "EvalStep", "compiled_train_programs"]
 
 # Compile observability: each shared-cache (aot.CACHE) miss that cannot be
 # satisfied by a persisted artifact is one model trace + XLA compile.
@@ -206,11 +206,16 @@ class TrainStep:
                         _functional.FunctionalScope(key) as st:
                     with autograd.pause(train_mode=True):
                         nd_inputs = [NDArray(d) for d in input_datas]
-                        # bypass hybridize's own cache: trace the eager forward
-                        out = net.forward(*nd_inputs[:n_inputs])
+                        # bypass hybridize's own cache: trace the eager
+                        # forward; Block.__call__ names every child's scope,
+                        # these two direct calls name their own
+                        with jax.named_scope(net.name):
+                            out = net.forward(*nd_inputs[:n_inputs])
                         outs = out if isinstance(out, (list, tuple)) else (out,)
-                        loss = loss_fn.forward(outs[0] if len(outs) == 1 else outs,
-                                               *nd_inputs[n_inputs:])
+                        with jax.named_scope("loss"):
+                            loss = loss_fn.forward(
+                                outs[0] if len(outs) == 1 else outs,
+                                *nd_inputs[n_inputs:])
                     # seed-of-ones semantics: grads of the SUM; Trainer's
                     # rescale_grad (1/batch) then normalises — matches eager
                     loss_scalar = loss._data.sum()
@@ -240,27 +245,28 @@ class TrainStep:
                 grads = grad_postprocess(grads)
             new_t, new_opt = [], []
             lowp = (jnp.bfloat16, jnp.float16)
-            for i, (w, g, s) in enumerate(zip(t_datas, grads, opt_states)):
-                g = g * rescale
-                if optimizer.clip_gradient is not None:
-                    g = jnp.clip(g, -optimizer.clip_gradient, optimizer.clip_gradient)
-                gf = g.astype(jnp.float32)
-                mp = optimizer.multi_precision and w.dtype in lowp
-                if mp:
-                    # fp32 master-weight flow (ref optimizer.py:320): state is
-                    # (master, inner); update the master, cast down the copy
-                    master, inner_state = s
-                    state_nd = _tree_wrap(inner_state)
-                    new_w, new_state_nd = optimizer.update_rule(
-                        master, gf, state_nd, lrs[i], wds[i], t)
-                    new_t.append(new_w.astype(w.dtype))
-                    new_opt.append((new_w, _tree_to_data(new_state_nd)))
-                else:
-                    state_nd = _tree_wrap(s)
-                    new_w, new_state_nd = optimizer.update_rule(
-                        w.astype(jnp.float32), gf, state_nd, lrs[i], wds[i], t)
-                    new_t.append(new_w.astype(w.dtype))
-                    new_opt.append(_tree_to_data(new_state_nd))
+            with jax.named_scope("optimizer"):
+                for i, (w, g, s) in enumerate(zip(t_datas, grads, opt_states)):
+                    g = g * rescale
+                    if optimizer.clip_gradient is not None:
+                        g = jnp.clip(g, -optimizer.clip_gradient, optimizer.clip_gradient)
+                    gf = g.astype(jnp.float32)
+                    mp = optimizer.multi_precision and w.dtype in lowp
+                    if mp:
+                        # fp32 master-weight flow (ref optimizer.py:320): state is
+                        # (master, inner); update the master, cast down the copy
+                        master, inner_state = s
+                        state_nd = _tree_wrap(inner_state)
+                        new_w, new_state_nd = optimizer.update_rule(
+                            master, gf, state_nd, lrs[i], wds[i], t)
+                        new_t.append(new_w.astype(w.dtype))
+                        new_opt.append((new_w, _tree_to_data(new_state_nd)))
+                    else:
+                        state_nd = _tree_wrap(s)
+                        new_w, new_state_nd = optimizer.update_rule(
+                            w.astype(jnp.float32), gf, state_nd, lrs[i], wds[i], t)
+                        new_t.append(new_w.astype(w.dtype))
+                        new_opt.append(_tree_to_data(new_state_nd))
             if constrain_update is not None:
                 new_t, new_opt = constrain_update(new_t, new_opt)
             return loss_full, new_t, new_opt, aux_vals
@@ -510,21 +516,19 @@ class TrainStep:
         trainable, frozen, t_arrs, f_arrs, aux_box = entry.extras
 
         optimizer = trainer._optimizer
-        # python-side schedule state (lr scheduler, update counts) advances here
-        self._step_count += 1
-        lrs, wds = [], []
-        for i, p in enumerate(trainable):
-            idx = trainer._param2idx.get(p.name, i)
-            optimizer._update_count(idx)
-            lrs.append(optimizer._get_lr(idx))
-            wds.append(optimizer._get_wd(idx))
-        t = self._step_count
-        rescale = optimizer.rescale_grad / batch_size
-
-        opt_states = []
-        for i, p in enumerate(trainable):
-            idx = trainer._param2idx.get(p.name, i)
-            opt_states.append(_tree_to_data(trainer._states[idx]))
+        # python-side schedule state (lr scheduler, update counts) advances
+        # here; the span covers the step's only per-parameter host loops
+        with spans.span("train:schedule"):
+            self._step_count += 1
+            lrs, wds, opt_states = [], [], []
+            for i, p in enumerate(trainable):
+                idx = trainer._param2idx.get(p.name, i)
+                optimizer._update_count(idx)
+                lrs.append(optimizer._get_lr(idx))
+                wds.append(optimizer._get_wd(idx))
+                opt_states.append(_tree_to_data(trainer._states[idx]))
+            t = self._step_count
+            rescale = optimizer.rescale_grad / batch_size
 
         # the whole dispatch + write-back holds the net's trace lock: a
         # mesh-path MISS dispatch IS the lazy train trace (inner swaps
@@ -589,6 +593,23 @@ class TrainStep:
         flightrec.record("step_end", step=self._step_count,
                          dur_s=round(step_dur, 6))
         return NDArray(loss_full)
+
+
+def compiled_train_programs():
+    """``[(model_id, optimised HLO text)]`` of the live train programs in
+    ``aot.CACHE``. Every instruction of the text carries its scope path in
+    ``metadata={op_name="jit(step_fn)/.../<scopes>/<primitive>"}`` (block
+    names, ``ffn``, ``loss``, ``optimizer``), which is how a profiler
+    capture's device events — named by instruction — are booked to a block.
+    The text is rendered only here, on demand. Only AOT-compiled entries
+    have one: the mesh path's lazily compiling wrapper yields nothing."""
+    out = []
+    for key in aot.CACHE.keys():
+        entry = aot.CACHE.peek(key) if key.kind == "train" else None
+        as_text = getattr(entry.fn, "as_text", None) if entry else None
+        if as_text is not None:
+            out.append((key.model_id, as_text()))
+    return out
 
 
 def _rewrap_state(old, new_data):

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 from jax.sharding import PartitionSpec as P
 
 from .. import ndarray as nd
@@ -128,7 +129,8 @@ class TransformerEncoderLayer(HybridBlock):
         if self.dropout_layer:
             h = self.dropout_layer(h)
         x = self.ln1(x + h)
-        h = self.ffn2(nd.LeakyReLU(self.ffn1(x), act_type="gelu"))
+        with jax.named_scope("ffn"):
+            h = self.ffn2(nd.LeakyReLU(self.ffn1(x), act_type="gelu"))
         if self.dropout_layer:
             h = self.dropout_layer(h)
         return self.ln2(x + h)

@@ -12,6 +12,7 @@ TPU-first choices:
 """
 from __future__ import annotations
 
+import jax
 from jax.sharding import PartitionSpec as P
 
 from .. import ndarray as nd
@@ -46,8 +47,10 @@ class TransformerDecoderLayer(HybridBlock):
 
     def forward(self, x):
         x = x + self.attn(self.ln1(x))
-        h = nd.LeakyReLU(self.fc1(self.ln2(x)), act_type="gelu")
-        return x + self.fc2(h)
+        h = self.ln2(x)
+        with jax.named_scope("ffn"):
+            h = self.fc2(nd.LeakyReLU(self.fc1(h), act_type="gelu"))
+        return x + h
 
 
 class GPTModel(HybridBlock):

@@ -189,6 +189,7 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k):
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(B, H, S, D), lse
 
@@ -314,6 +315,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         in_specs=[qspec, qspec, rowspec, rowspec, kvspec, kvspec],
         out_specs=(kvspec, kvspec),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qf, dof, lse, delta, kf, vf)
 
     if causal:
@@ -337,6 +339,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         in_specs=[kvspec2, kvspec2, qspec2, qspec2, rowspec2, rowspec2],
         out_specs=qspec2,
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(kf, vf, qf, dof, lse, delta)
 
     shape = (B, H, S, D)

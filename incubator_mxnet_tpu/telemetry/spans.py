@@ -43,6 +43,8 @@ import itertools
 import json
 import threading
 
+import jax
+
 from . import trace
 from .ringbuf import BoundedRing
 
@@ -151,7 +153,7 @@ class Span:
     R008)."""
 
     __slots__ = ("name", "span_id", "parent_id", "request_id", "args",
-                 "start_us", "_open")
+                 "start_us", "_open", "_annotation")
 
     def __init__(self, name, parent=None, request_id=None, **args):
         self.name = name
@@ -183,6 +185,11 @@ class Span:
         self.start_us = _now_us()
         _stack().append(self)
         self._open = True
+        # the same region on the profiler's own clock: under a
+        # jax.profiler capture the span shows on the host plane beside the
+        # device's ops; with no session active this is a flag test
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         return self
 
     def end(self, **extra_args):
@@ -195,6 +202,7 @@ class Span:
         if self in st:
             while st and st.pop() is not self:
                 pass
+        self._annotation.__exit__(None, None, None)
         if extra_args:
             self.args = dict(self.args or (), **extra_args)
         _emit(self.name, self.start_us, _now_us() - self.start_us,

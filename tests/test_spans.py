@@ -153,6 +153,73 @@ def test_spans_not_mirrored_when_profiler_stopped(tmp_path):
 
 
 # -------------------------------------------------------------- export
+def _host_events(capture_dir):
+    """{event name: [duration_ns]} of a jax.profiler capture's host planes."""
+    import glob
+    import jax
+    [path] = glob.glob(str(capture_dir / "**" / "*.xplane.pb"),
+                       recursive=True)
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(ev.duration_ns)
+    return out
+
+
+@pytest.mark.parametrize("session", [True, False],
+                         ids=["under-a-capture", "no-session"])
+def test_spans_are_trace_annotations_too(tmp_path, session):
+    """Under a jax.profiler capture a span is on the host plane under its
+    own name, on the profiler's clock; with no session active it costs a
+    flag test, and either way it lands in the ring."""
+    import jax
+    if session:
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("train:step"):
+            with spans.span("train:dispatch", compile=False):
+                pass
+            leaked = spans.span("train:leaked").start()
+        # the parent's end closed the leaked child's stack entry, and the
+        # child's own late end() still exits its annotation once
+        leaked.end()
+    finally:
+        if session:
+            jax.profiler.stop_trace()
+    assert [r["name"] for r in spans.snapshot()] == [
+        "train:dispatch", "train:step", "train:leaked"]
+    assert spans.current_span() is None
+    if session:
+        events = _host_events(tmp_path)
+        for name in ("train:step", "train:dispatch", "train:leaked"):
+            assert len(events[name]) == 1, name
+        assert events["train:step"][0] >= events["train:dispatch"][0]
+
+
+def test_train_step_emits_its_spans_with_schedule():
+    """train:schedule (update counts, lrs, wds, optimizer states) is one of
+    train:step's children, between the build and the dispatch."""
+    import numpy as np
+    from incubator_mxnet_tpu import gluon, jit, nd
+    net = gluon.nn.Dense(4, in_units=4)
+    net.initialize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 1e-2})
+    step = jit.TrainStep(net, gluon.loss.L2Loss(), trainer)
+    x = nd.array(np.ones((4, 4), "float32"))
+    step(x, x)
+    spans.reset()
+    step(x, x)
+    recs = spans.snapshot()
+    names = [r["name"] for r in recs]
+    assert names == ["train:host_transfer", "train:schedule",
+                     "train:dispatch", "train:step"]
+    parent = by_name(recs)["train:step"]["span_id"]
+    assert all(r["parent_id"] == parent for r in recs[:-1])
+
+
 def test_jsonl_export_and_dump(tmp_path):
     with spans.span("a", n=1):
         pass
