@@ -38,18 +38,22 @@ PHASES = ("kernels", "bert", "gpt", "resnet", "serve", "generate",
 
 # the bench's widths (bench.py bench_transformer / bench_long_context / main)
 FULL = {
-    # (B, H, S, D), causal: S picks the block (512 as BERT, 1024 as GPT)
-    "kernels": [((2, 4, 512, 128), False), ((1, 4, 2048, 128), True)],
+    # (B, H, S, D), causal: S picks the block (512 as BERT, 1024 as GPT);
+    # D = 64 below S = 2048 takes the short family (BERT-large's shape, and
+    # its longest tile with the causal mask)
+    "kernels": [((2, 4, 512, 128), False), ((1, 4, 2048, 128), True),
+                ((16, 16, 512, 64), False), ((8, 16, 768, 64), True)],
     "bert": dict(B=64, S=512, V=32768, U=1024, L=12, H=8),
     "gpt": dict(S=8192, V=32768, U=1024, L=4, H=8),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
 }
-# --rehearse: same code paths, toy shapes (D stays 128 so the flash kernels
-# are legal and run interpreted)
+# --rehearse: same code paths, toy shapes (D stays 128 in the models so the
+# streamed kernels are legal and run interpreted)
 TOY = {
-    "kernels": [((1, 2, 128, 128), False), ((1, 2, 256, 128), True)],
+    "kernels": [((1, 2, 128, 128), False), ((1, 2, 256, 128), True),
+                ((1, 2, 256, 64), False)],
     "bert": dict(B=4, S=128, V=512, U=256, L=1, H=2),
     "gpt": dict(S=256, V=512, U=256, L=1, H=2),
     "resnet": dict(B=16, HW=64),
@@ -66,8 +70,8 @@ def log(msg):
 def phase_kernels(cases, on_chip, shared):
     """flash_attention's output and its three gradients against a float32
     jax.numpy reference, on the device, at the block sizes the BERT and
-    GPT phases use (the 1024-block causal backward is the kernel with the
-    largest VMEM footprint)."""
+    GPT phases use (the 1024-block causal backward is the streamed kernel
+    with the largest VMEM footprint) and at the short family's shapes."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops.attention import flash_attention

@@ -25,11 +25,6 @@ ENV_VARS = {
         bool, False,
         "Run the flash-attention Pallas kernels in interpret mode on CPU "
         "(CI/testing; ops/attention.py)."),
-    "MXTPU_FLASH_FORCE": (
-        bool, False,
-        "Use the flash-attention kernels for every LEGAL shape, overriding "
-        "the narrow-head (D<128) short-S profitability heuristic — opt in "
-        "when the composite's B*H*S^2 score memory nears OOM."),
     "MXTPU_FLASH_BLOCK_Q": (
         int, 0,
         "Override the flash-attention q-block size (ops/attention.py). "

@@ -1,21 +1,36 @@
 """Fused (flash) attention Pallas kernels for TPU — forward AND backward.
 
 The hot exception to "let XLA fuse" (SURVEY §7 table): attention's softmax
-forces an HBM round-trip of the (S, S) score matrix under plain XLA. The
-forward kernel tiles Q against K/V blocks in VMEM with an online-softmax
-accumulator and saves the per-row log-sum-exp (LSE); the backward kernels
-recompute probabilities blockwise from the LSE (FlashAttention-2
-formulation) and accumulate dQ/dK/dV across sequential grid steps, so the
-(S, S) score matrix NEVER materializes in HBM in either direction and VMEM
-use is O(block^2 + block*D) — long sequences fit.
+forces an HBM round-trip of the (S, S) score matrix under plain XLA. Two
+kernel families keep it in VMEM, chosen by attention_route() from the
+shape alone:
 
-Used by models.bert MultiHeadAttention (attention='flash'). The kernels run
-on a TPU for every shape flash_attention_supported accepts; a Mosaic
-refusal of such a shape surfaces as the compile error it is — nothing
-catches it to degrade. Off-TPU (the CPU test backend) and for shapes no
-block divides, the XLA composite runs instead. MXTPU_FLASH_INTERPRET=1 runs
-the kernels in Pallas interpret mode (CPU tests only; chip_smoke.py and
-bench.py refuse to start with it set).
+* **streamed** (flash_fwd, flash_bwd_dkv, flash_bwd_dq): Q blocks against
+  K/V blocks streamed by the grid, with an online-softmax carry; the
+  backward kernels recompute probabilities blockwise from the saved
+  log-sum-exp (FlashAttention-2) and accumulate dQ/dK/dV across sequential
+  grid steps. VMEM use is O(block^2 + block*D), so 16k-32k sequences fit.
+  Takes D % 128 == 0, or S >= 2048.
+* **short** (flash_short_fwd, flash_short_bwd): narrow heads that tile 128
+  lanes (D of 32 or 64, whole blocks of H*D) below S = 2048 whose whole
+  (S, S) float32 tile fits VMEM and is worth a visit (_SHORT_MIN_S <= S <=
+  _SHORT_MAX_S). One visit per head: no carry, no rescale, no second
+  recompute; one backward kernel of five matmuls; operands in the input
+  type, float32 accumulation. Reads and writes the projections' (B, S, H*D)
+  layout in 128-lane blocks of whole heads: no transposes, no padded lanes.
+
+Neither family ever writes an (S, S) tensor to HBM. Everything else (cross
+attention, lengths no block divides, any shape off the TPU) takes the XLA
+composite. Used by models.bert MultiHeadAttention (attention='flash'). A
+Mosaic refusal of a routed shape surfaces as the compile error it is —
+nothing catches it to degrade. MXTPU_FLASH_INTERPRET=1 runs the kernels in
+Pallas interpret mode (CPU tests only; chip_smoke.py and bench.py refuse to
+start with it set).
+
+Why two families (v5e, BERT-large's (16, 16, 512, 64) bf16, attention
+alone, forward + backward, a call; PERF.md §6, PR 26): the composite takes
+3.09 ms, the streamed kernels with 512-blocks 1.93 ms, the short family
+1.11 ms, of which the two kernels are 0.89 ms in the compiled train step.
 """
 from __future__ import annotations
 
@@ -25,14 +40,37 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
+
 __all__ = ["flash_attention", "flash_attention_supported",
            "flash_attention_legal", "flash_attention_lse",
-           "attention_with_lse", "flash_attention_on_mesh"]
+           "attention_with_lse", "flash_attention_on_mesh",
+           "attention_route"]
+
+# The short family visits one head's whole (S, S) float32 tile per grid step.
+# 768 is the longest tile that compiles for a v5e within Mosaic's default
+# scoped VMEM in every variant (causal or not, bf16 or float32; at 1024 only
+# the causal bf16 one does). At 128 a head is seven 128-wide matmuls whose
+# latencies nothing hides, and the composite wins. The router sends it what
+# was timed against the composite on a v5e with these kernels (forward +
+# backward, bf16, ms a call; PERF.md §6, PR 26): D = 64 at S = 256 2.09
+# against 3.30, 384 0.78 / 1.44, 512 1.11 / 3.09, 640 1.68 / 4.74, 768
+# 1.08 / 3.36 (causal 1.06 / 3.38); D = 32 at S = 512 1.88 / 6.00.
+_SHORT_MIN_S, _SHORT_MAX_S, _SHORT_D = 256, 768, (32, 64)
+
+_ROUTES = telemetry.counter(
+    "mxtpu_attention_route_total",
+    "flash_attention calls traced, by the path the shape was routed to "
+    "(short / streamed Pallas kernels, or the XLA composite).", ("route",))
 
 
 def _interpret():
     from ..config import get_env
     return get_env("MXTPU_FLASH_INTERPRET")
+
+
+def _kernels_run_here():
+    return _interpret() or jax.devices()[0].platform == "tpu"
 
 
 def _auto_block(S):
@@ -67,34 +105,51 @@ def _blocked_reference(q, k, v, causal, scale):
 
 
 def flash_attention_legal(q_shape, block_q=None, block_k=None):
-    """Capability: the kernels can run this shape. D rides each BlockSpec as
-    the FULL last dim (legal for any size when equal to the array dim);
-    8-alignment keeps sublanes packed."""
+    """Capability: the streamed kernels can run this shape. D rides each
+    BlockSpec as the FULL last dim (legal for any size when equal to the
+    array dim); 8-alignment keeps sublanes packed."""
     B, H, S, D = q_shape
     block_q, block_k = _resolve_blocks(S, block_q, block_k)
     if block_q is None or block_k is None:
         return False
-    if not _interpret() and jax.devices()[0].platform != "tpu":
+    if not _kernels_run_here():
         return False
     return S % block_q == 0 and S % block_k == 0 and D % 8 == 0
 
 
+def _narrow_and_short(q_shape):
+    """D = 64-style heads half-fill the MXU and, below S = 2048, the
+    streamed kernels' carry and second recompute cost more than they save:
+    these shapes belong to the short family (or the composite)."""
+    return q_shape[3] % 128 != 0 and q_shape[2] < 2048
+
+
 def flash_attention_supported(q_shape, block_q=None, block_k=None):
-    """Legality AND profitability: D=64-style narrow heads leave MXU lanes
-    half-empty, so the kernel only engages once S is long enough that the
-    composite's (S,S) materialization hits HBM pressure (v5e, H=16, 512
-    blocks: parity at ~2k, 2x at 4k, >6x at 8k — and the composite's score
-    memory scales with B*H*S^2, so real batches hit the cliff earlier).
-    Set MXTPU_FLASH_FORCE=1 to override the heuristic (e.g. large B*H at
-    moderate S nearing OOM); interpret mode ignores it so CI exercises
-    every legal shape."""
-    if not flash_attention_legal(q_shape, block_q, block_k):
-        return False
-    B, H, S, D = q_shape
-    if D % 128 != 0 and S < 2048 and not _interpret():
-        from ..config import get_env
-        return get_env("MXTPU_FLASH_FORCE")
-    return True
+    """The STREAMED kernels take this self-attention shape: the same answer
+    as attention_route(q_shape) == 'streamed', interpreted or on the chip.
+    Ring and Ulysses ask this before calling flash_attention_lse, which has
+    only the streamed kernels."""
+    return attention_route(q_shape, block_q=block_q,
+                           block_k=block_k) == "streamed"
+
+
+def attention_route(q_shape, k_shape=None, v_shape=None, block_q=None,
+                    block_k=None):
+    """'short', 'streamed' or 'composite': which path flash_attention
+    takes, from the shapes alone (and whether kernels can run here at all:
+    a TPU, or interpret mode). Both kernel families assume self-attention
+    (Sq == Sk); cross-attention takes the composite, which handles it."""
+    k_shape, v_shape = k_shape or q_shape, v_shape or q_shape
+    if not tuple(q_shape) == tuple(k_shape) == tuple(v_shape):
+        return "composite"
+    _, H, S, D = q_shape
+    if _narrow_and_short(q_shape):
+        # whole heads in 128-lane blocks of the (B, S, H*D) layout
+        fits = _SHORT_MIN_S <= S <= _SHORT_MAX_S and S % 128 == 0 \
+            and D in _SHORT_D and (H * D) % 128 == 0
+        return "short" if fits and _kernels_run_here() else "composite"
+    return "streamed" if flash_attention_legal(q_shape, block_q, block_k) \
+        else "composite"
 
 
 # --------------------------------------------------------------- forward
@@ -348,23 +403,216 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
             dv.reshape(shape).astype(v.dtype))
 
 
+# ------------------------------------------- short sequences: one visit
+# The short kernels read and write the projections' own (B, S, H*D) layout in
+# 128-lane blocks: 128 // D whole heads side by side (a pair at D = 64). A
+# (.., S, 64) operand would be padded to 128 lanes in HBM and VMEM — twice
+# the bytes of every q, k, v and gradient, 1.9 GB a chip of BERT-large's
+# saved activations, which the dp4 cell does not have (PERF.md §6, PR 26) —
+# and would need the (B,S,H,D) -> (B,H,S,D) transposes as ops of their own.
+# One head of a block is picked by zeroing the other heads' lanes of q (and
+# of dO): a contraction over all 128 lanes then sees that head alone and
+# costs the MXU the pass a half-filled 64-deep one costs; results that come
+# out 128 wide (o, dV, dQ) keep that head's lanes.
+def _lane_blocks_per_step(n_blocks, heads_per_block, S):
+    """128-lane blocks one grid step visits (the loops over blocks and heads
+    are unrolled): the largest divisor of n_blocks that keeps a step at or
+    under two (512, 512) tiles, so one block from S = 384 up and four (eight
+    heads of 64) at S = 256. Measured on a v5e with these kernels at
+    (64, 16, 256, 64), forward + backward (PERF.md §6, PR 26): four blocks
+    a step take 2.09 ms where one takes 2.28."""
+    want = max(1, 2 * (512 * 512) // (S * S) // heads_per_block)
+    return max(g for g in range(1, min(want, n_blocks) + 1)
+               if n_blocks % g == 0)
+
+
+def _nt(a, b):
+    """a @ b.T on the MXU, float32 result, no transpose materialized."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    """a.T @ b, float32 result."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _mm(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _causal_tile(S, rows_are_queries):
+    r = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
+    return r >= c if rows_are_queries else c >= r
+
+
+def _lane_blocks(ref):
+    return [slice(128 * i, 128 * (i + 1)) for i in range(ref.shape[2] // 128)]
+
+
+def _head_masks(D):
+    """One (1, 128) mask per head of a 128-lane block: the head's lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    return [(lane >= h * D) & (lane < (h + 1) * D) for h in range(128 // D)]
+
+
+def _one_head(mine, x):
+    """A 128-lane operand with every lane but one head's zeroed."""
+    return jnp.where(mine, x, 0).astype(x.dtype)
+
+
+def _short_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, D, causal,
+                      scale):
+    """Each head of the step, whole: s = (q*scale) k^T, row max, exp, row
+    sum, o = p v / l. Operands stay in the input type and every matmul
+    accumulates in float32; max, sum, exp and the LSE are float32. The
+    whole key length is in the one tile, so there is no carry, no rescale
+    and (the diagonal is always live) no row without a finite maximum."""
+    S = q_ref.shape[1]
+    for blk, lanes in enumerate(_lane_blocks(q_ref)):
+        k, v = k_ref[0, :, lanes], v_ref[0, :, lanes]
+        out = None
+        for h, mine in enumerate(_head_masks(D)):
+            q = _one_head(mine, q_ref[0, :, lanes] * scale)
+            s = _nt(q, k)                                 # (S, S) float32
+            if causal:
+                s = jnp.where(_causal_tile(S, True), s, -jnp.inf)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            o = _mm(p.astype(v.dtype), v) / l             # every head's lanes
+            out = o if out is None else jnp.where(mine, o, out)
+            lse_ref[0, blk, h, :] = (m + jnp.log(l))[:, 0]
+        o_ref[0, :, lanes] = out.astype(o_ref.dtype)
+
+
+def _short_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, *, D, causal, scale):
+    """Each head of the step, whole, in the transposed frame (rows are
+    keys, columns queries): the saved LSE is a (1, S) row and broadcasts as
+    it lies, delta = sum_k p dP is a sum down the rows, and dV = p^T dO and
+    dK = ds^T q are plain matmuls; only dQ contracts over the tile's rows.
+    Five matmuls, the scores recomputed once, nothing accumulated across
+    grid steps, each gradient written once in the operands' type."""
+    S = q_ref.shape[1]
+    for blk, lanes in enumerate(_lane_blocks(q_ref)):
+        k, v = k_ref[0, :, lanes], v_ref[0, :, lanes]
+        dq = dk = dv = None
+        for h, mine in enumerate(_head_masks(D)):
+            q = _one_head(mine, q_ref[0, :, lanes] * scale)
+            do = _one_head(mine, do_ref[0, :, lanes])
+            st = _nt(k, q)                                # (Sk, Sq) float32
+            if causal:
+                st = jnp.where(_causal_tile(S, False), st, -jnp.inf)
+            pt = jnp.exp(st - lse_ref[0, blk, h:h + 1, :])
+            dpt = _nt(v, do)
+            # delta_q = dO_q . O_q = sum_k p_qk dP_qk, without reading O
+            dst = (pt * (dpt - jnp.sum(pt * dpt, axis=0, keepdims=True))
+                   ).astype(k.dtype)
+            # q and dO are zero outside the head's lanes, so dV's and dK's
+            # other lanes are too; dQ comes out of all of K's lanes.
+            # s = (q*scale) k^T: dK takes the scaled q, dQ the factor itself
+            dv_h, dk_h = _mm(pt.astype(k.dtype), do), _mm(dst, q)
+            dq_h = _tn(dst, k) * scale
+            dv = dv_h if dv is None else dv + dv_h
+            dk = dk_h if dk is None else dk + dk_h
+            dq = dq_h if dq is None else jnp.where(mine, dq_h, dq)
+        dq_ref[0, :, lanes] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, lanes] = dk.astype(dk_ref.dtype)
+        dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
+
+
+def _short_specs(B, H, S, D):
+    from jax.experimental import pallas as pl
+    per_block = 128 // D
+    n_blocks = H // per_block
+    G = _lane_blocks_per_step(n_blocks, per_block, S)
+    return (B, n_blocks // G), \
+        pl.BlockSpec((1, S, 128 * G), lambda b, j: (b, 0, j)), \
+        pl.BlockSpec((1, G, per_block, S), lambda b, j: (b, j, 0, 0))
+
+
+def _to_rows(x):
+    """(B, H, S, D) -> (B, S, H*D), the layout the projections produce:
+    XLA cancels this against the model's own transpose."""
+    B, H, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
+def _to_heads(x, H):
+    B, S, U = x.shape
+    return x.reshape(B, S, H, U // H).transpose(0, 2, 1, 3)
+
+
+# Both calls are jitted: a model's layers then trace and lower each kernel
+# once and call it N times, where N inline pallas_calls cost every start of
+# BERT-large, warm cache or not, 4-6 s of host time (PERF.md §6, PR 26).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _short_call(q, k, v, causal, scale, interpret):
+    """Returns (out (B,H,S,D), lse (B, H*D/128, 128/D, S) fp32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, S, D = q.shape
+    grid, tile, rows = _short_specs(B, H, S, D)
+    out, lse = pl.pallas_call(
+        functools.partial(_short_fwd_kernel, D=D, causal=causal, scale=scale),
+        out_shape=(jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
+                   jax.ShapeDtypeStruct((B, H * D // 128, 128 // D, S),
+                                        jnp.float32)),
+        grid=grid, in_specs=[tile, tile, tile], out_specs=(tile, rows),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="flash_short_fwd",
+    )(_to_rows(q), _to_rows(k), _to_rows(v))
+    return _to_heads(out, H), lse
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _short_bwd_call(q, k, v, lse, do, causal, scale, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, S, D = q.shape
+    grid, tile, rows = _short_specs(B, H, S, D)
+    grads = pl.pallas_call(
+        functools.partial(_short_bwd_kernel, D=D, causal=causal, scale=scale),
+        out_shape=tuple(jax.ShapeDtypeStruct((B, S, H * D), x.dtype)
+                        for x in (q, k, v)),
+        grid=grid, in_specs=[tile, tile, tile, tile, rows],
+        out_specs=(tile, tile, tile),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="flash_short_bwd",
+    )(_to_rows(q), _to_rows(k), _to_rows(v), _to_rows(do), lse)
+    return tuple(_to_heads(g, H) for g in grads)
+
+
 # --------------------------------------------------------------- custom VJP
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None):
-    """q,k,v: (B, H, S, D) → (B, H, S, D). Blocks default to the measured
-    optimum (largest of 512/256/128 dividing S)."""
+    """q,k,v: (B, H, S, D) → (B, H, S, D), by the path attention_route()
+    names for the shape. ``block_q``/``block_k`` size the streamed kernels'
+    blocks (default: the largest of 1024/512/256/128 dividing S) and mean
+    nothing on the other two paths."""
     return _fa_fwd(q, k, v, causal, scale, block_q, block_k)[0]
 
 
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
-    block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    # the kernels assume self-attention shapes (Sq == Sk); cross-attention
-    # with mismatched lengths takes the composite (which handles it)
-    if k.shape == q.shape and v.shape == q.shape \
-            and flash_attention_supported(q.shape, block_q, block_k):
+    route = attention_route(q.shape, k.shape, v.shape, block_q, block_k)
+    _ROUTES.inc(route=route)
+    if route == "short":
+        out, lse = _short_call(q, k, v, causal, scale, _interpret())
+        return out, (q, k, v, None, lse)        # its backward needs no O
+    if route == "streamed":
+        block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k)
         out, lse = _fa_call(q, k, v, causal, scale, block_q, block_k)
     else:
         out, lse = _blocked_reference(q, k, v, causal, scale), None
@@ -373,13 +621,18 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
 
 def _fa_bwd(causal, scale, block_q, block_k, res, do):
     q, k, v, o, lse = res
-    block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if lse is not None:
+    route = attention_route(q.shape, k.shape, v.shape, block_q, block_k)
+    if route == "short":
+        return _short_bwd_call(q, k, v, lse, do, causal, scale,
+                               _interpret())
+    if route == "streamed":
+        block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k)
         return _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q,
                             block_k)
-    # XLA composite fallback (materializes (S,S); only off-TPU small shapes)
+    # XLA composite (materializes (S,S)): off the TPU, cross-attention,
+    # lengths no block divides, narrow heads past the short tile
     qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
     if causal:

@@ -2,8 +2,12 @@
 
 The same kernels run compiled on TPU (verified on-chip); interpret mode
 exercises the kernel bodies, BlockSpecs, and the custom-VJP plumbing in CI.
-Ref parity target: the XLA composite (ops/attention.py _blocked_reference).
+Ref parity target: the XLA composite (ops/attention.py _blocked_reference)
+for the streamed family, a float32 jax.numpy reference for the short one.
 """
+import math
+import types
+
 import numpy as onp
 import pytest
 
@@ -32,14 +36,12 @@ def test_auto_block_rejects_odd_lengths():
 
 @pytest.mark.parametrize("D", [64, 128])
 def test_flash_head_dims(D):
-    # standard head dims (BERT/GPT use 64) ride the kernels too; in
-    # interpret mode the profitability heuristic is bypassed, so this
-    # EXERCISES the kernels at D=64 (on hardware, narrow heads engage at
-    # long S or under MXTPU_FLASH_FORCE)
+    # standard head dims ride kernels too: D=128 the streamed family,
+    # D=64 (BERT's) at this short S the short one
     from incubator_mxnet_tpu.ops import attention as A
     q, k, v = _rand_qkv(D=D)
     assert A.flash_attention_legal(q.shape)
-    assert A.flash_attention_supported(q.shape)  # interpret mode: kernel runs
+    assert A.attention_route(q.shape) == {64: "short", 128: "streamed"}[D]
     out = A.flash_attention(q, k, v, True)
     ref = A._blocked_reference(q, k, v, True, 1.0 / onp.sqrt(D))
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-4
@@ -119,7 +121,8 @@ def test_flash_streamed_kv_long_chain(causal):
     VMEM). 8 sequential k-blocks per q-block exercises the scratch carry
     (m/l/acc) across grid steps + the causal dead-block index clamping."""
     from incubator_mxnet_tpu.ops import attention as A
-    q, k, v = _rand_qkv(B=1, H=1, S=1024, D=8)
+    q, k, v = _rand_qkv(B=1, H=1, S=1024, D=128)  # D=128: streamed by shape
+    assert A.attention_route(q.shape, block_q=128, block_k=128) == "streamed"
     scale = 1.0 / onp.sqrt(q.shape[-1])
 
     def loss_flash(q, k, v):
@@ -137,6 +140,39 @@ def test_flash_streamed_kv_long_chain(causal):
     for a, b in zip(gf, gr):
         rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
         assert rel < 1e-3
+
+
+@pytest.mark.parametrize("D", [8, 64])
+def test_streamed_kernels_take_narrow_heads(D):
+    """D rides each BlockSpec as the full last dim, so the streamed kernels
+    run any D % 8 == 0. The router sends them narrow heads only from
+    S = 2048 (next test); here they are called directly, as the short
+    tests call theirs, on a 2 x 2 grid of blocks."""
+    from incubator_mxnet_tpu.ops import attention as A
+    q, k, v = _rand_qkv(B=1, H=2, S=256, D=D)
+    w = _rand_qkv(B=1, H=2, S=256, D=D, seed=1)[0]
+    scale = 1.0 / math.sqrt(D)
+    out, lse = A._fa_call(q, k, v, True, scale, 128, 128)
+    grads = A._fa_bwd_call(q, k, v, out, lse, w, True, scale, 128, 128)
+    ref, vjp = jax.vjp(lambda q, k, v: _f32_reference(q, k, v, True),
+                       q, k, v)
+    for got, want in zip((out,) + tuple(grads), (ref,) + tuple(vjp(w))):
+        assert float(jnp.abs(got - want).max() / jnp.abs(want).max()) < 1e-5
+
+
+def test_narrow_heads_at_2048_ride_the_streamed_kernels():
+    """D = 64 from S = 2048 up is a streamed shape on the chip and here."""
+    from incubator_mxnet_tpu.ops import attention as A
+    q, k, v = _rand_qkv(B=1, H=1, S=2048, D=64)
+    assert A.attention_route(q.shape) == "streamed"
+    assert A.flash_attention_supported(q.shape)
+    loss = lambda attn: lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v)))  # noqa: E731
+    text = str(jax.make_jaxpr(jax.grad(loss(
+        lambda q, k, v: A.flash_attention(q, k, v, True)), (0, 1, 2)))(q, k, v))
+    assert text.count("pallas_call") == 3 and "flash_short" not in text
+    out = A.flash_attention(q, k, v, True)
+    ref = _f32_reference(q, k, v, True)
+    assert float(jnp.max(jnp.abs(out - ref))) < 2e-4
 
 
 def test_flash_cross_attention_shape_guard():
@@ -157,3 +193,157 @@ def test_flash_cross_attention_shape_guard():
     g = jax.grad(lambda q, k, v: jnp.sum(
         A.flash_attention(q, k, v, False) ** 2), (0, 1, 2))(q, k, v)
     assert all(bool(jnp.isfinite(x).all()) for x in g)
+
+
+# ------------------------------------------------------------ short family
+def _f32_reference(q, k, v, causal):
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
+        / math.sqrt(q.shape[-1])
+    if causal:
+        n = q.shape[2]
+        s = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :],
+                      s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def _short_against_reference(S, causal, dtype, spoil=lambda *g: g):
+    """Worst error of out, dq, dk, dv from the short kernels (called
+    directly: what the router sends them is another test's business),
+    as a share of the reference's largest value. ``spoil`` stands in for a
+    faulty backward kernel."""
+    from incubator_mxnet_tpu.ops import attention as A
+    rng = onp.random.RandomState(S)
+    # (B, H) = (2, 4): two lane blocks of a head pair, one grid step each
+    q, k, v, w = (jnp.asarray(rng.randn(2, 4, S, 64), jnp.float32)
+                  .astype(dtype) for _ in range(4))
+    scale = 1.0 / math.sqrt(64)
+    out, lse = A._short_call(q, k, v, causal, scale, True)
+    grads = spoil(*A._short_bwd_call(q, k, v, lse, w, causal, scale, True))
+    assert out.dtype == dtype and all(g.dtype == dtype for g in grads)
+    ref_out, vjp = jax.vjp(lambda q, k, v: _f32_reference(q, k, v, causal),
+                           q, k, v)
+    ref_grads = vjp(w.astype(jnp.float32))
+    return max(float(jnp.abs(got.astype(jnp.float32) - want).max()
+                     / jnp.abs(want).max())
+               for got, want in zip((out,) + tuple(grads),
+                                    (ref_out,) + tuple(ref_grads)))
+
+
+# one rounding of a bf16 operand or output is 2^-9 of its value; float32
+# operands leave only the order of the sums
+_SHORT_LIMIT = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("S", [128, 256, 512])
+def test_short_kernels_match_float32_reference(S, causal, dtype):
+    assert _short_against_reference(S, causal, dtype) < _SHORT_LIMIT[dtype]
+
+
+@pytest.mark.parametrize("fault", ["negated dQ", "zeroed dK", "swapped dV"])
+def test_short_reference_check_catches_a_faulty_backward(fault):
+    spoil = {"negated dQ": lambda dq, dk, dv: (-dq, dk, dv),
+             "zeroed dK": lambda dq, dk, dv: (dq, jnp.zeros_like(dk), dv),
+             "swapped dV": lambda dq, dk, dv: (dq, dk, dv[:, ::-1])}[fault]
+    assert _short_against_reference(256, False, jnp.bfloat16, spoil) \
+        > 10 * _SHORT_LIMIT[jnp.bfloat16]
+
+
+def test_short_path_honours_a_callers_scale():
+    """Ulysses passes its own scale through flash_attention."""
+    from incubator_mxnet_tpu.ops import attention as A
+    q, k, v = _rand_qkv(S=256, D=64)
+    assert A.attention_route(q.shape) == "short"
+    f = lambda attn: jax.value_and_grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v))), (0, 1, 2))(q, k, v)
+    (got, g), (want, gr) = (
+        f(lambda q, k, v: A.flash_attention(q, k, v, True, 0.3)),
+        f(lambda q, k, v: A._blocked_reference(q, k, v, True, 0.3)))
+    assert abs(float(got - want)) < 1e-3 * abs(float(want))
+    for a, b in zip(g, gr):
+        assert float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))) < 1e-3
+
+
+_BERT_CELL, _GPT_CELL = (16, 16, 512, 64), (1, 16, 16384, 128)
+
+
+@pytest.fixture()
+def as_on_a_tpu(monkeypatch):
+    """No device, no interpreter: the platform test answered 'tpu'."""
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET")
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")])
+
+
+@pytest.mark.parametrize("q_shape,k_shape,route", [
+    (_BERT_CELL, None, "short"),               # bert-large.train-s512
+    (_GPT_CELL, None, "streamed"),             # cerebras-gpt-1.3b.train-s16k
+    ((1, 2, 200, 128), None, "composite"),     # no block divides S
+    ((2, 16, 4096, 64), None, "streamed"),     # narrow but long
+    ((2, 4, 512, 128), None, "streamed"),      # wide heads stay streamed
+    ((8, 16, 768, 64), None, "short"),         # the longest short tile
+    ((8, 16, 1024, 64), None, "composite"),    # narrow, past it, under 2048
+    ((32, 16, 128, 64), None, "composite"),    # narrow, too short to win
+    ((16, 8, 512, 96), None, "composite"),     # heads do not tile 128 lanes
+    ((16, 1, 512, 64), None, "composite"),     # one head: half a lane block
+    ((8, 8, 512, 32), None, "short"),          # four heads a lane block
+    ((8, 16, 512, 16), None, "composite"),     # eight: never timed
+    ((4, 16, 200, 64), None, "composite"),     # narrow, no whole lane tiles
+    ((1, 2, 256, 64), (1, 2, 384, 64), "composite"),    # cross-attention
+    ((1, 2, 256, 128), (1, 2, 384, 128), "composite"),
+], ids=str)
+def test_route_is_a_function_of_the_shape(q_shape, k_shape, route,
+                                          as_on_a_tpu, monkeypatch):
+    from incubator_mxnet_tpu.ops import attention as A
+
+    def check():
+        assert A.attention_route(q_shape, k_shape, k_shape) == route
+        if k_shape is None:     # what ring and Ulysses ask, of their q alone
+            assert A.flash_attention_supported(q_shape) == (
+                route == "streamed")
+    check()
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")         # same table
+    check()
+
+
+def test_kernels_route_nothing_off_the_tpu(monkeypatch):
+    from incubator_mxnet_tpu.ops import attention as A
+    assert A.attention_route(_BERT_CELL) == "short"           # interpreted
+    assert A.flash_attention_supported((1, 2, 128, 128))       # likewise
+    assert not A.flash_attention_supported((1, 2, 128, 8))     # as on a chip
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET")                # this CPU host
+    assert A.attention_route(_BERT_CELL) == "composite"
+    assert A.attention_route(_GPT_CELL) == "composite"
+    assert not A.flash_attention_supported(_GPT_CELL)
+
+
+def test_supported_still_means_the_streamed_kernels_take_it(as_on_a_tpu):
+    """What ring and Ulysses ask before flash_attention_lse, which has only
+    the streamed kernels: never a short-family shape on the chip."""
+    from incubator_mxnet_tpu.ops import attention as A
+    assert not A.flash_attention_supported(_BERT_CELL)
+    assert not A.flash_attention_supported((8, 16, 1024, 64))
+    assert A.flash_attention_supported(_GPT_CELL)
+    assert A.flash_attention_supported((2, 16, 4096, 64))
+    assert not A.flash_attention_supported((1, 2, 200, 128))
+
+
+def test_route_counter_counts_traced_calls():
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.ops import attention as A
+    before = {r: A._ROUTES.value(route=r)
+              for r in ("short", "streamed", "composite")}
+    f = jax.jit(lambda q, k, v: A.flash_attention(q, k, v))
+    for _ in range(3):                       # traced once, run three times
+        f(*_rand_qkv(S=256, D=64))
+    A.flash_attention(*_rand_qkv(S=256, D=128))
+    A.flash_attention(*_rand_qkv(S=200, D=64))
+    after = {r: A._ROUTES.value(route=r) for r in before}
+    assert {r: after[r] - before[r] for r in before} == {
+        "short": 1, "streamed": 1, "composite": 1}
+    assert 'mxtpu_attention_route_total{route="short"}' \
+        in telemetry.REGISTRY.export_text()

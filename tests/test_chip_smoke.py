@@ -86,7 +86,7 @@ def test_flash_on_a_mesh_is_wrapped_in_shard_map(monkeypatch):
     import numpy as onp
     from incubator_mxnet_tpu.parallel import ring_attention
     monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
-    monkeypatch.setattr(A, "flash_attention_supported", lambda *a, **k: True)
+    monkeypatch.setattr(A, "flash_attention_legal", lambda *a, **k: True)
     mesh = Mesh(onp.array(jax.devices()[:4]).reshape(2, 2), ("dp", "sp"))
     x = jax.ShapeDtypeStruct((4, 2, 512, 128), jnp.bfloat16,
                              sharding=NamedSharding(mesh, P("dp")))
@@ -104,6 +104,32 @@ def test_flash_on_a_mesh_is_wrapped_in_shard_map(monkeypatch):
     text = _lower_for_tpu(grads(lambda q, k, v: ring_attention(
         q, k, v, mesh=mesh, axis="sp")), x, x, x)
     assert "tpu_custom_call" in text
+
+
+def test_short_kernels_on_a_mesh_lower_inside_shard_map(monkeypatch):
+    """The same guard for the short family, at the dp4 cell's shape: each
+    device gets BERT-large's (16, 16, 512, 64) and runs flash_short_fwd and
+    flash_short_bwd under the manual shard_map."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as onp
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    monkeypatch.setattr(A, "_kernels_run_here", lambda: True)
+    mesh = Mesh(onp.array(jax.devices()[:4]), ("dp",))
+    x = jax.ShapeDtypeStruct((64, 16, 512, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+
+    def grads(attn):
+        return jax.grad(lambda q, k, v: attn(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _lower_for_tpu(grads(lambda q, k, v: A.flash_attention(q, k, v)),
+                       x, x, x)
+    text = _lower_for_tpu(grads(lambda q, k, v: A.flash_attention_on_mesh(
+        q, k, v, mesh, batch_axis="dp")), x, x, x)
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("flash_short_fwd", "flash_short_bwd"):
+        assert 'kernel_name = "%s"' % kernel in text
 
 
 def test_mesh_train_step_hands_flash_its_mesh(monkeypatch):

@@ -430,10 +430,12 @@ def test_ring_attention_flash_local_step(monkeypatch):
     _need_devices(8)
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     import jax.numpy as jnp
-    from incubator_mxnet_tpu.ops.attention import flash_attention_supported
+    from incubator_mxnet_tpu.ops.attention import (attention_route,
+                                                   flash_attention_supported)
     mesh = parallel.make_mesh({"sp": 8})
-    B, H, S, D = 1, 2, 1024, 8
+    B, H, S, D = 1, 2, 1024, 128   # D=128: the streamed kernels, by shape
     assert flash_attention_supported((B, H, S // 8, D))  # kernel engages
+    assert attention_route((B, H, S // 8, D)) == "streamed"
     rng = onp.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(B, H, S, D).astype("float32")) * 0.3
                for _ in range(3))
@@ -447,6 +449,8 @@ def test_ring_attention_flash_local_step(monkeypatch):
         p = jax.nn.softmax(s, -1)
         return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda q, k, v: parallel.ring_attention(q, k, v, mesh=mesh))(q, k, v))
     for causal in (False, True):
         out = parallel.ring_attention(q, k, v, mesh=mesh, causal=causal)
         want = ref(q, k, v, causal)
@@ -468,10 +472,12 @@ def test_ulysses_attention_flash_local_step(monkeypatch):
     _need_devices(8)
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     import jax.numpy as jnp
-    from incubator_mxnet_tpu.ops.attention import flash_attention_supported
+    from incubator_mxnet_tpu.ops.attention import (attention_route,
+                                                   flash_attention_supported)
     mesh = parallel.make_mesh({"sp": 8})
-    B, H, S, D = 1, 8, 256, 8
+    B, H, S, D = 1, 8, 256, 128    # D=128: the streamed kernels, by shape
     assert flash_attention_supported((B, H // 8, S, D))  # kernel engages
+    assert attention_route((B, H // 8, S, D)) == "streamed"
     rng = onp.random.RandomState(1)
     q, k, v = (jnp.asarray(rng.randn(B, H, S, D).astype("float32")) * 0.3
                for _ in range(3))
@@ -485,6 +491,8 @@ def test_ulysses_attention_flash_local_step(monkeypatch):
         p = jax.nn.softmax(s, -1)
         return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda q, k, v: parallel.ulysses_attention(q, k, v, mesh=mesh))(q, k, v))
     for causal in (False, True):
         out = parallel.ulysses_attention(q, k, v, mesh=mesh, causal=causal)
         want = ref(q, k, v, causal)
