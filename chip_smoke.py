@@ -2,7 +2,8 @@
 
 One process, no arguments: drives the system's main paths once, through the
 public package, at the full width of the models bench.py times — the Pallas
-flash-attention kernels against a reference, BERT and a long-context GPT
+flash-attention kernels against a reference, the dropless expert dispatch
+against its dense form, BERT and a long-context GPT
 through gluon.Trainer -> jit.TrainStep with those kernels, ResNet-50 training, ResNet-50 behind the HTTP
 server, the generative engine, and (on a host with >= 4 chips) the dp and
 dp x sp mesh steps — and checks what comes out. Weights are random from a
@@ -33,7 +34,7 @@ import threading
 import time
 import urllib.request
 
-PHASES = ("kernels", "bert", "gpt", "resnet", "serve", "generate",
+PHASES = ("kernels", "moe", "bert", "gpt", "resnet", "serve", "generate",
           "multichip")
 
 # the bench's widths (bench.py bench_transformer / bench_long_context / main)
@@ -43,6 +44,8 @@ FULL = {
     # its longest tile with the causal mask)
     "kernels": [((2, 4, 512, 128), False), ((1, 4, 2048, 128), True),
                 ((16, 16, 512, 64), False), ((8, 16, 768, 64), True)],
+    # OLMoE's expert layer: 64 experts of 2048 -> 1024, 8 per token
+    "moe": dict(T=4096, U=2048, I=1024, E=64, K=8),
     "bert": dict(B=64, S=512, V=32768, U=1024, L=12, H=8),
     "gpt": dict(S=8192, V=32768, U=1024, L=4, H=8),
     "resnet": dict(B=256, HW=224),
@@ -54,6 +57,7 @@ FULL = {
 TOY = {
     "kernels": [((1, 2, 128, 128), False), ((1, 2, 256, 128), True),
                 ((1, 2, 256, 64), False)],
+    "moe": dict(T=64, U=32, I=16, E=8, K=2),
     "bert": dict(B=4, S=128, V=512, U=256, L=1, H=2),
     "gpt": dict(S=256, V=512, U=256, L=1, H=2),
     "resnet": dict(B=16, HW=64),
@@ -126,6 +130,89 @@ def phase_kernels(cases, on_chip, shared):
     return compile_s, statistics.median(steady), \
         "out/dq/dk/dv vs float32 reference at %s, worst error %.2g of max" \
         % ([c[0] for c in cases], worst)
+
+
+# ----------------------------------------------------------------------- moe
+def phase_moe(cfg, on_chip, shared):
+    """parallel.moe.dropless_moe (sort, gather, the grouped matmuls, un-sort
+    and combine) and its gradients against the dense form — every expert on
+    every token in float32, a (T, E) matrix of weights picking what counts
+    — on the device, at OLMoE's expert layer. A new libtpu that lowers
+    jax.lax.ragged_dot differently fails here first."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.parallel.moe import dropless_moe
+    T, U, I, E, K = (cfg[k] for k in "TUIEK")
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    x, probe = (jax.random.normal(k, (T, U), jnp.float32)
+                .astype(jnp.bfloat16) for k in keys[:2])
+    gate, up = (jax.random.normal(k, (E, U, I), jnp.float32)
+                .astype(jnp.bfloat16) / math.sqrt(U) for k in keys[2:4])
+    down = (jax.random.normal(keys[4], (E, I, U), jnp.float32)
+            / math.sqrt(I)).astype(jnp.bfloat16)
+    gates = jax.nn.softmax(
+        1.4 * jax.random.normal(keys[5], (T, E), jnp.float32), -1)
+    top_vals, top_idx = jax.lax.top_k(gates, K)
+
+    def dense(x, top_vals, gate, up, down):
+        x, gate, up, down = (a.astype(jnp.float32)
+                             for a in (x, gate, up, down))
+        weight = jnp.zeros((T, E), jnp.float32) \
+            .at[jnp.arange(T)[:, None], top_idx].set(top_vals)
+
+        def one(out, expert):
+            g, u, d, w_e = expert
+            h = jax.nn.silu(jnp.dot(x, g, precision="highest")) \
+                * jnp.dot(x, u, precision="highest")
+            return out + w_e[:, None] * jnp.dot(h, d, precision="highest"), \
+                None
+
+        return jax.lax.scan(jax.checkpoint(one), jnp.zeros((T, U)),
+                            (gate, up, down, weight.T))[0]
+
+    def dropless(x, top_vals, gate, up, down):
+        return dropless_moe(x, top_vals, top_idx, up, down, jax.nn.silu, gate)
+
+    def both(fn):
+        def f(*args):
+            out = fn(*args)
+            return (out.astype(jnp.float32)
+                    * probe.astype(jnp.float32)).sum(), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                          has_aux=True))
+
+    args = (x, top_vals, gate, up, down)
+    system = both(dropless)
+    if on_chip and "ragged" not in system.lower(*args).compile().as_text():
+        raise RuntimeError("dropless_moe compiled without a grouped matmul")
+    t0 = time.perf_counter()
+    (_, out), grads = jax.block_until_ready(system(*args))
+    compile_s = time.perf_counter() - t0
+    steady = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(system(*args))
+        steady.append(time.perf_counter() - t0)
+    (_, ref_out), ref_grads = both(dense)(*args)
+    worst = 0.0
+    for name, got, want in zip(("out", "dx", "dp", "dgate", "dup", "ddown"),
+                               (out,) + grads, (ref_out,) + ref_grads):
+        got, want = (jnp.asarray(a, jnp.float32) for a in (got, want))
+        err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        worst = max(worst, err)
+        # bf16 rows and hidden layer, float32 sums: a few roundings of 2^-8
+        if not err <= 3e-2:
+            raise RuntimeError("dropless_moe %s: max error %.3g of the "
+                               "dense form's max" % (name, err))
+    counts = jnp.bincount(top_idx.reshape(-1), length=E)
+    flops = 6 * K * 3 * U * I * T
+    return compile_s, statistics.median(steady), \
+        "out and 5 gradients vs the dense float32 form at %d tokens, %d " \
+        "experts top-%d of %d -> %d (rows per expert %d..%d), worst error " \
+        "%.2g of max%s" % (
+            T, E, K, U, I, int(counts.min()), int(counts.max()), worst,
+            "; %.1f TFLOP/s of required expert FLOPs over the whole call"
+            % (flops / statistics.median(steady) / 1e12) if on_chip else "")
 
 
 # ------------------------------------------------------------------ training
@@ -559,6 +646,7 @@ def main():
     on_chip = not args.rehearse
     shared = {}
     table = {"kernels": (phase_kernels, cfgs["kernels"]),
+             "moe": (phase_moe, cfgs["moe"]),
              "bert": (phase_bert, cfgs["bert"]),
              "gpt": (phase_gpt, cfgs["gpt"]),
              "resnet": (phase_resnet, cfgs["resnet"]),

@@ -4,12 +4,12 @@ from __future__ import annotations
 import numpy as onp
 
 from ... import ndarray as nd
-from ...ndarray import NDArray
+from ...ndarray import NDArray, _apply
 from ..block import Block, HybridBlock
 from ..parameter import Parameter
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Embedding",
-           "BatchNorm", "SyncBatchNorm", "InstanceNorm", "LayerNorm", "GroupNorm", "Flatten",
+           "BatchNorm", "SyncBatchNorm", "InstanceNorm", "LayerNorm", "RMSNorm", "GroupNorm", "Flatten",
            "Lambda", "HybridLambda", "Activation", "LeakyReLU", "PReLU", "ELU",
            "SELU", "Swish", "GELU", "Identity"]
 
@@ -256,6 +256,40 @@ class LayerNorm(HybridBlock):
                 p._finish_deferred_init()
         return nd.LayerNorm(x, self.gamma.data(), self.beta.data(),
                             axis=self._axis, eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """y = x / sqrt(mean(x^2, axis) + epsilon) * gamma (Zhang & Sennrich
+    2019): LayerNorm without the mean and the shift. The statistics are
+    float32 whatever the input's type, as nd.LayerNorm's are."""
+
+    def __init__(self, axis=-1, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._axis = axis
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init=gamma_initializer,
+                                         allow_deferred_init=True)
+
+    def forward(self, x):
+        if self.gamma._data is None:
+            self.gamma.shape = (x.shape[self._axis],)
+            self.gamma._finish_deferred_init()
+        import jax.numpy as jnp
+        from jax import lax
+        axis, eps = self._axis, self._epsilon
+
+        def fn(x, g):
+            xf = x.astype(jnp.float32)
+            ms = jnp.mean(xf * xf, axis=axis, keepdims=True)
+            shape = [1] * x.ndim
+            shape[axis] = x.shape[axis]
+            return (xf * lax.rsqrt(ms + eps)
+                    * g.astype(jnp.float32).reshape(shape)).astype(x.dtype)
+
+        return _apply(fn, x, self.gamma.data())
 
 
 class GroupNorm(HybridBlock):

@@ -5,12 +5,15 @@
 - bert:       BERT-base pretraining w/ TP + ring-attention SP (config 3)
 - ssd:        SSD object detection w/ MultiBox ops (config 4)
 - lstm_lm:    LSTM language model (config 5)
+- olmoe:      OLMoE decoder (RoPE + QK-norm attention, dropless SwiGLU MoE)
 """
 from .lenet import LeNet  # noqa
 from .bert import (BERTEncoder, BERTModel, TransformerEncoderLayer,  # noqa
                    MultiHeadAttention, ChunkedMLMLoss)
 from .gpt import (GPTModel, TransformerDecoderLayer, ChunkedLMLoss,  # noqa
                   FeaturesView)
+from .olmoe import (OLMoEModel, OLMoETransformerDecoderLayer,  # noqa
+                    RotaryMultiHeadAttention, ChunkedUntiedLMLoss)
 from .lstm_lm import LSTMLanguageModel  # noqa
 from .ssd import SSD  # noqa
 from ..gluon.model_zoo.vision import get_model  # noqa

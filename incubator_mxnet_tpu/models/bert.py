@@ -24,7 +24,7 @@ from .lm_head import ChunkedHeadLossBase
 
 class MultiHeadAttention(HybridBlock):
     def __init__(self, units, num_heads, dropout=0.0, attention="dense",
-                 sp_axis="sp", tp_axis=None, causal=False, **kwargs):
+                 sp_axis="sp", tp_axis=None, causal=False, use_bias=True, **kwargs):
         super().__init__(**kwargs)
         assert units % num_heads == 0
         self._units = units
@@ -35,24 +35,24 @@ class MultiHeadAttention(HybridBlock):
         self._tp_axis = tp_axis
         self._causal = causal
         with self.name_scope():
-            self.query = nn.Dense(units, flatten=False, in_units=units)
-            self.key = nn.Dense(units, flatten=False, in_units=units)
-            self.value = nn.Dense(units, flatten=False, in_units=units)
-            self.proj = nn.Dense(units, flatten=False, in_units=units)
+            self.query = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
+            self.key = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
+            self.value = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
+            self.proj = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
         if tp_axis:
             # shard heads over tp: qkv col-parallel, out proj row-parallel
             for lyr in (self.query, self.key, self.value):
                 lyr.weight.sharding = P(tp_axis, None)
-                lyr.bias.sharding = P(tp_axis)
+                if use_bias:
+                    lyr.bias.sharding = P(tp_axis)
             self.proj.weight.sharding = P(None, tp_axis)
 
     def forward(self, x, mask=None):
         B, S, U = x.shape
         H = self._num_heads
         D = U // H
-        q = self.query(x).reshape((B, S, H, D)).transpose((0, 2, 1, 3))
-        k = self.key(x).reshape((B, S, H, D)).transpose((0, 2, 1, 3))
-        v = self.value(x).reshape((B, S, H, D)).transpose((0, 2, 1, 3))
+        # (B, H, S, D) each; a subclass's QK-norm and RoPE live in project()
+        q, k, v = self.project(x)
 
         causal = self._causal
         if self._attention == "ring":
@@ -105,6 +105,19 @@ class MultiHeadAttention(HybridBlock):
             out = nd.batch_dot(attn, v.reshape((B * H, S, D))).reshape((B, H, S, D))
         out = out.transpose((0, 2, 1, 3)).reshape((B, S, U))
         return self.proj(out)
+
+    def split_heads(self, t):
+        """(B, S, U) -> (B, H, S, D)."""
+        B, S, U = t.shape
+        H = self._num_heads
+        return t.reshape((B, S, H, U // H)).transpose((0, 2, 1, 3))
+
+    def project(self, x):
+        """x (B, S, U) -> q, k, v, each (B, H, S, D). What a subclass
+        changes between the projections and the scores (QK-norm, RoPE)
+        goes here; the attention itself is shared."""
+        return tuple(self.split_heads(lyr(x))
+                     for lyr in (self.query, self.key, self.value))
 
 
 class TransformerEncoderLayer(HybridBlock):

@@ -229,22 +229,39 @@ def test_pipeline_grad_through_ring():
     assert_almost_equal(onp.asarray(gp), onp.asarray(gs), rtol=1e-3, atol=1e-5)
 
 
+def _dense_form_of(layer, x):
+    """The layer's output by the dense O(T*E) form (tests/moe_dense.py)."""
+    import jax.numpy as jnp
+    from moe_dense import dense_moe
+    tokens = x._data.reshape(-1, x.shape[-1])
+    gates = jax.nn.softmax(tokens @ layer.gate_weight.data()._data.T, -1)
+    top_vals, top_idx = jax.lax.top_k(gates, layer.top_k)
+    top_vals = top_vals / jnp.sum(top_vals, -1, keepdims=True)
+    return onp.asarray(dense_moe(
+        tokens, top_vals, top_idx, layer.w1.data()._data,
+        layer.w2.data()._data, jax.nn.relu)).reshape(x.shape)
+
+
 def test_moe_layer():
     _need_devices(8)
     mesh = parallel.make_mesh({"ep": 8})
     layer = parallel.MoELayer(num_experts=8, hidden_size=16, ffn_hidden=32,
-                              top_k=2, capacity_factor=1.25)
+                              top_k=2)
     layer.initialize()
+    assert layer.w1.sharding == jax.sharding.PartitionSpec("ep", None, None)
     x = nd.random.normal(shape=(4, 6, 16))
     out = layer(x)
     assert out.shape == (4, 6, 16)
-    assert bool(onp.isfinite(out.asnumpy()).all())
-    # the dense default at scale is a documented footgun -> warns
+    assert_almost_equal(out.asnumpy(), _dense_form_of(layer, x), rtol=1e-4,
+                        atol=1e-5)
+    # a capacity no longer exists: asking for one warns and drops nothing
     import warnings as _w
     with _w.catch_warnings(record=True) as rec:
         _w.simplefilter("always")
-        parallel.MoELayer(num_experts=8, hidden_size=4, ffn_hidden=8)
+        capped = parallel.MoELayer(num_experts=8, hidden_size=4,
+                                   ffn_hidden=8, capacity_factor=0.5)
     assert any("capacity_factor" in str(w.message) for w in rec)
+    assert not hasattr(capped, "capacity_factor")
 
 
 def test_moe_router_z_loss():
@@ -272,24 +289,25 @@ def test_moe_router_z_loss():
     assert float(a2.asnumpy()) > float(a1.asnumpy())
 
 
-def test_moe_capacity_and_aux_loss():
-    # With ample capacity no token is dropped → capacity path == dense path.
+def test_moe_dropless_and_aux_loss():
+    # Nothing is dropped: the one dispatch equals the dense form, however
+    # few tokens there are for how many experts.
     layer = parallel.MoELayer(num_experts=4, hidden_size=8, ffn_hidden=16,
-                              top_k=2, capacity_factor=None)
+                              top_k=2)
     layer.initialize()
     x = nd.random.normal(shape=(3, 5, 8))
-    dense, aux = layer.forward_with_aux(x)
-    assert dense.shape == (3, 5, 8)
+    out, aux = layer.forward_with_aux(x)
+    assert out.shape == (3, 5, 8)
     # aux loss is >= 1 (equals 1 at perfect balance) and finite
     a = float(aux.asnumpy())
     assert a >= 0.99 and onp.isfinite(a)
-    layer.capacity_factor = 100.0  # capacity >> tokens → no drops
-    capped = layer(x)
-    assert_almost_equal(capped.asnumpy(), dense.asnumpy(), rtol=1e-4, atol=1e-5)
-    # Tight capacity drops tokens but stays finite and differs from dense.
-    layer.capacity_factor = 0.5
-    dropped = layer(x)
-    assert bool(onp.isfinite(dropped.asnumpy()).all())
+    assert_almost_equal(out.asnumpy(), _dense_form_of(layer, x), rtol=1e-4,
+                        atol=1e-5)
+    assert_almost_equal(layer(x).asnumpy(), out.asnumpy(), rtol=0, atol=0)
+    # one token, more experts than tokens: still every choice served
+    one = nd.random.normal(shape=(1, 1, 8))
+    assert_almost_equal(layer(one).asnumpy(), _dense_form_of(layer, one),
+                        rtol=1e-4, atol=1e-5)
 
 
 def test_kvstore_pull_isolation():
