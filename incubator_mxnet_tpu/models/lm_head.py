@@ -15,13 +15,14 @@ __all__ = ["ChunkedHeadLossBase"]
 
 class ChunkedHeadLossBase:
     """Loss head fusing a (V, U) vocab projection with the CHUNKED
-    softmax-CE (ops/lm_ce.py): the full (T, V) logits never materialize —
-    the vocab-CE HBM lever measured in docs/PERF_BERT.md. Pair with
-    ``FeaturesView(model)`` so TrainStep feeds the trunk activations."""
+    softmax-CE (ops/lm_ce.py): the full (T, V) logits never materialize
+    (what the chunking costs and saves on a v5e: PERF.md S6, PR 28). Pair
+    with ``FeaturesView(model)`` so TrainStep feeds the trunk activations."""
 
     def __init__(self, model, chunk=None):
-        # chunk=None auto-routes (ops/lm_ce.py): dense below ~128 MB of
-        # logits, ~32 MB chunks above — default-on for long-T/large-V
+        # chunk=None auto-routes (ops/lm_ce.py): dense below 128 MiB of
+        # float32 logits; above, 512 to 1024 rows a chunk, chosen from
+        # (T, V) alone so that a trip's four matmuls are compute-bound
         self._model = model
         self._chunk = chunk
 

@@ -2,6 +2,8 @@
 vocab-softmax HBM lever from docs/PERF_BERT.md: parity with the dense
 logits+softmax path, gradient flow into the tied embedding, and the
 structural guarantee that the full (T, V) logits never materialize."""
+import math
+
 import numpy as onp
 import pytest
 
@@ -11,6 +13,20 @@ import jax.numpy as jnp
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import nd, gluon, jit, models
 from incubator_mxnet_tpu.ops.lm_ce import chunked_lm_cross_entropy
+
+
+def _largest_intermediate(jaxpr):
+    """Elements of the largest value any equation of a jaxpr produces,
+    nested jaxprs (scan and checkpoint bodies) included."""
+    best = 0
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            best = max(best, math.prod(shape) if shape else 0)
+        for sub in eqn.params.values():
+            if hasattr(sub, "jaxpr"):
+                best = max(best, _largest_intermediate(sub.jaxpr))
+    return best
 
 
 def test_chunked_ce_matches_dense():
@@ -52,21 +68,7 @@ def test_chunked_ce_never_materializes_full_logits():
     jaxpr = jax.make_jaxpr(
         jax.grad(lambda h, w: chunked_lm_cross_entropy(h, w, y, chunk)
                  .sum(), argnums=(0, 1)))(h, w)
-
-    import math
-
-    def walk(jx):
-        for eqn in jx.eqns:
-            for var in eqn.outvars:
-                shape = getattr(var.aval, "shape", ())
-                size = math.prod(shape) if shape else 0
-                assert size < T * V, \
-                    "full-logits-sized intermediate: %s" % (shape,)
-            for sub in eqn.params.values():
-                if hasattr(sub, "jaxpr"):
-                    walk(sub.jaxpr)
-
-    walk(jaxpr.jaxpr)
+    assert _largest_intermediate(jaxpr.jaxpr) < T * V
 
 
 def test_chunked_ce_non_dividing_stays_chunked():
@@ -80,19 +82,7 @@ def test_chunked_ce_non_dividing_stays_chunked():
     # chunk=40, T=96 -> padded to 120, 3 chunks of 40 (never dense)
     jaxpr = jax.make_jaxpr(
         lambda h, w: chunked_lm_cross_entropy(h, w, y, 40).sum())(h, w)
-    import math
-
-    def max_size(jx, best=0):
-        for eqn in jx.eqns:
-            for var in eqn.outvars:
-                shape = getattr(var.aval, "shape", ())
-                best = max(best, math.prod(shape) if shape else 0)
-            for sub in eqn.params.values():
-                if hasattr(sub, "jaxpr"):
-                    best = max(best, max_size(sub.jaxpr, best))
-        return best
-
-    assert max_size(jaxpr.jaxpr) < T * V  # never the dense block
+    assert _largest_intermediate(jaxpr.jaxpr) < T * V  # never dense
     # and the values still match the dense computation
     dense_logits = h @ w.T
     lse = jax.nn.logsumexp(dense_logits, axis=-1)
@@ -133,9 +123,9 @@ def test_gpt_chunked_loss_trains_and_ties_embedding():
 
 
 def test_auto_chunk_routing():
-    """chunk=None: dense (one chunk) below the 128 MB logits threshold,
-    ~32 MB chunks above. Parity asserted across genuinely DIFFERENT
-    lowerings (auto-dense vs explicit small chunks, even and odd T)."""
+    """chunk=None: dense (one chunk) below the 128 MiB logits threshold.
+    Parity asserted across genuinely DIFFERENT lowerings (auto-dense vs
+    explicit small chunks, even and odd T)."""
     from incubator_mxnet_tpu.ops import lm_ce
     U = 8
     for T in (256, 251):             # odd/prime T takes the padding path
@@ -146,11 +136,98 @@ def test_auto_chunk_routing():
         small = lm_ce.chunked_lm_cross_entropy(h, w, y, chunk=64)
         onp.testing.assert_allclose(onp.asarray(auto), onp.asarray(small),
                                     rtol=1e-4, atol=1e-5)
-    # the auto chunk picker at scale: T=32k, V=32k -> 4 GB logits ->
-    # 32 MB blocks of 256 tokens
-    T, V = 32768, 32768
+
+
+def _scans(jaxpr):
+    """Every scan equation in a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in eqn.params.values():
+            if hasattr(sub, "jaxpr"):
+                yield from _scans(sub.jaxpr)
+
+
+def _abstract(T, V, U):
+    return (jax.ShapeDtypeStruct((T, U), jnp.bfloat16),
+            jax.ShapeDtypeStruct((V, U), jnp.bfloat16),
+            jax.ShapeDtypeStruct((T,), jnp.int32))
+
+
+# (T, V, U) of heads users train: the GPT cells, OLMoE, BERT-large's MLM
+# head at 16 x 512, a 32k x 32k pre-training batch, Llama-3's and Gemma's
+# vocabularies, and a token count nothing divides
+@pytest.mark.parametrize("T,V,U", [
+    (16384, 50257, 2048), (16384, 50304, 2048), (8192, 30522, 1024),
+    (32768, 32768, 1024), (8192, 128256, 4096), (4096, 262144, 2048),
+    (16385, 50257, 2048), (8193, 30522, 1024), (4096, 524288, 1024)])
+def test_auto_rows_over_the_ridge_and_under_the_ceiling(T, V, U):
+    """The picker reads the shape alone: whole MXU passes, never under
+    twice the v5e's ridge, a chunk's float32 logits under the ceiling
+    wherever the floor leaves room, and next to no padded rows. The op
+    traced at that shape (nothing runs) loops over exactly those rows."""
+    from incubator_mxnet_tpu.ops import lm_ce
     assert T * V * 4 > lm_ce._DENSE_BYTES
-    assert lm_ce._BLOCK_BYTES // (V * 4) == 256
+    rows = lm_ce._auto_rows(T, V)
+    assert rows % 256 == 0 and rows >= 512
+    if 512 * V * 4 <= lm_ce._CEILING_BYTES:
+        assert rows * V * 4 <= lm_ce._CEILING_BYTES
+    else:
+        assert rows == 512              # V > 256 k: the floor wins
+    trips = -(-T // rows)
+    assert trips * rows - T <= T // 32
+    if T % 512 == 0:
+        assert T % rows == 0            # no padded row where T allows
+    jaxpr = jax.make_jaxpr(lm_ce.chunked_lm_cross_entropy)(
+        *_abstract(T, V, U))
+    scan, = _scans(jaxpr.jaxpr)
+    assert scan.params["length"] == trips
+    assert scan.params["jaxpr"].jaxpr.invars[-2].aval.shape == (rows, U)
+
+
+@pytest.mark.parametrize("T,V", [(512, 50257), (2048, 16384), (1024, 32768)])
+def test_auto_route_is_dense_under_128_mib(T, V):
+    from incubator_mxnet_tpu.ops import lm_ce
+    assert T * V * 4 <= lm_ce._DENSE_BYTES
+    jaxpr = jax.make_jaxpr(lm_ce.chunked_lm_cross_entropy)(
+        *_abstract(T, V, 64))
+    assert not list(_scans(jaxpr.jaxpr))
+
+
+def test_auto_routed_grad_loops_over_chunks_and_never_holds_full_logits():
+    """grad of the op at the GPT cells' (T, V), traced on abstract inputs
+    (nothing runs): the backward is one scan of T / rows trips, and no
+    intermediate anywhere has T x V elements
+    (test_chunked_ce_never_materializes_full_logits at the auto size)."""
+    from incubator_mxnet_tpu.ops import lm_ce
+    T, V, U = 16384, 50257, 64
+    rows = lm_ce._auto_rows(T, V)
+    h, w, y = _abstract(T, V, U)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda h, w, y: lm_ce.chunked_lm_cross_entropy(h, w, y)
+        .astype(jnp.float32).sum(), argnums=(0, 1)))(h, w, y)
+    lengths = [s.params["length"] for s in _scans(jaxpr.jaxpr)]
+    assert lengths and set(lengths) == {T // rows}
+    assert _largest_intermediate(jaxpr.jaxpr) < T * V
+
+
+def test_route_counter_counts_traced_calls():
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.ops import lm_ce
+    before = {r: lm_ce._ROUTES.value(route=r) for r in ("dense", "chunked")}
+    f = jax.jit(lm_ce.chunked_lm_cross_entropy, static_argnums=3)
+    args = (jnp.zeros((64, 8)), jnp.zeros((16, 8)),
+            jnp.zeros((64,), jnp.int32))
+    for _ in range(3):                       # traced once, run three times
+        f(*args, None)
+    f(*args, 16)
+    jax.make_jaxpr(lm_ce.chunked_lm_cross_entropy)(
+        *_abstract(16384, 50257, 64))        # auto-routed over the threshold
+    after = {r: lm_ce._ROUTES.value(route=r) for r in before}
+    assert {r: after[r] - before[r] for r in before} == {
+        "dense": 1, "chunked": 2}
+    assert 'mxtpu_lm_ce_route_total{route="chunked"}' \
+        in telemetry.REGISTRY.export_text()
 
 
 def test_odd_token_count_keeps_chunk_size():
