@@ -706,12 +706,6 @@ ENV_VARS = {
         int, None,
         "Global RNG seed applied at package import (MXNET_SEED analog): "
         "seeds nd.random, np.random and the functional key stream."),
-    "MXTPU_CONV_BWD_PALLAS": (
-        bool, True,
-        "Gate for the fused Pallas conv-backward kernel (dgrad+wgrad in "
-        "one HBM pass): ops.conv_bwd.conv3x3_s1 routes its backward "
-        "through it when the shape is legal on TPU. Model-zoo convs keep "
-        "XLA's lowering (see docs/PERF_RESNET.md pilot disposition)."),
     "MXTPU_CPU_WORKER_NTHREADS": (
         int, 4,
         "Default decode/augment thread count for the native "

@@ -22,6 +22,7 @@ import time as _time
 
 import jax
 import jax.numpy as jnp
+import numpy as _onp
 
 from . import aot
 from . import autograd
@@ -72,10 +73,9 @@ __all__ = ["TrainStep", "EvalStep", "compiled_train_programs"]
 
 # Compile observability: each shared-cache (aot.CACHE) miss that cannot be
 # satisfied by a persisted artifact is one model trace + XLA compile.
-# Single-device train programs AOT-compile inside the build (jit().lower()
-# .compile() with the step's arg specs — which also hands devstats the
-# compiled program's cost/memory analysis); mesh-train wrappers still
-# compile lazily on the first dispatch. Either way the miss's whole
+# Train programs, on one device or a mesh, compile ahead inside the build
+# (jit().lower().compile() with the step's arg specs — which also hands
+# devstats the compiled program's cost/memory analysis). The miss's whole
 # first step — trace + compile + run — is what gets attributed to compile
 # time. Watching compiles_total climb under bucketed variable-shape
 # traffic is how an undersized MXTPU_AOT_CACHE_SIZE shows itself (so is
@@ -102,9 +102,9 @@ _EXAMPLES = telemetry.counter(
 
 
 def _record_compile_span(name, dur_s):
-    """Retroactive span for a just-finished compile window (jax.jit
-    compiles lazily inside the first call, so the window is only
-    measurable after the fact), parented onto the ambient step span."""
+    """Retroactive span for a just-finished compile window (it ends with
+    the miss's first run, so it is only measurable after the fact),
+    parented onto the ambient step span."""
     try:
         from . import profiler
         spans.record_span(name, profiler.now_us() - dur_s * 1e6,
@@ -133,6 +133,22 @@ def _tree_wrap(data):
     return NDArray(data)
 
 
+def _placed(x, sharding):
+    """``x`` on ``sharding``: ``x`` itself where the step has no layout
+    (None) or ``x`` is laid out so already. Equivalence, not ``==``: a
+    step's outputs come back as P('dp') where the rule says P('dp', None)."""
+    if sharding is None or x.sharding.is_equivalent_to(sharding, x.ndim):
+        return x
+    return jax.device_put(x, sharding)
+
+
+def _with_layout(f, tree, shardings):
+    """``tree`` with ``f(leaf, sharding)`` in place of each leaf;
+    ``shardings`` is flat, in ``tree_flatten``'s order."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    return treedef.unflatten([f(x, s) for x, s in zip(leaves, shardings)])
+
+
 class TrainStep:
     """Compile net forward + loss + backward + optimizer update into one program."""
 
@@ -156,8 +172,7 @@ class TrainStep:
         # remat: rematerialize the forward during backward (jax.checkpoint)
         # — trades ~1 extra forward of FLOPs for O(layer) activation memory,
         # the long-sequence HBM lever (SURVEY §7 guidance)
-        from .config import get_env
-        self.remat = get_env("MXTPU_REMAT") if remat is None else remat
+        self.remat = config.get_env("MXTPU_REMAT") if remat is None else remat
         # zero: ZeRO-1 / automatic cross-replica sharding of the weight
         # update (arXiv:2004.13336, the GSPMD-annotation form): optimizer
         # states (incl. fp32 masters) are SHARDED over the dp axis on dim 0,
@@ -168,21 +183,17 @@ class TrainStep:
         self.zero = zero
         # device truth of the most recently dispatched program (aot entry
         # stats: flops / bytes_accessed / peak_bytes / output_bytes), or
-        # None pre-dispatch / on the lazy mesh path — what bench.py's
-        # cost-analysis-derived MFU reads
+        # None pre-dispatch — what bench.py's cost-analysis-derived MFU
+        # reads
         self._last_stats = None
         # watchdog bookkeeping: counts once this instance starts stepping
         self._hb_registered = False
 
     # ------------------------------------------------------------------
-    def _split_params(self):
+    def _build(self, n_inputs):
         params = list(self.net.collect_params().values())
         trainable = [p for p in params if p.grad_req != "null"]
         frozen = [p for p in params if p.grad_req == "null"]
-        return trainable, frozen
-
-    def _build(self, meta, n_inputs):
-        trainable, frozen = self._split_params()
         t_arrs = [p.data() for p in trainable]
         f_arrs = [p.data() for p in frozen]
         net, loss_fn = self.net, self.loss_fn
@@ -235,7 +246,8 @@ class TrainStep:
         # would keep __del__ (which releases the entry) from ever running
         # — capture the needed config as plain locals instead
         grad_postprocess = self._grad_postprocess
-        constrain_update = self._make_constrainer(trainable)
+        layout = self._layout(trainable, frozen)
+        constrain_update = self._make_constrainer(layout)
 
         def step_fn(t_datas, f_datas, opt_states, input_datas, key, lrs, wds, t,
                     rescale):
@@ -271,102 +283,139 @@ class TrainStep:
                 new_t, new_opt = constrain_update(new_t, new_opt)
             return loss_full, new_t, new_opt, aux_vals
 
-        if self.mesh is not None:
-            jitted = self._jit_sharded(step_fn, trainable, frozen)
-        else:
-            jitted = jax.jit(step_fn, donate_argnums=_donate((0, 2)))
-        return jitted, trainable, frozen, t_arrs, f_arrs, aux_box
+        return step_fn, layout, trainable, t_arrs, f_arrs, aux_box
 
-    def _build_entry(self, n_inputs, arg_specs=None):
-        """aot.compile_cached build hook: (compiled callable, instance
+    def _build_entry(self, n_inputs, arrs, key):
+        """aot.compile_cached build hook: (compiled program, instance
         extras, no exported artifact — train programs stay in-memory).
 
-        With ``arg_specs`` (the single-device path), the program is
-        AOT-compiled HERE — ``jit().lower(specs).compile()`` under the
-        net's trace lock, the same explicit pipeline EvalStep uses — so
-        the XLA compile lands inside the train:build span instead of
-        lazily inside the first dispatch, and the cache entry is an
-        analyzable compiled program (devstats harvests its cost/memory
-        analysis at insert). A failed lower/compile raises to the
-        caller: a lazy retry would compile the same program again, and
-        swallowing the first error is how a compiler refusal (a Mosaic
-        kernel over its VMEM budget, an HBM OOM) gets hidden."""
-        jitted, trainable, frozen, t_arrs, f_arrs, aux_box = \
-            self._build(None, n_inputs)
-        if arg_specs is not None and self.mesh is None:
-            # the trace swaps tracers into the live param NDArrays
-            # (inner's _data swap) — hold the net's trace lock for
-            # the whole window, exactly like the eval build
-            with self._trace_lock:
-                jitted = jitted.lower(*arg_specs).compile()
-        return jitted, (trainable, frozen, t_arrs, f_arrs, aux_box), None
+        One path, whatever the mesh: the state is laid out once, HERE —
+        each parameter, frozen array and optimizer-state leaf is put onto
+        its sharding of ``_layout`` and written back into its NDArray /
+        ``trainer._states`` slot, so a dispatch passes what it holds —
+        and the program is compiled ahead, ``jit().lower(specs)
+        .compile()`` under the net's trace lock, the same explicit
+        pipeline EvalStep uses. The XLA compile lands inside the
+        train:build span and the cache entry is an analyzable compiled
+        program (devstats harvests its cost/memory analysis at insert).
+        A failed spec, lower or compile raises to the caller: a lazy
+        retry would compile the same program again, and swallowing the
+        first error is how a compiler refusal (a Mosaic kernel over its
+        VMEM budget, an HBM OOM) gets hidden."""
+        step_fn, layout, trainable, t_arrs, f_arrs, aux_box = \
+            self._build(n_inputs)
+        t_sh, f_sh, state_rules, data_sh, repl = layout
+        trainer = self.trainer
+        slots = [trainer._param2idx.get(p.name, i)
+                 for i, p in enumerate(trainable)]
+        # the lay-out writes, and the trace swaps tracers into, the live
+        # param NDArrays (inner's _data swap) — hold the net's trace lock
+        # for the whole window, exactly like the eval build
+        with self._trace_lock:
+            state = ([a._data for a in t_arrs], [a._data for a in f_arrs],
+                     [_tree_to_data(trainer._states[idx]) for idx in slots])
+            state_sh = t_sh + f_sh + [
+                rule(leaf) for st, rule in zip(state[2], state_rules)
+                for leaf in jax.tree_util.tree_leaves(st)]
+            specs = self._arg_specs(state, arrs, key, state_sh, data_sh, repl)
+            # rebound, so that nothing holds what was there before: the
+            # program loads beside the laid-out state alone
+            state = _with_layout(_placed, state, state_sh)
+            self._write_back(t_arrs + f_arrs, slots, state[0] + state[1],
+                             state[2])
+            compiled = jax.jit(
+                step_fn, donate_argnums=_donate((0, 2))).lower(*specs).compile()
+        return compiled, (slots, t_arrs, f_arrs, aux_box, state_sh,
+                          data_sh), None
 
-    def _arg_specs(self, arrs, key):
-        """jax.ShapeDtypeStruct tree matching one step_fn call — what
-        _build_entry AOT-lowers with. None (→ lazy compile, no program
-        stats) on the mesh path or when any piece is unavailable."""
-        if self.mesh is not None:
-            return None
-        try:
-            def sds(x):
-                return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
+    def _arg_specs(self, state, arrs, key, state_sh, data_sh, repl):
+        """jax.ShapeDtypeStruct tree matching one step_fn call, every
+        leaf with its sharding of the layout (None without a mesh) —
+        what _build_entry lowers with."""
+        def sds(x, sharding):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
-            trainer = self.trainer
-            trainable, frozen = self._split_params()
-            t_specs = [sds(p.data()._data) for p in trainable]
-            f_specs = [sds(p.data()._data) for p in frozen]
-            opt_specs = []
-            for i, p in enumerate(trainable):
-                idx = trainer._param2idx.get(p.name, i)
-                opt_specs.append(jax.tree_util.tree_map(
-                    sds, _tree_to_data(trainer._states[idx])))
-            in_specs = [sds(a._data) for a in arrs]
-            vec = jax.ShapeDtypeStruct((len(trainable),), jnp.float32)
-            return (t_specs, f_specs, opt_specs, in_specs, sds(key),
-                    vec, vec, jax.ShapeDtypeStruct((), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.float32))
-        except Exception:
-            _LOG.debug("train arg-spec construction failed; program "
-                       "compiles lazily on first dispatch", exc_info=True)
-            return None
+        def scalar(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
-    def _zero_leaf_sharding(self, p):
-        """Per-leaf optimizer-state sharding rule under zero=True: shard
-        dim 0 over the dp axis when divisible (masters/momenta share the
-        param shape); scalars and indivisible leaves replicate; params a
-        tensor/expert-parallel layer already sharded keep their spec."""
+        t_specs, f_specs, opt_specs = _with_layout(sds, state, state_sh)
+        vec = scalar((len(t_specs),), jnp.float32)
+        return (t_specs, f_specs, opt_specs,
+                [sds(a._data, data_sh) for a in arrs], sds(key, repl),
+                vec, vec, scalar((), jnp.int32), scalar((), jnp.float32))
+
+    def _write_back(self, p_arrs, slots, new_p, new_opt):
+        """Parameters and optimizer state into their NDArray slots."""
+        for a, d in zip(p_arrs, new_p):
+            a._data = d
+        states = self.trainer._states
+        for idx, new in zip(slots, new_opt):
+            states[idx] = _rewrap_state(states[idx], new)
+
+    def _layout(self, trainable, frozen):
+        """THE layout of this step: which sharding each leaf of one
+        step_fn call has, as ``(per trainable parameter, per frozen one,
+        per trainable parameter a rule leaf -> sharding for its optimizer
+        state, the inputs', the scalars' and key's)``. Nothing else
+        builds a sharding; without a mesh every answer is None.
+
+        On a mesh (SPMD data(+tensor)-parallel) the batch is sharded over
+        ``data_axis`` and XLA inserts the gradient all-reduce (psum over
+        dp) itself — this IS the kvstore dist_device_sync path on ICI
+        (SURVEY §2.5 north star). A parameter has its ``p.sharding`` (the
+        PartitionSpec a tensor/expert-parallel layer set) or is
+        replicated, and its optimizer state follows it unless ``zero``
+        shards that: dim 0 over the dp axis when divisible
+        (masters/momenta share the param shape); scalars and indivisible
+        leaves replicate; params a tensor/expert-parallel layer already
+        sharded keep their spec. The rules close over the mesh, never
+        over self: step_fn's constrainer holds them."""
+        mesh = self.mesh
+        if mesh is None:
+            return ([None] * len(trainable), [None] * len(frozen),
+                    [lambda leaf: None] * len(trainable), None, None)
         from jax.sharding import NamedSharding, PartitionSpec
-        repl = NamedSharding(self.mesh, PartitionSpec())
-        if not self.zero or self.mesh is None \
-                or self.mesh.shape.get(self.data_axis, 1) <= 1 \
-                or getattr(p, "sharding", None) is not None:
-            base = self._param_sharding(p)
-            return lambda leaf: base
-        n = self.mesh.shape[self.data_axis]
+        repl = NamedSharding(mesh, PartitionSpec())
         dp = self.data_axis
+        n = mesh.shape.get(dp, 1)
 
-        def rule(leaf):
+        def of_param(p):
+            spec = getattr(p, "sharding", None)
+            if spec is None:
+                return repl
+            if isinstance(spec, NamedSharding):
+                return spec
+            return NamedSharding(mesh, spec)
+
+        def zero_rule(leaf):
             shape = getattr(leaf, "shape", ())
             if len(shape) >= 1 and shape[0] and shape[0] % n == 0:
                 return NamedSharding(
-                    self.mesh,
-                    PartitionSpec(dp, *([None] * (len(shape) - 1))))
+                    mesh, PartitionSpec(dp, *([None] * (len(shape) - 1))))
             return repl
 
-        return rule
+        def of_state(p):
+            if self.zero and n > 1 and getattr(p, "sharding", None) is None:
+                return zero_rule
+            base = of_param(p)
+            return lambda leaf: base
 
-    def _make_constrainer(self, trainable):
+        return ([of_param(p) for p in trainable],
+                [of_param(p) for p in frozen],
+                [of_state(p) for p in trainable],
+                NamedSharding(mesh, PartitionSpec(dp)), repl)
+
+    def _make_constrainer(self, layout):
         """Build the update-sharding constrainer (zero mode): new states
         stay dp-sharded, new weights return to their (replicated/TP) param
         sharding — the mismatch is what GSPMD lowers to
         reduce-scatter + sharded update + all-gather. Returns None when
-        inactive; the returned closure is SELF-FREE (sharding rules are
-        resolved here, at build time) so the shared-cache entry never pins
+        inactive; the returned closure is SELF-FREE (the layout is
+        resolved at build time) so the shared-cache entry never pins
         this instance."""
         if not self.zero or self.mesh is None:
             return None
-        rules = [self._zero_leaf_sharding(p) for p in trainable]
-        shards = [self._param_sharding(p) for p in trainable]
+        shards, _, rules, _, _ = layout
 
         def constrain(new_t, new_opt):
             out_t, out_opt = [], []
@@ -378,47 +427,6 @@ class TrainStep:
             return out_t, out_opt
 
         return constrain
-
-    def _param_sharding(self, p):
-        """Per-parameter sharding: p.sharding (a PartitionSpec) if set by a
-        tensor/expert-parallel layer, else fully replicated."""
-        from jax.sharding import NamedSharding, PartitionSpec
-        if getattr(p, "sharding", None) is not None:
-            spec = p.sharding
-            if isinstance(spec, NamedSharding):
-                return spec
-            return NamedSharding(self.mesh, spec)
-        return NamedSharding(self.mesh, PartitionSpec())
-
-    def _jit_sharded(self, step_fn, trainable, frozen):
-        """SPMD data(+tensor)-parallel: inputs sharded on the batch axis over
-        ``data_axis``; params/optimizer state follow their own shardings. XLA
-        inserts the gradient all-reduce (psum over dp) automatically — this IS
-        the kvstore dist_device_sync path on ICI (SURVEY §2.5 north star)."""
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        repl = NamedSharding(self.mesh, PartitionSpec())
-        t_sh = [self._param_sharding(p) for p in trainable]
-        f_sh = [self._param_sharding(p) for p in frozen]
-        data_sh = NamedSharding(self.mesh, PartitionSpec(self.data_axis))
-        jitted = jax.jit(step_fn, donate_argnums=_donate((0, 2)))
-
-        state_rules = [self._zero_leaf_sharding(p) for p in trainable]
-
-        def wrapper(t_datas, f_datas, opt_states, input_datas, *rest):
-            # lay out operands on the mesh; no-op once steady-state shardings
-            # are established (outputs inherit them), so the reshard cost is
-            # first-step-only
-            t_datas = [jax.device_put(d, s) for d, s in zip(t_datas, t_sh)]
-            f_datas = [jax.device_put(d, s) for d, s in zip(f_datas, f_sh)]
-            opt_states = [jax.tree_util.tree_map(
-                lambda x, _r=r: jax.device_put(x, _r(x)), st)
-                for st, r in zip(opt_states, state_rules)]
-            input_datas = [jax.device_put(d, data_sh) for d in input_datas]
-            rest = [jax.device_put(r, repl) for r in rest]
-            return jitted(t_datas, f_datas, opt_states, input_datas, *rest)
-
-        return wrapper
 
     # ------------------------------------------------------------------
     #: live instances that have stepped at least once — the shared
@@ -485,8 +493,7 @@ class TrainStep:
             extra=(n_net_inputs, "i%x" % id(self)))
         step_t0 = _time.perf_counter()
         # the per-step RNG key is drawn BEFORE the build so a compile
-        # miss can shape its arg specs from it (one draw per step either
-        # way — only the draw's position moved)
+        # miss can shape its arg specs from it (one draw per step either way)
         key = _rnd._next_key()
         entry = aot.CACHE.lookup(cache_key)
         compile_miss = entry is None
@@ -494,26 +501,21 @@ class TrainStep:
                          compile=compile_miss)
         if compile_miss:
             flightrec.record("compile_begin", kind="train")
-            # Single-device train programs AOT-compile inside this build
-            # span (jit().lower(arg_specs).compile() in _build_entry) so
-            # the entry is an analyzable compiled program; the mesh-train
-            # wrapper (and any spec-construction failure) still
-            # jax.jit-compiles LAZILY inside the first train:dispatch
-            # (donated-buffer programs are never jax.export-persisted
-            # either way). The retroactive train:compile span below
-            # covers the whole trace+compile+first-run window (same
+            # The program compiles ahead inside this build span
+            # (_build_entry), so the entry is an analyzable compiled
+            # program (donated-buffer programs are never
+            # jax.export-persisted). The retroactive train:compile span
+            # below covers the whole trace+compile+first-run window (same
             # definition as the mxtpu_jit_compile_seconds_total counter),
             # which is what separates "slow step" from "recompiling
             # every step".
-            arg_specs = self._arg_specs(arrs, key)
             with spans.span("train:build"):
                 entry = aot.compile_cached(
                     cache_key,
-                    lambda: self._build_entry(n_net_inputs, arg_specs))
+                    lambda: self._build_entry(n_net_inputs, arrs, key))
                 self._cache_keys.add(cache_key)
-        jitted = entry.fn
         self._last_stats = entry.stats
-        trainable, frozen, t_arrs, f_arrs, aux_box = entry.extras
+        slots, t_arrs, f_arrs, aux_box, state_sh, data_sh = entry.extras
 
         optimizer = trainer._optimizer
         # python-side schedule state (lr scheduler, update counts) advances
@@ -521,8 +523,7 @@ class TrainStep:
         with spans.span("train:schedule"):
             self._step_count += 1
             lrs, wds, opt_states = [], [], []
-            for i, p in enumerate(trainable):
-                idx = trainer._param2idx.get(p.name, i)
+            for idx in slots:
                 optimizer._update_count(idx)
                 lrs.append(optimizer._get_lr(idx))
                 wds.append(optimizer._get_wd(idx))
@@ -530,44 +531,43 @@ class TrainStep:
             t = self._step_count
             rescale = optimizer.rescale_grad / batch_size
 
-        # the whole dispatch + write-back holds the net's trace lock: a
-        # mesh-path MISS dispatch IS the lazy train trace (inner swaps
-        # tracers into the live param NDArrays), a HIT dispatch reads and
-        # then writes those same ``_data`` slots — either interleaved
-        # with a concurrent eval/warm trace of this net would capture
-        # tracers or lose the step's update to the trace's
+        # the dispatch + write-back holds the net's trace lock: it reads
+        # and then writes the live param NDArrays' ``_data`` slots —
+        # interleaved with a concurrent eval/warm trace of this net it
+        # would capture tracers or lose the step's update to the trace's
         # finally-restore. Uncontended (the common case: nothing else
         # traces this net) the RLock costs sub-µs per step.
         with spans.span("train:dispatch", compile=compile_miss), \
                 self._trace_lock:
             dispatch_t0 = _time.perf_counter()
-            loss_full, new_t, new_opt, aux_vals = jitted(
-                [a._data for a in t_arrs], [a._data for a in f_arrs],
-                opt_states, [a._data for a in arrs], key,
-                jnp.asarray(lrs, jnp.float32), jnp.asarray(wds, jnp.float32),
-                jnp.asarray(t, jnp.int32), jnp.asarray(rescale, jnp.float32))
-            if entry.stats is not None:
-                # device-truth MFU: opt-in sync (the block defeats
-                # donated-buffer step chaining — docs/OBSERVABILITY.md);
-                # unsynced, the observed span is the host dispatch window
-                # and the rolling train MFU can read high while steps
-                # pipeline
-                if config.get_env("MXTPU_DEVSTATS_TRAIN_SYNC"):
-                    try:
-                        jax.block_until_ready(loss_full)
-                    except Exception:
-                        pass
-                devstats.observe_dispatch(
-                    "train", entry.stats,
-                    _time.perf_counter() - dispatch_t0,
-                    model=self._model_id)
+            # the state is passed as it is held: a leaf is put again only
+            # where it left its layout between two steps (set_data,
+            # load_parameters, trainer.load_states), which a compiled
+            # program refuses; the inputs go onto the data sharding; the
+            # scalars stay host arrays: on a mesh every chip gets its copy
+            # from the host, none crosses from chip 0 between two programs
+            loss_full, new_t, new_opt, aux_vals = entry.fn(
+                *_with_layout(_placed, (
+                    [a._data for a in t_arrs], [a._data for a in f_arrs],
+                    opt_states), state_sh),
+                [_placed(a._data, data_sh) for a in arrs], key,
+                _onp.asarray(lrs, _onp.float32), _onp.asarray(wds, _onp.float32),
+                _onp.asarray(t, _onp.int32), _onp.asarray(rescale, _onp.float32))
+            # device-truth MFU: opt-in sync (the block defeats
+            # donated-buffer step chaining — docs/OBSERVABILITY.md);
+            # unsynced, the observed span is the host dispatch window
+            # and the rolling train MFU can read high while steps
+            # pipeline
+            if config.get_env("MXTPU_DEVSTATS_TRAIN_SYNC"):
+                try:
+                    jax.block_until_ready(loss_full)
+                except Exception:
+                    pass
+            devstats.observe_dispatch(
+                "train", entry.stats, _time.perf_counter() - dispatch_t0,
+                model=self._model_id)
 
-            for a, d in zip(t_arrs, new_t):
-                a._data = d
-            for i, p in enumerate(trainable):
-                idx = trainer._param2idx.get(p.name, i)
-                trainer._states[idx] = _rewrap_state(trainer._states[idx],
-                                                     new_opt[i])
+            self._write_back(t_arrs, slots, new_t, new_opt)
             for a, v in zip(aux_box, aux_vals):
                 a._data = v
         # numerics sentinel (stride-sampled, default off): on-device
@@ -585,8 +585,8 @@ class TrainStep:
             _COMPILES.inc(kind="train")
             _COMPILE_SECONDS.inc(step_dur, kind="train")
             # retroactive: the compile window IS this whole cache-miss
-            # step (trace + XLA compile + first run — see the lazy-compile
-            # note above), emitted as a child of the open train:step span
+            # step (trace + XLA compile + first run — see the note above),
+            # emitted as a child of the open train:step span
             _record_compile_span("train:compile", step_dur)
             flightrec.record("compile_end", kind="train",
                              dur_s=round(step_dur, 6))
@@ -601,14 +601,12 @@ def compiled_train_programs():
     ``metadata={op_name="jit(step_fn)/.../<scopes>/<primitive>"}`` (block
     names, ``ffn``, ``loss``, ``optimizer``), which is how a profiler
     capture's device events — named by instruction — are booked to a block.
-    The text is rendered only here, on demand. Only AOT-compiled entries
-    have one: the mesh path's lazily compiling wrapper yields nothing."""
+    The text is rendered only here, on demand."""
     out = []
     for key in aot.CACHE.keys():
         entry = aot.CACHE.peek(key) if key.kind == "train" else None
-        as_text = getattr(entry.fn, "as_text", None) if entry else None
-        if as_text is not None:
-            out.append((key.model_id, as_text()))
+        if entry is not None:
+            out.append((key.model_id, entry.fn.as_text()))
     return out
 
 

@@ -99,8 +99,9 @@ def test_a_released_step_leaves_no_program():
     assert model_id not in [m for m, _ in jit.compiled_train_programs()]
 
 
-def test_mesh_step_has_no_text_and_raises_nothing():
-    """The mesh path compiles lazily inside its wrapper: nothing to read."""
+def test_mesh_step_is_a_compiled_program_with_text_and_stats():
+    """A mesh step is compiled ahead like any other: it has a text that
+    names its scopes, and the cost analysis devstats reads."""
     net = gluon.nn.Dense(4, in_units=4)
     net.initialize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
@@ -111,8 +112,11 @@ def test_mesh_step_has_no_text_and_raises_nothing():
                                               trainer, mesh=mesh)
         x = nd.array(np.ones((4, 4), "float32"))
         assert np.isfinite(step(x, x).asnumpy()).all()
-        assert step._model_id not in [
-            m for m, _ in jit.compiled_train_programs()]
+        mine = [text for model_id, text in jit.compiled_train_programs()
+                if model_id == step._model_id]
+        assert len(mine) == 1
+        assert _under(re.findall(r'op_name="([^"]*)"', mine[0]), "optimizer")
+        assert step._last_stats["flops"] > 0
     finally:
         parallel.set_current_mesh(None)
 

@@ -824,3 +824,155 @@ def test_zero1_optimizer_state_sharding():
     for p in net1.collect_params().values():
         spec = getattr(p.data()._data.sharding, "spec", ())
         assert not spec or all(s is None for s in spec), spec
+
+
+# ---- one build path: the mesh step is compiled ahead, its state laid out
+# once at the build, and a dispatch passes what it holds ------------------
+def _mlp(seed=11):
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu", in_units=8),
+            nn.Dense(4, in_units=16))
+    mx.random.seed(seed)
+    net.initialize(mx.init.Xavier())
+    return net
+
+
+def _mlp_batch():
+    rng = onp.random.RandomState(5)
+    return (nd.array(rng.randn(8, 8).astype("float32")),
+            nd.array(rng.randint(0, 4, 8).astype("float32")))
+
+
+def _state_leaves(trainer):
+    return [x for st in trainer._states
+            for x in jax.tree_util.tree_leaves(jit._tree_to_data(st))]
+
+
+@pytest.mark.parametrize("zero", [False, True])
+def test_mesh_state_is_laid_out_once_and_passed_as_held(monkeypatch, zero):
+    _need_devices(2)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = parallel.make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    net = _mlp()
+    tr = gluon.Trainer(net.collect_params(), "adam", {"learning_rate": 1e-2})
+    step = parallel.DataParallelTrainStep(net, gluon.loss.
+                                          SoftmaxCrossEntropyLoss(), tr,
+                                          mesh=mesh, zero=zero)
+    X, y = _mlp_batch()
+    step(X, y)
+    repl = NamedSharding(mesh, P())
+    for p in net.collect_params().values():
+        d = p.data()._data
+        assert d.sharding.is_equivalent_to(repl, d.ndim), p.name
+    sharded = 0
+    for leaf in _state_leaves(tr):
+        over_dp = zero and leaf.ndim and leaf.shape[0] % 2 == 0
+        want = NamedSharding(mesh, P("dp")) if over_dp else repl
+        assert leaf.sharding.is_equivalent_to(want, leaf.ndim), leaf.shape
+        sharded += bool(over_dp)
+    assert sharded == (8 if zero else 0)
+
+    # the second call puts its two inputs on the mesh, and no state leaf
+    put = []
+    real = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda x, *a, **k: put.append(x) or real(x, *a, **k))
+    step(X, y)
+    assert len(put) == 2 and all(
+        any(x is a._data for a in (X, y)) for x in put), put
+
+
+@pytest.mark.parametrize("moved", ["set_data", "load_states"])
+def test_state_moved_between_mesh_steps_is_laid_out_again(tmp_path, moved):
+    """A `set_data` or `trainer.load_states` between two steps leaves a
+    single-device array where the compiled mesh program expects its
+    layout: the step puts it back and trains as the one-device step."""
+    _need_devices(2)
+    mesh = parallel.make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    X, y = _mlp_batch()
+    new_w = onp.random.RandomState(9).randn(16, 8).astype("float32") * 0.1
+
+    def run(**mesh_kw):
+        net = _mlp()
+        tr = gluon.Trainer(net.collect_params(), "adam",
+                           {"learning_rate": 1e-2})
+        step = jit.TrainStep(net, loss_fn, tr, **mesh_kw)
+        losses = [step(X, y).asnumpy()]
+        if moved == "set_data":
+            list(net.collect_params().values())[0].set_data(new_w)
+        else:
+            fname = str(tmp_path / ("states%d" % len(mesh_kw)))
+            tr.save_states(fname)
+            tr.load_states(fname)
+        losses += [step(X, y).asnumpy() for _ in range(2)]
+        return net, tr, losses
+
+    net1, tr1, l1 = run()
+    net2, tr2, l2 = run(mesh=mesh, zero=True)
+    onp.testing.assert_allclose(l2, l1, rtol=1e-6, atol=1e-6)
+    for p1, p2 in zip(net1.collect_params().values(),
+                      net2.collect_params().values()):
+        onp.testing.assert_allclose(p2.data().asnumpy(), p1.data().asnumpy(),
+                                    rtol=1e-6, atol=1e-6)
+    for s1, s2 in zip(_state_leaves(tr1), _state_leaves(tr2)):
+        onp.testing.assert_allclose(onp.asarray(s2), onp.asarray(s1),
+                                    rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("fault", ["unknown_axis", "unshaped_state_leaf"])
+def test_a_spec_that_cannot_be_built_raises_out_of_the_step(fault):
+    """No second, lazily compiling path behind a failed spec: the first
+    error reaches the caller and nothing is cached."""
+    _need_devices(2)
+    from jax.sharding import PartitionSpec as P
+    net = _mlp()
+    tr = gluon.Trainer(net.collect_params(), "sgd", {"learning_rate": 0.1})
+    X, y = _mlp_batch()
+    if fault == "unknown_axis":
+        mesh = parallel.make_mesh({"dp": 2}, devices=jax.devices()[:2])
+        list(net.collect_params().values())[0].sharding = P("tp")
+        step = parallel.DataParallelTrainStep(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), tr, mesh=mesh)
+        error = ValueError
+    else:
+        step = jit.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(), tr)
+        tr._init_kvstore()
+        tr._init_states()
+        tr._states[0] = 0.0          # no shape, no dtype
+        error = AttributeError
+    with pytest.raises(error):
+        step(X, y)
+    assert not step._cache_keys
+
+
+def test_dp_tp_step_keeps_a_sharded_parameter_sharded():
+    _need_devices(4)
+    from jax.sharding import NamedSharding
+    mesh = parallel.make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    net = nn.HybridSequential()
+    net.add(parallel.ColParallelDense(32, activation="relu", in_units=8),
+            parallel.RowParallelDense(4, in_units=32))
+    mx.random.seed(5)
+    net.initialize(mx.init.Xavier())
+    tr = gluon.Trainer(net.collect_params(), "adam", {"learning_rate": 1e-2})
+    step = parallel.DataParallelTrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), tr, mesh=mesh, zero=True)
+    X, y = _mlp_batch()
+    params = list(net.collect_params().values())
+    assert sum(p.sharding is not None for p in params) >= 2
+    losses = []
+    for _ in range(3):
+        losses.append(float(step(X, y).mean().asnumpy()))
+        for idx, p in enumerate(params):
+            if p.sharding is None:
+                continue
+            want = NamedSharding(mesh, p.sharding)
+            d = p.data()._data
+            assert d.sharding.is_equivalent_to(want, d.ndim), p.name
+            assert d.addressable_shards[0].data.size * 2 == d.size
+            for leaf in jax.tree_util.tree_leaves(
+                    jit._tree_to_data(tr._states[idx])):
+                assert leaf.sharding.is_equivalent_to(want, leaf.ndim)
+    assert losses[2] < losses[0]
+    assert step._last_stats["flops"] > 0
