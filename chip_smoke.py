@@ -245,7 +245,7 @@ def mosaic_kernels_in_train_program(expect_at_least):
     if n < expect_at_least:
         raise RuntimeError(
             "compiled train step holds %d tpu_custom_call(s), expected >= %d"
-            " (3 per attention layer: fwd, dK/dV, dQ) — attention did not "
+            " (2 per attention layer: forward, backward) — attention did not "
             "run as the Pallas kernels" % (n, expect_at_least))
     return n
 
@@ -286,7 +286,7 @@ def phase_bert(cfg, on_chip, shared):
     losses, compile_s, steady_s = run_steps(step, (tokens, tokens), 2, 3)
     if not losses[-1] < losses[0]:
         raise RuntimeError("loss did not fall: %r" % (losses,))
-    kernels = (mosaic_kernels_in_train_program(3 * cfg["L"]) if on_chip
+    kernels = (mosaic_kernels_in_train_program(2 * cfg["L"]) if on_chip
                else "skipped (interpreted)")
     shared["bert_first_loss"] = losses[0]
     del step, trainer, net, tokens
@@ -311,7 +311,7 @@ def phase_gpt(cfg, on_chip, shared):
     losses, compile_s, steady_s = run_steps(step, (tokens, tokens), 2, 2)
     if not losses[-1] < losses[0]:
         raise RuntimeError("loss did not fall: %r" % (losses,))
-    kernels = (mosaic_kernels_in_train_program(3 * cfg["L"]) if on_chip
+    kernels = (mosaic_kernels_in_train_program(2 * cfg["L"]) if on_chip
                else "skipped (interpreted)")
     del step, trainer, view, net, tokens
     gc.collect()

@@ -5,11 +5,21 @@ forces an HBM round-trip of the (S, S) score matrix under plain XLA. Two
 kernel families keep it in VMEM, chosen by attention_route() from the
 shape alone:
 
-* **streamed** (flash_fwd, flash_bwd_dkv, flash_bwd_dq): Q blocks against
-  K/V blocks streamed by the grid, with an online-softmax carry; the
-  backward kernels recompute probabilities blockwise from the saved
-  log-sum-exp (FlashAttention-2) and accumulate dQ/dK/dV across sequential
-  grid steps. VMEM use is O(block^2 + block*D), so 16k-32k sequences fit.
+* **streamed** (flash_fwd, flash_bwd_dkvq): Q blocks against K/V blocks
+  streamed by the grid, with an online-softmax carry in the forward. ONE
+  backward kernel visits each live block pair once: it recomputes the
+  probabilities from the saved log-sum-exp (FlashAttention-2) and
+  accumulates dK/dV across the inner grid steps and dQ into a float32
+  (S, D) slab of the (batch*head) that stays in VMEM until the head
+  changes: five matmuls a pair. The forward's VMEM use is O(block^2 +
+  block*D); the backward's adds the slab, 2 * S * roundup(D, 128) * 4
+  bytes with its second buffer, and while that is within _DQ_SLAB_BYTES
+  (every S <= 65 536 at D <= 128) the backward is one call. Past it the
+  same kernel is called once a q-segment whose slab fits and XLA sums the
+  dK/dV partials, so the length stays bounded by HBM, not VMEM. The
+  budgets assume a v5e-class VMEM (_V5E_VMEM_BYTES, 128 MiB a core: the
+  backward asks for a scoped limit of the slab + 24 MiB, the old pair
+  needed only the default); a chip with less refuses at compile time.
   Takes D % 128 == 0, or S >= 2048.
 * **short** (flash_short_fwd, flash_short_bwd): narrow heads that tile 128
   lanes (D of 32 or 64, whole blocks of H*D) below S = 2048 whose whole
@@ -25,12 +35,21 @@ composite. Used by models.bert MultiHeadAttention (attention='flash'). A
 Mosaic refusal of a routed shape surfaces as the compile error it is —
 nothing catches it to degrade. MXTPU_FLASH_INTERPRET=1 runs the kernels in
 Pallas interpret mode (CPU tests only; chip_smoke.py and bench.py refuse to
-start with it set).
+start with it set). Counters at /metrics, one increment per traced call:
+mxtpu_attention_route_total{route} (the backward follows the forward's
+route) and mxtpu_attention_backward_total{kernel} (a streamed backward:
+one call, or segmented).
 
 Why two families (v5e, BERT-large's (16, 16, 512, 64) bf16, attention
 alone, forward + backward, a call; PERF.md §6, PR 26): the composite takes
 3.09 ms, the streamed kernels with 512-blocks 1.93 ms, the short family
 1.11 ms, of which the two kernels are 0.89 ms in the compiled train step.
+Why one streamed backward kernel (v5e, (1, 16, 16384, 128) bf16 causal, a
+call with delta and the output casts; PERF.md §6, PR 30): the dK/dV + dQ
+pair it replaced ran seven matmuls and the element-wise chain twice, 26.3
+ms; one visit takes 18.6, every gradient equal to the pair's to the bit.
+Segmented it ran at (1, 4, 131072, 128), two segments: 264.5 ms a call;
+forced to two segments at 16k it takes 19.9 ms, dQ equal to the bit.
 """
 from __future__ import annotations
 
@@ -62,6 +81,12 @@ _ROUTES = telemetry.counter(
     "mxtpu_attention_route_total",
     "flash_attention calls traced, by the path the shape was routed to "
     "(short / streamed Pallas kernels, or the XLA composite).", ("route",))
+_BACKWARDS = telemetry.counter(
+    "mxtpu_attention_backward_total",
+    "Streamed attention backwards traced (the other routes' backwards "
+    "follow mxtpu_attention_route_total): flash_bwd_dkvq (one call) or "
+    "flash_bwd_dkvq_segmented (one call a q-segment: the dQ slab is over "
+    "the VMEM budget).", ("kernel",))
 
 
 def _interpret():
@@ -150,6 +175,22 @@ def attention_route(q_shape, k_shape=None, v_shape=None, block_q=None,
         return "short" if fits and _kernels_run_here() else "composite"
     return "streamed" if flash_attention_legal(q_shape, block_q, block_k) \
         else "composite"
+
+
+def _nt(a, b):
+    """a @ b.T on the MXU, float32 result, no transpose materialized."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    """a.T @ b, float32 result."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _mm(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
 # --------------------------------------------------------------- forward
@@ -250,82 +291,136 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k):
 
 
 # --------------------------------------------------------------- backward
-def _recompute_p_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qb, kb,
-                    causal, scale, block_q, block_k):
-    """Shared FA2 recompute: returns (q, do, k_blk, p, ds) for one block pair."""
-    q = q_ref[0].astype(jnp.float32)                     # (block_q, D)
-    do = do_ref[0].astype(jnp.float32)                   # (block_q, D)
-    lse = lse_ref[0, 0][:, None]                         # (block_q, 1)
-    delta = delta_ref[0, 0][:, None]                     # (block_q, 1)
-    k_blk = k_ref[0].astype(jnp.float32)                 # (block_k, D)
-    v_blk = v_ref[0].astype(jnp.float32)
-
-    s = (q @ k_blk.T) * scale                            # (block_q, block_k)
-    if causal:
-        qi = qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        ki = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        s = jnp.where(qi >= ki, s, -jnp.inf)
-    p = jnp.exp(s - lse)                                 # (block_q, block_k)
-    p = jnp.where(jnp.isfinite(s), p, 0.0)
-    dp = do @ v_blk.T                                    # (block_q, block_k)
-    ds = p * (dp - delta) * scale
-    return q, do, k_blk, p, ds
+# The streamed backward assumes a v5e-class VMEM: the one number below is
+# what a v5e TensorCore has, and the two budgets follow from it. Compiled
+# for a described v5e (PERF.md §6, PR 30): S = 65 536 at D = 128 fits in one
+# call (two 32 MiB buffers of the slab), 131 072 does not.
+_V5E_VMEM_BYTES = 128 << 20
+# Both pipeline buffers of the resident dQ slab: half the core's VMEM.
+_DQ_SLAB_BYTES = _V5E_VMEM_BYTES // 2
+# The tiles and the kernel's temporaries beside the slab. Mosaic's scoped
+# allocation at 1024-blocks and D = 128, less the slab (compiled for a
+# described v5e, PR 30): 13.0 MiB with bf16 operands, 14.7 MiB with float32;
+# 12 MiB refuses, 16 MiB compiles. The rest is room for wider heads.
+_BWD_TILE_BYTES = 24 << 20
 
 
-def _fa_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                       dk_ref, dv_ref, *, causal, scale, block_q, block_k):
-    """Grid (bh, kv-block, q-block): accumulate dK/dV over sequential q steps."""
+def _slab_bytes(rows, D):
+    """Both buffers of a float32 (rows, D) block, D padded to 128 lanes."""
+    return 2 * rows * -(-D // 128) * 128 * 4
+
+
+def _dq_segments(S, D, block_q):
+    """How many q-segments the backward is called for: the fewest that
+    divide the q-blocks evenly and keep one segment's dQ slab within
+    _DQ_SLAB_BYTES. One for every S <= 65 536 at D <= 128."""
+    n_blocks = S // block_q
+    return next((n for n in range(1, n_blocks + 1) if n_blocks % n == 0
+                 and _slab_bytes(S // n, D) <= _DQ_SLAB_BYTES), n_blocks)
+
+
+def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                   dk_ref, dv_ref, dq_ref, *, causal, scale, block_q,
+                   block_k, q0):
+    """Grid (bh, kv-block, q-block), each live pair visited ONCE: P and dS
+    are recomputed from the saved LSE (FlashAttention-2) and all three
+    gradients accumulate from them. dK/dV blocks accumulate over the inner
+    q steps; dQ accumulates into dq_ref, the (batch*head)'s whole slab of
+    this call's q rows, which stays in VMEM across both inner axes and is
+    written back when bh changes. ``q0`` is the call's first q-block.
+
+    Written in the transposed frame, as the short family's backward is
+    (rows are keys, columns queries): LSE and delta broadcast as the (1,
+    block_q) rows they are stored as, dV and dK are plain matmuls, and only
+    dQ contracts over the tile's rows. The arithmetic is the replaced
+    pair's, value for value: every operand is cast to float32 (lossless),
+    the scale multiplies the float32 scores and dS and never an operand (a
+    scaled q is no longer a bf16 value, and what the MXU keeps of it cost
+    0.03-0.09 % of the gradients on a v5e: PERF.md §6, PR 30), P and dS
+    reach the MXU as float32 and every matmul accumulates in float32."""
     from jax.experimental import pallas as pl
 
-    qb = pl.program_id(2)
+    kb, qb = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((kb == 0) & (qb == 0))
+    def _new_head():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
 
     @pl.when(qb == 0)
-    def _init():
-        dk_ref[0, :, :] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0, :, :] = jnp.zeros_like(dv_ref[0])
+    def _new_kv_block():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    kb = pl.program_id(1)
     # q-blocks fully above the diagonal contribute nothing in causal mode
-    live = (qb + 1) * block_q - 1 >= kb * block_k if causal else qb >= 0
+    live = (q0 + qb + 1) * block_q - 1 >= kb * block_k if causal else qb >= 0
 
     @pl.when(live)
     def _compute():
-        q, do, _k, p, ds = _recompute_p_ds(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qb, kb,
-            causal, scale, block_q, block_k)
-        dv_ref[0, :, :] += (p.T @ do).astype(dv_ref.dtype)
-        dk_ref[0, :, :] += (ds.T @ q).astype(dk_ref.dtype)
+        q = q_ref[0].astype(jnp.float32)                  # (block_q, D)
+        do = do_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                  # (block_k, D)
+        v = v_ref[0].astype(jnp.float32)
+        pt = jnp.exp(_nt(k, q) * scale - lse_ref[0])      # (block_k, block_q)
+        if causal:
+            ki = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            qi = (q0 + qb) * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
+            pt = jnp.where(qi >= ki, pt, 0.0)
+        dst = pt * (_nt(v, do) - delta_ref[0]) * scale
+        dv_ref[0, :, :] += _mm(pt, do)
+        dk_ref[0, :, :] += _mm(dst, q)
+        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+        dq_ref[0, rows, :] += _tn(dst, k)
 
 
-def _fa_bwd_dq_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, *, causal, scale, block_q, block_k):
-    """Grid (bh, q-block, kv-block): accumulate dQ over sequential kv steps."""
+def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
+                    block_k, q0, n_q):
+    """dQ of q-blocks [q0, q0 + n_q) and their contribution to dK/dV (of
+    the kv-blocks they can see), all float32, from one kernel call."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    kb = pl.program_id(2)
+    BH, S, D = qf.shape
+    n_kv = S // block_k
+    if causal:
+        # kv-blocks past the segment's last row are dead for all of it
+        n_kv = min(n_kv, -(-(q0 + n_q) * block_q // block_k))
 
-    @pl.when(kb == 0)
-    def _init():
-        dq_ref[0, :, :] = jnp.zeros_like(dq_ref[0])
-
-    qb = pl.program_id(1)
-    # K/V blocks fully above the diagonal contribute nothing in causal mode
-    live = (qb + 1) * block_q - 1 >= kb * block_k if causal else kb >= 0
-
-    @pl.when(live)
-    def _compute():
-        _q, _do, k_blk, _p, ds = _recompute_p_ds(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qb, kb,
-            causal, scale, block_q, block_k)
-        dq_ref[0, :, :] += (ds @ k_blk).astype(dq_ref.dtype)
+        # the grid streams q-blocks (j) per kv-block (i): q-blocks strictly
+        # above the diagonal are dead — clamp to the first live one so no
+        # DMA is issued for blocks pl.when will skip
+        def q_blk(i, j):
+            return jnp.maximum(q0 + j, (i * block_k) // block_q)
+    else:
+        def q_blk(i, j):
+            return q0 + j
+    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, q_blk(i, j), 0))
+    rowspec = pl.BlockSpec((1, 1, block_q),
+                           lambda b, i, j: (b, 0, q_blk(i, j)))
+    kvspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
+    # an index that depends on b alone: resident across the inner axes
+    slab = pl.BlockSpec((1, n_q * block_q, D), lambda b, i, j: (b, 0, 0))
+    kernel = functools.partial(_fa_bwd_kernel, causal=causal, scale=scale,
+                               block_q=block_q, block_k=block_k, q0=q0)
+    return pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, n_q * block_q, D), jnp.float32)),
+        grid=(BH, n_kv, n_q),
+        in_specs=[qspec, qspec, rowspec, rowspec, kvspec, kvspec],
+        out_specs=(kvspec, kvspec, slab),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_slab_bytes(n_q * block_q, D) + _BWD_TILE_BYTES),
+        interpret=_interpret(),
+        name="flash_bwd_dkvq",
+    )(qf, dof, lse, delta, kf, vf)
 
 
 def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                  g_lse=None):
-    from jax.experimental import pallas as pl
-
     B, H, S, D = q.shape
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
@@ -341,61 +436,20 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         # so ds_ij = p_ij*(dp_ij - delta_i + g_lse_i)*scale — fold it in.
         delta = delta - g_lse.astype(jnp.float32)
 
-    if causal:
-        # dkv grid streams q-blocks (j) per kv-block (i): q-blocks strictly
-        # above the diagonal are dead — clamp to the first live one so no
-        # DMA is issued for blocks pl.when will skip
-        def q_idx(b, i, j):
-            return (b, jnp.maximum(j, (i * block_k) // block_q), 0)
-
-        def row_idx(b, i, j):
-            return (b, 0, jnp.maximum(j, (i * block_k) // block_q))
-    else:
-        def q_idx(b, i, j):
-            return (b, j, 0)
-
-        def row_idx(b, i, j):
-            return (b, 0, j)
-    qspec = pl.BlockSpec((1, block_q, D), q_idx)
-    rowspec = pl.BlockSpec((1, 1, block_q), row_idx)
-    kvspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
-    dkv_kernel = functools.partial(_fa_bwd_dkv_kernel, causal=causal,
-                                   scale=scale, block_q=block_q,
-                                   block_k=block_k)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        out_shape=(jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
-                   jax.ShapeDtypeStruct((B * H, S, D), jnp.float32)),
-        grid=(B * H, S // block_k, S // block_q),
-        in_specs=[qspec, qspec, rowspec, rowspec, kvspec, kvspec],
-        out_specs=(kvspec, kvspec),
-        interpret=_interpret(),
-        name="flash_bwd_dkv",
-    )(qf, dof, lse, delta, kf, vf)
-
-    if causal:
-        # dq grid streams kv-blocks (j) per q-block (i): kv-blocks above
-        # the diagonal are dead — clamp to the last live one
-        def kv_idx2(b, i, j):
-            return (b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
-    else:
-        def kv_idx2(b, i, j):
-            return (b, j, 0)
-    qspec2 = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    rowspec2 = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
-    kvspec2 = pl.BlockSpec((1, block_k, D), kv_idx2)
-    dq_kernel = functools.partial(_fa_bwd_dq_kernel, causal=causal,
-                                  scale=scale, block_q=block_q,
-                                  block_k=block_k)
-    dq = pl.pallas_call(
-        dq_kernel,
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
-        grid=(B * H, S // block_q, S // block_k),
-        in_specs=[kvspec2, kvspec2, qspec2, qspec2, rowspec2, rowspec2],
-        out_specs=qspec2,
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(kf, vf, qf, dof, lse, delta)
+    # One kernel call while a (batch*head)'s whole dQ slab fits VMEM; past
+    # that the same kernel a q-segment, XLA summing the dK/dV partials.
+    n_seg = _dq_segments(S, D, block_q)
+    _BACKWARDS.inc(kernel="flash_bwd_dkvq" if n_seg == 1
+                   else "flash_bwd_dkvq_segmented")
+    n_q = S // block_q // n_seg
+    parts = [_fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale,
+                             block_q, block_k, s * n_q, n_q)
+             for s in range(n_seg)]
+    dk, dv, _ = parts[-1]                     # the last segment sees all of K
+    for dk_s, dv_s, _ in parts[:-1]:
+        dk = dk.at[:, :dk_s.shape[1]].add(dk_s)
+        dv = dv.at[:, :dv_s.shape[1]].add(dv_s)
+    dq = jnp.concatenate([dq_s for _, _, dq_s in parts], axis=1)
 
     shape = (B, H, S, D)
     return (dq.reshape(shape).astype(q.dtype),
@@ -424,22 +478,6 @@ def _lane_blocks_per_step(n_blocks, heads_per_block, S):
     want = max(1, 2 * (512 * 512) // (S * S) // heads_per_block)
     return max(g for g in range(1, min(want, n_blocks) + 1)
                if n_blocks % g == 0)
-
-
-def _nt(a, b):
-    """a @ b.T on the MXU, float32 result, no transpose materialized."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-def _tn(a, b):
-    """a.T @ b, float32 result."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-def _mm(a, b):
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
 def _causal_tile(S, rows_are_queries):

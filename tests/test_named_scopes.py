@@ -1,7 +1,7 @@
 """Names inside the compiled train step (PR 25): every block's forward runs
 under `jax.named_scope(block.name)`, the transformer layers' feed-forward
 expression under `ffn`, `TrainStep`'s loss and update loop under `loss` and
-`optimizer`, and the three Pallas kernels carry a name. The names are HLO
+`optimizer`, and the Pallas kernels carry a name. The names are HLO
 metadata: `jit.compiled_train_programs()` hands out the text that has them."""
 import re
 
@@ -131,8 +131,7 @@ def test_eval_step_names_the_root_too():
     assert 'op_name="jit(pure_fn)/%s/' % net.name in text
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkv",
-                                    "flash_bwd_dq"])
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dkvq"])
 def test_flash_kernels_carry_their_names(kernel, monkeypatch):
     """Lowered for 'tpu' from this CPU host, through the public entry
     point and jax.grad: each Mosaic call is named."""
@@ -145,5 +144,5 @@ def test_flash_kernels_carry_their_names(kernel, monkeypatch):
     x = jax.ShapeDtypeStruct((1, 2, 2048, 128), jnp.bfloat16)
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
     assert 'kernel_name = "%s"' % kernel in text
