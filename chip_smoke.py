@@ -4,7 +4,9 @@ One process, no arguments: drives the system's main paths once, through the
 public package, at the full width of the models bench.py times — the Pallas
 flash-attention kernels against a reference, the dropless expert dispatch
 against its dense form, BERT and a long-context GPT
-through gluon.Trainer -> jit.TrainStep with those kernels, ResNet-50 training, ResNet-50 behind the HTTP
+through gluon.Trainer -> jit.TrainStep with those kernels, one tiny
+Nemotron-H share (chunked Mamba-2 scan, held expert dispatch, grouped-query
+attention, each layer recomputed), ResNet-50 training, ResNet-50 behind the HTTP
 server, the generative engine, and (on a host with >= 4 chips) the dp and
 dp x sp mesh steps — and checks what comes out. Weights are random from a
 seed; depth is the bench's. It measures nothing: the compile and step
@@ -34,8 +36,8 @@ import threading
 import time
 import urllib.request
 
-PHASES = ("kernels", "moe", "bert", "gpt", "resnet", "serve", "generate",
-          "multichip")
+PHASES = ("kernels", "moe", "bert", "gpt", "hybrid", "resnet", "serve",
+          "generate", "multichip")
 
 # the bench's widths (bench.py bench_transformer / bench_long_context / main)
 FULL = {
@@ -48,6 +50,11 @@ FULL = {
     "moe": dict(T=4096, U=2048, I=1024, E=64, K=8),
     "bert": dict(B=64, S=512, V=32768, U=1024, L=12, H=8),
     "gpt": dict(S=8192, V=32768, U=1024, L=4, H=8),
+    # one Mamba-2, one expert and one attention layer of a Nemotron-H share:
+    # 8 of 64 experts held, 4 a token, one of two mixer shards
+    "hybrid": dict(S=2048, V=8192, U=1024, P="ME*", MH=32, MD=64, G=2, N=128,
+                   Q=128, QH=4, KV=1, L=512, I=1024, SH=2048, E=64, HELD=8,
+                   K=4, SHARDS=2, SCAN_S=8192),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -60,6 +67,9 @@ TOY = {
     "moe": dict(T=64, U=32, I=16, E=8, K=2),
     "bert": dict(B=4, S=128, V=512, U=256, L=1, H=2),
     "gpt": dict(S=256, V=512, U=256, L=1, H=2),
+    "hybrid": dict(S=256, V=512, U=128, P="ME*", MH=16, MD=8, G=2, N=16,
+                   Q=16, QH=2, KV=1, L=64, I=48, SH=96, E=16, HELD=4, K=4,
+                   SHARDS=2, SCAN_S=256),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -317,6 +327,107 @@ def phase_gpt(cfg, on_chip, shared):
     gc.collect()
     return compile_s, steady_s, "S=%d causal, loss %.4f -> %.4f, mosaic " \
         "kernels: %s" % (cfg["S"], losses[0], losses[-1], kernels)
+
+
+#: the chunked scan alone, float32 at "highest", may be this far (of its
+#: largest output) from the recurrence run a position at a time; a state
+#: rounded to bfloat16 once a chunk must be further. Between the two
+#: readings of one v5e (PERF.md section 6, PR 31)
+SCAN_ALONE_LIMIT = 1e-4
+
+
+def scan_alone(cfg):
+    """`ops.ssd.ssd_chunked` alone at one shard's shape (half the heads, one
+    group, SCAN_S positions), float32 inputs at "highest" precision,
+    against the recurrence a position at a time: the one comparison in
+    which the state's float32 shows (against bfloat16 activations a
+    bfloat16 state is inside the rounding). -> (its distance, the distance
+    of the recurrence with its state rounded to bfloat16 once a chunk)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    from incubator_mxnet_tpu.ops.ssd import ssd_chunked
+    s, h, p, n, q = cfg["SCAN_S"], cfg["MH"] // 2, cfg["MD"], cfg["N"], \
+        cfg["Q"]
+    rng = onp.random.default_rng(0)
+    x, bm, cm = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                 for shape in ((1, s, h, p), (1, s, 1, n), (1, s, 1, n)))
+    dt = jnp.asarray(onp.exp(rng.uniform(onp.log(1e-3), onp.log(0.1),
+                                         (1, s, h))), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1, 16, (h,)), jnp.float32)
+
+    def recurrence(round_state):
+        def step(state, at):
+            x_t, dt_t, b_t, c_t, rounds = at
+            state = jnp.exp(dt_t * a)[..., None, None] * state \
+                + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :]
+            if round_state:
+                state = jnp.where(
+                    rounds, jax.lax.reduce_precision(state, 8, 7), state)
+            return state, (state * c_t[:, :, None, :]).sum(-1)
+        by_time = tuple(t.swapaxes(0, 1) for t in (x, dt, bm, cm)) \
+            + (jnp.arange(s) % q == q - 1,)
+        return jax.lax.scan(step, jnp.zeros((1, h, p, n), jnp.float32),
+                            by_time)[1].swapaxes(0, 1)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda: recurrence(False))()
+        rounded = jax.jit(lambda: recurrence(True))()
+        got = jax.jit(lambda: ssd_chunked(
+            x, dt, a, bm, cm, jnp.zeros((h,), jnp.float32), q))()
+    top = float(jnp.abs(want).max())
+    return float(jnp.abs(got - want).max()) / top, \
+        float(jnp.abs(rounded - want).max()) / top
+
+
+def phase_hybrid(cfg, on_chip, shared):
+    """One tiny Nemotron-H share through TrainStep: the chunked Mamba-2 scan
+    with its backward, the held dispatch (experts 8..15 of 64) and the
+    grouped-query kernels meet the device outside the benchmark, each layer
+    recomputed in the backward."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import jit, models
+    mx.random.seed(0)
+    net = models.NemotronHModel(
+        cfg["V"], cfg["U"], cfg["P"],
+        mamba=dict(num_heads=cfg["MH"], head_dim=cfg["MD"], n_groups=cfg["G"],
+                   state=cfg["N"], chunk=cfg["Q"], shards=cfg["SHARDS"]),
+        attention=dict(num_heads=cfg["QH"], num_kv_heads=cfg["KV"],
+                       head_dim=128, attention="flash"),
+        moe=dict(latent=cfg["L"], num_experts=cfg["E"], ffn_hidden=cfg["I"],
+                 top_k=cfg["K"], shared_hidden=cfg["SH"], scale=2.5,
+                 held=(cfg["HELD"], cfg["HELD"])),
+        remat_layers=True)
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    tokens = fixed_tokens(cfg, 1)
+    view = models.FeaturesView(net)
+    trainer = adam_trainer(view)
+    step = jit.TrainStep(view, models.ChunkedUntiedLMLoss(net), trainer)
+    losses, compile_s, steady_s = run_steps(step, (tokens, tokens), 2, 2)
+    if not losses[-1] < losses[0]:
+        raise RuntimeError("loss did not fall: %r" % (losses,))
+    (text,) = [t for model_id, t in jit.compiled_train_programs()
+               if model_id == step._model_id]
+    if "ssd_scan" not in text or "moe_dispatch" not in text:
+        raise RuntimeError("the step lost the scan's or the dispatch's name")
+    if on_chip and "ragged" not in text:
+        raise RuntimeError("held dispatch compiled without a grouped matmul")
+    kernels = (mosaic_kernels_in_train_program(2) if on_chip
+               else "skipped (interpreted)")
+    del step, trainer, view, net, tokens
+    gc.collect()
+    sound, rounded = scan_alone(cfg)
+    if not sound < SCAN_ALONE_LIMIT < rounded:
+        raise RuntimeError(
+            "scan alone, float32: %.3g of the largest output from the "
+            "recurrence (limit %g), a bfloat16 state a chunk %.3g"
+            % (sound, SCAN_ALONE_LIMIT, rounded))
+    return compile_s, steady_s, "pattern %s, S=%d, loss %.4f -> %.4f, " \
+        "mosaic kernels: %s; scan alone at S=%d in float32 %.2g of its " \
+        "largest output from the recurrence (a bfloat16 state a chunk " \
+        "%.2g)" % (cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
+                   cfg["SCAN_S"], sound, rounded)
 
 
 def build_resnet():
@@ -649,6 +760,7 @@ def main():
              "moe": (phase_moe, cfgs["moe"]),
              "bert": (phase_bert, cfgs["bert"]),
              "gpt": (phase_gpt, cfgs["gpt"]),
+             "hybrid": (phase_hybrid, cfgs["hybrid"]),
              "resnet": (phase_resnet, cfgs["resnet"]),
              "serve": (phase_serve, cfgs["serve"]),
              "generate": (phase_generate, None),

@@ -6,7 +6,8 @@ import math
 from .. import ndarray as nd
 from ..ndarray import NDArray
 
-__all__ = ["split_data", "split_and_load", "clip_global_norm", "check_sha1", "download"]
+__all__ = ["split_data", "split_and_load", "clip_global_norm", "check_sha1", "download",
+           "recompute"]
 
 
 def split_data(data, num_slice, batch_axis=0, even_split=True):
@@ -64,3 +65,48 @@ def download(url, path=None, overwrite=False, sha1_hash=None, retries=5,
              verify_ssl=True):
     raise RuntimeError("network egress is unavailable in this environment; "
                        "place files locally instead (url=%s)" % url)
+
+
+
+def recompute(block, *args):
+    """``block(*args)`` with its forward recomputed in the backward pass
+    (``jax.checkpoint`` around this one block): a gradient through it keeps
+    the block's inputs and none of what it computed. Around each layer of a
+    stack, the activations held for the backward are one input a layer plus
+    one layer's working set, where ``TrainStep(remat=True)`` checkpoints the
+    whole forward at once and lowers nothing at the peak.
+
+    The block's parameters go in as arguments, like the inputs, so the call
+    is one pure function of arrays: one op on the eager tape, and inside a
+    compiled step its scope names (``Block.__call__``'s) stay on every op,
+    forward and recomputed. A block that updates auxiliary state or draws
+    random numbers in its forward (BatchNorm, Dropout) would do so twice:
+    refused."""
+    from .. import autograd
+    from ..ndarray import _apply
+    from . import _functional
+    arrs = [p.data() for p in block.collect_params().values()]
+    n = len(args)
+
+    def pure(*datas):
+        state = _functional._STATE
+        saved = [a._data for a in arrs]
+        for a, d in zip(arrs, datas[n:]):
+            a._data = d
+        key, n_aux = state.key, len(state.aux_updates or ())
+        try:
+            # one op on the eager tape: nothing inside is recorded
+            with autograd.pause(train_mode=autograd.is_training()):
+                out = block(*[NDArray(d) for d in datas[:n]])
+        finally:
+            for a, s in zip(arrs, saved):
+                a._data = s
+        if state.key is not key or len(state.aux_updates or ()) != n_aux:
+            raise ValueError(
+                "recompute(%s): the block updates auxiliary state or draws "
+                "random numbers in its forward, which a recomputation would "
+                "repeat" % block.name)
+        return out._data
+
+    import jax
+    return _apply(jax.checkpoint(pure), *args, *arrs)

@@ -6,6 +6,8 @@
 - ssd:        SSD object detection w/ MultiBox ops (config 4)
 - lstm_lm:    LSTM language model (config 5)
 - olmoe:      OLMoE decoder (RoPE + QK-norm attention, dropless SwiGLU MoE)
+- nemotron_h: Nemotron-H hybrid (Mamba-2, grouped-query attention, a latent
+              mixture of relu^2 experts), whole or as one chip's share
 """
 from .lenet import LeNet  # noqa
 from .bert import (BERTEncoder, BERTModel, TransformerEncoderLayer,  # noqa
@@ -14,6 +16,8 @@ from .gpt import (GPTModel, TransformerDecoderLayer, ChunkedLMLoss,  # noqa
                   FeaturesView)
 from .olmoe import (OLMoEModel, OLMoETransformerDecoderLayer,  # noqa
                     RotaryMultiHeadAttention, ChunkedUntiedLMLoss)
+from .nemotron_h import (NemotronHModel, NemotronHLayer, Mamba2Mixer,  # noqa
+                         GroupedQueryAttention, LatentMoE)
 from .lstm_lm import LSTMLanguageModel  # noqa
 from .ssd import SSD  # noqa
 from ..gluon.model_zoo.vision import get_model  # noqa

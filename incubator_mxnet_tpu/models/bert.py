@@ -23,22 +23,35 @@ from .lm_head import ChunkedHeadLossBase
 
 
 class MultiHeadAttention(HybridBlock):
+    """``num_kv_heads`` < ``num_heads`` is grouped-query attention (each
+    key-value head serves num_heads / num_kv_heads query heads);
+    ``head_dim`` is the heads' size where it is not units / num_heads, so
+    the q, k, v widths may differ from the input's. Both default to the
+    multi-head layout."""
+
     def __init__(self, units, num_heads, dropout=0.0, attention="dense",
-                 sp_axis="sp", tp_axis=None, causal=False, use_bias=True, **kwargs):
+                 sp_axis="sp", tp_axis=None, causal=False, use_bias=True,
+                 num_kv_heads=None, head_dim=None, **kwargs):
         super().__init__(**kwargs)
-        assert units % num_heads == 0
+        if head_dim is None:
+            assert units % num_heads == 0
+            head_dim = units // num_heads
+        num_kv_heads = num_kv_heads or num_heads
+        assert num_heads % num_kv_heads == 0
         self._units = units
         self._num_heads = num_heads
+        self._num_kv_heads = num_kv_heads
         self._dropout = dropout
         self._attention = attention
         self._sp_axis = sp_axis
         self._tp_axis = tp_axis
         self._causal = causal
+        q_units, kv_units = num_heads * head_dim, num_kv_heads * head_dim
         with self.name_scope():
-            self.query = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
-            self.key = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
-            self.value = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
-            self.proj = nn.Dense(units, flatten=False, in_units=units, use_bias=use_bias)
+            self.query = nn.Dense(q_units, flatten=False, in_units=units, use_bias=use_bias)
+            self.key = nn.Dense(kv_units, flatten=False, in_units=units, use_bias=use_bias)
+            self.value = nn.Dense(kv_units, flatten=False, in_units=units, use_bias=use_bias)
+            self.proj = nn.Dense(units, flatten=False, in_units=q_units, use_bias=use_bias)
         if tp_axis:
             # shard heads over tp: qkv col-parallel, out proj row-parallel
             for lyr in (self.query, self.key, self.value):
@@ -48,11 +61,11 @@ class MultiHeadAttention(HybridBlock):
             self.proj.weight.sharding = P(None, tp_axis)
 
     def forward(self, x, mask=None):
-        B, S, U = x.shape
+        B, S, _ = x.shape
         H = self._num_heads
-        D = U // H
         # (B, H, S, D) each; a subclass's QK-norm and RoPE live in project()
         q, k, v = self.project(x)
+        D = q.shape[-1]
 
         causal = self._causal
         if self._attention == "ring":
@@ -103,21 +116,27 @@ class MultiHeadAttention(HybridBlock):
             if self._dropout:
                 attn = nd.Dropout(attn, p=self._dropout)
             out = nd.batch_dot(attn, v.reshape((B * H, S, D))).reshape((B, H, S, D))
-        out = out.transpose((0, 2, 1, 3)).reshape((B, S, U))
+        out = out.transpose((0, 2, 1, 3)).reshape((B, S, H * D))
         return self.proj(out)
 
-    def split_heads(self, t):
-        """(B, S, U) -> (B, H, S, D)."""
+    def split_heads(self, t, kv=False):
+        """(B, S, heads * D) -> (B, H, S, D). ``kv``: t holds the
+        key-value heads, which grouped-query attention has fewer of; they
+        are repeated to the query heads here, so every attention path sees
+        three tensors of one shape."""
         B, S, U = t.shape
         H = self._num_heads
-        return t.reshape((B, S, H, U // H)).transpose((0, 2, 1, 3))
+        heads = self._num_kv_heads if kv else H
+        t = t.reshape((B, S, heads, U // heads)).transpose((0, 2, 1, 3))
+        return t if heads == H else nd.repeat(t, H // heads, axis=1)
 
     def project(self, x):
         """x (B, S, U) -> q, k, v, each (B, H, S, D). What a subclass
         changes between the projections and the scores (QK-norm, RoPE)
         goes here; the attention itself is shared."""
-        return tuple(self.split_heads(lyr(x))
-                     for lyr in (self.query, self.key, self.value))
+        return (self.split_heads(self.query(x)),
+                self.split_heads(self.key(x), kv=True),
+                self.split_heads(self.value(x), kv=True))
 
 
 class TransformerEncoderLayer(HybridBlock):

@@ -17,6 +17,13 @@ so on a mesh with an ``ep`` axis GSPMD shards the experts' state; the
 token exchange between chips (an all-to-all of the sorted rows) is not
 written yet (ROADMAP R2), and GSPMD gathers what the grouped matmul needs.
 
+A layer can be told which experts it HOLDS (``held=(first, count)``): one
+chip's share of an expert-parallel group. It routes over all the experts,
+as every rank does, and computes the part of the result its own experts
+give; what the absent ones would add is some other chip's and is left out
+(`dropless_moe_held`: at most T x min(k, count) rows, none dropped). On one
+chip that runs without its exchange; nothing stands in for the other ranks.
+
 An auxiliary load-balancing loss (Switch-Transformer form,
 ``E * sum_e fraction_routed_e * mean_gate_e``) and the ST-MoE router z-loss
 are returned by ``forward_with_aux`` for the trainer to add to the task
@@ -37,13 +44,14 @@ from .. import ndarray as nd
 from ..gluon.block import HybridBlock
 from ..ndarray import _apply
 
-__all__ = ["MoELayer", "dropless_moe", "load_balancing_loss",
-           "router_z_loss"]
+__all__ = ["MoELayer", "dropless_moe", "dropless_moe_held",
+           "load_balancing_loss", "router_z_loss", "relu2"]
 
 _DISPATCHES = telemetry.counter(
     "mxtpu_moe_dispatch_total",
-    "MoE expert dispatches traced, by path (one is left: the sort-based "
-    "dropless grouped matmul).", ("path",))
+    "MoE expert dispatches traced, by path (dropless: the sort-based "
+    "grouped matmul over every expert; dropless_held: the same over the "
+    "experts this chip holds).", ("path",))
 
 
 def load_balancing_loss(gates, top_idx, num_experts):
@@ -141,6 +149,114 @@ def dropless_moe(tokens, top_vals, top_idx, w_up, w_down, act, w_gate=None):
     return out.astype(tokens.dtype)
 
 
+# The held share's two moves, each other's transpose. Row i of the sorted
+# assignments belongs to token `token_of_row[i]`; slot j of token t sits at
+# row `row_of_slot[t, j]`. Written as gathers in both directions, as above.
+@jax.custom_vjp
+def _rows_of_tokens(tokens, token_of_row, row_of_slot, live_row, live_slot):
+    """tokens (T, D) -> rows (R, D): the token of each live row, zero for
+    a row no held expert owns."""
+    return jnp.where(live_row[:, None], tokens[token_of_row], 0)
+
+
+def _rows_of_tokens_fwd(tokens, token_of_row, row_of_slot, live_row,
+                        live_slot):
+    return _rows_of_tokens(tokens, token_of_row, row_of_slot, live_row,
+                           live_slot), (token_of_row, row_of_slot, live_row,
+                                        live_slot)
+
+
+def _rows_of_tokens_bwd(res, g):
+    token_of_row, row_of_slot, live_row, live_slot = res
+    return (_tokens_of_rows(g, token_of_row, row_of_slot, live_row,
+                            live_slot),) + (None,) * 4
+
+
+@jax.custom_vjp
+def _tokens_of_rows(rows, token_of_row, row_of_slot, live_row, live_slot):
+    """rows (R, D) -> (T, D): each token's sum over its live rows, in
+    float32."""
+    per_slot = jnp.where(live_slot[..., None], rows[row_of_slot], 0)
+    return per_slot.astype(jnp.float32).sum(1).astype(rows.dtype)
+
+
+def _tokens_of_rows_fwd(rows, token_of_row, row_of_slot, live_row, live_slot):
+    return _tokens_of_rows(rows, token_of_row, row_of_slot, live_row,
+                           live_slot), (token_of_row, row_of_slot, live_row,
+                                        live_slot)
+
+
+def _tokens_of_rows_bwd(res, g):
+    token_of_row, row_of_slot, live_row, live_slot = res
+    return (_rows_of_tokens(g, token_of_row, row_of_slot, live_row,
+                            live_slot),) + (None,) * 4
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
+def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
+                      w_gate=None):
+    """The held experts' part of `dropless_moe`'s sum:
+    y_t = sum_{j: first <= top_idx[t, j] < first + count} top_vals[t, j] *
+    FFN_{top_idx[t, j]}(tokens[t]), with the stacked weights those of the
+    `count` = w_up.shape[0] experts from `first` on.
+
+    The T x k assignments are sorted so that the held experts' come first,
+    in expert order; the static bound is R = T x min(k, count) rows, and a
+    token has at most min(k, count) held experts, so nothing can be
+    dropped. `group_sizes` counts the held experts only: the grouped matmul
+    leaves the rows past their sum zero. Scopes as in `dropless_moe`. Each
+    weighted row is rounded to the tokens' type before a token's rows are
+    summed in float32 (`dropless_moe` sums the products in float32).
+    """
+    n_tokens, k = top_idx.shape
+    count = w_up.shape[0]
+    per_token = min(k, count)
+    n_rows = n_tokens * per_token
+    _DISPATCHES.inc(path="dropless_held")
+    with jax.named_scope("moe_dispatch"):
+        local = top_idx.astype(jnp.int32) - first                 # (T, k)
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count).reshape(-1)           # (T*k,)
+        slots = jnp.arange(key.shape[0], dtype=jnp.int32)
+        # a stable sort: held assignments first, by expert; and the way back
+        _, order = jax.lax.sort_key_val(key, slots)
+        _, inverse = jax.lax.sort_key_val(order, slots)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(count, dtype=jnp.int32),
+            axis=0, dtype=jnp.int32)                              # (count,)
+        rows_iota = jnp.arange(n_rows, dtype=jnp.int32)
+        live_row = rows_iota < group_sizes.sum()
+        # of a token's k slots, its held ones first (at most per_token)
+        live_slot, slot = jax.lax.top_k(held.astype(jnp.int32), per_token)
+        live_slot = live_slot.astype(bool)                        # (T, m)
+        # a dead row or slot is masked wherever it is read, so its index is
+        # free: its own position, which keeps both gathers sequential but
+        # for the few live entries (thousands of slots all reading one
+        # clamped row made the gather's time follow the data)
+        token_of_row = jnp.where(live_row, order[:n_rows] // k,
+                                 rows_iota // per_token)
+        row_of_slot = jnp.where(
+            live_slot,
+            jnp.take_along_axis(inverse.reshape(n_tokens, k), slot, 1),
+            rows_iota.reshape(n_tokens, per_token))
+        weight = jnp.where(live_row, top_vals.reshape(-1)[order[:n_rows]], 0)
+        rows = _rows_of_tokens(tokens, token_of_row, row_of_slot, live_row,
+                               live_slot)                         # (R, D)
+    with jax.named_scope("moe_experts"):
+        grouped = functools.partial(jax.lax.ragged_dot,
+                                    group_sizes=group_sizes)
+        h = act(grouped(rows, w_up)) if w_gate is None \
+            else act(grouped(rows, w_gate)) * grouped(rows, w_up)
+        y = grouped(h, w_down)                                    # (R, D)
+    with jax.named_scope("moe_combine"):
+        y = (y.astype(jnp.float32) * weight[:, None]).astype(tokens.dtype)
+        return _tokens_of_rows(y, token_of_row, row_of_slot, live_row,
+                               live_slot)
+
+
 class _StackedXavier(initializer.Initializer):
     """Xavier (uniform, avg) for E stacked (fan_in, fan_out) matrices:
     every expert gets the scale its own matrix would. Xavier itself reads a
@@ -154,32 +270,56 @@ class _StackedXavier(initializer.Initializer):
             arr.dtype)._data
 
 
+def relu2(x):
+    """relu(x)^2 (Nemotron-H's `mlp_hidden_act`)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 _ACTIVATIONS = {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
-                "silu": jax.nn.silu}
+                "silu": jax.nn.silu, "relu2": relu2}
+_ROUTERS = ("softmax", "sigmoid_bias")
 
 
 class MoELayer(HybridBlock):
     """Top-k routed expert FFN, dropless: y = sum_k g_k * FFN_{e_k}(x).
 
-    The router is softmax(x Wr) over all experts in float32; the k largest
-    probabilities weigh the chosen experts' outputs, renormalised to sum
-    to one when ``norm_topk_prob`` (the Switch/GShard convention and the
-    default) and used as they are otherwise (OLMoE, Mixtral-style
+    ``router="softmax"``: softmax(x Wr) over all experts in float32; the k
+    largest probabilities weigh the chosen experts' outputs, renormalised
+    to sum to one when ``norm_topk_prob`` (the Switch/GShard convention and
+    the default) and used as they are otherwise (OLMoE, Mixtral-style
     configurations with ``norm_topk_prob: false``).
+    ``router="sigmoid_bias"`` (DeepSeek-V3, Nemotron-H): s = sigmoid(x Wr)
+    in float32; the k experts are those of the largest s + b, where b
+    (``router_bias``, float32, not trained by the gradient: the source
+    moves it by a load-balancing rule between steps) only CHOOSES; the
+    weights are the chosen s themselves, over their sum (+ 1e-20) when
+    ``norm_topk_prob``. Either router's weights are then times ``scale``
+    (``routed_scaling_factor``).
 
     Weights, stacked over experts with E sharded over ``ep_axis``:
     ``w1`` (E, D, H) and ``w2`` (E, H, D); ``gated=True`` adds ``w3``
     (E, D, H) and the experts become act(x w1) * (x w3) -> w2 (SwiGLU with
-    activation='silu'). ``forward`` returns the output only;
-    ``forward_with_aux`` also the load-balancing + z loss for the trainer
-    to add to the task loss. Every token reaches all its k experts
-    (`dropless_moe`): there is no capacity and no second dispatch.
+    activation='silu'); ``activation="relu2"`` is relu(x)^2. ``forward``
+    returns the output only; ``forward_with_aux`` also the load-balancing +
+    z loss for the trainer to add to the task loss. Every token reaches all
+    its k experts (`dropless_moe`): there is no capacity and no second
+    dispatch.
+
+    ``held=(first, count)``: this layer holds the experts first ..
+    first + count - 1 of ``num_experts`` and the stacked weights are
+    (count, ..). The router is as wide as ever and chooses among all; the
+    output is the held experts' part of the sum (`dropless_moe_held`),
+    normalised over all k chosen. ``held=None`` holds every expert and is
+    the path above, unchanged. ``router_units`` is the width of what the
+    router reads where that is not the experts' input (``forward(x,
+    route_on)``: a latent mixture routes on the full-width activations).
     """
 
     def __init__(self, num_experts, hidden_size, ffn_hidden, top_k=2,
                  ep_axis="ep", activation="relu", gated=False,
                  norm_topk_prob=True, z_loss_coef=1e-3,
-                 capacity_factor=None, **kwargs):
+                 capacity_factor=None, router="softmax", scale=1.0,
+                 held=None, router_units=None, **kwargs):
         super().__init__(**kwargs)
         if capacity_factor is not None:
             import warnings
@@ -190,46 +330,93 @@ class MoELayer(HybridBlock):
                 % (capacity_factor, top_k), stacklevel=2)
         if not 1 <= top_k <= num_experts:
             raise ValueError("top_k=%d of %d experts" % (top_k, num_experts))
+        if router not in _ROUTERS:
+            raise ValueError("router=%r (one of %s)" % (router, _ROUTERS))
+        if held is not None and not (
+                0 <= held[0] and held[1] >= 1
+                and held[0] + held[1] <= num_experts):
+            raise ValueError("held=%r of %d experts" % (held, num_experts))
         self.num_experts = num_experts
         self.top_k = top_k
         self.norm_topk_prob = norm_topk_prob
         self.z_loss_coef = z_loss_coef
+        self.held = held
         self._act = activation
         self._gated = gated
-        stacked = {"w1": (num_experts, hidden_size, ffn_hidden),
-                   "w2": (num_experts, ffn_hidden, hidden_size)}
+        self._router = router
+        self._scale = scale
+        n_held = num_experts if held is None else held[1]
+        stacked = {"w1": (n_held, hidden_size, ffn_hidden),
+                   "w2": (n_held, ffn_hidden, hidden_size)}
         if gated:
             stacked["w3"] = stacked["w1"]
         with self.name_scope():
             self.gate_weight = self.params.get(
-                "gate_weight", shape=(num_experts, hidden_size), init="xavier")
+                "gate_weight", shape=(num_experts, router_units or hidden_size),
+                init="xavier")
+            if router == "sigmoid_bias":
+                self.router_bias = self.params.get(
+                    "router_bias", shape=(num_experts,), init="zeros",
+                    grad_req="null")
             for name, shape in stacked.items():
                 param = self.params.get(name, shape=shape,
                                         init=_StackedXavier())
                 param.sharding = P(ep_axis, None, None)
                 setattr(self, name, param)
 
-    def route(self, tokens, gw):
+    def cast(self, dtype):
+        super().cast(dtype)
+        if self._router == "sigmoid_bias":
+            # it is added to float32 scores and only chooses
+            self.router_bias.cast("float32")
+
+    def route(self, tokens, gw, bias=None):
         """tokens (T, D), gw (E, D) -> (logits, gates, top_vals, top_idx),
         all float32 but the indices: the matmul accumulates in float32 and
-        the softmax and top-k never see a narrower type."""
+        the scores and top-k never see a narrower type."""
         logits = jnp.einsum("td,ed->te", tokens, gw,
                             preferred_element_type=jnp.float32)
-        gates = jax.nn.softmax(logits, axis=-1)
-        top_vals, top_idx = jax.lax.top_k(gates, self.top_k)
-        if self.norm_topk_prob:
-            top_vals = top_vals / jnp.sum(top_vals, -1, keepdims=True)
+        if self._router == "softmax":
+            gates = jax.nn.softmax(logits, axis=-1)
+            top_vals, top_idx = jax.lax.top_k(gates, self.top_k)
+            if self.norm_topk_prob:
+                top_vals = top_vals / jnp.sum(top_vals, -1, keepdims=True)
+        else:
+            gates = jax.nn.sigmoid(logits)
+            _, top_idx = jax.lax.top_k(gates + bias.astype(jnp.float32),
+                                       self.top_k)
+            top_vals = jnp.take_along_axis(gates, top_idx, -1)
+            if self.norm_topk_prob:
+                top_vals = top_vals / (jnp.sum(top_vals, -1, keepdims=True)
+                                       + 1e-20)
+        if self._scale != 1.0:
+            top_vals = top_vals * self._scale
         return logits, gates, top_vals, top_idx
 
-    def _fn(self, xd, gw, w1, w2, *w3, compute_aux):
+    def _fn(self, arrays, compute_aux):
+        """``arrays``: {name: data} of the call's inputs (``x``, where the
+        router reads something else ``route_on``) and ``_weight_names``."""
+        xd = arrays["x"]
         shape = xd.shape
         tokens = xd.reshape(-1, shape[-1])                        # (T, D)
+        seen = arrays.get("route_on")
+        seen = tokens if seen is None else seen.reshape(-1, seen.shape[-1])
+        # the softmax router is called as it always was: (tokens, gw)
+        bias = (arrays["router_bias"],) if "router_bias" in arrays else ()
         with jax.named_scope("router"):
-            logits, gates, top_vals, top_idx = self.route(tokens, gw)
+            logits, gates, top_vals, top_idx = self.route(
+                seen, arrays["gate_weight"], *bias)
         # gated: w1 is the activated (gate) projection, w3 the linear one
-        w_gate, w_up = (w1, w3[0]) if w3 else (None, w1)
-        out = dropless_moe(tokens, top_vals, top_idx, w_up, w2,
-                           _ACTIVATIONS[self._act], w_gate).reshape(shape)
+        w_gate, w_up = (arrays["w1"], arrays["w3"]) if self._gated \
+            else (None, arrays["w1"])
+        act = _ACTIVATIONS[self._act]
+        if self.held is None:
+            out = dropless_moe(tokens, top_vals, top_idx, w_up, arrays["w2"],
+                               act, w_gate)
+        else:
+            out = dropless_moe_held(tokens, top_vals, top_idx, w_up,
+                                    arrays["w2"], act, self.held[0], w_gate)
+        out = out.reshape(shape)
         if compute_aux:
             with jax.named_scope("router"):
                 aux = load_balancing_loss(gates, top_idx, self.num_experts) \
@@ -237,17 +424,28 @@ class MoELayer(HybridBlock):
             return out, aux
         return out
 
+    def _weight_names(self):
+        return ("gate_weight",) \
+            + (("router_bias",) if self._router == "sigmoid_bias" else ()) \
+            + ("w1", "w2") + (("w3",) if self._gated else ())
+
     def _weights(self):
-        names = ("gate_weight", "w1", "w2") + (("w3",) if self._gated else ())
-        return [getattr(self, n).data() for n in names]
+        return [getattr(self, n).data() for n in self._weight_names()]
 
-    def forward(self, x):
-        """x: (..., D) → (..., D)."""
-        return _apply(functools.partial(self._fn, compute_aux=False), x,
-                      *self._weights())
+    def _call(self, x, route_on, compute_aux):
+        given = {"x": x} if route_on is None else {"x": x,
+                                                   "route_on": route_on}
+        names = tuple(given) + self._weight_names()
+        return _apply(
+            lambda *datas: self._fn(dict(zip(names, datas)), compute_aux),
+            *given.values(), *self._weights())
 
-    def forward_with_aux(self, x):
+    def forward(self, x, route_on=None):
+        """x: (..., D) → (..., D); the router reads ``route_on``
+        (..., router_units) where given, else x."""
+        return self._call(x, route_on, False)
+
+    def forward_with_aux(self, x, route_on=None):
         """Returns (y, aux) where aux = Switch load-balancing loss +
         z_loss_coef * ST-MoE router z-loss (add to the task loss)."""
-        return _apply(functools.partial(self._fn, compute_aux=True), x,
-                      *self._weights())
+        return self._call(x, route_on, True)

@@ -158,3 +158,13 @@ def test_mesh_train_step_hands_flash_its_mesh(monkeypatch):
         parallel.set_current_mesh(None)
     assert seen == [(mesh, "dp")], seen
     assert loss == loss and parallel.mesh.step_mesh() is None
+
+
+def test_the_scan_alone_reads_between_a_float32_and_a_bfloat16_state():
+    """The hybrid phase's own limit at the rehearsal shape: the chunked
+    scan in float32 is under it (summation order: 3e-7 here, 1.9e-5 on a
+    v5e at the cell's shape), the recurrence with a state rounded to
+    bfloat16 once a chunk is over it (2.7e-3 here, 9.3e-4 there)."""
+    sound, rounded = chip_smoke.scan_alone(chip_smoke.TOY["hybrid"])
+    assert sound < chip_smoke.SCAN_ALONE_LIMIT / 10
+    assert rounded > chip_smoke.SCAN_ALONE_LIMIT * 5
