@@ -21,8 +21,10 @@ A layer can be told which experts it HOLDS (``held=(first, count)``): one
 chip's share of an expert-parallel group. It routes over all the experts,
 as every rank does, and computes the part of the result its own experts
 give; what the absent ones would add is some other chip's and is left out
-(`dropless_moe_held`: at most T x min(k, count) rows, none dropped). On one
-chip that runs without its exchange; nothing stands in for the other ranks.
+(`dropless_moe_held`: windows of W sorted rows, W twice what a balanced
+router sends the held experts, as many windows as hold a live row, none
+dropped). On one chip that runs without its exchange; nothing stands in for
+the other ranks.
 
 An auxiliary load-balancing loss (Switch-Transformer form,
 ``E * sum_e fraction_routed_e * mean_gate_e``) and the ST-MoE router z-loss
@@ -52,6 +54,11 @@ _DISPATCHES = telemetry.counter(
     "MoE expert dispatches traced, by path (dropless: the sort-based "
     "grouped matmul over every expert; dropless_held: the same over the "
     "experts this chip holds).", ("path",))
+_HELD_ROWS = telemetry.gauge(
+    "mxtpu_moe_held_rows",
+    "Rows of the held dispatch's buffers as last traced: one window of "
+    "live rows (window), and the T x min(k, count) rows that every token "
+    "on every held expert would make (worst_case).", ("kind",))
 
 
 def load_balancing_loss(gates, top_idx, num_experts):
@@ -149,112 +156,216 @@ def dropless_moe(tokens, top_vals, top_idx, w_up, w_down, act, w_gate=None):
     return out.astype(tokens.dtype)
 
 
-# The held share's two moves, each other's transpose. Row i of the sorted
-# assignments belongs to token `token_of_row[i]`; slot j of token t sits at
-# row `row_of_slot[t, j]`. Written as gathers in both directions, as above.
-@jax.custom_vjp
-def _rows_of_tokens(tokens, token_of_row, row_of_slot, live_row, live_slot):
-    """tokens (T, D) -> rows (R, D): the token of each live row, zero for
-    a row no held expert owns."""
-    return jnp.where(live_row[:, None], tokens[token_of_row], 0)
+def held_window_rows(n_tokens, k, count, num_experts):
+    """W, the rows one window of the held dispatch handles: twice the
+    T x k x count / E assignments a balanced router sends the held experts,
+    in whole 1024s, and never more than the worst case T x min(k, count).
+    From shapes alone; `held=(0, E)` gives T x k, one window."""
+    return min(n_tokens * min(k, count),
+               -(-2 * n_tokens * k * count // (1024 * num_experts)) * 1024)
 
 
-def _rows_of_tokens_fwd(tokens, token_of_row, row_of_slot, live_row,
-                        live_slot):
-    return _rows_of_tokens(tokens, token_of_row, row_of_slot, live_row,
-                           live_slot), (token_of_row, row_of_slot, live_row,
-                                        live_slot)
+def _over_windows(window, order, group_sizes, body, init):
+    """`body(w, carry)` for every window w of `order` that holds a live
+    row, ceil(group_sizes.sum() / window) of them (data): one `while_loop`,
+    or where one window is all of `order` the body itself, once."""
+    def named(w, carry):
+        with jax.named_scope("moe_window"):
+            return body(w, carry)
+
+    if order.shape[0] == window:
+        return named(0, init)
+    n_windows = (group_sizes.sum() + window - 1) // window
+    return jax.lax.while_loop(
+        lambda c: c[0] < n_windows,
+        lambda c: (c[0] + 1, named(c[0], c[1])),
+        (jnp.int32(0), init))[1]
 
 
-def _rows_of_tokens_bwd(res, g):
-    token_of_row, row_of_slot, live_row, live_slot = res
-    return (_tokens_of_rows(g, token_of_row, row_of_slot, live_row,
-                            live_slot),) + (None,) * 4
+def _window_of(w, window, tokens, top_vals, order, group_sizes):
+    """Sorted rows [w W, (w + 1) W) -> (slot, token, live, sizes, rows,
+    weight): the (token, slot) assignment of each row as an index into
+    T x k and its token, whether a held expert owns the row, each held
+    expert's rows inside the window, the rows' tokens (W, D) and router
+    weights. A dead row's token is its own position: it adds zero wherever
+    it lands, and no two dead rows land on one token."""
+    with jax.named_scope("moe_dispatch"):
+        lo = w * window
+        iota = jnp.arange(window, dtype=jnp.int32)
+        ends = jnp.cumsum(group_sizes)
+        live = lo + iota < ends[-1]
+        slot = jax.lax.dynamic_slice(order, (lo,), (window,))
+        token = jnp.where(live, slot // top_vals.shape[1],
+                          iota % tokens.shape[0])
+        sizes = jnp.clip(ends, lo, lo + window) \
+            - jnp.clip(ends - group_sizes, lo, lo + window)
+        return slot, token, live, sizes, tokens[token], \
+            top_vals.reshape(-1)[slot]
 
 
-@jax.custom_vjp
-def _tokens_of_rows(rows, token_of_row, row_of_slot, live_row, live_slot):
-    """rows (R, D) -> (T, D): each token's sum over its live rows, in
-    float32."""
-    per_slot = jnp.where(live_slot[..., None], rows[row_of_slot], 0)
-    return per_slot.astype(jnp.float32).sum(1).astype(rows.dtype)
+def _hidden(act, *pre):
+    """The experts' hidden rows of their pre-activations: act(up), or gated
+    act(gate) * up."""
+    return act(pre[0]) if len(pre) == 1 else act(pre[0]) * pre[1]
 
 
-def _tokens_of_rows_fwd(rows, token_of_row, row_of_slot, live_row, live_slot):
-    return _tokens_of_rows(rows, token_of_row, row_of_slot, live_row,
-                           live_slot), (token_of_row, row_of_slot, live_row,
-                                        live_slot)
+def _weighted(y, weight):
+    """Each expert row times its router weight, rounded to the rows' type."""
+    return (y.astype(jnp.float32) * weight[:, None]).astype(y.dtype)
 
 
-def _tokens_of_rows_bwd(res, g):
-    token_of_row, row_of_slot, live_row, live_slot = res
-    return (_rows_of_tokens(g, token_of_row, row_of_slot, live_row,
-                            live_slot),) + (None,) * 4
+# A grouped matmul answers for the rows its groups own. What it leaves in
+# the others is the kernel's business (zeros off the TPU, whatever was
+# there on it), and those rows go on into sums: they are made zero here.
+def _grouped(lhs, rhs, sizes, live):
+    return jnp.where(live[:, None], jax.lax.ragged_dot(lhs, rhs, sizes), 0)
 
 
-_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
-_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+def _grouped_t(lhs, rhs, sizes, live, g):
+    """Both transposes of ragged_dot(lhs, rhs, sizes) at its cotangent."""
+    (d_lhs,) = jax.linear_transpose(
+        lambda x: jax.lax.ragged_dot(x, rhs, sizes), lhs)(g)
+    (d_rhs,) = jax.linear_transpose(
+        lambda w: jax.lax.ragged_dot(lhs, w, sizes), rhs)(g)
+    return jnp.where(live[:, None], d_lhs, 0), d_rhs
+
+
+def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
+                  order, group_sizes):
+    """-> ((T, D) float32 sum, kept). With `keep` each window's
+    pre-activations and expert rows are written into (windows x W)-row
+    buffers for the backward; without, `kept` is empty."""
+    def body(w, carry):
+        total, kept = carry
+        _, token, live, sizes, rows, weight = _window_of(
+            w, window, tokens, top_vals, order, group_sizes)
+        with jax.named_scope("moe_experts"):
+            pre = tuple(_grouped(rows, m, sizes, live)
+                        for m in projections)                     # (W, H)
+            y = _grouped(_hidden(act, *pre), w_down, sizes, live)
+        with jax.named_scope("moe_combine"):
+            total = total.at[token].add(
+                _weighted(y, weight).astype(jnp.float32))
+        return total, jax.tree.map(
+            lambda buf, x: jax.lax.dynamic_update_slice(
+                buf, x, (w * window, 0)), kept, (pre, y) if keep else ())
+
+    def buffer(width):
+        return jnp.zeros((order.shape[0], width), tokens.dtype)
+
+    kept = ((buffer(w_down.shape[1]),) * len(projections),
+            buffer(w_down.shape[2])) if keep else ()
+    return _over_windows(window, order, group_sizes, body,
+                         (jnp.zeros(tokens.shape, jnp.float32), kept))
+
+
+# The held experts' sum, one window of live rows at a time. Forward and
+# backward are each ONE loop over ONE body: the grouped matmuls are traced
+# once a pass, however many windows run. The forward that is differentiated
+# keeps what the backward reads (pre-activations and expert rows) in
+# buffers a window writes its own rows of; a window that does not run
+# costs those buffers' zeros and nothing else. The moves between tokens
+# and rows are a gather one way and a float32 scatter-add the other, W rows
+# each.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_sum(act, window, tokens, top_vals, projections, w_down, order,
+              group_sizes):
+    """tokens (T, D), top_vals (T, k), projections (w_up,) or (w_gate,
+    w_up), order (windows x W,) the sorted assignments' slots, group_sizes
+    (count,) -> (T, D) float32."""
+    return _held_forward(act, window, False, tokens, top_vals, projections,
+                         w_down, order, group_sizes)[0]
+
+
+def _held_sum_fwd(act, window, *args):
+    total, kept = _held_forward(act, window, True, *args)
+    return total, args + (kept,)
+
+
+def _held_sum_bwd(act, window, res, g):
+    tokens, top_vals, projections, w_down, order, group_sizes, kept = res
+    def body(w, sums):
+        d_tokens, d_vals, d_projections, d_w_down = sums
+        slot, token, live, sizes, rows, weight = _window_of(
+            w, window, tokens, top_vals, order, group_sizes)
+        pre, y = jax.tree.map(
+            lambda buf: jax.lax.dynamic_slice(
+                buf, (w * window, 0), (window, buf.shape[1])), kept)
+        with jax.named_scope("moe_combine"):
+            _, pull = jax.vjp(_weighted, y, weight)
+            d_y, d_weight = pull(g[token].astype(y.dtype))
+        with jax.named_scope("moe_experts"):
+            h, pull = jax.vjp(functools.partial(_hidden, act), *pre)
+            d_h, d_down = _grouped_t(h, w_down, sizes, live, d_y)
+            d_rows, d_ms = zip(*(_grouped_t(rows, m, sizes, live, d)
+                                 for m, d in zip(projections, pull(d_h))))
+        with jax.named_scope("moe_dispatch"):
+            d_tokens = d_tokens.at[token].add(
+                sum(d.astype(jnp.float32) for d in d_rows))
+            d_vals = d_vals.at[slot].add(d_weight)
+        return d_tokens, d_vals, tuple(
+            total + d.astype(jnp.float32)
+            for total, d in zip(d_projections, d_ms)), \
+            d_w_down + d_down.astype(jnp.float32)
+
+    primals = (tokens, top_vals.reshape(-1), projections, w_down)
+    sums = _over_windows(
+        window, order, group_sizes, body,
+        jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), primals))
+    d_tokens, d_vals, d_projections, d_w_down = jax.tree.map(
+        lambda d, x: d.astype(x.dtype), sums, primals)
+    return (d_tokens, d_vals.reshape(top_vals.shape), d_projections,
+            d_w_down, None, None)
+
+
+_held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
 
 
 def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
-                      w_gate=None):
+                      num_experts, w_gate=None):
     """The held experts' part of `dropless_moe`'s sum:
     y_t = sum_{j: first <= top_idx[t, j] < first + count} top_vals[t, j] *
     FFN_{top_idx[t, j]}(tokens[t]), with the stacked weights those of the
-    `count` = w_up.shape[0] experts from `first` on.
+    `count` = w_up.shape[0] experts from `first` on, of `num_experts` the
+    router chooses among.
 
     The T x k assignments are sorted so that the held experts' come first,
-    in expert order; the static bound is R = T x min(k, count) rows, and a
-    token has at most min(k, count) held experts, so nothing can be
-    dropped. `group_sizes` counts the held experts only: the grouped matmul
-    leaves the rows past their sum zero. Scopes as in `dropless_moe`. Each
-    weighted row is rounded to the tokens' type before a token's rows are
-    summed in float32 (`dropless_moe` sums the products in float32).
+    in expert order, and handled in windows of W = `held_window_rows(T, k,
+    count, num_experts)` sorted rows: a window gathers its rows' tokens,
+    runs the grouped matmuls with each held expert's rows inside it as
+    `group_sizes`, weighs and rounds each row to the tokens' type and adds
+    it into its token's float32 sum. As many windows run as hold a live
+    row (`lax.while_loop`: one where the router is anywhere near balanced,
+    T x min(k, count) / W at worst), so nothing is dropped at any load, and
+    every op works on W rows (what the backward reads of the forward is
+    kept in buffers of all the windows' rows, which a window that runs
+    writes its part of). Where W is the worst case there is no loop.
+    Scopes as in `dropless_moe`, inside `moe_window`.
     """
     n_tokens, k = top_idx.shape
     count = w_up.shape[0]
-    per_token = min(k, count)
-    n_rows = n_tokens * per_token
+    worst = n_tokens * min(k, count)
+    window = held_window_rows(n_tokens, k, count, num_experts)
+    n_windows = -(-worst // window)
     _DISPATCHES.inc(path="dropless_held")
+    _HELD_ROWS.set(window, kind="window")
+    _HELD_ROWS.set(worst, kind="worst_case")
     with jax.named_scope("moe_dispatch"):
         local = top_idx.astype(jnp.int32) - first                 # (T, k)
-        held = (local >= 0) & (local < count)
-        key = jnp.where(held, local, count).reshape(-1)           # (T*k,)
+        key = jnp.where((local >= 0) & (local < count), local,
+                        count).reshape(-1)                        # (T*k,)
         slots = jnp.arange(key.shape[0], dtype=jnp.int32)
-        # a stable sort: held assignments first, by expert; and the way back
+        # a stable sort: held assignments first, by expert
         _, order = jax.lax.sort_key_val(key, slots)
-        _, inverse = jax.lax.sort_key_val(order, slots)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(count, dtype=jnp.int32),
             axis=0, dtype=jnp.int32)                              # (count,)
-        rows_iota = jnp.arange(n_rows, dtype=jnp.int32)
-        live_row = rows_iota < group_sizes.sum()
-        # of a token's k slots, its held ones first (at most per_token)
-        live_slot, slot = jax.lax.top_k(held.astype(jnp.int32), per_token)
-        live_slot = live_slot.astype(bool)                        # (T, m)
-        # a dead row or slot is masked wherever it is read, so its index is
-        # free: its own position, which keeps both gathers sequential but
-        # for the few live entries (thousands of slots all reading one
-        # clamped row made the gather's time follow the data)
-        token_of_row = jnp.where(live_row, order[:n_rows] // k,
-                                 rows_iota // per_token)
-        row_of_slot = jnp.where(
-            live_slot,
-            jnp.take_along_axis(inverse.reshape(n_tokens, k), slot, 1),
-            rows_iota.reshape(n_tokens, per_token))
-        weight = jnp.where(live_row, top_vals.reshape(-1)[order[:n_rows]], 0)
-        rows = _rows_of_tokens(tokens, token_of_row, row_of_slot, live_row,
-                               live_slot)                         # (R, D)
-    with jax.named_scope("moe_experts"):
-        grouped = functools.partial(jax.lax.ragged_dot,
-                                    group_sizes=group_sizes)
-        h = act(grouped(rows, w_up)) if w_gate is None \
-            else act(grouped(rows, w_gate)) * grouped(rows, w_up)
-        y = grouped(h, w_down)                                    # (R, D)
-    with jax.named_scope("moe_combine"):
-        y = (y.astype(jnp.float32) * weight[:, None]).astype(tokens.dtype)
-        return _tokens_of_rows(y, token_of_row, row_of_slot, live_row,
-                               live_slot)
+        # no live row lies past the worst case; whole windows
+        order = jnp.pad(order[:worst], (0, n_windows * window - worst))
+    out = _held_sum(act, window, tokens, top_vals,
+                    (w_up,) if w_gate is None else (w_gate, w_up), w_down,
+                    order, group_sizes)
+    return out.astype(tokens.dtype)
 
 
 class _StackedXavier(initializer.Initializer):
@@ -415,7 +526,8 @@ class MoELayer(HybridBlock):
                                act, w_gate)
         else:
             out = dropless_moe_held(tokens, top_vals, top_idx, w_up,
-                                    arrays["w2"], act, self.held[0], w_gate)
+                                    arrays["w2"], act, self.held[0],
+                                    self.num_experts, w_gate)
         out = out.reshape(shape)
         if compute_aux:
             with jax.named_scope("router"):

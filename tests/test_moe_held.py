@@ -47,7 +47,7 @@ def test_held_dispatch_is_the_held_experts_part_of_the_dense_sum(
     sl = slice(first, first + count)
     with jax.default_matmul_precision("highest"):
         got = moe.dropless_moe_held(
-            tokens, top_vals, top_idx, w_up[sl], w_down[sl], act, first,
+            tokens, top_vals, top_idx, w_up[sl], w_down[sl], act, first, E,
             None if w_gate is None else w_gate[sl])
         want = dense_moe(tokens, _only(top_vals, top_idx, first, count),
                          top_idx, w_up, w_down, act, w_gate)
@@ -62,7 +62,7 @@ def test_held_dispatch_gradients_are_the_dense_ones():
 
     def held(tokens, top_vals, w_up_s, w_down_s):
         return jnp.sum(moe.dropless_moe_held(
-            tokens, top_vals, top_idx, w_up_s, w_down_s, act, first) ** 2)
+            tokens, top_vals, top_idx, w_up_s, w_down_s, act, first, E) ** 2)
 
     def dense(tokens, top_vals, w_up_s, w_down_s):
         full_up = w_up.at[sl].set(w_up_s)
@@ -80,16 +80,246 @@ def test_held_dispatch_gradients_are_the_dense_ones():
                                     atol=2e-5 * float(jnp.abs(w).max()))
 
 
-def test_held_rows_are_the_shares_bound_not_t_times_k():
-    """R = T x min(k, count) rows reach the grouped matmul, never T x k;
-    no (T x k, D) tensor exists."""
+@pytest.mark.parametrize("shape,rows", [
+    ((8192, 22, 8, 512), 6144),     # Nemotron's share: 5632 in whole 1024s
+    ((1024, 4, 4, 64), 1024),       # 512 -> one 1024
+    ((4096, 8, 64, 64), 4096 * 8),  # every expert held: T x k, the worst case
+    ((48, 5, 3, 12), 48 * 3),       # a small share: the worst case is less
+])
+def test_window_rows_come_from_the_shapes(shape, rows):
+    """W = min(T min(k, count), roundup(2 T k count / E, 1024))."""
+    assert moe.held_window_rows(*shape) == rows
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its loops' and calls' bodies
+    among them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _row_counts(jaxpr, widths):
+    return {v.aval.shape[0] for eqn in _eqns(jaxpr) for v in eqn.outvars
+            if len(v.aval.shape) == 2 and v.aval.shape[1] in widths}
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_no_op_reads_or_writes_more_rows_than_the_window(gated):
+    """At Nemotron's ratio (8 of 512 held, 22 a token) W is 6144 of the
+    65 536 worst-case rows. The value's jaxpr holds no (n, D) or (n, H)
+    tensor with n > W but the tokens' own T rows. The gradient's holds the
+    kept buffers of 11 windows beside them, and the only ops that touch
+    those are the zeros they start as, the loop that carries them and a
+    window's slice in and out: no dead row is gathered, multiplied, squared
+    or cast. The loop is a `while`."""
+    n_tokens, k, count, n_experts = 8192, 22, 8, 512
+    window = moe.held_window_rows(n_tokens, k, count, n_experts)
+    assert window == 6144
+    tokens = jnp.zeros((n_tokens, D))
+    top_vals = jnp.ones((n_tokens, k))
+    top_idx = jnp.zeros((n_tokens, k), jnp.int32)
+    w_up, w_down = jnp.zeros((count, D, H)), jnp.zeros((count, H, D))
+    w_gate = w_up if gated else None
+
+    def value(tokens, top_vals, w_up, w_down, w_gate):
+        return jnp.sum(moe.dropless_moe_held(
+            tokens, top_vals, top_idx, w_up, w_down, moe.relu2, 0, n_experts,
+            w_gate))
+
+    args = (tokens, top_vals, w_up, w_down, w_gate)
+    jaxpr = jax.make_jaxpr(value)(*args).jaxpr
+    assert any(e.primitive.name == "while" for e in _eqns(jaxpr))
+    assert _row_counts(jaxpr, (D, H)) - {D, H} == {window, n_tokens}
+    jaxpr = jax.make_jaxpr(jax.grad(value, (0, 1, 2, 3)))(*args).jaxpr
+    kept = 11 * window
+    assert _row_counts(jaxpr, (D, H)) - {D, H} == {window, n_tokens, kept}
+    touching = {e.primitive.name for e in _eqns(jaxpr)
+                if any(getattr(v.aval, "shape", ()) in ((kept, D), (kept, H))
+                       for v in list(e.invars) + list(e.outvars))}
+    assert touching == {"broadcast_in_dim", "while", "dynamic_update_slice",
+                        "dynamic_slice"}
+
+
+def test_holding_every_expert_is_one_window_and_no_loop():
+    """`held=(0, E)`: the window is T x k, and the program is straight-line
+    in value and gradient."""
     tokens, top_vals, top_idx, w_up, w_down = _case(2)
-    jaxpr = jax.make_jaxpr(lambda t: moe.dropless_moe_held(
-        t, top_vals, top_idx, w_up[:3], w_down[:3], jax.nn.relu, 0))(tokens)
-    shapes = {v.aval.shape for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars}
-    assert (T * 3, D) in shapes and (T * 3, H) in shapes
-    assert not any(s and s[0] == T * K and len(s) == 2 and s[1] in (D, H)
-                   for s in shapes)
+
+    def value(tokens, top_vals, w_up, w_down):
+        return jnp.sum(moe.dropless_moe_held(
+            tokens, top_vals, top_idx, w_up, w_down, jax.nn.relu, 0, E))
+
+    args = (tokens, top_vals, w_up, w_down)
+    for fn in (value, jax.grad(value, (0, 1, 2, 3))):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+        assert not any(e.primitive.name in ("while", "scan", "cond")
+                       for e in _eqns(jaxpr))
+        assert T * K in _row_counts(jaxpr, (D, H))
+
+
+def _routing(kind, n_tokens, k, first, count, n_experts, rng):
+    """(T, k) distinct experts a token, by what the held experts get."""
+    held = onp.arange(first, first + count)
+    others = onp.setdiff1d(onp.arange(n_experts), held)
+    idx = onp.stack([rng.permutation(others)[:k] for _ in range(n_tokens)])
+    if kind == "every_token_on_every_held":          # the most windows
+        idx[:, :count] = held
+    elif kind == "two_held_a_token":                 # two windows
+        idx[:, :2] = held[:2]
+    elif kind == "all_on_one_expert":                # one window, full
+        idx[:, 0] = held[1]
+    elif kind == "random":                           # one window, part full
+        idx = onp.stack([rng.permutation(n_experts)[:k]
+                         for _ in range(n_tokens)])
+    else:
+        assert kind == "none_held"                   # no window at all
+    return jnp.asarray(rng.permuted(idx, axis=1), jnp.int32)
+
+
+@pytest.mark.parametrize("gated", [False, True])
+@pytest.mark.parametrize("kind,windows", [
+    ("none_held", 0), ("random", 1), ("all_on_one_expert", 1),
+    ("two_held_a_token", 2), ("every_token_on_every_held", 4)])
+def test_windows_are_exact_at_any_load(kind, windows, gated):
+    """T = 1024, 4 of 64 held from the 9th on, 4 a token: W = 1024 of 4096
+    rows. Value and every gradient against the dense form, whether no
+    window runs, one, two or all four: nothing is dropped at any load."""
+    n_tokens, k, first, count, n_experts = 1024, 4, 8, 4, 64
+    window = moe.held_window_rows(n_tokens, k, count, n_experts)
+    assert window == 1024
+    rng = onp.random.default_rng(7)
+    top_idx = _routing(kind, n_tokens, k, first, count, n_experts, rng)
+    live = int(((top_idx >= first) & (top_idx < first + count)).sum())
+    assert -(-live // window) == windows
+    tokens = jnp.asarray(rng.standard_normal((n_tokens, D)), jnp.float32)
+    top_vals = jnp.asarray(rng.random((n_tokens, k)), jnp.float32)
+    shape = (n_experts, D, H)
+    w_up = jnp.asarray(rng.standard_normal(shape) / 4, jnp.float32)
+    w_gate = jnp.asarray(rng.standard_normal(shape) / 4, jnp.float32) \
+        if gated else None
+    w_down = jnp.asarray(rng.standard_normal((n_experts, H, D)) / 4,
+                         jnp.float32)
+    sl = slice(first, first + count)
+    act = moe._ACTIVATIONS["relu2"]
+    out_weights = jnp.asarray(rng.standard_normal((n_tokens, D)), jnp.float32)
+
+    def held(tokens, top_vals, w_up_s, w_down_s, w_gate_s):
+        return moe.dropless_moe_held(tokens, top_vals, top_idx, w_up_s,
+                                     w_down_s, act, first, n_experts,
+                                     w_gate_s)
+
+    def dense(tokens, top_vals, w_up_s, w_down_s, w_gate_s):
+        return dense_moe(
+            tokens, _only(top_vals, top_idx, first, count), top_idx,
+            w_up.at[sl].set(w_up_s), w_down.at[sl].set(w_down_s), act,
+            None if w_gate_s is None else w_gate.at[sl].set(w_gate_s))
+
+    args = (tokens, top_vals, w_up[sl], w_down[sl],
+            None if w_gate is None else w_gate[sl])
+    which = (0, 1, 2, 3, 4) if gated else (0, 1, 2, 3)
+    def graded(fn):
+        def loss(*a):
+            out = fn(*a)
+            return jnp.sum(out * out_weights), out
+        return jax.value_and_grad(loss, which, has_aux=True)(*args)
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), got_grads = graded(held)
+        (_, want), want_grads = graded(dense)
+    onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        onp.testing.assert_allclose(
+            g, w, rtol=2e-5, atol=2e-5 * max(float(jnp.abs(w).max()), 1e-6))
+    if windows == 0:
+        assert not any(bool(jnp.any(g)) for g in got_grads)
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_what_a_kernel_leaves_in_dead_rows_reaches_no_sum(gated, monkeypatch):
+    """A grouped matmul answers for the rows its groups own; on the chip
+    the others hold whatever was there. With every such row of every
+    grouped matmul's output and of its transpose poisoned (NaN), value and
+    gradients are still the dense form's. Off the TPU those rows are
+    zeros, which is why nothing else here can see a missing mask."""
+    n_tokens, k, first, count, n_experts = 1024, 4, 8, 4, 64
+    window = moe.held_window_rows(n_tokens, k, count, n_experts)
+    rng = onp.random.default_rng(11)
+    top_idx = _routing("random", n_tokens, k, first, count, n_experts, rng)
+    live = int(((top_idx >= first) & (top_idx < first + count)).sum())
+    assert 0 < live < window
+
+    def poisoned(x):
+        if getattr(x, "shape", ())[:1] != (window,) or x.ndim != 2:
+            return x
+        return jnp.where(jnp.arange(window)[:, None] < live, x, jnp.nan)
+
+    ragged_dot, transpose = jax.lax.ragged_dot, jax.linear_transpose
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        lambda *a, **kw: poisoned(ragged_dot(*a, **kw)))
+    monkeypatch.setattr(
+        jax, "linear_transpose", lambda fn, *primals: lambda g: tuple(
+            poisoned(d) for d in transpose(fn, *primals)(g)))
+    tokens = jnp.asarray(rng.standard_normal((n_tokens, D)), jnp.float32)
+    top_vals = jnp.asarray(rng.random((n_tokens, k)), jnp.float32)
+    w_up = jnp.asarray(rng.standard_normal((count, D, H)) / 4, jnp.float32)
+    w_down = jnp.asarray(rng.standard_normal((count, H, D)) / 4, jnp.float32)
+    w_gate = w_up[::-1] if gated else None
+    act = moe._ACTIVATIONS["relu2"]
+
+    def held(tokens, top_vals, w_up, w_down, w_gate):
+        return jnp.sum(moe.dropless_moe_held(
+            tokens, top_vals, top_idx, w_up, w_down, act, first, n_experts,
+            w_gate) ** 2)
+
+    def dense(tokens, top_vals, w_up, w_down, w_gate):
+        full = [jnp.zeros((n_experts,) + w.shape[1:]).at[
+            first:first + count].set(w) for w in (w_up, w_down)]
+        return jnp.sum(dense_moe(
+            tokens, _only(top_vals, top_idx, first, count), top_idx, *full,
+            act, None if w_gate is None else jnp.zeros(
+                (n_experts, D, H)).at[first:first + count].set(w_gate)) ** 2)
+
+    args = (tokens, top_vals, w_up, w_down, w_gate)
+    which = (0, 1, 2, 3, 4) if gated else (0, 1, 2, 3)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(held, which)(*args)
+        monkeypatch.undo()
+        want = jax.value_and_grad(dense, which)(*args)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        onp.testing.assert_allclose(g, w, rtol=2e-5,
+                                    atol=2e-5 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("gated,forward,both", [(False, 2, 8), (True, 3, 12)])
+def test_one_body_the_grouped_matmuls_are_traced_once_a_pass(
+        gated, forward, both):
+    """The forward holds each grouped matmul once and the gradient at most
+    four times (forward, recomputed, and the two transposes): one body run
+    once a window, never a bounded path beside a full one. A second traced
+    copy of the dispatch is a second set of Mosaic kernels in every expert
+    layer of both compiled programs, which is set-up time (PERF.md: PR 32)."""
+    layer = parallel.MoELayer(512, D, H, top_k=22, router="sigmoid_bias",
+                              activation="relu2", held=(8, 8), gated=gated)
+    layer.initialize()
+    names = ("x",) + layer._weight_names()
+    x = jnp.ones((2, 512, D), jnp.float32)
+    weights = [w._data for w in layer._weights()]
+    trained = tuple(i for i, n in enumerate(names) if n != "router_bias")
+
+    def value(*arrays):
+        return jnp.sum(layer._fn(dict(zip(names, arrays)), False))
+
+    def grouped(fn):
+        jaxpr = jax.make_jaxpr(fn)(x, *weights).jaxpr
+        return sum(e.primitive.name.startswith("ragged_dot")
+                   for e in _eqns(jaxpr))
+
+    assert any(e.primitive.name == "while" for e in _eqns(
+        jax.make_jaxpr(value)(x, *weights).jaxpr))
+    assert grouped(value) == forward
+    assert forward < grouped(jax.grad(value, trained)) <= both
 
 
 def _layer(**kwargs):
@@ -188,9 +418,12 @@ def test_the_held_path_has_its_own_counter():
     before = moe._DISPATCHES.value(path="dropless_held")
     tokens, top_vals, top_idx, w_up, w_down = _case(6)
     f = jax.jit(lambda t: moe.dropless_moe_held(
-        t, top_vals, top_idx, w_up[:2], w_down[:2], jax.nn.relu, 0))
+        t, top_vals, top_idx, w_up[:2], w_down[:2], jax.nn.relu, 0, E))
     for _ in range(3):
         f(tokens)
     assert moe._DISPATCHES.value(path="dropless_held") - before == 1
-    assert 'mxtpu_moe_dispatch_total{path="dropless_held"}' \
-        in telemetry.REGISTRY.export_text()
+    text = telemetry.REGISTRY.export_text()
+    assert 'mxtpu_moe_dispatch_total{path="dropless_held"}' in text
+    # the rows of the buffers as traced: here the worst case is the window
+    assert 'mxtpu_moe_held_rows{kind="window"} %d' % (T * 2) in text
+    assert 'mxtpu_moe_held_rows{kind="worst_case"} %d' % (T * 2) in text
