@@ -54,7 +54,9 @@ FULL = {
     # 8 of 64 experts held, 4 a token, one of two mixer shards
     "hybrid": dict(S=2048, V=8192, U=1024, P="ME*", MH=32, MD=64, G=2, N=128,
                    Q=128, QH=4, KV=1, L=512, I=1024, SH=2048, E=64, HELD=8,
-                   K=4, SHARDS=2, SCAN_S=8192),
+                   K=4, SHARDS=2, SCAN_S=8192,
+                   # the Mamba-1 scan alone at the SambaY cell's shape
+                   SEL_S=16384, SEL_C=5120, SEL_N=16, SEL_R=160),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -69,7 +71,8 @@ TOY = {
     "gpt": dict(S=256, V=512, U=256, L=1, H=2),
     "hybrid": dict(S=256, V=512, U=128, P="ME*", MH=16, MD=8, G=2, N=16,
                    Q=16, QH=2, KV=1, L=64, I=48, SH=96, E=16, HELD=4, K=4,
-                   SHARDS=2, SCAN_S=256),
+                   SHARDS=2, SCAN_S=256, SEL_S=256, SEL_C=64, SEL_N=16,
+                   SEL_R=4),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -380,6 +383,56 @@ def scan_alone(cfg):
         float(jnp.abs(rounded - want).max()) / top
 
 
+def selective_scan_alone(cfg):
+    """`ops.selective_scan.selective_scan` alone at the SambaY cell's shape
+    (SEL_S positions, SEL_C channels, SEL_N states, the step sizes'
+    projection SEL_R wide, the op's own chunk), float32 inputs at "highest"
+    precision, through the one entry the cell runs (the step sizes formed
+    inside the channel block), against the Mamba-1 recurrence a position
+    at a time fed softplus(low W^T + b): as `scan_alone`, the one
+    comparison in which the state's float32 shows. -> (its distance, the
+    distance of the recurrence with its state rounded to bfloat16 once a
+    chunk)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    from incubator_mxnet_tpu.ops.selective_scan import _CHUNK, selective_scan
+    s, c, n, r = cfg["SEL_S"], cfg["SEL_C"], cfg["SEL_N"], cfg["SEL_R"]
+    rng = onp.random.default_rng(0)
+    x, low, bm, cm = (
+        jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        for shape in ((1, s, c), (1, s, r), (1, s, n), (1, s, n)))
+    # step sizes about log-normal around 0.011, 0.001 to 0.1
+    w = jnp.asarray(rng.standard_normal((c, r)) / onp.sqrt(r), jnp.float32)
+    bias = jnp.full((c,), -4.5, jnp.float32)
+    a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (c, n))
+
+    def recurrence(round_state):
+        dt = jax.nn.softplus(jnp.einsum("bsr,cr->bsc", low, w) + bias)
+
+        def step(state, at):
+            x_t, dt_t, b_t, c_t, rounds = at
+            state = jnp.exp(dt_t[..., None] * a) * state \
+                + (dt_t * x_t)[..., None] * b_t[:, None, :]
+            if round_state:
+                state = jnp.where(
+                    rounds, jax.lax.reduce_precision(state, 8, 7), state)
+            return state, (state * c_t[:, None, :]).sum(-1)
+        by_time = tuple(t.swapaxes(0, 1) for t in (x, dt, bm, cm)) \
+            + (jnp.arange(s) % _CHUNK == _CHUNK - 1,)
+        return jax.lax.scan(step, jnp.zeros((1, c, n), jnp.float32),
+                            by_time)[1].swapaxes(0, 1)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda: recurrence(False))()
+        rounded = jax.jit(lambda: recurrence(True))()
+        got = jax.jit(lambda: selective_scan(
+            x, low, a, bm, cm, jnp.zeros((c,), jnp.float32), (w, bias)))()
+    top = float(jnp.abs(want).max())
+    return float(jnp.abs(got - want).max()) / top, \
+        float(jnp.abs(rounded - want).max()) / top
+
+
 def phase_hybrid(cfg, on_chip, shared):
     """One tiny Nemotron-H share through TrainStep: the chunked Mamba-2 scan
     with its backward, the held dispatch (experts 8..15 of 64) and the
@@ -423,11 +476,19 @@ def phase_hybrid(cfg, on_chip, shared):
             "scan alone, float32: %.3g of the largest output from the "
             "recurrence (limit %g), a bfloat16 state a chunk %.3g"
             % (sound, SCAN_ALONE_LIMIT, rounded))
+    sel_sound, sel_rounded = selective_scan_alone(cfg)
+    if not sel_sound < SCAN_ALONE_LIMIT < sel_rounded:
+        raise RuntimeError(
+            "selective scan alone, float32: %.3g of the largest output from "
+            "the recurrence (limit %g), a bfloat16 state a chunk %.3g"
+            % (sel_sound, SCAN_ALONE_LIMIT, sel_rounded))
     return compile_s, steady_s, "pattern %s, S=%d, loss %.4f -> %.4f, " \
         "mosaic kernels: %s; scan alone at S=%d in float32 %.2g of its " \
         "largest output from the recurrence (a bfloat16 state a chunk " \
-        "%.2g)" % (cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
-                   cfg["SCAN_S"], sound, rounded)
+        "%.2g); selective scan alone at %d x %d x %d %.2g (%.2g)" % (
+            cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
+            cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
+            cfg["SEL_N"], sel_sound, sel_rounded)
 
 
 def build_resnet():

@@ -74,7 +74,9 @@ def recompute(block, *args):
     the block's inputs and none of what it computed. Around each layer of a
     stack, the activations held for the backward are one input a layer plus
     one layer's working set, where ``TrainStep(remat=True)`` checkpoints the
-    whole forward at once and lowers nothing at the peak.
+    whole forward at once and lowers nothing at the peak. A block that
+    returns several arrays (a layer that hands later layers more than the
+    residual stream) returns them here too, as a tuple.
 
     The block's parameters go in as arguments, like the inputs, so the call
     is one pure function of arrays: one op on the eager tape, and inside a
@@ -106,6 +108,8 @@ def recompute(block, *args):
                 "recompute(%s): the block updates auxiliary state or draws "
                 "random numbers in its forward, which a recomputation would "
                 "repeat" % block.name)
+        if isinstance(out, (tuple, list)):
+            return tuple(o._data for o in out)
         return out._data
 
     import jax

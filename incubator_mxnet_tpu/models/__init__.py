@@ -8,6 +8,9 @@
 - olmoe:      OLMoE decoder (RoPE + QK-norm attention, dropless SwiGLU MoE)
 - nemotron_h: Nemotron-H hybrid (Mamba-2, grouped-query attention, a latent
               mixture of relu^2 experts), whole or as one chip's share
+- phi4flash:  Phi-4-mini-flash / SambaY (Mamba-1, sliding-window and full
+              differential attention, a cross-decoder of gated memory units
+              and attention over ONE layer's K/V, dense SwiGLU MLPs)
 """
 from .lenet import LeNet  # noqa
 from .bert import (BERTEncoder, BERTModel, TransformerEncoderLayer,  # noqa
@@ -18,6 +21,8 @@ from .olmoe import (OLMoEModel, OLMoETransformerDecoderLayer,  # noqa
                     RotaryMultiHeadAttention, ChunkedUntiedLMLoss)
 from .nemotron_h import (NemotronHModel, NemotronHLayer, Mamba2Mixer,  # noqa
                          GroupedQueryAttention, LatentMoE)
+from .phi4flash import (Phi4FlashModel, SambaYLayer, Mamba1Mixer,  # noqa
+                        DifferentialAttention, GatedMemoryUnit, SwiGLU)
 from .lstm_lm import LSTMLanguageModel  # noqa
 from .ssd import SSD  # noqa
 from ..gluon.model_zoo.vision import get_model  # noqa

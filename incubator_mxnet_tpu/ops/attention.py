@@ -20,7 +20,16 @@ shape alone:
   budgets assume a v5e-class VMEM (_V5E_VMEM_BYTES, 128 MiB a core: the
   backward asks for a scoped limit of the slab + 24 MiB, the old pair
   needed only the default); a chip with less refuses at compile time.
-  Takes D % 128 == 0, or S >= 2048.
+  Takes D % 128 == 0, or S >= 2048. The kernels know dense, causal and,
+  with ``window=``, a causal sliding window (key j seen from query i iff
+  0 <= i - j < window): the same two bodies under the names
+  flash_window_fwd / flash_window_bwd, so a capture tells them from the
+  causal calls of the same program. Under a window the grid's last axis
+  counts the blocks of a row's BAND instead of all of them (at S = 16k,
+  window 512, blocks of 512: 63 live pairs a head where the causal grid
+  visits 528), the index maps are clamped from both sides so a step past
+  the band moves nothing, and every live pair is masked. ``window=None``
+  traces exactly the calls there were before there was a window.
 * **short** (flash_short_fwd, flash_short_bwd): narrow heads that tile 128
   lanes (D of 32 or 64, whole blocks of H*D) below S = 2048 whose whole
   (S, S) float32 tile fits VMEM and is worth a visit (_SHORT_MIN_S <= S <=
@@ -30,15 +39,19 @@ shape alone:
   layout in 128-lane blocks of whole heads: no transposes, no padded lanes.
 
 Neither family ever writes an (S, S) tensor to HBM. Everything else (cross
-attention, lengths no block divides, any shape off the TPU) takes the XLA
-composite. Used by models.bert MultiHeadAttention (attention='flash'). A
-Mosaic refusal of a routed shape surfaces as the compile error it is —
-nothing catches it to degrade. MXTPU_FLASH_INTERPRET=1 runs the kernels in
+attention, lengths no block divides, a window on a shape of the short
+family, any shape off the TPU) takes the XLA composite. Used by models.bert
+MultiHeadAttention (attention='flash') and models.phi4flash
+DifferentialAttention. A Mosaic refusal of a routed shape surfaces as the
+compile error it is — nothing catches it to degrade. MXTPU_FLASH_INTERPRET=1 runs the kernels in
 Pallas interpret mode (CPU tests only; chip_smoke.py and bench.py refuse to
 start with it set). Counters at /metrics, one increment per traced call:
 mxtpu_attention_route_total{route} (the backward follows the forward's
-route) and mxtpu_attention_backward_total{kernel} (a streamed backward:
-one call, or segmented).
+route), mxtpu_attention_backward_total{kernel} (a streamed backward:
+one call, or segmented; flash_window_bwd under a window) and
+mxtpu_attention_window_total{route}; the gauge
+mxtpu_attention_live_block_pairs{kind="window"|"causal"} holds what the
+last traced windowed forward visits a head, and what the causal grid would.
 
 Why two families (v5e, BERT-large's (16, 16, 512, 64) bf16, attention
 alone, forward + backward, a call; PERF.md §6, PR 26): the composite takes
@@ -89,6 +102,18 @@ _BACKWARDS = telemetry.counter(
     "the VMEM budget).", ("kernel",))
 
 
+_WINDOWS = telemetry.counter(
+    "mxtpu_attention_window_total",
+    "flash_attention calls traced with a sliding window, by the path they "
+    "took (streamed: flash_window_fwd / flash_window_bwd; composite).",
+    ("route",))
+_LIVE_PAIRS = telemetry.gauge(
+    "mxtpu_attention_live_block_pairs",
+    "Block pairs a head's streamed forward visits, set when a call is "
+    "traced: under a window, and what the causal kernel would visit at "
+    "the same blocks.", ("kind",))
+
+
 def _interpret():
     from ..config import get_env
     return get_env("MXTPU_FLASH_INTERPRET")
@@ -111,20 +136,46 @@ def _auto_block(S):
     return None
 
 
-def _resolve_blocks(S, block_q, block_k):
+def _resolve_blocks(S, block_q, block_k, window=None):
     from ..config import get_env
     block_q = block_q or get_env("MXTPU_FLASH_BLOCK_Q") or None
     block_k = block_k or get_env("MXTPU_FLASH_BLOCK_K") or None
-    return (block_q or _auto_block(S)), (block_k or _auto_block(S))
+    auto = _auto_block(S) if window is None else _window_block(S, window)
+    return (block_q or auto), (block_k or auto)
 
 
-def _blocked_reference(q, k, v, causal, scale):
+def _window_block(S, window):
+    """Blocks under a window: the largest candidate dividing S that is no
+    wider than the window (128 at least). A q-block of b rows sees b x
+    (window + b / 2) keys and its band of kv-blocks holds about 2 b^2
+    (window = b): at window 512 blocks of 512 compute 4 scores for 3 that
+    are used, blocks of 1024 2 for 1."""
+    for b in (1024, 512, 256, 128):
+        if S % b == 0 and b <= max(window, 128):
+            return b
+    return None
+
+
+def _band(n_blocks, first, last):
+    """(most blocks any row of the grid visits, pairs visited in all):
+    `first(i)`, `last(i)` the ends of row i's band (the index maps' own
+    functions, here on Python integers)."""
+    with jax.ensure_compile_time_eval():      # they are jnp arithmetic
+        counts = [int(last(i)) - int(first(i)) + 1 for i in range(n_blocks)]
+    return max(counts), sum(counts)
+
+
+def _seen(Sq, Sk, window):
+    """(Sq, Sk) bool: key j is seen from query i iff 0 <= i - j (< window)."""
+    d = jnp.arange(Sq)[:, None] - jnp.arange(Sk)[None, :]
+    return d >= 0 if window is None else (d >= 0) & (d < window)
+
+
+def _blocked_reference(q, k, v, causal, scale, window=None):
     """XLA fallback with fp32 softmax (numerics match the kernel)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        qi = jnp.arange(q.shape[2])[:, None]
-        ki = jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(qi >= ki, s, -jnp.inf)
+        s = jnp.where(_seen(q.shape[2], k.shape[2], window), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
@@ -159,11 +210,15 @@ def flash_attention_supported(q_shape, block_q=None, block_k=None):
 
 
 def attention_route(q_shape, k_shape=None, v_shape=None, block_q=None,
-                    block_k=None):
+                    block_k=None, window=None):
     """'short', 'streamed' or 'composite': which path flash_attention
     takes, from the shapes alone (and whether kernels can run here at all:
     a TPU, or interpret mode). Both kernel families assume self-attention
-    (Sq == Sk); cross-attention takes the composite, which handles it."""
+    (Sq == Sk); cross-attention takes the composite, which handles it.
+    A sliding ``window`` adds no family: the streamed kernels take it (as
+    flash_window_fwd / flash_window_bwd), the short family knows the
+    diagonal only, so a windowed shape it would have taken goes to the
+    composite."""
     k_shape, v_shape = k_shape or q_shape, v_shape or q_shape
     if not tuple(q_shape) == tuple(k_shape) == tuple(v_shape):
         return "composite"
@@ -171,8 +226,10 @@ def attention_route(q_shape, k_shape=None, v_shape=None, block_q=None,
     if _narrow_and_short(q_shape):
         # whole heads in 128-lane blocks of the (B, S, H*D) layout
         fits = _SHORT_MIN_S <= S <= _SHORT_MAX_S and S % 128 == 0 \
-            and D in _SHORT_D and (H * D) % 128 == 0
+            and D in _SHORT_D and (H * D) % 128 == 0 and window is None
         return "short" if fits and _kernels_run_here() else "composite"
+    if window is not None:
+        block_q, block_k = _resolve_blocks(S, block_q, block_k, window)
     return "streamed" if flash_attention_legal(q_shape, block_q, block_k) \
         else "composite"
 
@@ -194,13 +251,32 @@ def _mm(a, b):
 
 
 # --------------------------------------------------------------- forward
+def _first_kv_block(qb, block_q, block_k, window):
+    """The first kv-block a query of q-block ``qb`` sees under ``window``:
+    its first query's oldest key is qb * block_q - window + 1."""
+    return jnp.maximum(qb * block_q - window + 1, 0) // block_k
+
+
+def _last_q_block(kb, block_q, block_k, window, n_q):
+    """The last q-block that sees a key of kv-block ``kb`` under
+    ``window``: its last key's youngest reader is that key + window - 1."""
+    return jnp.minimum(((kb + 1) * block_k + window - 2) // block_q, n_q - 1)
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-               block_k, causal, scale):
+               block_k, causal, scale, window=None):
     """One (batch*head, q-block, k-block) program: K/V are STREAMED by the
     grid — VMEM holds only (block_q + 2*block_k) x D tiles plus the online
     softmax carry (m/l/acc scratch, persisted across the sequential k-block
     steps), so sequence length is bounded by HBM, not VMEM (S=32k+ on one
     chip).  Writes the per-row LSE (m + log l) the backward kernels consume.
+
+    Under a ``window`` (key j seen from i iff 0 <= i - j < window) the last
+    grid axis counts the kv-blocks of the q-block's BAND, not all of them:
+    step j is kv-block `_first_kv_block(qb) + j`, live while it is not past
+    the diagonal. A row may see no key of a live block (its window starts
+    further right): the carry's -inf guards, which the causal kernel needs
+    for no row, hold it at zero until its first key comes.
     """
     from jax.experimental import pallas as pl
 
@@ -213,6 +289,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
+    step = kb
+    if window is not None:
+        kb = _first_kv_block(qb, block_q, block_k, window) + step
     # K/V blocks fully above the diagonal contribute nothing in causal mode
     live = ((qb + 1) * block_q - 1 >= kb * block_k) if causal else (kb >= 0)
 
@@ -227,7 +306,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
                 jnp.int32, (block_q, 1), 0)
             ki = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
-            s = jnp.where(qi >= ki, s, -jnp.inf)
+            seen = qi >= ki if window is None \
+                else (qi >= ki) & (qi - ki < window)
+            s = jnp.where(seen, s, -jnp.inf)
         m, l, acc = m_s[...], l_s[...], acc_s[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -239,7 +320,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         l_s[...] = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[...] = acc * alpha + p @ v_blk
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         l = jnp.maximum(l_s[...], 1e-37)
         o_ref[0, :, :] = (acc_s[...] / l).astype(o_ref.dtype)
@@ -248,7 +329,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         lse_ref[0, 0, :] = (m_s[...] + jnp.log(l))[:, 0]
 
 
-def _fa_call(q, k, v, causal, scale, block_q, block_k):
+def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
     """Returns (out (B,H,S,D), lse (B*H,S) fp32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -260,7 +341,26 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k):
     grid = (B * H, S // block_q, S // block_k)
     kernel = functools.partial(_fa_kernel, block_k=block_k, causal=causal,
                                scale=scale)
-    if causal:
+    if window is not None:
+        # the grid's last axis spans a q-block's band of kv-blocks; the
+        # index map is clamped from BOTH sides, so a step past the diagonal
+        # re-uses the resident block and moves nothing
+        def first(i):
+            return _first_kv_block(i, block_q, block_k, window)
+
+        def last(i):
+            return ((i + 1) * block_q - 1) // block_k
+
+        steps, pairs = _band(S // block_q, first, last)
+        _LIVE_PAIRS.set(pairs, kind="window")
+        _LIVE_PAIRS.set(_band(S // block_q, lambda i: 0, last)[1],
+                        kind="causal")
+        grid = grid[:2] + (steps,)
+        kernel = functools.partial(kernel, window=window)
+
+        def kv_idx(b, i, j):
+            return (b, jnp.minimum(first(i) + j, last(i)), 0)
+    elif causal:
         # dead blocks above the diagonal: clamp the index map so the grid
         # step re-uses the resident block instead of DMA-ing one it will
         # never read (compute is skipped by pl.when in the kernel)
@@ -285,7 +385,7 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k):
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_window_fwd",
     )(qf, kf, vf)
     return out.reshape(B, H, S, D), lse
 
@@ -321,7 +421,7 @@ def _dq_segments(S, D, block_q):
 
 def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                    dk_ref, dv_ref, dq_ref, *, causal, scale, block_q,
-                   block_k, q0):
+                   block_k, q0, window=None, n_q=None):
     """Grid (bh, kv-block, q-block), each live pair visited ONCE: P and dS
     are recomputed from the saved LSE (FlashAttention-2) and all three
     gradients accumulate from them. dK/dV blocks accumulate over the inner
@@ -337,7 +437,12 @@ def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     the scale multiplies the float32 scores and dS and never an operand (a
     scaled q is no longer a bf16 value, and what the MXU keeps of it cost
     0.03-0.09 % of the gradients on a v5e: PERF.md §6, PR 30), P and dS
-    reach the MXU as float32 and every matmul accumulates in float32."""
+    reach the MXU as float32 and every matmul accumulates in float32.
+
+    Under a ``window`` the last grid axis counts the q-blocks of the
+    kv-block's BAND: step j is the call's q-block `first + j`, first the one
+    that holds the kv-block's first key (or the call's first), live while
+    it is not past `_last_q_block` (``n_q``: the call's q-blocks)."""
     from jax.experimental import pallas as pl
 
     kb, qb = pl.program_id(1), pl.program_id(2)
@@ -351,8 +456,14 @@ def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    # q-blocks fully above the diagonal contribute nothing in causal mode
-    live = (q0 + qb + 1) * block_q - 1 >= kb * block_k if causal else qb >= 0
+    if window is not None:
+        qb = jnp.maximum(kb * block_k // block_q - q0, 0) + qb
+        live = q0 + qb <= _last_q_block(kb, block_q, block_k, window,
+                                        q0 + n_q)
+    else:
+        # q-blocks fully above the diagonal contribute nothing when causal
+        live = (q0 + qb + 1) * block_q - 1 >= kb * block_k if causal \
+            else qb >= 0
 
     @pl.when(live)
     def _compute():
@@ -366,7 +477,9 @@ def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                 jnp.int32, (block_k, 1), 0)
             qi = (q0 + qb) * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_q), 1)
-            pt = jnp.where(qi >= ki, pt, 0.0)
+            seen = qi >= ki if window is None \
+                else (qi >= ki) & (qi - ki < window)
+            pt = jnp.where(seen, pt, 0.0)
         dst = pt * (_nt(v, do) - delta_ref[0]) * scale
         dv_ref[0, :, :] += _mm(pt, do)
         dk_ref[0, :, :] += _mm(dst, q)
@@ -375,7 +488,7 @@ def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 
 def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
-                    block_k, q0, n_q):
+                    block_k, q0, n_q, window=None):
     """dQ of q-blocks [q0, q0 + n_q) and their contribution to dK/dV (of
     the kv-blocks they can see), all float32, from one kernel call."""
     from jax.experimental import pallas as pl
@@ -383,9 +496,27 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
 
     BH, S, D = qf.shape
     n_kv = S // block_k
+    steps = n_q
+    kernel = functools.partial(_fa_bwd_kernel, causal=causal, scale=scale,
+                               block_q=block_q, block_k=block_k, q0=q0)
     if causal:
         # kv-blocks past the segment's last row are dead for all of it
         n_kv = min(n_kv, -(-(q0 + n_q) * block_q // block_k))
+    if window is not None:
+        # the last axis spans a kv-block's band of q-blocks, clamped from
+        # both sides: a step past the band moves nothing
+        def first(i):
+            return jnp.maximum(i * block_k // block_q, q0)
+
+        def last(i):
+            return _last_q_block(i, block_q, block_k, window, q0 + n_q)
+
+        steps = max(1, _band(n_kv, first, last)[0])
+        kernel = functools.partial(kernel, window=window, n_q=n_q)
+
+        def q_blk(i, j):
+            return jnp.clip(first(i) + j, q0, last(i))
+    elif causal:
 
         # the grid streams q-blocks (j) per kv-block (i): q-blocks strictly
         # above the diagonal are dead — clamp to the first live one so no
@@ -401,26 +532,24 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
     kvspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
     # an index that depends on b alone: resident across the inner axes
     slab = pl.BlockSpec((1, n_q * block_q, D), lambda b, i, j: (b, 0, 0))
-    kernel = functools.partial(_fa_bwd_kernel, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k, q0=q0)
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
                    jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
                    jax.ShapeDtypeStruct((BH, n_q * block_q, D), jnp.float32)),
-        grid=(BH, n_kv, n_q),
+        grid=(BH, n_kv, steps),
         in_specs=[qspec, qspec, rowspec, rowspec, kvspec, kvspec],
         out_specs=(kvspec, kvspec, slab),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_slab_bytes(n_q * block_q, D) + _BWD_TILE_BYTES),
         interpret=_interpret(),
-        name="flash_bwd_dkvq",
+        name="flash_bwd_dkvq" if window is None else "flash_window_bwd",
     )(qf, dof, lse, delta, kf, vf)
 
 
 def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-                 g_lse=None):
+                 g_lse=None, window=None):
     B, H, S, D = q.shape
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
@@ -439,11 +568,15 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     # One kernel call while a (batch*head)'s whole dQ slab fits VMEM; past
     # that the same kernel a q-segment, XLA summing the dK/dV partials.
     n_seg = _dq_segments(S, D, block_q)
-    _BACKWARDS.inc(kernel="flash_bwd_dkvq" if n_seg == 1
-                   else "flash_bwd_dkvq_segmented")
+    if window is None:
+        _BACKWARDS.inc(kernel="flash_bwd_dkvq" if n_seg == 1
+                       else "flash_bwd_dkvq_segmented")
+    else:
+        _BACKWARDS.inc(kernel="flash_window_bwd" if n_seg == 1
+                       else "flash_window_bwd_segmented")
     n_q = S // block_q // n_seg
     parts = [_fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale,
-                             block_q, block_k, s * n_q, n_q)
+                             block_q, block_k, s * n_q, n_q, window)
              for s in range(n_seg)]
     dk, dv, _ = parts[-1]                     # the last segment sees all of K
     for dk_s, dv_s, _ in parts[:-1]:
@@ -631,52 +764,62 @@ def _short_bwd_call(q, k, v, lse, do, causal, scale, interpret):
 
 
 # --------------------------------------------------------------- custom VJP
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None):
+                    block_k=None, window=None):
     """q,k,v: (B, H, S, D) → (B, H, S, D), by the path attention_route()
     names for the shape. ``block_q``/``block_k`` size the streamed kernels'
-    blocks (default: the largest of 1024/512/256/128 dividing S) and mean
-    nothing on the other two paths."""
-    return _fa_fwd(q, k, v, causal, scale, block_q, block_k)[0]
+    blocks (default: the largest of 1024/512/256/128 dividing S; under a
+    window the largest no wider than it) and mean nothing on the other two
+    paths. ``window`` (with ``causal``): key j is seen from query i iff
+    0 <= i - j < window; None is plain causal or dense attention, the
+    calls they always were."""
+    return _fa_fwd(q, k, v, causal, scale, block_q, block_k, window)[0]
 
 
-def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
+def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    route = attention_route(q.shape, k.shape, v.shape, block_q, block_k)
+    if window is not None and not causal:
+        raise ValueError("a window is causal: key j is seen from i iff "
+                         "0 <= i - j < window")
+    route = attention_route(q.shape, k.shape, v.shape, block_q, block_k,
+                            window)
     _ROUTES.inc(route=route)
+    if window is not None:
+        _WINDOWS.inc(route=route)
     if route == "short":
         out, lse = _short_call(q, k, v, causal, scale, _interpret())
         return out, (q, k, v, None, lse)        # its backward needs no O
     if route == "streamed":
-        block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k)
-        out, lse = _fa_call(q, k, v, causal, scale, block_q, block_k)
+        block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k,
+                                           window)
+        out, lse = _fa_call(q, k, v, causal, scale, block_q, block_k, window)
     else:
-        out, lse = _blocked_reference(q, k, v, causal, scale), None
+        out, lse = _blocked_reference(q, k, v, causal, scale, window), None
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, res, do):
+def _fa_bwd(causal, scale, block_q, block_k, window, res, do):
     q, k, v, o, lse = res
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    route = attention_route(q.shape, k.shape, v.shape, block_q, block_k)
+    route = attention_route(q.shape, k.shape, v.shape, block_q, block_k,
+                            window)
     if route == "short":
         return _short_bwd_call(q, k, v, lse, do, causal, scale,
                                _interpret())
     if route == "streamed":
-        block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k)
+        block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k,
+                                           window)
         return _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q,
-                            block_k)
+                            block_k, window=window)
     # XLA composite (materializes (S,S)): off the TPU, cross-attention,
     # lengths no block divides, narrow heads past the short tile
     qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
     if causal:
-        qi = jnp.arange(q.shape[2])[:, None]
-        ki = jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(qi >= ki, s, -jnp.inf)
+        s = jnp.where(_seen(q.shape[2], k.shape[2], window), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     dof = do.astype(jnp.float32)
     dv = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
@@ -692,7 +835,7 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention_on_mesh(q, k, v, mesh, batch_axis=None, head_axis=None,
-                            causal=False):
+                            causal=False, window=None):
     """flash_attention inside a program partitioned over ``mesh``.
 
     GSPMD cannot partition a Mosaic kernel: lowering a pallas_call under a
@@ -707,7 +850,8 @@ def flash_attention_on_mesh(q, k, v, mesh, batch_axis=None, head_axis=None,
     spec = P(batch_axis, head_axis, None, None)
     # check_vma=False: pallas_call out_shapes carry no vma annotation
     return jax.shard_map(
-        lambda q, k, v: flash_attention(q, k, v, causal), mesh=mesh,
+        lambda q, k, v: flash_attention(q, k, v, causal, window=window),
+        mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)(q, k, v)
 
 
