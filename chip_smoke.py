@@ -25,6 +25,7 @@ chip time is spent; `--phases a,b` runs a subset. Neither prints the result
 line: they are not a pass.
 """
 import argparse
+import functools
 import gc
 import json
 import math
@@ -71,7 +72,8 @@ TOY = {
     "gpt": dict(S=256, V=512, U=256, L=1, H=2),
     "hybrid": dict(S=256, V=512, U=128, P="ME*", MH=16, MD=8, G=2, N=16,
                    Q=16, QH=2, KV=1, L=64, I=48, SH=96, E=16, HELD=4, K=4,
-                   SHARDS=2, SCAN_S=256, SEL_S=256, SEL_C=64, SEL_N=16,
+                   # (1024 channels: the narrowest the scan's kernels take)
+                   SHARDS=2, SCAN_S=256, SEL_S=160, SEL_C=1024, SEL_N=16,
                    SEL_R=4),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
@@ -383,21 +385,28 @@ def scan_alone(cfg):
         float(jnp.abs(rounded - want).max()) / top
 
 
-def selective_scan_alone(cfg):
+def selective_scan_alone(cfg, on_chip):
     """`ops.selective_scan.selective_scan` alone at the SambaY cell's shape
     (SEL_S positions, SEL_C channels, SEL_N states, the step sizes'
     projection SEL_R wide, the op's own chunk), float32 inputs at "highest"
-    precision, through the one entry the cell runs (the step sizes formed
-    inside the channel block), against the Mamba-1 recurrence a position
-    at a time fed softplus(low W^T + b): as `scan_alone`, the one
-    comparison in which the state's float32 shows. -> (its distance, the
-    distance of the recurrence with its state rounded to bfloat16 once a
-    chunk)."""
+    precision, through the one entry the cell runs and on the route it runs
+    there (the Pallas kernel pair: a Mosaic call in the lowered text on the
+    chip, interpreted in a rehearsal), against the Mamba-1 recurrence a
+    position at a time fed softplus(low W^T + b): as `scan_alone`, the one
+    comparison in which the state's float32 shows. The eight gradients are
+    compared on ONE channel block (the first 1024 channels: autodiff of the
+    recurrence keeps every position's state, 1 GB a block in float32).
+    -> {"sound": the output's distance, "rounded": the recurrence's with
+    its state rounded to bfloat16 once a chunk, "gradients": the worst of
+    the eight, each over its own largest entry, "forward_ms", "both_ms":
+    the op alone in bfloat16 at the whole shape (None off the chip: a CPU
+    time is no device number)}."""
     import jax
     import jax.numpy as jnp
     import numpy as onp
-    from incubator_mxnet_tpu.ops.selective_scan import _CHUNK, selective_scan
+    from incubator_mxnet_tpu.ops import selective_scan as op
     s, c, n, r = cfg["SEL_S"], cfg["SEL_C"], cfg["SEL_N"], cfg["SEL_R"]
+    block = op._KERNEL_CHANNELS
     rng = onp.random.default_rng(0)
     x, low, bm, cm = (
         jnp.asarray(rng.standard_normal(shape), jnp.float32)
@@ -406,8 +415,14 @@ def selective_scan_alone(cfg):
     w = jnp.asarray(rng.standard_normal((c, r)) / onp.sqrt(r), jnp.float32)
     bias = jnp.full((c,), -4.5, jnp.float32)
     a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (c, n))
+    # (no skip in the outputs' comparison, as PR 34 read it; the gradients'
+    # has one)
+    args = (x, low, a, bm, cm, jnp.zeros((c,), jnp.float32), w, bias)
 
-    def recurrence(round_state):
+    def system(x, low, a, bm, cm, skip, w, bias):
+        return op.selective_scan(x, low, a, bm, cm, skip, (w, bias))
+
+    def recurrence(x, low, a, bm, cm, skip, w, bias, round_state=False):
         dt = jax.nn.softplus(jnp.einsum("bsr,cr->bsc", low, w) + bias)
 
         def step(state, at):
@@ -419,18 +434,61 @@ def selective_scan_alone(cfg):
                     rounds, jax.lax.reduce_precision(state, 8, 7), state)
             return state, (state * c_t[:, None, :]).sum(-1)
         by_time = tuple(t.swapaxes(0, 1) for t in (x, dt, bm, cm)) \
-            + (jnp.arange(s) % _CHUNK == _CHUNK - 1,)
-        return jax.lax.scan(step, jnp.zeros((1, c, n), jnp.float32),
-                            by_time)[1].swapaxes(0, 1)
+            + (jnp.arange(s) % op._CHUNK == op._CHUNK - 1,)
+        return jax.lax.scan(step, jnp.zeros(a.shape, jnp.float32)[None],
+                            by_time)[1].swapaxes(0, 1) + skip * x
 
+    def distance(got, want):
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    def one_block(t, channel_axis):
+        return t if channel_axis is None else \
+            jax.lax.slice_in_dim(t, 0, block, axis=channel_axis)
+
+    def gradients(fn):
+        """(cotangent, *inputs) -> the eight gradients of sum(y cot)."""
+        return jax.jit(jax.grad(lambda cot, *t: jnp.sum(fn(*t) * cot),
+                                tuple(range(1, 1 + len(args)))))
+
+    def median_ms(fn, operands):
+        jax.block_until_ready(fn(*operands))
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*operands))
+            times.append(time.perf_counter() - t0)
+        return 1e3 * statistics.median(times)
+
+    kernels = op._SCANS.value(path="pallas")
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda: recurrence(False))()
-        rounded = jax.jit(lambda: recurrence(True))()
-        got = jax.jit(lambda: selective_scan(
-            x, low, a, bm, cm, jnp.zeros((c,), jnp.float32), (w, bias)))()
-    top = float(jnp.abs(want).max())
-    return float(jnp.abs(got - want).max()) / top, \
-        float(jnp.abs(rounded - want).max()) / top
+        if on_chip and "tpu_custom_call" not in \
+                jax.jit(system).lower(*args).as_text():
+            raise RuntimeError("the selective scan lowered to no Mosaic "
+                               "kernel at %d x %d x %d" % (s, c, n))
+        want = jax.jit(recurrence)(*args)
+        out = {"sound": distance(jax.jit(system)(*args), want),
+               "rounded": distance(jax.jit(functools.partial(
+                   recurrence, round_state=True))(*args), want)}
+        del want
+        part = tuple(one_block(t, axis) for t, axis in zip(
+            args, (2, None, 0, None, None, 0, 0, 0)))
+        part = part[:5] + (jnp.asarray(
+            rng.standard_normal((block,)), jnp.float32),) + part[6:]
+        cot = jnp.asarray(rng.standard_normal(part[0].shape), jnp.float32)
+        out["gradients"] = max(
+            distance(got, want) for got, want in zip(
+                gradients(system)(cot, *part),
+                gradients(recurrence)(cot, *part)))
+    if op._SCANS.value(path="pallas") == kernels:
+        raise RuntimeError("the selective scan took the XLA form")
+    out["forward_ms"] = out["both_ms"] = None
+    if on_chip:
+        lowp = tuple(t.astype(jnp.bfloat16) if i in (0, 1, 3, 4, 6) else t
+                     for i, t in enumerate(args))
+        out["forward_ms"] = median_ms(jax.jit(system), lowp)
+        out["both_ms"] = median_ms(
+            gradients(system), (jnp.ones(x.shape, jnp.bfloat16),) + lowp)
+    return out
 
 
 def phase_hybrid(cfg, on_chip, shared):
@@ -476,19 +534,27 @@ def phase_hybrid(cfg, on_chip, shared):
             "scan alone, float32: %.3g of the largest output from the "
             "recurrence (limit %g), a bfloat16 state a chunk %.3g"
             % (sound, SCAN_ALONE_LIMIT, rounded))
-    sel_sound, sel_rounded = selective_scan_alone(cfg)
-    if not sel_sound < SCAN_ALONE_LIMIT < sel_rounded:
+    sel = selective_scan_alone(cfg, on_chip)
+    if not (sel["sound"] < SCAN_ALONE_LIMIT < sel["rounded"]
+            and sel["gradients"] < SCAN_ALONE_LIMIT):
         raise RuntimeError(
             "selective scan alone, float32: %.3g of the largest output from "
-            "the recurrence (limit %g), a bfloat16 state a chunk %.3g"
-            % (sel_sound, SCAN_ALONE_LIMIT, sel_rounded))
+            "the recurrence and %.3g of a gradient's largest entry (limit "
+            "%g), a bfloat16 state a chunk %.3g" % (
+                sel["sound"], sel["gradients"], SCAN_ALONE_LIMIT,
+                sel["rounded"]))
+    alone = "" if sel["forward_ms"] is None else \
+        "; forward %.1f ms, forward + backward %.1f ms in bfloat16 (the XLA " \
+        "form 36-38 / 77-80, PR 34)" % (sel["forward_ms"], sel["both_ms"])
     return compile_s, steady_s, "pattern %s, S=%d, loss %.4f -> %.4f, " \
         "mosaic kernels: %s; scan alone at S=%d in float32 %.2g of its " \
         "largest output from the recurrence (a bfloat16 state a chunk " \
-        "%.2g); selective scan alone at %d x %d x %d %.2g (%.2g)" % (
+        "%.2g); selective scan alone at %d x %d x %d, the kernel pair: " \
+        "%.2g (%.2g), the eight gradients of one channel block %.2g%s" % (
             cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
             cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
-            cfg["SEL_N"], sel_sound, sel_rounded)
+            cfg["SEL_N"], sel["sound"], sel["rounded"], sel["gradients"],
+            alone)
 
 
 def build_resnet():

@@ -12,7 +12,9 @@ position). The mixer is read from a pattern string:
         x'' = silu(conv1d_causal,k(x') + b);  [d, B, C] = W_x x''
         D_t = softplus(W_dt d + b_dt);  A = -exp(A_log)   (float32)
         h_t = exp(D_t A) h_{t-1} + (D_t x''_t) B_t^T;  y_t = h_t C_t + D x''_t
-        out = W_out (y * silu(z))                (ops/selective_scan.py)
+        out = W_out (y * silu(z))                (ops/selective_scan.py:
+        on a TPU at channels a multiple of 1024 a Pallas kernel pair that
+        keeps the state in VMEM, forward and backward; else XLA ops)
         The LAST M before F also hands on m = y, before the gate.
     S   differential attention (arXiv:2410.05258) under a sliding window
     F   the same, full causal; it also hands on its k and v
@@ -180,9 +182,11 @@ class Mamba1Mixer(HybridBlock):
 
     def _scan(self, x, dbc, dt_w, a_log, dt_bias, d_skip):
         r, n = self.dt_rank, self.state
-        # the step sizes are formed inside the scan, a channel block at a
-        # time, in float32 from the matmul on: no bfloat16 rounding between
-        # the projection and the softplus, no (S, inner) float32 tensor
+        # the step sizes are formed inside the scan where they are used (a
+        # chunk of a channel block in VMEM in the kernels, a channel block
+        # in the XLA form), in float32 from the matmul on: no bfloat16
+        # rounding between the projection and the softplus, no (S, inner)
+        # float32 tensor
         return selective_scan(
             x, dbc[..., :r], -jnp.exp(a_log.astype(jnp.float32)),
             dbc[..., r:r + n], dbc[..., r + n:], d_skip, (dt_w, dt_bias))

@@ -351,22 +351,35 @@ def _grads(net, tokens, labels):
     return swapped(grads, lambda: builder.reference_params(net))
 
 
-@pytest.mark.parametrize("remat", [False, True],
-                         ids=["stored", "recomputed"])
-def test_every_gradient_matches_the_reference(remat):
+# 16 x 64 = 1024 channels: the narrowest Mamba the scan's kernels take (4
+# states: the interpreted kernels' unrolled bodies are what the case costs)
+KERNEL_CFG = dict(CFG, mamba_expand=16, mamba_d_state=4)
+
+
+@pytest.mark.parametrize("remat,cfg,path", [
+    (False, CFG, "chunked_xla"), (True, CFG, "chunked_xla"),
+    (True, KERNEL_CFG, "pallas")],
+    ids=["stored", "recomputed", "recomputed_scan_kernels"])
+def test_every_gradient_matches_the_reference(monkeypatch, remat, cfg, path):
     """EVERY parameter's gradient against autodiff of the reference,
     float32 at "highest", with and without per-layer recomputation (the
     tuples `gluon.utils.recompute` carries): 2e-4 of each gradient's
     largest entry. Two G and two C behind the F: F's projection holds dK
     and dV summed over itself and both readers, the memory's Mamba the sum
-    over both gates."""
-    net = build(remat=remat)
+    over both gates. The last case: the scans as their kernel pair
+    (interpreted; three chunks of 64, the last one padded), forward,
+    recomputed with the chunks' start states kept, and backward."""
+    if path == "pallas":
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    scans = scan_mod._SCANS.value(path=path)
+    net = build(cfg, remat=remat)
     tokens, labels = batch()
     with jax.default_matmul_precision("highest"):
         want = jax.grad(lambda p: reference.forward(
-            p, CFG, jnp.asarray(tokens), jnp.asarray(labels), 1)[1].sum())(
+            p, cfg, jnp.asarray(tokens), jnp.asarray(labels), 1)[1].sum())(
                 reference._f32(builder.reference_params(net)))
         got = _grads(net, tokens, labels)
+    assert scan_mod._SCANS.value(path=path) > scans
     flat_w, tree_w = jax.tree_util.tree_flatten_with_path(want)
     flat_g, tree_g = jax.tree_util.tree_flatten_with_path(got)
     assert tree_w == tree_g and len(flat_w) > 90
@@ -468,17 +481,23 @@ def test_recompute_carries_a_tuple_and_one_array_as_before():
         onp.testing.assert_array_equal(got.asnumpy(), want.asnumpy())
 
 
+@pytest.mark.parametrize("mamba,path", [
+    ({}, "chunked_xla"),
+    ({"mamba_expand": 4, "mamba_d_state": 4}, "pallas")],
+    ids=["scan_in_xla_ops", "scan_kernels"])
 def test_one_train_step_lowers_once_and_keeps_the_scopes_under_recompute(
-        monkeypatch):
+        monkeypatch, mamba, path):
     """The normal path (FeaturesView + ChunkedLMLoss over the tied
     embedding through TrainStep, bfloat16 with float32 masters, the
     interpreted streamed kernels at heads of 128, every layer recomputed):
     one program, a falling loss, the new scopes on forward, recomputed and
-    backward ops, and both kinds of kernel in the one program."""
+    backward ops, and both kinds of kernel in the one program. At 1024
+    channels the scans are their kernel pair, under the same scope."""
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    scans = scan_mod._SCANS.value(path=path)
     cfg = dict(CFG, hidden_size=256, num_attention_heads=2,
                num_key_value_heads=2, sliding_window=64,
-               layer_pattern_run="MSMFGC")
+               layer_pattern_run="MSMFGC", **mamba)
     net = build(cfg, dtype="bfloat16", remat=True)
     view = models.FeaturesView(net)
     trainer = gluon.Trainer(view.collect_params(), "adam",
@@ -497,3 +516,4 @@ def test_one_train_step_lowers_once_and_keeps_the_scopes_under_recompute(
         assert any("transpose(" in l for l in paths), scope
         assert any("transpose(" not in l for l in paths), scope
     assert attention._WINDOWS.value(route="streamed") >= 1
+    assert scan_mod._SCANS.value(path=path) == scans + 2    # two M layers
