@@ -15,6 +15,10 @@ Usage mirrors MXNet::
 """
 __version__ = "0.1.0"
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()     # mxtpu_import_seconds, set at the end
+
 # Multi-process (DCN) workers: jax.distributed must come up BEFORE anything
 # touches the XLA backend, and importing this package initialises it (device
 # queries in context/ndarray). tools/launch.py sets this env per worker.
@@ -112,3 +116,9 @@ if config.get_env("MXTPU_PROFILER_AUTOSTART"):
     profiler.set_config(filename=config.get_env("MXTPU_PROFILER_FILENAME"))
     profiler.set_state("run")
     _atexit.register(profiler.dump)
+
+# this import, as the program times it: the first device touch (the global
+# PRNG key of ndarray/random.py starts the backend and runs a program) and
+# everything else
+telemetry.setup_phases.record_import(_time.perf_counter() - _T_IMPORT,
+                                     random.BACKEND_TOUCH_S)

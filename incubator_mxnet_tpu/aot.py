@@ -337,7 +337,10 @@ class AOTCache:
             # device truth is harvested HERE, once per cache entry — the
             # one place every executable (train/eval/serve, build or
             # artifact) passes through on its way to a dispatch
-            stats = devstats.program_stats(fn)
+            # (a span of its own: inside train:build it is what the
+            # trace / lower / compile children leave over)
+            with spans.span("aot:analyze", kind=key.kind):
+                stats = devstats.program_stats(fn)
         entry = _Entry(key, fn, extras, source, stats)
         with self._lock:
             self._entries[key] = entry

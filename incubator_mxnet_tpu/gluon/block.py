@@ -18,6 +18,7 @@ import numpy as onp
 from .. import autograd
 from .. import ndarray as nd
 from ..ndarray import NDArray
+from ..telemetry import spans
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 from . import _functional
 
@@ -162,9 +163,23 @@ class Block:
         return ret
 
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
-        self.collect_params().initialize(init, ctx, verbose, force_reinit)
+        # set-up work of the program's own (a small program or two a
+        # parameter): the span owns their compile-pipeline events
+        # (telemetry/setup_phases.py)
+        params = self.collect_params()
+        with spans.span("gluon:initialize", params=len(params)):
+            params.initialize(init, ctx, verbose, force_reinit)
 
     def cast(self, dtype):
+        # ONE span for the tree: the children's casts run inside the root's
+        outer = spans.current_span()
+        if outer is not None and outer.name == "gluon:cast":
+            return self._cast_tree(dtype)
+        with spans.span("gluon:cast", dtype=str(dtype),
+                        params=len(self.collect_params())):
+            self._cast_tree(dtype)
+
+    def _cast_tree(self, dtype):
         for child in self._children.values():
             child.cast(dtype)
         for _, p in self.params.items():

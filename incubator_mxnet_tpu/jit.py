@@ -80,6 +80,16 @@ __all__ = ["TrainStep", "EvalStep", "compiled_train_programs"]
 # time. Watching compiles_total climb under bucketed variable-shape
 # traffic is how an undersized MXTPU_AOT_CACHE_SIZE shows itself (so is
 # mxtpu_aot_evictions_total, its direct cause).
+# The PARTS of that lump have homes of their own (telemetry/setup_phases.py
+# books JAX's own pipeline events to the span open around them): creating
+# the optimizer state is the span train:init_states; placing the state
+# train:layout; the model's trace, jaxpr -> MLIR and the XLA compile or the
+# read from the persistent cache are the children train:trace / :lower /
+# :backend_compile / :cache_read of train:build (eval:* of eval:build) and
+# mxtpu_compile_phase_seconds_total{phase, owner}; whether the cache
+# answered is mxtpu_compile_cache_total{result, owner}; the Pallas kernel
+# bodies traced on the way are mxtpu_kernel_trace_seconds_total{kernel};
+# the first run is the first train:dispatch (eval:step).
 _COMPILES = telemetry.counter(
     "mxtpu_jit_compiles_total",
     "Shape-keyed executable-cache misses (one XLA compile each).",
@@ -104,7 +114,13 @@ _EXAMPLES = telemetry.counter(
 def _record_compile_span(name, dur_s):
     """Retroactive span for a just-finished compile window (it ends with
     the miss's first run, so it is only measurable after the fact),
-    parented onto the ambient step span."""
+    parented onto the ambient step span. It is the lump, kept for the
+    operators' uses docs/OBSERVABILITY.md, AOT.md and GENERATE.md give it;
+    train:compile = train:host_transfer + train:init_states + train:build
+    (train:layout, train:trace, train:lower, train:backend_compile or
+    train:cache_read) + train:schedule + the first train:dispatch, and
+    eval:compile = eval:build (eval:trace, eval:lower, eval:backend_compile
+    or eval:cache_read): read those to know which part moved."""
     try:
         from . import profiler
         spans.record_span(name, profiler.now_us() - dur_s * 1e6,
@@ -298,6 +314,9 @@ class TrainStep:
         pipeline EvalStep uses. The XLA compile lands inside the
         train:build span and the cache entry is an analyzable compiled
         program (devstats harvests its cost/memory analysis at insert).
+        Inside train:build the lay-out is the span train:layout and
+        JAX's trace / lower / compile-or-cache-read events become its
+        other children (telemetry/setup_phases.py).
         A failed spec, lower or compile raises to the caller: a lazy
         retry would compile the same program again, and swallowing the
         first error is how a compiler refusal (a Mosaic kernel over its
@@ -312,17 +331,21 @@ class TrainStep:
         # param NDArrays (inner's _data swap) — hold the net's trace lock
         # for the whole window, exactly like the eval build
         with self._trace_lock:
-            state = ([a._data for a in t_arrs], [a._data for a in f_arrs],
-                     [_tree_to_data(trainer._states[idx]) for idx in slots])
-            state_sh = t_sh + f_sh + [
-                rule(leaf) for st, rule in zip(state[2], state_rules)
-                for leaf in jax.tree_util.tree_leaves(st)]
-            specs = self._arg_specs(state, arrs, key, state_sh, data_sh, repl)
-            # rebound, so that nothing holds what was there before: the
-            # program loads beside the laid-out state alone
-            state = _with_layout(_placed, state, state_sh)
-            self._write_back(t_arrs + f_arrs, slots, state[0] + state[1],
-                             state[2])
+            with spans.span("train:layout"):
+                state = ([a._data for a in t_arrs],
+                         [a._data for a in f_arrs],
+                         [_tree_to_data(trainer._states[idx])
+                          for idx in slots])
+                state_sh = t_sh + f_sh + [
+                    rule(leaf) for st, rule in zip(state[2], state_rules)
+                    for leaf in jax.tree_util.tree_leaves(st)]
+                specs = self._arg_specs(state, arrs, key, state_sh, data_sh,
+                                        repl)
+                # rebound, so that nothing holds what was there before: the
+                # program loads beside the laid-out state alone
+                state = _with_layout(_placed, state, state_sh)
+                self._write_back(t_arrs + f_arrs, slots,
+                                 state[0] + state[1], state[2])
             compiled = jax.jit(
                 step_fn, donate_argnums=_donate((0, 2))).lower(*specs).compile()
         return compiled, (slots, t_arrs, f_arrs, aux_box, state_sh,
@@ -472,10 +495,14 @@ class TrainStep:
         if any(p._data is None for p in self.net.collect_params().values()):
             with autograd.pause(train_mode=True):
                 self.net.forward(*arrs[:n_net_inputs])
-        if not trainer._kv_initialized:
-            trainer._init_kvstore()
-        if not trainer._states_initialized:
-            trainer._init_states()
+        if not (trainer._kv_initialized and trainer._states_initialized):
+            # fp32 masters and the moments of every parameter: a first
+            # call's own set-up, a small program or two a parameter
+            with spans.span("train:init_states"):
+                if not trainer._kv_initialized:
+                    trainer._init_kvstore()
+                if not trainer._states_initialized:
+                    trainer._init_states()
 
         if self._model_id is None:
             self._model_id = aot.model_id_for(

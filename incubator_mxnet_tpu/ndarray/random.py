@@ -7,6 +7,7 @@ which vectorises on the VPU with no sequential state.
 from __future__ import annotations
 
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,13 @@ class _RngState(threading.local):
         self.key = jax.random.PRNGKey(0)
 
 
+_t0 = time.perf_counter()
 _RNG = _RngState()
+#: seconds of the process's first device touch (the key above starts the
+#: backend and runs a program): the package's import reports it as
+#: mxtpu_import_seconds{part="backend"}
+BACKEND_TOUCH_S = time.perf_counter() - _t0
+del _t0
 
 
 def seed(seed_state, ctx="all"):

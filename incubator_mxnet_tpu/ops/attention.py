@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from . import kernel_trace
 
 __all__ = ["flash_attention", "flash_attention_supported",
            "flash_attention_legal", "flash_attention_lse",
@@ -369,8 +370,8 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
     else:
         def kv_idx(b, i, j):
             return (b, j, 0)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = kernel_trace.pallas_call(
+        kernel, (qf, kf, vf),
         out_shape=(jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)),
         grid=grid,
@@ -386,7 +387,7 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
                         pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
         name="flash_fwd" if window is None else "flash_window_fwd",
-    )(qf, kf, vf)
+    )
     return out.reshape(B, H, S, D), lse
 
 
@@ -532,8 +533,8 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
     kvspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
     # an index that depends on b alone: resident across the inner axes
     slab = pl.BlockSpec((1, n_q * block_q, D), lambda b, i, j: (b, 0, 0))
-    return pl.pallas_call(
-        kernel,
+    return kernel_trace.pallas_call(
+        kernel, (qf, dof, lse, delta, kf, vf),
         out_shape=(jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
                    jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
                    jax.ShapeDtypeStruct((BH, n_q * block_q, D), jnp.float32)),
@@ -545,7 +546,7 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
             vmem_limit_bytes=_slab_bytes(n_q * block_q, D) + _BWD_TILE_BYTES),
         interpret=_interpret(),
         name="flash_bwd_dkvq" if window is None else "flash_window_bwd",
-    )(qf, dof, lse, delta, kf, vf)
+    )
 
 
 def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
@@ -728,8 +729,9 @@ def _short_call(q, k, v, causal, scale, interpret):
 
     B, H, S, D = q.shape
     grid, tile, rows = _short_specs(B, H, S, D)
-    out, lse = pl.pallas_call(
+    out, lse = kernel_trace.pallas_call(
         functools.partial(_short_fwd_kernel, D=D, causal=causal, scale=scale),
+        (_to_rows(q), _to_rows(k), _to_rows(v)),
         out_shape=(jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
                    jax.ShapeDtypeStruct((B, H * D // 128, 128 // D, S),
                                         jnp.float32)),
@@ -738,7 +740,7 @@ def _short_call(q, k, v, causal, scale, interpret):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="flash_short_fwd",
-    )(_to_rows(q), _to_rows(k), _to_rows(v))
+    )
     return _to_heads(out, H), lse
 
 
@@ -749,8 +751,9 @@ def _short_bwd_call(q, k, v, lse, do, causal, scale, interpret):
 
     B, H, S, D = q.shape
     grid, tile, rows = _short_specs(B, H, S, D)
-    grads = pl.pallas_call(
+    grads = kernel_trace.pallas_call(
         functools.partial(_short_bwd_kernel, D=D, causal=causal, scale=scale),
+        (_to_rows(q), _to_rows(k), _to_rows(v), _to_rows(do), lse),
         out_shape=tuple(jax.ShapeDtypeStruct((B, S, H * D), x.dtype)
                         for x in (q, k, v)),
         grid=grid, in_specs=[tile, tile, tile, tile, rows],
@@ -759,7 +762,7 @@ def _short_bwd_call(q, k, v, lse, do, causal, scale, interpret):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="flash_short_bwd",
-    )(_to_rows(q), _to_rows(k), _to_rows(v), _to_rows(do), lse)
+    )
     return tuple(_to_heads(g, H) for g in grads)
 
 

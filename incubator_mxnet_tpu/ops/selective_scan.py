@@ -78,6 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from . import kernel_trace
 from .attention import _interpret, _kernels_run_here
 
 __all__ = ["selective_scan"]
@@ -494,14 +495,14 @@ def _fwd_call(args, q, keep, interpret):
         out_specs.append(pl.BlockSpec(
             (None, None, None, n, _SUB, _LANE),
             lambda b, j, g: (b, j, g, 0, 0, 0)))
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, keep=keep),
+    return kernel_trace.pallas_call(
+        functools.partial(_fwd_kernel, keep=keep), _operands(*args),
         out_shape=out_shape, grid=(b, nb, chunks), in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((n, _SUB, _LANE), _F32)]
         + [_stepped(q)] * 3,
         compiler_params=_params(), interpret=interpret,
-        name="selective_scan_fwd")(*_operands(*args))
+        name="selective_scan_fwd")
 
 
 @_traced_once("q", "interpret")
@@ -527,8 +528,9 @@ def _bwd_call(args, starts, dy, q, interpret):
             part(r), part(n), part(n), kept(n, _SUB, _LANE),
             kept(_KERNEL_CHANNELS, r), kept(_SUB, _SUB, _LANE),
             kept(_SUB, _SUB, _LANE)]
-    dx, dlow, db, dc, da, dw, dbias, dd = pl.pallas_call(
-        _bwd_kernel, out_shape=[o[0] for o in outs], grid=(b, nb, chunks),
+    dx, dlow, db, dc, da, dw, dbias, dd = kernel_trace.pallas_call(
+        _bwd_kernel, (*_operands(*args), dy, starts),
+        out_shape=[o[0] for o in outs], grid=(b, nb, chunks),
         in_specs=in_specs + [in_specs[0], pl.BlockSpec(
             (None, None, None, n, _SUB, _LANE),
             lambda b, j, g: (b, j, chunk(g), 0, 0, 0))],
@@ -538,7 +540,7 @@ def _bwd_call(args, starts, dy, q, interpret):
                                    _F32)]
         + [_stepped(q)] * 5,
         compiler_params=_params(), interpret=interpret,
-        name="selective_scan_bwd")(*_operands(*args), dy, starts)
+        name="selective_scan_bwd")
     return (dx, dlow.sum(1).astype(low.dtype),
             da.sum(0).swapaxes(0, 1).reshape(n, c).T.astype(A.dtype),
             db.sum(1).astype(B.dtype), dc.sum(1).astype(C.dtype),

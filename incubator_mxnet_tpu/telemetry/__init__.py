@@ -40,6 +40,7 @@ from . import flightrec
 from . import history
 from . import numwatch
 from . import profstats
+from . import setup_phases
 from . import slo
 from . import spans
 from . import watchdog
@@ -54,7 +55,7 @@ __all__ = [
     "request_scope", "REQUEST_ID_HEADER",
     "start_periodic_flush", "stop_periodic_flush", "flush_to_file",
     "devstats", "faultlab", "flightrec", "history", "numwatch",
-    "profstats", "slo", "spans", "watchdog",
+    "profstats", "setup_phases", "slo", "spans", "watchdog",
     "Span", "SpanContext", "span", "record_span", "current_span",
     "current_context",
 ]
@@ -147,9 +148,14 @@ def _maybe_autostart():
     """Package-import hook: MXTPU_TELEMETRY_FLUSH_S > 0 starts the flusher
     (headless training jobs get metrics with zero code changes), the
     flight recorder chains its crash-dump excepthooks (gated per-crash by
-    MXTPU_FLIGHTREC_DUMP_ON_CRASH), and MXTPU_WATCHDOG=1 starts the stall
-    watchdog monitor."""
+    MXTPU_FLIGHTREC_DUMP_ON_CRASH), MXTPU_WATCHDOG=1 starts the stall
+    watchdog monitor, and the set-up listener joins ``jax.monitoring``
+    (always: it runs only when JAX traces, lowers or compiles)."""
     from .. import config
+    try:
+        setup_phases.install()
+    except Exception:
+        pass
     try:
         if config.get_env("MXTPU_TELEMETRY_FLUSH_S") > 0:
             start_periodic_flush()
