@@ -57,7 +57,9 @@ FULL = {
                    Q=128, QH=4, KV=1, L=512, I=1024, SH=2048, E=64, HELD=8,
                    K=4, SHARDS=2, SCAN_S=8192,
                    # the Mamba-1 scan alone at the SambaY cell's shape
-                   SEL_S=16384, SEL_C=5120, SEL_N=16, SEL_R=160),
+                   SEL_S=16384, SEL_C=5120, SEL_N=16, SEL_R=160,
+                   # a differential layer's call there: (1, H, S, D | 2 D)
+                   WIDE_H=20, WIDE_S=16384, WIDE_D=64, WIDE_WINDOW=512),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -74,7 +76,9 @@ TOY = {
                    Q=16, QH=2, KV=1, L=64, I=48, SH=96, E=16, HELD=4, K=4,
                    # (1024 channels: the narrowest the scan's kernels take)
                    SHARDS=2, SCAN_S=256, SEL_S=160, SEL_C=1024, SEL_N=16,
-                   SEL_R=4),
+                   SEL_R=4,
+                   # (narrow heads ride the streamed kernels from 2048 on)
+                   WIDE_H=1, WIDE_S=2048, WIDE_D=64, WIDE_WINDOW=512),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -385,6 +389,18 @@ def scan_alone(cfg):
         float(jnp.abs(rounded - want).max()) / top
 
 
+def median_ms(fn, operands):
+    """Milliseconds of a jitted call, the median of five after the first."""
+    import jax
+    jax.block_until_ready(fn(*operands))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*operands))
+        times.append(time.perf_counter() - t0)
+    return 1e3 * statistics.median(times)
+
+
 def selective_scan_alone(cfg, on_chip):
     """`ops.selective_scan.selective_scan` alone at the SambaY cell's shape
     (SEL_S positions, SEL_C channels, SEL_N states, the step sizes'
@@ -450,15 +466,6 @@ def selective_scan_alone(cfg, on_chip):
         return jax.jit(jax.grad(lambda cot, *t: jnp.sum(fn(*t) * cot),
                                 tuple(range(1, 1 + len(args)))))
 
-    def median_ms(fn, operands):
-        jax.block_until_ready(fn(*operands))
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*operands))
-            times.append(time.perf_counter() - t0)
-        return 1e3 * statistics.median(times)
-
     kernels = op._SCANS.value(path="pallas")
     with jax.default_matmul_precision("highest"):
         if on_chip and "tpu_custom_call" not in \
@@ -488,6 +495,83 @@ def selective_scan_alone(cfg, on_chip):
         out["forward_ms"] = median_ms(jax.jit(system), lowp)
         out["both_ms"] = median_ms(
             gradients(system), (jnp.ones(x.shape, jnp.bfloat16),) + lowp)
+    return out
+
+
+#: a wide-value call against the two narrow calls it replaces, bfloat16, as
+#: a share of each tensor's largest entry: an output column is the same
+#: float32 sum rounded once on both sides; a narrow pair's dQ and dK are two
+#: bfloat16 gradients added where the wide call sums in float32 and rounds
+#: once (readings of one v5e: PERF.md section 6, PR 37)
+WIDE_VALUE_LIMITS = {"outputs": 2.0 ** -7, "gradients": 2.0 ** -6}
+
+
+def wide_value_alone(cfg, on_chip):
+    """`flash_attention` with a value twice as wide as its keys, at a
+    differential layer's shape in the SambaY cell ((1, WIDE_H, WIDE_S,
+    WIDE_D | 2 WIDE_D), bfloat16), full causal and under WIDE_WINDOW:
+    forward and the three gradients of ONE call against `[v_1; v_2]`
+    against the TWO calls of one width it replaces (a_i v_1, a_i v_2: the
+    same map twice), on the streamed kernels both (a Mosaic call in the
+    lowered text on the chip: a refusal or a VMEM limit at the wide tiles
+    is met here, not in the cell). -> {"causal" | "window": {"outputs",
+    "gradients": the worst distance over the tensor's largest entry,
+    "wide_ms", "narrow_ms": (forward, forward + backward) of the one call
+    and of the pair, None off the chip}}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    from incubator_mxnet_tpu.ops import attention as op
+    h, s, d = cfg["WIDE_H"], cfg["WIDE_S"], cfg["WIDE_D"]
+    rng = onp.random.default_rng(0)
+    q, k, vv, cot = (
+        jnp.asarray(rng.standard_normal((1, h, s, w)), jnp.bfloat16)
+        for w in (d, d, 2 * d, 2 * d))
+
+    def distance(got, want):
+        got, want = (t.astype(jnp.float32) for t in (got, want))
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    out = {}
+    for name, window in (("causal", None), ("window", cfg["WIDE_WINDOW"])):
+        def wide(q, k, vv):
+            return op.flash_attention(q, k, vv, True, window=window)
+
+        def narrow(q, k, vv):
+            return jnp.concatenate(
+                [op.flash_attention(q, k, vv[..., :d], True, window=window),
+                 op.flash_attention(q, k, vv[..., d:], True, window=window)],
+                -1)
+
+        def both(fn):
+            """-> (output, dq, dk, dv) of sum(out * cot)."""
+            def run(q, k, vv):
+                y, back = jax.vjp(fn, q, k, vv)
+                return (y,) + back(cot)
+            return jax.jit(run)
+
+        if op.attention_route(q.shape, k.shape, vv.shape,
+                              window=window) != "streamed":
+            raise RuntimeError("a value %d wide on keys of %d at S=%d left "
+                               "the streamed kernels" % (2 * d, d, s))
+        calls = op._WIDE_VALUES.value(route="streamed")
+        if on_chip and jax.jit(wide).lower(q, k, vv).as_text() \
+                .count("tpu_custom_call") != 1:
+            raise RuntimeError("the wide-value forward is not ONE Mosaic "
+                               "call (%s)" % name)
+        got, want = both(wide)(q, k, vv), both(narrow)(q, k, vv)
+        if op._WIDE_VALUES.value(route="streamed") == calls:
+            raise RuntimeError("mxtpu_attention_wide_value_total did not "
+                               "count the wide call")
+        out[name] = {
+            "outputs": distance(got[0], want[0]),
+            "gradients": max(distance(g, w)
+                             for g, w in zip(got[1:], want[1:])),
+            "wide_ms": None, "narrow_ms": None}
+        if on_chip:
+            for key, fn in (("wide_ms", wide), ("narrow_ms", narrow)):
+                out[name][key] = (median_ms(jax.jit(fn), (q, k, vv)),
+                                  median_ms(both(fn), (q, k, vv)))
     return out
 
 
@@ -543,6 +627,16 @@ def phase_hybrid(cfg, on_chip, shared):
             "%g), a bfloat16 state a chunk %.3g" % (
                 sel["sound"], sel["gradients"], SCAN_ALONE_LIMIT,
                 sel["rounded"]))
+    wide = wide_value_alone(cfg, on_chip)
+    for name, read in wide.items():
+        if not (read["outputs"] <= WIDE_VALUE_LIMITS["outputs"]
+                and read["gradients"] <= WIDE_VALUE_LIMITS["gradients"]):
+            raise RuntimeError(
+                "a wide-value call (%s) against the two narrow calls it "
+                "replaces: outputs %.3g (limit %.3g), gradients %.3g (limit "
+                "%.3g) of the largest entry" % (
+                    name, read["outputs"], WIDE_VALUE_LIMITS["outputs"],
+                    read["gradients"], WIDE_VALUE_LIMITS["gradients"]))
     alone = "" if sel["forward_ms"] is None else \
         "; forward %.1f ms, forward + backward %.1f ms in bfloat16 (the XLA " \
         "form 36-38 / 77-80, PR 34)" % (sel["forward_ms"], sel["both_ms"])
@@ -550,11 +644,20 @@ def phase_hybrid(cfg, on_chip, shared):
         "mosaic kernels: %s; scan alone at S=%d in float32 %.2g of its " \
         "largest output from the recurrence (a bfloat16 state a chunk " \
         "%.2g); selective scan alone at %d x %d x %d, the kernel pair: " \
-        "%.2g (%.2g), the eight gradients of one channel block %.2g%s" % (
+        "%.2g (%.2g), the eight gradients of one channel block %.2g%s; " \
+        "attention with a value of its own width at (1, %d, %d, %d | %d) " \
+        "against the two narrow calls it replaces: %s" % (
             cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
             cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
             cfg["SEL_N"], sel["sound"], sel["rounded"], sel["gradients"],
-            alone)
+            alone, cfg["WIDE_H"], cfg["WIDE_S"], cfg["WIDE_D"],
+            2 * cfg["WIDE_D"], "; ".join(
+                "%s outputs %.2g, gradients %.2g%s" % (
+                    name, read["outputs"], read["gradients"],
+                    "" if read["wide_ms"] is None else
+                    ", forward %.1f ms and forward + backward %.1f ms for "
+                    "%.1f and %.1f" % (read["wide_ms"] + read["narrow_ms"]))
+                for name, read in wide.items()))
 
 
 def build_resnet():

@@ -34,15 +34,29 @@ over the readers by autodiff), and `gluon.utils.recompute` carries the
 tuples. The float32 reference of these equations is
 perfbench/reference/phi-4-mini-flash-reasoning.py.
 
-Differential attention runs on the streamed kernels as the source runs it
-on its own: a_1 v_1, a_1 v_2, a_2 v_1, a_2 v_2 are four attentions of one
-shape and four calls here too (scope `diff_attention`; under a window the
-kernels are flash_window_fwd / flash_window_bwd): stacked into one call
-over 4 x the head pairs, the backward's float32 dQ, dK, dV of all four
-exist at once, 2 GB at 16k tokens in 64-wide rows that HBM pads to 128
-lanes, and the step does not load beside 9.8 GB of state (PERF.md
-section 6). The scores are computed twice; a v wider than q and k
-in one call would halve that (PERF.md section 7).
+Differential attention runs on the streamed kernels as TWO calls a layer
+(scope `diff_attention`; under a window the kernels are flash_window_fwd /
+flash_window_bwd): each softmax map a_i = softmax(q_i k_i^T) is computed
+once and meets [v_1; v_2], v's own layout read 2 d wide, through the
+kernels' value width D_v (ops/attention.py): q, k 64 wide, v and the
+output 128. Where the equal-width shape belongs to the short family
+(d 32 / 64 at 256 <= S <= 768), whose lane layout knows one width, the
+block makes the four calls of one shape it made up to PR 36, a_1 v_1,
+a_1 v_2, a_2 v_1, a_2 v_2; everywhere else, the composite included, two.
+
+Why two calls and not four (v5e, the SambaY stage's (1, 20, 16384, 64)
+bfloat16 causal, a call alone; PERF.md section 6, PR 37): a head of 64
+half-fills the MXU's 128 lanes and every (.., S, 64) row is padded to 128
+in HBM, so a call against a value 128 wide takes what a call against one
+64 wide takes, and replaces two: forward 14.2 ms and forward + backward
+37.7 ms, where the two calls of one width take 28.3 and 74.6 (under the
+window of 512: 4.0 and 8.9 for 7.7 and 17.3), the output columns equal to
+the bit; in the stage's step 13.1 ms a forward call and 21.7 a backward,
+what ONE of the four took. Why not one call over 4 x the
+head pairs (PR 34): the backward's float32 dQ, dK, dV of all four exist
+at once, 2 GB at 16k tokens in 64-wide rows padded to 128 lanes, and the
+step does not load beside 9.8 GB of state; two calls a layer hold what
+one of the four held.
 """
 from __future__ import annotations
 
@@ -220,10 +234,13 @@ class DifferentialAttention(HybridBlock):
     ``cross``: the block has the query projection alone and forward takes
     (x, k, v) of the layer that handed them on.
 
-    Scope `diff_attention` (the four maps, their combination and the
-    per-head norm), under `cross_attention` where the block is the
-    cross-decoder's; the four l vectors and the norm's gain stay float32
-    under ``cast``."""
+    Two attention calls a layer: each map of a pair against [v_1; v_2],
+    a value 2 x ``head_dim`` wide on keys of ``head_dim``
+    (`flash_attention`'s D_v; four calls of one width where that shape is
+    the short family's: the module's docstring). Scope `diff_attention`
+    (the two maps, their combination and the per-head norm), under
+    `cross_attention` where the block is the cross-decoder's; the four l
+    vectors and the norm's gain stay float32 under ``cast``."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim, depth,
                  window=None, hand_on=False, cross=False, epsilon=1e-5,
@@ -254,7 +271,8 @@ class DifferentialAttention(HybridBlock):
 
     def _attend(self, q, k, v, lambdas, gamma):
         """q (B, S, H d); k, v (B, S, Hkv d) -> (B, S, H d)."""
-        from ..ops.attention import flash_attention, flash_attention_on_mesh
+        from ..ops.attention import attention_route, flash_attention, \
+            flash_attention_on_mesh
         from ..parallel.mesh import step_mesh
         b, s, _ = q.shape
         d = self._d
@@ -265,10 +283,12 @@ class DifferentialAttention(HybridBlock):
 
         with jax.named_scope("diff_attention"):
             q1, q2 = halves(q, self._h)
+            k1, k2 = halves(k, self._hkv)
+            # [v_1; v_2] of a pair is v's own layout read 2 d wide
+            vv = v.reshape(b, s, self._hkv // 2, 2 * d).transpose(0, 2, 1, 3)
             rep = self._h // self._hkv
-            k1, k2, v1, v2 = (
-                t if rep == 1 else jnp.repeat(t, rep, 1)
-                for t in halves(k, self._hkv) + halves(v, self._hkv))
+            if rep > 1:
+                k1, k2, vv = (jnp.repeat(t, rep, 1) for t in (k1, k2, vv))
             step = step_mesh()
 
             def attend(q, k, v):
@@ -280,13 +300,17 @@ class DifferentialAttention(HybridBlock):
                     out = flash_attention(q, k, v, True, window=self._window)
                 return out.astype(jnp.float32)
 
-            # a_1 v_1, a_1 v_2, a_2 v_1, a_2 v_2: four calls of one shape
-            a11, a12 = attend(q1, k1, v1), attend(q1, k1, v2)
-            a21, a22 = attend(q2, k2, v1), attend(q2, k2, v2)
+            if attention_route(q1.shape, window=self._window) == "short":
+                # the short family's lane layout knows one width: the four
+                # calls a pair, each map against v_1 and against v_2
+                def a(q, k):
+                    return jnp.concatenate([attend(q, k, vv[..., :d]),
+                                            attend(q, k, vv[..., d:])], -1)
+            else:
+                a = functools.partial(attend, v=vv)   # a value 2 d wide
             lam = jnp.exp(jnp.sum(lambdas[0] * lambdas[1])) \
                 - jnp.exp(jnp.sum(lambdas[2] * lambdas[3])) + self._l_init
-            o = jnp.concatenate([a11, a12], -1) \
-                - lam * jnp.concatenate([a21, a22], -1)   # (B, pairs, S, 2d)
+            o = a(q1, k1) - lam * a(q2, k2)           # (B, pairs, S, 2d)
             o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                                   + self._eps) * gamma * (1.0 - self._l_init)
             return o.transpose(0, 2, 1, 3).reshape(b, s, self._h * d) \

@@ -24,7 +24,15 @@ shape alone:
   with ``window=``, a causal sliding window (key j seen from query i iff
   0 <= i - j < window): the same two bodies under the names
   flash_window_fwd / flash_window_bwd, so a capture tells them from the
-  causal calls of the same program. Under a window the grid's last axis
+  causal calls of the same program. **The value has a width of its own:**
+  v, O, dO and dV are D_v = v.shape[-1] wide (their blocks, the forward's
+  accumulator, delta = sum dO O), q, k, dQ, dK and the dQ slab stay D, the
+  scale stays 1 / sqrt(D) of q; at D_v = D the traced calls are the ones
+  they always were, text for text. A differential pair's two maps each
+  meet [v_1; v_2] (D 64, D_v 128) in ONE call this way, not two. The
+  tiles' VMEM was sized at D = 128: D_v = 256 compiles for a v5e in
+  bfloat16 and, at blocks of 1024, not in float32 (Mosaic refuses it, as
+  it refuses equal heads of 256). Under a window the grid's last axis
   counts the blocks of a row's BAND instead of all of them (at S = 16k,
   window 512, blocks of 512: 63 live pairs a head where the causal grid
   visits 528), the index maps are clamped from both sides so a step past
@@ -39,17 +47,19 @@ shape alone:
   layout in 128-lane blocks of whole heads: no transposes, no padded lanes.
 
 Neither family ever writes an (S, S) tensor to HBM. Everything else (cross
-attention, lengths no block divides, a window on a shape of the short
-family, any shape off the TPU) takes the XLA composite. Used by models.bert
-MultiHeadAttention (attention='flash') and models.phi4flash
-DifferentialAttention. A Mosaic refusal of a routed shape surfaces as the
+attention, lengths no block divides, a window or a value width of its own
+on a shape of the short family, whose lane layout knows one width, any
+shape off the TPU) takes the XLA composite, whose einsums carry any D_v.
+Used by models.bert MultiHeadAttention (attention='flash') and
+models.phi4flash DifferentialAttention. A Mosaic refusal of a routed shape surfaces as the
 compile error it is — nothing catches it to degrade. MXTPU_FLASH_INTERPRET=1 runs the kernels in
 Pallas interpret mode (CPU tests only; chip_smoke.py and bench.py refuse to
 start with it set). Counters at /metrics, one increment per traced call:
 mxtpu_attention_route_total{route} (the backward follows the forward's
 route), mxtpu_attention_backward_total{kernel} (a streamed backward:
-one call, or segmented; flash_window_bwd under a window) and
-mxtpu_attention_window_total{route}; the gauge
+one call, or segmented; flash_window_bwd under a window),
+mxtpu_attention_window_total{route} and
+mxtpu_attention_wide_value_total{route} (v wider than k); the gauge
 mxtpu_attention_live_block_pairs{kind="window"|"causal"} holds what the
 last traced windowed forward visits a head, and what the causal grid would.
 
@@ -63,6 +73,13 @@ pair it replaced ran seven matmuls and the element-wise chain twice, 26.3
 ms; one visit takes 18.6, every gradient equal to the pair's to the bit.
 Segmented it ran at (1, 4, 131072, 128), two segments: 264.5 ms a call;
 forced to two segments at 16k it takes 19.9 ms, dQ equal to the bit.
+Why a value width of its own (v5e, (1, 20, 16384, 64) bf16 causal, a
+differential layer of the SambaY stage; PERF.md §6, PR 37): a call's time
+a head is the same at D = 64 as at D = 128 (half-filled lanes, rows
+padded to 128 in HBM), so each softmax map against [v_1; v_2] 128 wide in
+ONE call takes 14.2 ms forward and 37.7 with the backward where the two
+calls of one width it replaces take 28.3 and 74.6 (window 512: 4.0 / 8.9
+for 7.7 / 17.3), the output columns equal to the bit.
 """
 from __future__ import annotations
 
@@ -108,6 +125,12 @@ _WINDOWS = telemetry.counter(
     "flash_attention calls traced with a sliding window, by the path they "
     "took (streamed: flash_window_fwd / flash_window_bwd; composite).",
     ("route",))
+_WIDE_VALUES = telemetry.counter(
+    "mxtpu_attention_wide_value_total",
+    "flash_attention calls traced whose value is wider than its keys (a "
+    "width D_v of its own: [v_1; v_2] of a differential pair), by the path "
+    "they took (streamed: the same kernels, v / dO / O / dV D_v wide; "
+    "composite).", ("route",))
 _LIVE_PAIRS = telemetry.gauge(
     "mxtpu_attention_live_block_pairs",
     "Block pairs a head's streamed forward visits, set when a call is "
@@ -216,23 +239,28 @@ def attention_route(q_shape, k_shape=None, v_shape=None, block_q=None,
     takes, from the shapes alone (and whether kernels can run here at all:
     a TPU, or interpret mode). Both kernel families assume self-attention
     (Sq == Sk); cross-attention takes the composite, which handles it.
+    v may differ from q and k in its last dimension alone: the streamed
+    family takes a D_v of its own (a multiple of 8) wherever it takes the
+    equal shape, the short family's lane layout is D_v = D only.
     A sliding ``window`` adds no family: the streamed kernels take it (as
     flash_window_fwd / flash_window_bwd), the short family knows the
     diagonal only, so a windowed shape it would have taken goes to the
     composite."""
     k_shape, v_shape = k_shape or q_shape, v_shape or q_shape
-    if not tuple(q_shape) == tuple(k_shape) == tuple(v_shape):
+    if not tuple(q_shape) == tuple(k_shape) \
+            == tuple(v_shape[:-1]) + (q_shape[-1],):
         return "composite"
     _, H, S, D = q_shape
     if _narrow_and_short(q_shape):
         # whole heads in 128-lane blocks of the (B, S, H*D) layout
         fits = _SHORT_MIN_S <= S <= _SHORT_MAX_S and S % 128 == 0 \
-            and D in _SHORT_D and (H * D) % 128 == 0 and window is None
+            and D in _SHORT_D and (H * D) % 128 == 0 and window is None \
+            and v_shape[-1] == D
         return "short" if fits and _kernels_run_here() else "composite"
     if window is not None:
         block_q, block_k = _resolve_blocks(S, block_q, block_k, window)
     return "streamed" if flash_attention_legal(q_shape, block_q, block_k) \
-        else "composite"
+        and v_shape[-1] % 8 == 0 else "composite"
 
 
 def _nt(a, b):
@@ -267,7 +295,9 @@ def _last_q_block(kb, block_q, block_k, window, n_q):
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
                block_k, causal, scale, window=None):
     """One (batch*head, q-block, k-block) program: K/V are STREAMED by the
-    grid — VMEM holds only (block_q + 2*block_k) x D tiles plus the online
+    grid — VMEM holds only (block_q + 2*block_k) x D tiles (v's, the
+    accumulator and the output D_v wide: the body reads every width off
+    its refs) plus the online
     softmax carry (m/l/acc scratch, persisted across the sequential k-block
     steps), so sequence length is bounded by HBM, not VMEM (S=32k+ on one
     chip).  Writes the per-row LSE (m + log l) the backward kernels consume.
@@ -331,14 +361,15 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
 
 
 def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
-    """Returns (out (B,H,S,D), lse (B*H,S) fp32)."""
+    """Returns (out (B,H,S,Dv), lse (B*H,S) fp32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, D)
+    vf = v.reshape(B * H, S, Dv)
     grid = (B * H, S // block_q, S // block_k)
     kernel = functools.partial(_fa_kernel, block_k=block_k, causal=causal,
                                scale=scale)
@@ -372,23 +403,23 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
             return (b, j, 0)
     out, lse = kernel_trace.pallas_call(
         kernel, (qf, kf, vf),
-        out_shape=(jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), kv_idx),
-            pl.BlockSpec((1, block_k, D), kv_idx),
+            pl.BlockSpec((1, block_k, Dv), kv_idx),
         ],
-        out_specs=(pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+        out_specs=(pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))),
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, D), jnp.float32)],
+                        pltpu.VMEM((block_q, Dv), jnp.float32)],
         interpret=_interpret(),
         name="flash_fwd" if window is None else "flash_window_fwd",
     )
-    return out.reshape(B, H, S, D), lse
+    return out.reshape(B, H, S, Dv), lse
 
 
 # --------------------------------------------------------------- backward
@@ -425,7 +456,8 @@ def _fa_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                    block_k, q0, window=None, n_q=None):
     """Grid (bh, kv-block, q-block), each live pair visited ONCE: P and dS
     are recomputed from the saved LSE (FlashAttention-2) and all three
-    gradients accumulate from them. dK/dV blocks accumulate over the inner
+    gradients accumulate from them (v, dO and dV D_v wide, read off the
+    refs: ONE dS from all D_v columns). dK/dV blocks accumulate over the inner
     q steps; dQ accumulates into dq_ref, the (batch*head)'s whole slab of
     this call's q rows, which stays in VMEM across both inner axes and is
     written back when bh changes. ``q0`` is the call's first q-block.
@@ -496,6 +528,7 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
     from jax.experimental.pallas import tpu as pltpu
 
     BH, S, D = qf.shape
+    Dv = vf.shape[-1]
     n_kv = S // block_k
     steps = n_q
     kernel = functools.partial(_fa_bwd_kernel, causal=causal, scale=scale,
@@ -527,20 +560,25 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
     else:
         def q_blk(i, j):
             return q0 + j
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, q_blk(i, j), 0))
+    def qspec(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, j: (b, q_blk(i, j), 0))
+
+    def kvspec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, i, j: (b, i, 0))
     rowspec = pl.BlockSpec((1, 1, block_q),
                            lambda b, i, j: (b, 0, q_blk(i, j)))
-    kvspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
     # an index that depends on b alone: resident across the inner axes
     slab = pl.BlockSpec((1, n_q * block_q, D), lambda b, i, j: (b, 0, 0))
     return kernel_trace.pallas_call(
         kernel, (qf, dof, lse, delta, kf, vf),
         out_shape=(jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, n_kv * block_k, D), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, n_kv * block_k, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((BH, n_q * block_q, D), jnp.float32)),
         grid=(BH, n_kv, steps),
-        in_specs=[qspec, qspec, rowspec, rowspec, kvspec, kvspec],
-        out_specs=(kvspec, kvspec, slab),
+        in_specs=[qspec(D), qspec(Dv), rowspec, rowspec, kvspec(D),
+                  kvspec(Dv)],
+        out_specs=(kvspec(D), kvspec(Dv), slab),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_slab_bytes(n_q * block_q, D) + _BWD_TILE_BYTES),
@@ -552,13 +590,14 @@ def _fa_bwd_segment(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
 def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                  g_lse=None, window=None):
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, D)
-    dof = do.reshape(B * H, S, D)
-    # delta_i = sum_d dO_i * O_i — O(S*D), computed by XLA
+    vf = v.reshape(B * H, S, Dv)
+    dof = do.reshape(B * H, S, Dv)
+    # delta_i = sum_d dO_i * O_i — O(S*Dv), computed by XLA
     delta = jnp.sum(dof.astype(jnp.float32) *
-                    o.reshape(B * H, S, D).astype(jnp.float32),
+                    o.reshape(B * H, S, Dv).astype(jnp.float32),
                     axis=-1)[:, None, :]                 # (B*H, 1, S)
     if g_lse is not None:
         # When LSE is a second primal output (flash_attention_lse), its
@@ -588,7 +627,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     shape = (B, H, S, D)
     return (dq.reshape(shape).astype(q.dtype),
             dk.reshape(shape).astype(k.dtype),
-            dv.reshape(shape).astype(v.dtype))
+            dv.reshape(v.shape).astype(v.dtype))
 
 
 # ------------------------------------------- short sequences: one visit
@@ -770,8 +809,9 @@ def _short_bwd_call(q, k, v, lse, do, causal, scale, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, window=None):
-    """q,k,v: (B, H, S, D) → (B, H, S, D), by the path attention_route()
-    names for the shape. ``block_q``/``block_k`` size the streamed kernels'
+    """q, k: (B, H, S, D), v: (B, H, S, D_v) → (B, H, S, D_v), by the
+    path attention_route() names for the shapes; ``scale`` defaults to
+    1 / sqrt(D) of q, whatever D_v. ``block_q``/``block_k`` size the streamed kernels'
     blocks (default: the largest of 1024/512/256/128 dividing S; under a
     window the largest no wider than it) and mean nothing on the other two
     paths. ``window`` (with ``causal``): key j is seen from query i iff
@@ -791,6 +831,8 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     _ROUTES.inc(route=route)
     if window is not None:
         _WINDOWS.inc(route=route)
+    if v.shape[-1] > k.shape[-1]:
+        _WIDE_VALUES.inc(route=route)
     if route == "short":
         out, lse = _short_call(q, k, v, causal, scale, _interpret())
         return out, (q, k, v, None, lse)        # its backward needs no O
@@ -866,7 +908,10 @@ def flash_attention_lse(q, k, v, causal=False, scale=None, block_q=None,
     (B, H, S) fp32 — the sufficient statistic ring attention's online
     combine needs. Both outputs are differentiable: the LSE cotangent
     folds into the existing backward kernels as a delta shift (see
-    _fa_bwd_call). Requires flash_attention_supported(q.shape)."""
+    _fa_bwd_call). Requires flash_attention_supported(q.shape). Ring and
+    Ulysses hand it q, k, v of ONE shape (attention_with_lse sends
+    anything else to the dense form): a value width of its own is
+    flash_attention's."""
     return _fa_lse_fwd(q, k, v, causal, scale, block_q, block_k)[0]
 
 

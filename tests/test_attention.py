@@ -528,3 +528,151 @@ def test_backward_counter_counts_a_streamed_backward_once_a_trace():
               if ln.startswith("mxtpu_attention_backward_total{")}
     assert "flash_bwd_dkvq" in labels
     assert labels <= {"flash_bwd_dkvq", "flash_bwd_dkvq_segmented"}
+
+
+# --------------------------------- a value width of its own (streamed family)
+#: (q / k shape, D_v): narrow heads ride the streamed kernels from S = 2048
+#: on, so the 64 | 128 of a differential pair is met there
+_WIDE = {"64|128": ((1, 1, 2048, 64), 128), "128|256": ((1, 2, 512, 128), 256)}
+_MODES = {"dense": (False, None), "causal": (True, None),
+          "window": (True, 200)}
+
+
+def _wide_qkv(shape, d_v, seed):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    wide = shape[:3] + (d_v,)
+    return tuple(jax.random.normal(key, s) for key, s in
+                 zip(keys, (shape, shape, wide, wide)))
+
+
+def _dense_masked(q, k, v, causal, window):
+    """The dense masked softmax of tests/test_phi4flash.py's window tests,
+    at any value width."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    d = jnp.arange(q.shape[2])[:, None] - jnp.arange(q.shape[2])[None, :]
+    if causal:
+        seen = d >= 0 if window is None else (d >= 0) & (d < window)
+        s = jnp.where(seen, s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("mode", list(_MODES))
+@pytest.mark.parametrize("widths", list(_WIDE))
+def test_wide_value_kernels_are_the_dense_masked_softmax(widths, mode):
+    """Interpreted, float32, blocks of 128: forward and all three gradients
+    of ONE call whose v, dO, O and dV are D_v wide while q, k, dQ, dK stay
+    D, to the limits the window kernels are held to; the scale is q's."""
+    from incubator_mxnet_tpu.ops import attention as A
+    (shape, d_v), (causal, window) = _WIDE[widths], _MODES[mode]
+    q, k, v, do = _wide_qkv(shape, d_v, seed=d_v + (window or 0))
+    assert A.attention_route(shape, shape, v.shape, 128, 128,
+                             window) == "streamed"
+
+    def system(q, k, v):
+        return A.flash_attention(q, k, v, causal, None, 128, 128, window)
+
+    want = _dense_masked(q, k, v, causal, window)
+    got = system(q, k, v)
+    assert got.shape == v.shape and got.dtype == v.dtype
+    assert jnp.abs(got - want).max() < 1e-5
+    grads = jax.grad(lambda *a: (system(*a) * do).sum(), (0, 1, 2))(q, k, v)
+    refs = jax.grad(lambda *a: (_dense_masked(*a, causal, window) * do).sum(),
+                    (0, 1, 2))(q, k, v)
+    for g, r, x in zip(grads, refs, (q, k, v)):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        assert jnp.abs(g - r).max() < 1e-5 * (jnp.abs(r).max() + 1)
+
+
+@pytest.mark.parametrize("widths,mode", [
+    ("128|256", "dense"), ("128|256", "causal"), ("128|256", "window"),
+    ("64|128", "causal")])
+def test_a_wide_call_is_the_two_narrow_calls_it_replaces(widths, mode):
+    """`P [v_1 v_2]` is `[P v_1, P v_2]` column for column: the output
+    columns to the bit; dV's columns likewise are each half's own; dQ and
+    dK are one dS from all D_v columns where the narrow calls' two are
+    added outside, so they agree inside the kernels' limit."""
+    from incubator_mxnet_tpu.ops import attention as A
+    (shape, d_v), (causal, window) = _WIDE[widths], _MODES[mode]
+    q, k, v, do = _wide_qkv(shape, d_v, seed=3)
+    half = d_v // 2
+
+    def wide(q, k, v):
+        return A.flash_attention(q, k, v, causal, None, 128, 128, window)
+
+    def narrow(q, k, v):
+        # the scale is 1 / sqrt(D of q) on both sides
+        return jnp.concatenate(
+            [A.flash_attention(q, k, v[..., :half], causal, None, 128, 128,
+                               window),
+             A.flash_attention(q, k, v[..., half:], causal, None, 128, 128,
+                               window)], -1)
+
+    before = A._WIDE_VALUES.value(route="streamed")
+    onp.testing.assert_array_equal(wide(q, k, v), narrow(q, k, v))
+    assert A._WIDE_VALUES.value(route="streamed") == before + (
+        1 if half == shape[-1] else 3)     # 128 | 256: the halves are wide
+    got = jax.grad(lambda *a: (wide(*a) * do).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (narrow(*a) * do).sum(), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert jnp.abs(g - w).max() < 1e-5 * (jnp.abs(w).max() + 1)
+
+
+@pytest.mark.parametrize("q_shape,d_v,window,route", [
+    (_GPT_CELL, 256, None, "streamed"),             # where the equal shape is
+    ((1, 20, 16384, 64), 128, None, "streamed"),    # the SambaY cell's F, C
+    ((1, 20, 16384, 64), 128, 512, "streamed"),     # and its S
+    ((2, 4, 512, 128), 64, None, "streamed"),       # narrower than its keys
+    (_BERT_CELL, 128, None, "composite"),           # short: one width only
+    ((8, 8, 512, 32), 64, None, "composite"),
+    ((8, 16, 1024, 64), 128, None, "composite"),    # narrow, under 2048
+    ((1, 2, 200, 128), 256, None, "composite"),     # no block divides S
+    ((2, 4, 512, 128), 132, None, "composite"),     # sublanes not packed
+], ids=str)
+def test_route_of_a_value_with_a_width_of_its_own(q_shape, d_v, window,
+                                                  route, as_on_a_tpu,
+                                                  monkeypatch):
+    """The streamed family takes D_v != D wherever it takes the equal
+    shape; the short family and its lane layout know one width; nothing
+    but the last dimension of v may differ; off the TPU the composite."""
+    from incubator_mxnet_tpu.ops import attention as A
+    v_shape = q_shape[:3] + (d_v,)
+
+    def check():
+        assert A.attention_route(q_shape, q_shape, v_shape,
+                                 window=window) == route
+        longer = (q_shape[0], q_shape[1], 2 * q_shape[2], d_v)
+        assert A.attention_route(q_shape, q_shape, longer,
+                                 window=window) == "composite"
+    check()
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")         # same table
+    check()
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET")
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="cpu")])
+    assert A.attention_route(q_shape, q_shape, v_shape,
+                             window=window) == "composite"
+
+
+@pytest.mark.parametrize("mode", list(_MODES))
+def test_composite_carries_a_value_of_any_width(mode, monkeypatch):
+    """Off the TPU (no interpreter) a wide call is the XLA composite:
+    forward and its hand-written backward at D 64 | D_v 96."""
+    from incubator_mxnet_tpu.ops import attention as A
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET")
+    causal, window = _MODES[mode]
+    window = window and 40
+    q, k, v, do = _wide_qkv((2, 2, 160, 64), 96, seed=5)
+    before = A._WIDE_VALUES.value(route="composite")
+
+    def system(q, k, v):
+        return A.flash_attention(q, k, v, causal, window=window)
+
+    want = _dense_masked(q, k, v, causal, window)
+    assert jnp.abs(system(q, k, v) - want).max() < 1e-5
+    assert A._WIDE_VALUES.value(route="composite") == before + 1
+    grads = jax.grad(lambda *a: (system(*a) * do).sum(), (0, 1, 2))(q, k, v)
+    refs = jax.grad(lambda *a: (_dense_masked(*a, causal, window) * do).sum(),
+                    (0, 1, 2))(q, k, v)
+    for g, r, x in zip(grads, refs, (q, k, v)):
+        assert g.shape == x.shape
+        assert jnp.abs(g - r).max() < 1e-5 * (jnp.abs(r).max() + 1)

@@ -3,11 +3,15 @@ against the float32 reference the benchmark uses
 (perfbench/reference/phi-4-mini-flash-reasoning.py) in value, loss and
 every gradient; the selective scan against the recurrence a position at a
 time; the window kernels (interpreted) against the dense masked softmax;
-what the cross-decoder reads; and that `window=None` is the kernel it was.
+what the cross-decoder reads; that `window=None` is the kernel it was; and
+differential attention's two wide-value calls a layer against the four
+calls of one width it made before.
 """
+import collections
 import hashlib
 import importlib.util
 import os
+import re
 import sys
 
 import jax
@@ -286,6 +290,117 @@ def test_window_none_is_the_kernel_it_was(monkeypatch, shape, causal):
         == lowered[1].replace("none", "absent")
 
 
+# ------------------------------------------- differential attention's calls
+def _diff_block(heads, kv_heads, window=None):
+    return phi4flash.DifferentialAttention(
+        64, heads, kv_heads, 64, depth=1, window=window)
+
+
+def _diff_inputs(block, s, dtype, key=None):
+    """q (1, S, H d), k, v (1, S, Hkv d), the four l vectors, the gain: as
+    arrays from ``key``, else as shapes."""
+    d, h, hkv = block._d, block._h, block._hkv
+    shapes = [((1, s, h * d), dtype), ((1, s, hkv * d), dtype),
+              ((1, s, hkv * d), dtype), ((4, d), jnp.float32),
+              ((2 * d,), jnp.float32)]
+    if key is None:
+        return [jax.ShapeDtypeStruct(*x) for x in shapes]
+    keys = jax.random.split(key, len(shapes))
+    return [(0.3 * jax.random.normal(k, shape)).astype(t)
+            for k, (shape, t) in zip(keys, shapes)]
+
+
+def _four_calls(block, q, k, v, lambdas, gamma):
+    """Differential attention as it was called up to PR 36: a_1 v_1,
+    a_1 v_2, a_2 v_1, a_2 v_2, four attentions of one shape, concatenated
+    in pairs."""
+    b, s, _ = q.shape
+    d, h, hkv = block._d, block._h, block._hkv
+
+    def halves(t, n):
+        t = t.reshape(b, s, n // 2, 2, d).transpose(3, 0, 2, 1, 4)
+        return [jnp.repeat(x, h // hkv, 1) if n != h else x for x in t]
+
+    def attend(q, k, v):
+        return attention.flash_attention(
+            q, k, v, True, window=block._window).astype(jnp.float32)
+
+    (q1, q2), (k1, k2), (v1, v2) = halves(q, h), halves(k, hkv), \
+        halves(v, hkv)
+    lam = jnp.exp(jnp.sum(lambdas[0] * lambdas[1])) \
+        - jnp.exp(jnp.sum(lambdas[2] * lambdas[3])) + block._l_init
+    o = jnp.concatenate([attend(q1, k1, v1), attend(q1, k1, v2)], -1) \
+        - lam * jnp.concatenate([attend(q2, k2, v1), attend(q2, k2, v2)], -1)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + block._eps) \
+        * gamma * (1.0 - block._l_init)
+    return o.transpose(0, 2, 1, 3).reshape(b, s, h * d).astype(q.dtype)
+
+
+@pytest.mark.parametrize("s,window,forward,backward,wide", [
+    (2048, None, ("flash_fwd", 2), ("flash_bwd_dkvq", 2), 2),
+    (2048, 512, ("flash_window_fwd", 2), ("flash_window_bwd", 2), 2),
+    # (the short family's calls are jitted: the jaxpr names a call by its
+    # function and holds the kernel's text once)
+    (512, None, ("_short_call", 4), ("_short_bwd_call", 4), 0)],
+    ids=["full_causal", "window", "short"])
+def test_differential_attention_is_two_streamed_calls_a_layer(
+        monkeypatch, s, window, forward, backward, wide):
+    """Read from the jaxpr of the layer's gradient at 16 query heads on 8
+    key-value heads of 64, calls of (1, 8, S, 64 | 128): TWO forward and
+    two backward kernels, each map once against [v_1; v_2]; where the
+    equal-width shape belongs to the short family (one width in its lane
+    layout) the four calls there were; the counter reads the wide calls."""
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    block = _diff_block(16, 8, window)
+    assert attention.attention_route((1, 8, s, 64), window=window) \
+        == ("streamed" if s == 2048 else "short")
+    before = attention._WIDE_VALUES.value(route="streamed")
+
+    def loss(*args):
+        return block._attend(*args).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3, 4)))(
+        *_diff_inputs(block, s, jnp.bfloat16)))
+    named = collections.Counter(re.findall(r"name=(\w+)", text))
+    assert {k: named[k] for k in (
+        "flash_fwd", "flash_bwd_dkvq", "flash_window_fwd", "flash_window_bwd",
+        "_short_call", "_short_bwd_call") if named[k]} \
+        == dict([forward, backward])
+    assert attention._WIDE_VALUES.value(route="streamed") - before == wide
+    if wide:
+        assert 'mxtpu_attention_wide_value_total{route="streamed"}' \
+            in telemetry.REGISTRY.export_text()
+
+
+@pytest.mark.parametrize("s,heads,kv_heads,window,interpret", [
+    (192, 8, 4, None, False), (192, 8, 8, 24, False),
+    (2048, 4, 2, None, True), (2048, 4, 2, 512, True),
+    (512, 4, 4, None, True)],
+    ids=["composite", "composite_window", "streamed", "streamed_window",
+         "short"])
+def test_differential_attention_is_the_four_call_composition(
+        monkeypatch, s, heads, kv_heads, window, interpret):
+    """Output and every gradient (q, k, v, the four l vectors, the gain),
+    float32, against the four calls written above: on the composite, on the
+    interpreted streamed kernels (key-value pairs repeated to the query
+    pairs) and where the block itself still makes the four short calls."""
+    if interpret:
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    block = _diff_block(heads, kv_heads, window)
+    args = _diff_inputs(block, s, jnp.float32, jax.random.PRNGKey(s))
+    cot = jax.random.normal(jax.random.PRNGKey(1), args[0].shape)
+    got = block._attend(*args)
+    want = _four_calls(block, *args)
+    assert got.shape == want.shape and jnp.abs(got - want).max() < 1e-5
+    every = tuple(range(5))
+    grads = jax.grad(lambda *a: (block._attend(*a) * cot).sum(), every)(*args)
+    refs = jax.grad(lambda *a: (_four_calls(block, *a) * cot).sum(),
+                    every)(*args)
+    for g, r in zip(grads, refs):
+        assert jnp.abs(r).max() > 0
+        assert jnp.abs(g - r).max() < 1e-5 * (jnp.abs(r).max() + 1)
+
+
 # ------------------------------------------------------------ the model
 def test_the_pattern_rule_gives_the_published_order():
     assert phi4flash.sambay_pattern(32, 2) \
@@ -300,7 +415,7 @@ def test_the_pattern_rule_gives_the_published_order():
 
 def test_float32_model_matches_the_reference():
     """Features, logits' loss: float32 at "highest" on both sides, two
-    algorithms for the scan and for the four attentions."""
+    algorithms for the scan and for the attentions."""
     net = build()
     tokens, labels = batch()
     with jax.default_matmul_precision("highest"):
