@@ -59,7 +59,9 @@ FULL = {
                    # the Mamba-1 scan alone at the SambaY cell's shape
                    SEL_S=16384, SEL_C=5120, SEL_N=16, SEL_R=160,
                    # a differential layer's call there: (1, H, S, D | 2 D)
-                   WIDE_H=20, WIDE_S=16384, WIDE_D=64, WIDE_WINDOW=512),
+                   WIDE_H=20, WIDE_S=16384, WIDE_D=64, WIDE_WINDOW=512,
+                   # the gated delta rule alone at the Solar cell's shape
+                   DR_S=8192, DR_H=8, DR_D=128, DR_Q=64),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -78,7 +80,8 @@ TOY = {
                    SHARDS=2, SCAN_S=256, SEL_S=160, SEL_C=1024, SEL_N=16,
                    SEL_R=4,
                    # (narrow heads ride the streamed kernels from 2048 on)
-                   WIDE_H=1, WIDE_S=2048, WIDE_D=64, WIDE_WINDOW=512),
+                   WIDE_H=1, WIDE_S=2048, WIDE_D=64, WIDE_WINDOW=512,
+                   DR_S=160, DR_H=2, DR_D=16, DR_Q=16),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -343,6 +346,8 @@ def phase_gpt(cfg, on_chip, shared):
 #: rounded to bfloat16 once a chunk must be further. Between the two
 #: readings of one v5e (PERF.md section 6, PR 31)
 SCAN_ALONE_LIMIT = 1e-4
+#: the same for the gated delta rule alone (`delta_rule_alone`)
+DELTA_RULE_ALONE_LIMIT = 1e-4
 
 
 def scan_alone(cfg):
@@ -387,6 +392,87 @@ def scan_alone(cfg):
     top = float(jnp.abs(want).max())
     return float(jnp.abs(got - want).max()) / top, \
         float(jnp.abs(rounded - want).max()) / top
+
+
+def delta_rule_alone(cfg, on_chip):
+    """`ops.delta_rule.gated_delta_rule` alone at the Solar cell's shape
+    (DR_S positions, DR_H heads of DR_D x DR_D, chunks of DR_Q), float32
+    inputs (the op runs every matmul at "highest" itself), against the
+    recurrence a position at a time; as `scan_alone`, the one comparison in
+    which the state's float32 shows. q and k have unit length (q scaled),
+    the decay is A in [1, 16] a head times a step about log-normal around
+    0.011 a CHANNEL, b = 2 sigmoid(.) reaches both its ends.
+    -> {"sound": the output's distance, "rounded": the recurrence's with
+    its state rounded to bfloat16 once a chunk, "gradients": the worst of
+    the five (q, k, v, g, b), each over its own largest entry,
+    "forward_ms", "both_ms": the op alone with a bfloat16 v (None off the
+    chip: a CPU time is no device number)}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    from incubator_mxnet_tpu.ops.delta_rule import gated_delta_rule
+    s, h, d, q_ = cfg["DR_S"], cfg["DR_H"], cfg["DR_D"], cfg["DR_Q"]
+    rng = onp.random.default_rng(0)
+
+    def unit(x):
+        return x / onp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(rng.standard_normal((1, s, h, d))) * d ** -0.5
+    k = unit(rng.standard_normal((1, s, h, d)))
+    v = rng.standard_normal((1, s, h, d))
+    g = -rng.uniform(1, 16, (h, 1)) * onp.log1p(onp.exp(
+        rng.standard_normal((1, s, h, d)) - 4.5))
+    beta = 2 / (1 + onp.exp(-2 * rng.standard_normal((1, s, h))))
+    args = tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta))
+
+    def system(*a):
+        return gated_delta_rule(*a, chunk=q_)
+
+    def recurrence(q, k, v, g, beta, round_state=False):
+        def step(state, at):
+            q_t, k_t, v_t, g_t, b_t, rounds = at
+            state = jnp.exp(g_t)[..., None] * state
+            u = b_t[..., None] * (v_t - (state * k_t[..., None]).sum(-2))
+            state = state + k_t[..., None] * u[..., None, :]
+            if round_state:
+                state = jnp.where(
+                    rounds, jax.lax.reduce_precision(state, 8, 7), state)
+            return state, (state * q_t[..., None]).sum(-2)
+        by_time = tuple(t.swapaxes(0, 1) for t in (q, k, v, g, beta)) \
+            + (jnp.arange(q.shape[1]) % q_ == q_ - 1,)
+        return jax.lax.scan(step, jnp.zeros((1, h, d, d), jnp.float32),
+                            by_time)[1].swapaxes(0, 1)
+
+    def distance(got, want):
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    def gradients(fn, *a):
+        """The five gradients of sum(o cot), the recurrence's in
+        checkpointed segments (autodiff keeps a state a position)."""
+        return jax.jit(jax.grad(lambda cot, *t: jnp.sum(fn(*t) * cot),
+                                (1, 2, 3, 4, 5)))(*a)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(recurrence)(*args)
+        out = {"sound": distance(jax.jit(system)(*args), want),
+               "rounded": distance(jax.jit(functools.partial(
+                   recurrence, round_state=True))(*args), want)}
+        del want
+        # the gradients on the first eighth of the positions: autodiff of
+        # the recurrence keeps every position's state (64 KB a head)
+        short = tuple(t[:, :max(s // 8, 2 * q_)] for t in args)
+        cot = jnp.asarray(rng.standard_normal(short[2].shape), jnp.float32)
+        out["gradients"] = max(
+            distance(a, b) for a, b in zip(gradients(system, cot, *short),
+                                           gradients(recurrence, cot, *short)))
+    out["forward_ms"] = out["both_ms"] = None
+    if on_chip:
+        operands = args[:2] + (args[2].astype(jnp.bfloat16),) + args[3:]
+        out["forward_ms"] = median_ms(jax.jit(system), operands)
+        out["both_ms"] = median_ms(jax.jit(jax.grad(
+            lambda *t: jnp.sum(system(*t).astype(jnp.float32)),
+            (0, 1, 2, 3, 4))), operands)
+    return out
 
 
 def median_ms(fn, operands):
@@ -637,6 +723,15 @@ def phase_hybrid(cfg, on_chip, shared):
                 "%.3g) of the largest entry" % (
                     name, read["outputs"], WIDE_VALUE_LIMITS["outputs"],
                     read["gradients"], WIDE_VALUE_LIMITS["gradients"]))
+    rule = delta_rule_alone(cfg, on_chip)
+    if not (rule["sound"] < DELTA_RULE_ALONE_LIMIT < rule["rounded"]
+            and rule["gradients"] < DELTA_RULE_ALONE_LIMIT):
+        raise RuntimeError(
+            "gated delta rule alone, float32: %.3g of the largest output "
+            "from the recurrence and %.3g of a gradient's largest entry "
+            "(limit %g), a bfloat16 state a chunk %.3g" % (
+                rule["sound"], rule["gradients"], DELTA_RULE_ALONE_LIMIT,
+                rule["rounded"]))
     alone = "" if sel["forward_ms"] is None else \
         "; forward %.1f ms, forward + backward %.1f ms in bfloat16 (the XLA " \
         "form 36-38 / 77-80, PR 34)" % (sel["forward_ms"], sel["both_ms"])
@@ -646,7 +741,10 @@ def phase_hybrid(cfg, on_chip, shared):
         "%.2g); selective scan alone at %d x %d x %d, the kernel pair: " \
         "%.2g (%.2g), the eight gradients of one channel block %.2g%s; " \
         "attention with a value of its own width at (1, %d, %d, %d | %d) " \
-        "against the two narrow calls it replaces: %s" % (
+        "against the two narrow calls it replaces: %s; gated delta rule " \
+        "alone at %d x %d x %d x %d in float32 %.2g of its largest output " \
+        "from the recurrence (a bfloat16 state a chunk %.2g), the five " \
+        "gradients %.2g%s" % (
             cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
             cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
             cfg["SEL_N"], sel["sound"], sel["rounded"], sel["gradients"],
@@ -657,7 +755,12 @@ def phase_hybrid(cfg, on_chip, shared):
                     "" if read["wide_ms"] is None else
                     ", forward %.1f ms and forward + backward %.1f ms for "
                     "%.1f and %.1f" % (read["wide_ms"] + read["narrow_ms"]))
-                for name, read in wide.items()))
+                for name, read in wide.items()),
+            cfg["DR_S"], cfg["DR_H"], cfg["DR_D"], cfg["DR_D"],
+            rule["sound"], rule["rounded"], rule["gradients"],
+            "" if rule["forward_ms"] is None else
+            ", forward %.1f ms, forward + backward %.1f ms" % (
+                rule["forward_ms"], rule["both_ms"]))
 
 
 def build_resnet():

@@ -161,7 +161,20 @@ class Parameter:
         self._check_initialized()
         if self._grad is None:
             raise RuntimeError("Parameter %s has grad_req='null'" % self.name)
+        if self._grad.shape != tuple(self._shape):     # released: zeros again
+            self.zero_grad()
         return self._grad
+
+    def release_grad(self):
+        """Free the eager tape's gradient buffer (2 bytes a bfloat16
+        parameter of device memory). A compiled train step differentiates a
+        pure function and never reads it, so `jit.TrainStep` releases the
+        buffers of the net it takes. What stays is a scalar zero of the
+        buffer's type in the same NDArray: an eager `backward` writes (or
+        adds) its gradient into it as before, and `grad()` of a buffer that
+        nothing wrote since reads as zeros of the parameter's shape."""
+        if self._grad is not None:
+            self._grad._data = nd.zeros((), dtype=self._grad.dtype)._data
 
     def list_grad(self):
         return [self.grad()]
@@ -184,7 +197,7 @@ class Parameter:
 
     def zero_grad(self):
         if self._grad is not None:
-            self._grad._data = nd.zeros(self._grad.shape, dtype=self._grad.dtype)._data
+            self._grad._data = nd.zeros(self._shape, dtype=self._grad.dtype)._data
 
     def reset_ctx(self, ctx):
         if isinstance(ctx, Context):

@@ -346,6 +346,10 @@ class TrainStep:
                 state = _with_layout(_placed, state, state_sh)
                 self._write_back(t_arrs + f_arrs, slots,
                                  state[0] + state[1], state[2])
+                # the eager tape's gradient buffers: this step never reads
+                # them, and they are 2 bytes a parameter beside its state
+                for p in trainable:
+                    p.release_grad()
             compiled = jax.jit(
                 step_fn, donate_argnums=_donate((0, 2))).lower(*specs).compile()
         return compiled, (slots, t_arrs, f_arrs, aux_box, state_sh,

@@ -11,6 +11,10 @@
 - phi4flash:  Phi-4-mini-flash / SambaY (Mamba-1, sliding-window and full
               differential attention, a cross-decoder of gated memory units
               and attention over ONE layer's K/V, dense SwiGLU MLPs)
+- solar_open2: Solar Open 2 (Kimi Delta Attention: a gated delta rule with a
+              decay per channel; gated grouped-query attention without
+              position embedding; sigmoid-routed SwiGLU experts beside a
+              shared expert), whole or as one chip's share
 """
 from .lenet import LeNet  # noqa
 from .bert import (BERTEncoder, BERTModel, TransformerEncoderLayer,  # noqa
@@ -23,6 +27,9 @@ from .nemotron_h import (NemotronHModel, NemotronHLayer, Mamba2Mixer,  # noqa
                          GroupedQueryAttention, LatentMoE)
 from .phi4flash import (Phi4FlashModel, SambaYLayer, Mamba1Mixer,  # noqa
                         DifferentialAttention, GatedMemoryUnit, SwiGLU)
+from .solar_open2 import (SolarOpen2Model, SolarOpen2Layer,  # noqa
+                          KimiDeltaAttention, GatedGroupedQueryAttention,
+                          SharedExpertMoE)
 from .lstm_lm import LSTMLanguageModel  # noqa
 from .ssd import SSD  # noqa
 from ..gluon.model_zoo.vision import get_model  # noqa

@@ -61,6 +61,11 @@ class MultiHeadAttention(HybridBlock):
             self.proj.weight.sharding = P(None, tp_axis)
 
     def forward(self, x, mask=None):
+        return self.proj(self.heads_output(x, mask))
+
+    def heads_output(self, x, mask=None):
+        """x (B, S, U) -> the heads' outputs side by side (B, S, H * D),
+        before the out-projection: what a subclass gates or norms."""
         B, S, _ = x.shape
         H = self._num_heads
         # (B, H, S, D) each; a subclass's QK-norm and RoPE live in project()
@@ -116,8 +121,7 @@ class MultiHeadAttention(HybridBlock):
             if self._dropout:
                 attn = nd.Dropout(attn, p=self._dropout)
             out = nd.batch_dot(attn, v.reshape((B * H, S, D))).reshape((B, H, S, D))
-        out = out.transpose((0, 2, 1, 3)).reshape((B, S, H * D))
-        return self.proj(out)
+        return out.transpose((0, 2, 1, 3)).reshape((B, S, H * D))
 
     def split_heads(self, t, kv=False):
         """(B, S, heads * D) -> (B, H, S, D). ``kv``: t holds the

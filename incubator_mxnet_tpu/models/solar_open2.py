@@ -1,0 +1,297 @@
+"""Solar Open 2 (`model_type: solar_open2`; Solar-Open2-250B): a decoder
+whose every layer is a mixer and a mixture of SwiGLU experts beside a
+shared expert, behind pre-norm residuals,
+
+    x <- x + Mixer(RMSNorm(x));  x <- x + Experts(RMSNorm(x))
+
+RMSNorm with a gain, a final RMSNorm, an untied head without bias and NO
+position embedding of any kind (`use_rope: false`; the `K` layers carry
+position). The mixer is read from a pattern string (the source's
+`gqa_layers` are the `G`s):
+
+    K   Kimi Delta Attention (arXiv:2510.26692): the gated delta rule with
+        a decay per channel. Per head, d = head_dim:
+        q~, k~, v = silu(conv1d_causal,4(h W_q | h W_k | h W_v))  (no bias)
+        q = q~ / |q~|_2 d^-1/2;   k = k~ / |k~|_2
+        g_t = -exp(A_log) softplus((h W_f-) W_f+ + dt_bias)   (float32, d)
+        b_t = 2 sigmoid(h w_b)    (the 2: `kda_allow_neg_eigval`, the
+                                   eigenvalues of I - b k k^T reach -1)
+        S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T
+        o_t = S_t^T q_t                          (ops/delta_rule.py)
+        out = W_o [RMSNorm_d(o_t) * gamma * sigmoid((h W_g-) W_g+)]
+        W_f-, W_g- are units -> rank maps and W_f+, W_g+ rank -> heads x d
+        (`kda_use_full_proj: false`: low-rank pairs, rank = head_dim)
+    G   gated grouped-query attention without position embedding:
+        softmax(q k^T / sqrt(d) + causal) v, H query heads on H_kv
+        key-value heads; out = W_o [o * sigmoid(h W_gate)], W_gate
+        units -> H x d (a gate per channel). No QK-norm, no bias.
+    experts   s = sigmoid(W_r u) (float32);  chosen = top-k(s + b)
+        w_e = s_e / (sum_chosen s + 1e-20) * scale
+        out = sum_{e chosen} w_e W2_e (silu(W1_e u) * W3_e u)
+              + W_down (silu(W_gate u) * W_up u)          (shared expert)
+
+A block can be ONE CHIP'S SHARE of a tensor- and expert-parallel layout,
+as models/nemotron_h.py's: a mixer is told into how many shards its heads
+are divided and builds one (the two units -> rank maps are held whole, the
+rank -> heads x d maps by this shard's columns; a key-value head that
+fewer shards than there are would share is held once); the expert block is
+told which experts it holds (`parallel.MoELayer(held=...)`) and keeps the
+router and the shared expert whole. A share's output is its part of the
+layer's sum; nothing stands in for the other ranks. The float32 reference
+of these equations is perfbench/reference/solar-open2-250b.py.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from .. import initializer
+from ..gluon import nn, utils
+from ..gluon.block import HybridBlock
+from ..ndarray import _apply
+from ..ops.delta_rule import gated_delta_rule
+from ..parallel.moe import MoELayer
+from .nemotron_h import (GroupedQueryAttention, _InverseSoftplusOfLogUniform,
+                         _LogUniform)
+from .phi4flash import SwiGLU
+
+__all__ = ["SolarOpen2Model", "SolarOpen2Layer", "KimiDeltaAttention",
+           "GatedGroupedQueryAttention", "SharedExpertMoE"]
+
+#: the pattern's letters: Kimi Delta Attention, gated grouped-query attention
+MIXERS = "KG"
+#: the decay's step sizes at initialisation, as Mamba's: log-uniform in
+#: [min, max], floored
+_DT_INIT = (0.001, 0.1, 1e-4)
+#: what is added under the root of the L2 norms of q and k
+_L2_EPS = 1e-6
+
+
+class KimiDeltaAttention(HybridBlock):
+    """The gated delta-rule mixer, or one of ``shards`` equal parts of it:
+    ``num_heads`` heads of ``head_dim`` (keys, queries and values alike)
+    are the WHOLE mixer's; this block holds num_heads / shards of them. A
+    shard's output is its part of the row-parallel out-projection's sum.
+
+    ``in_proj`` is the column-parallel maps of h side by side: q, k, v
+    (this shard's heads), the two units -> ``rank`` maps (whole) and this
+    shard's heads' b. Scopes inside the block's own: `kda_conv` (the
+    three short convolutions and the L2 norms), `kda_decay` (g and b),
+    `delta_rule` (the op's), `kda_gate_norm`. ``A_log``, ``dt_bias`` and
+    the norm's gain stay float32 under ``cast``."""
+
+    def __init__(self, units, num_heads, head_dim, conv_kernel=4, rank=None,
+                 chunk=64, shards=1, neg_eigval=True, epsilon=1e-5, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % shards:
+            raise ValueError("%d heads do not divide into %d shards"
+                             % (num_heads, shards))
+        self.heads, self.head_dim = num_heads // shards, head_dim
+        self.inner = self.heads * head_dim
+        self.rank = rank or head_dim
+        self._chunk, self._eps, self._k = chunk, epsilon, conv_kernel
+        self._beta_max = 2.0 if neg_eigval else 1.0
+        with self.name_scope():
+            # rows: q, k, v (inner each), decay down, gate down (rank each),
+            # b (heads)
+            self.in_proj = nn.Dense(
+                3 * self.inner + 2 * self.rank + self.heads, flatten=False,
+                in_units=units, use_bias=False)
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(3 * self.inner, conv_kernel),
+                init=initializer.Uniform(1.0 / math.sqrt(conv_kernel)))
+            self.decay_up = self.params.get(
+                "decay_up", shape=(self.inner, self.rank), init="xavier")
+            self.gate_up = self.params.get(
+                "gate_up", shape=(self.inner, self.rank), init="xavier")
+            self.A_log = self.params.get("A_log", shape=(self.heads,),
+                                         init=_LogUniform(1.0, 16.0))
+            self.dt_bias = self.params.get(
+                "dt_bias", shape=(self.inner,),
+                init=_InverseSoftplusOfLogUniform(*_DT_INIT))
+            self.norm_gamma = self.params.get(
+                "norm_gamma", shape=(head_dim,), init="ones")
+            self.out_proj = nn.Dense(units, flatten=False,
+                                     in_units=self.inner, use_bias=False)
+
+    def cast(self, dtype):
+        super().cast(dtype)
+        for p in (self.A_log, self.dt_bias, self.norm_gamma):
+            p.cast("float32")
+
+    @functools.partial(jax.checkpoint, static_argnums=0)
+    def _conv(self, qkv, conv_w):
+        """(b, s, 3 inner) -> q, k (b, s, h, d) float32, normed; v in the
+        input's type. (checkpoint: the gradient keeps qkv, not the float32
+        sums.)"""
+        with jax.named_scope("kda_conv"):
+            b, s, _ = qkv.shape
+            padded = jnp.pad(qkv, [(0, 0), (self._k - 1, 0), (0, 0)])
+            taps = conv_w.astype(jnp.float32)
+            acc = 0.0
+            for j in range(self._k):        # position t sees t-k+1 .. t
+                acc = acc + padded[:, j:j + s].astype(jnp.float32) * taps[:, j]
+            q, k, v = (t.reshape(b, s, self.heads, self.head_dim)
+                       for t in jnp.split(jax.nn.silu(acc), 3, -1))
+
+            def unit(t):
+                return t * jax.lax.rsqrt(
+                    jnp.sum(t * t, -1, keepdims=True) + _L2_EPS)
+
+            return unit(q) * self.head_dim ** -0.5, unit(k), \
+                v.astype(qkv.dtype)
+
+    def _mix(self, proj, conv_w, decay_up, gate_up, a_log, dt_bias, gamma):
+        b, s, _ = proj.shape
+        inner, r, h, d = self.inner, self.rank, self.heads, self.head_dim
+        qkv, low_f, low_g, b_in = jnp.split(
+            proj, [3 * inner, 3 * inner + r, 3 * inner + 2 * r], -1)
+        q, k, v = self._conv(qkv, conv_w)
+        with jax.named_scope("kda_decay"):
+            step = jax.nn.softplus(jnp.einsum(
+                "bsr,cr->bsc", low_f, decay_up,
+                preferred_element_type=jnp.float32)
+                + dt_bias.astype(jnp.float32)).reshape(b, s, h, d)
+            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * step
+            beta = self._beta_max * jax.nn.sigmoid(b_in.astype(jnp.float32))
+        o = gated_delta_rule(q, k, v, g, beta, self._chunk)
+
+        @jax.checkpoint      # the gradient keeps o and the low-rank input
+        def gate_norm(o, low, gate_up, gamma):
+            with jax.named_scope("kda_gate_norm"):
+                o = o.astype(jnp.float32)
+                o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                      + self._eps) * gamma.astype(jnp.float32)
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsr,cr->bsc", low, gate_up,
+                    preferred_element_type=jnp.float32))
+                return (o.reshape(b, s, inner) * gate).astype(proj.dtype)
+
+        return gate_norm(o, low_g, gate_up, gamma)
+
+    def forward(self, u):
+        y = _apply(self._mix, self.in_proj(u), *(p.data() for p in (
+            self.conv_weight, self.decay_up, self.gate_up, self.A_log,
+            self.dt_bias, self.norm_gamma)))
+        return self.out_proj(y)
+
+
+class GatedGroupedQueryAttention(GroupedQueryAttention):
+    """`GroupedQueryAttention` (causal, no biases, no position embedding)
+    with a sigmoid gate on the heads' outputs, a channel at a time, before
+    the out-projection: W_o [o * sigmoid(h W_gate)]. Scope `gqa_gate`."""
+
+    def __init__(self, in_units, num_heads, num_kv_heads, head_dim,
+                 attention="flash", **kwargs):
+        super().__init__(in_units, num_heads, num_kv_heads, head_dim,
+                         attention=attention, **kwargs)
+        with self.name_scope():
+            self.gate = nn.Dense(num_heads * head_dim, flatten=False,
+                                 in_units=in_units, use_bias=False)
+
+    def forward(self, x, mask=None):
+        def gated(o, z):
+            with jax.named_scope("gqa_gate"):
+                return (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    z.astype(jnp.float32))).astype(o.dtype)
+
+        return self.proj(_apply(jax.checkpoint(gated),
+                                self.heads_output(x, mask), self.gate(x)))
+
+
+class SharedExpertMoE(HybridBlock):
+    """Routed SwiGLU experts beside a shared one of the same form:
+    `moe(u) + shared(u)`. The routed part is a `parallel.MoELayer` (sigmoid
+    scores, a bias that chooses, renormalised weights times ``scale``) told
+    which experts it holds; the router and the shared expert are whole on
+    every rank. ``bias_rate``: the MoELayer's balancing rule; the block
+    then returns (y, the moved selection bias). Scopes: the MoELayer's own
+    four under its block, the shared expert's `ffn` under its own."""
+
+    def __init__(self, units, num_experts, ffn_hidden, top_k, shared_hidden,
+                 scale=1.0, norm_topk_prob=True, held=None, bias_rate=None,
+                 **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.moe = MoELayer(num_experts, units, ffn_hidden, top_k=top_k,
+                                activation="silu", gated=True,
+                                norm_topk_prob=norm_topk_prob,
+                                router="sigmoid_bias", scale=scale, held=held,
+                                bias_rate=bias_rate)
+            self.shared = SwiGLU(units, shared_hidden)
+
+    def forward(self, u):
+        routed = self.moe(u)
+        if isinstance(routed, tuple):
+            return routed[0] + self.shared(u), routed[1]
+        return routed + self.shared(u)
+
+
+class SolarOpen2Layer(HybridBlock):
+    """x + mixer(RMSNorm(x)), then x + experts(RMSNorm(x)); ``mixer`` and
+    ``experts`` build the blocks (inside this layer's name scope). Experts
+    that hand out a moved selection bias: (x, that bias)."""
+
+    def __init__(self, units, mixer, experts, epsilon=1e-5, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.norm1 = nn.RMSNorm(in_channels=units, epsilon=epsilon)
+            self.mixer = mixer()
+            self.norm2 = nn.RMSNorm(in_channels=units, epsilon=epsilon)
+            self.experts = experts()
+
+    def forward(self, x):
+        x = x + self.mixer(self.norm1(x))
+        y = self.experts(self.norm2(x))
+        if isinstance(y, tuple):
+            return x + y[0], y[1]
+        return x + y
+
+
+class SolarOpen2Model(HybridBlock):
+    """tokens (B, S) int -> logits (B, S, vocab). ``pattern`` names the
+    layers' mixers (`K`, `G`); ``delta``, ``attention`` and ``moe`` are the
+    keyword arguments of `KimiDeltaAttention`, `GatedGroupedQueryAttention`
+    and `SharedExpertMoE` after ``units``. ``remat_layers``: each layer's
+    forward is recomputed in the backward (`gluon.utils.recompute`). With
+    ``moe["bias_rate"]`` every training step moves every router's selection
+    bias by the balancing rule, here, outside the recomputed layers."""
+
+    def __init__(self, vocab_size, units, pattern, delta, attention, moe,
+                 epsilon=1e-5, remat_layers=False, **kwargs):
+        super().__init__(**kwargs)
+        if set(pattern) - set(MIXERS) or not pattern:
+            raise ValueError("pattern %r: a mixer is one of %s"
+                             % (pattern, sorted(MIXERS)))
+        self.pattern = pattern
+        self._remat = remat_layers
+        build = {"K": lambda: KimiDeltaAttention(units, epsilon=epsilon,
+                                                 **delta),
+                 "G": lambda: GatedGroupedQueryAttention(units, **attention)}
+        with self.name_scope():
+            self.tok_embed = nn.Embedding(vocab_size, units)
+            self.layers = nn.HybridSequential()
+            for letter in pattern:
+                self.layers.add(SolarOpen2Layer(
+                    units, build[letter],
+                    lambda: SharedExpertMoE(units, **moe), epsilon=epsilon))
+            self.norm_f = nn.RMSNorm(in_channels=units, epsilon=epsilon)
+            self.lm_head = nn.Dense(vocab_size, flatten=False,
+                                    in_units=units, use_bias=False)
+
+    def features(self, token_ids):
+        """The final norm's output (B, S, U): pair with
+        ChunkedUntiedLMLoss so the (B*S, V) logits never materialise."""
+        x = self.tok_embed(token_ids)
+        for layer in self.layers:
+            x = utils.recompute(layer, x) if self._remat else layer(x)
+            if isinstance(x, tuple):
+                x, moved = x
+                layer.experts.moe.move_bias(moved)
+        return self.norm_f(x)
+
+    def forward(self, token_ids):
+        return self.lm_head(self.features(token_ids))

@@ -41,8 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import initializer, telemetry
+from .. import autograd, initializer, telemetry
 from .. import ndarray as nd
+from ..gluon import _functional
 from ..gluon.block import HybridBlock
 from ..ndarray import _apply
 
@@ -230,6 +231,13 @@ def _grouped_t(lhs, rhs, sizes, live, g):
     return jnp.where(live[:, None], d_lhs, 0), d_rhs
 
 
+def _expert_rows(act, rows, projections, w_down, sizes, live):
+    """A window's pre-activations (one a projection) and expert rows."""
+    with jax.named_scope("moe_experts"):
+        pre = tuple(_grouped(rows, m, sizes, live) for m in projections)
+        return pre, _grouped(_hidden(act, *pre), w_down, sizes, live)
+
+
 def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
                   order, group_sizes):
     """-> ((T, D) float32 sum, kept). With `keep` each window's
@@ -239,10 +247,8 @@ def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
         total, kept = carry
         _, token, live, sizes, rows, weight = _window_of(
             w, window, tokens, top_vals, order, group_sizes)
-        with jax.named_scope("moe_experts"):
-            pre = tuple(_grouped(rows, m, sizes, live)
-                        for m in projections)                     # (W, H)
-            y = _grouped(_hidden(act, *pre), w_down, sizes, live)
+        pre, y = _expert_rows(act, rows, projections, w_down, sizes,
+                              live)                               # (W, H)
         with jax.named_scope("moe_combine"):
             total = total.at[token].add(
                 _weighted(y, weight).astype(jnp.float32))
@@ -264,7 +270,9 @@ def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
 # once a pass, however many windows run. The forward that is differentiated
 # keeps what the backward reads (pre-activations and expert rows) in
 # buffers a window writes its own rows of; a window that does not run
-# costs those buffers' zeros and nothing else. The moves between tokens
+# costs those buffers' zeros and nothing else. Where those buffers would
+# pass HELD_KEEP_BYTES nothing is kept and the backward's body computes its
+# window's again. The moves between tokens
 # and rows are a gather one way and a float32 scatter-add the other, W rows
 # each.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -277,8 +285,27 @@ def _held_sum(act, window, tokens, top_vals, projections, w_down, order,
                          w_down, order, group_sizes)[0]
 
 
+#: what the held dispatch may keep of its forward for the backward, in bytes
+#: of the buffers of all the windows' rows. Beyond it the backward computes
+#: a running window's pre-activations and expert rows again: W rows of
+#: grouped matmuls a window, against buffers of T x min(k, count) rows that
+#: a balanced router fills to a fortieth (8 of 320 SwiGLU experts 1280 wide
+#: on 4096: 872 MB a layer, twice while the loop is entered; 8 of 512 in a
+#: 1024-wide latent space keep their 486 MB)
+HELD_KEEP_BYTES = 512 * 2 ** 20
+
+
+def _keeps(tokens, projections, w_down, order):
+    """Whether the forward's pre-activations and expert rows are kept for
+    the backward (from shapes alone)."""
+    widths = len(projections) * w_down.shape[1] + w_down.shape[2]
+    return order.shape[0] * widths * tokens.dtype.itemsize <= HELD_KEEP_BYTES
+
+
 def _held_sum_fwd(act, window, *args):
-    total, kept = _held_forward(act, window, True, *args)
+    tokens, _, projections, w_down, order, _ = args
+    total, kept = _held_forward(
+        act, window, _keeps(tokens, projections, w_down, order), *args)
     return total, args + (kept,)
 
 
@@ -288,9 +315,13 @@ def _held_sum_bwd(act, window, res, g):
         d_tokens, d_vals, d_projections, d_w_down = sums
         slot, token, live, sizes, rows, weight = _window_of(
             w, window, tokens, top_vals, order, group_sizes)
-        pre, y = jax.tree.map(
-            lambda buf: jax.lax.dynamic_slice(
-                buf, (w * window, 0), (window, buf.shape[1])), kept)
+        if kept:
+            pre, y = jax.tree.map(
+                lambda buf: jax.lax.dynamic_slice(
+                    buf, (w * window, 0), (window, buf.shape[1])), kept)
+        else:
+            pre, y = _expert_rows(act, rows, projections, w_down, sizes,
+                                  live)
         with jax.named_scope("moe_combine"):
             _, pull = jax.vjp(_weighted, y, weight)
             d_y, d_weight = pull(g[token].astype(y.dtype))
@@ -339,7 +370,8 @@ def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
     T x min(k, count) / W at worst), so nothing is dropped at any load, and
     every op works on W rows (what the backward reads of the forward is
     kept in buffers of all the windows' rows, which a window that runs
-    writes its part of). Where W is the worst case there is no loop.
+    writes its part of, or computed again where those buffers would pass
+    `HELD_KEEP_BYTES`). Where W is the worst case there is no loop.
     Scopes as in `dropless_moe`, inside `moe_window`.
     """
     n_tokens, k = top_idx.shape
@@ -414,7 +446,8 @@ class MoELayer(HybridBlock):
     returns the output only; ``forward_with_aux`` also the load-balancing +
     z loss for the trainer to add to the task loss. Every token reaches all
     its k experts (`dropless_moe`): there is no capacity and no second
-    dispatch.
+    dispatch. A shared expert is the caller's to add beside this layer
+    (`models.nemotron_h.LatentMoE`, `models.solar_open2.SharedExpertMoE`).
 
     ``held=(first, count)``: this layer holds the experts first ..
     first + count - 1 of ``num_experts`` and the stacked weights are
@@ -424,13 +457,21 @@ class MoELayer(HybridBlock):
     the path above, unchanged. ``router_units`` is the width of what the
     router reads where that is not the experts' input (``forward(x,
     route_on)``: a latent mixture routes on the full-width activations).
+
+    ``bias_rate`` (``sigmoid_bias`` only): the load-balancing rule itself.
+    ``forward`` then returns (y, b') with b' = b + bias_rate * ln(even load
+    / load) over this call's choices (an expert nobody chose counts as
+    chosen once): what b becomes for the next step once the caller hands
+    it to `move_bias`, which it does OUTSIDE any recomputed block. Without
+    the rule Adam moves a router off its balance within tens of steps
+    (PERF.md section 6, PR 38: a share's held experts lost every row).
     """
 
     def __init__(self, num_experts, hidden_size, ffn_hidden, top_k=2,
                  ep_axis="ep", activation="relu", gated=False,
                  norm_topk_prob=True, z_loss_coef=1e-3,
                  capacity_factor=None, router="softmax", scale=1.0,
-                 held=None, router_units=None, **kwargs):
+                 held=None, router_units=None, bias_rate=None, **kwargs):
         super().__init__(**kwargs)
         if capacity_factor is not None:
             import warnings
@@ -443,6 +484,10 @@ class MoELayer(HybridBlock):
             raise ValueError("top_k=%d of %d experts" % (top_k, num_experts))
         if router not in _ROUTERS:
             raise ValueError("router=%r (one of %s)" % (router, _ROUTERS))
+        if bias_rate is not None and router != "sigmoid_bias":
+            raise ValueError("bias_rate moves the selection bias of "
+                             "router='sigmoid_bias'; router=%r has none"
+                             % router)
         if held is not None and not (
                 0 <= held[0] and held[1] >= 1
                 and held[0] + held[1] <= num_experts):
@@ -456,6 +501,7 @@ class MoELayer(HybridBlock):
         self._gated = gated
         self._router = router
         self._scale = scale
+        self._bias_rate = bias_rate
         n_held = num_experts if held is None else held[1]
         stacked = {"w1": (n_held, hidden_size, ffn_hidden),
                    "w2": (n_held, ffn_hidden, hidden_size)}
@@ -534,6 +580,15 @@ class MoELayer(HybridBlock):
                 aux = load_balancing_loss(gates, top_idx, self.num_experts) \
                     + self.z_loss_coef * router_z_loss(logits)
             return out, aux
+        if self._bias_rate is not None:
+            with jax.named_scope("router"):
+                load = jnp.sum(
+                    top_idx.reshape(-1, 1) == jnp.arange(
+                        self.num_experts, dtype=top_idx.dtype),
+                    axis=0, dtype=jnp.float32)
+                moved = arrays["router_bias"] + self._bias_rate * jnp.log(
+                    top_idx.size / self.num_experts / jnp.maximum(load, 1.0))
+            return out, moved
         return out
 
     def _weight_names(self):
@@ -554,8 +609,22 @@ class MoELayer(HybridBlock):
 
     def forward(self, x, route_on=None):
         """x: (..., D) → (..., D); the router reads ``route_on``
-        (..., router_units) where given, else x."""
+        (..., router_units) where given, else x. With ``bias_rate`` →
+        (y, the selection bias as the rule moves it)."""
         return self._call(x, route_on, False)
+
+    def move_bias(self, moved):
+        """``moved`` (``forward``'s second output) becomes the selection
+        bias: when the step ends inside a compiled train step (an
+        auxiliary update, as BatchNorm's running statistics are), at once
+        in eager training, never outside training."""
+        if not autograd.is_training():
+            return
+        bias = self.router_bias.data()
+        if _functional.in_functional_mode():
+            _functional.collect_aux_update(bias, moved._data)
+        else:
+            bias._data = moved._data
 
     def forward_with_aux(self, x, route_on=None):
         """Returns (y, aux) where aux = Switch load-balancing loss +

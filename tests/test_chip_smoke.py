@@ -168,3 +168,16 @@ def test_the_scan_alone_reads_between_a_float32_and_a_bfloat16_state():
     sound, rounded = chip_smoke.scan_alone(chip_smoke.TOY["hybrid"])
     assert sound < chip_smoke.SCAN_ALONE_LIMIT / 10
     assert rounded > chip_smoke.SCAN_ALONE_LIMIT * 5
+
+
+def test_the_delta_rule_alone_reads_between_a_float32_and_a_bfloat16_state():
+    """The same for the gated delta rule alone at the rehearsal shape: the
+    chunk form and its five gradients in float32 are under the hybrid
+    phase's limit (summation order: 3e-7 here), the recurrence with a
+    state rounded to bfloat16 once a chunk is over it (1.2e-3 here); off
+    the chip it reports no milliseconds."""
+    read = chip_smoke.delta_rule_alone(chip_smoke.TOY["hybrid"], False)
+    assert read["sound"] < chip_smoke.DELTA_RULE_ALONE_LIMIT / 10
+    assert read["gradients"] < chip_smoke.DELTA_RULE_ALONE_LIMIT / 10
+    assert read["rounded"] > chip_smoke.DELTA_RULE_ALONE_LIMIT * 5
+    assert read["forward_ms"] is None and read["both_ms"] is None

@@ -341,3 +341,36 @@ def test_sdml_loss_learns_alignment():
         trainer.step(6)
         losses.append(float(loss.asscalar()))
     assert losses[-1] < losses[0]
+
+
+def test_train_step_releases_the_eager_gradient_buffers():
+    """`jit.TrainStep` differentiates a pure function and never reads the
+    buffers the eager tape writes gradients into: the net it takes keeps a
+    scalar zero of each buffer's type, `grad()` reads as zeros of the
+    parameter's shape again, and an eager backward on the same net writes
+    its gradient as before."""
+    import numpy as onp
+    from incubator_mxnet_tpu import autograd, gluon, jit, nd
+    net = gluon.nn.Dense(3, in_units=4)
+    net.initialize()
+    loss = gluon.loss.L2Loss()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    x = nd.array(onp.ones((2, 4), "float32"))
+    y = nd.array(onp.zeros((2, 3), "float32"))
+    assert net.weight._grad.shape == (3, 4)
+    before = net.weight.data().asnumpy()
+    jit.TrainStep(net, loss, trainer)(x, y)
+    assert (net.weight.data().asnumpy() != before).any()      # it trained
+    assert all(p._grad.shape == () for p in net.collect_params().values())
+    assert net.weight.grad().shape == (3, 4)
+    assert not net.weight.grad().asnumpy().any()
+    net.weight.release_grad()
+    with autograd.record():
+        out = loss(net(x), y)
+    out.backward()
+    assert net.weight.grad().shape == (3, 4)
+    assert net.weight.grad().asnumpy().any()
+    net.weight.zero_grad()
+    assert net.weight.grad().shape == (3, 4)
+    assert not net.weight.grad().asnumpy().any()

@@ -178,11 +178,20 @@ def _routing(kind, n_tokens, k, first, count, n_experts, rng):
     return jnp.asarray(rng.permuted(idx, axis=1), jnp.int32)
 
 
+@pytest.fixture(params=[True, False], ids=["kept", "computed-again"])
+def kept(request, monkeypatch):
+    """The backward reads the forward's rows from the kept buffers, or
+    (buffers over `HELD_KEEP_BYTES`) computes its window's again."""
+    if not request.param:
+        monkeypatch.setattr(moe, "HELD_KEEP_BYTES", 0)
+    return request.param
+
+
 @pytest.mark.parametrize("gated", [False, True])
 @pytest.mark.parametrize("kind,windows", [
     ("none_held", 0), ("random", 1), ("all_on_one_expert", 1),
     ("two_held_a_token", 2), ("every_token_on_every_held", 4)])
-def test_windows_are_exact_at_any_load(kind, windows, gated):
+def test_windows_are_exact_at_any_load(kind, windows, gated, kept):
     """T = 1024, 4 of 64 held from the 9th on, 4 a token: W = 1024 of 4096
     rows. Value and every gradient against the dense form, whether no
     window runs, one, two or all four: nothing is dropped at any load."""
@@ -237,7 +246,8 @@ def test_windows_are_exact_at_any_load(kind, windows, gated):
 
 
 @pytest.mark.parametrize("gated", [False, True])
-def test_what_a_kernel_leaves_in_dead_rows_reaches_no_sum(gated, monkeypatch):
+def test_what_a_kernel_leaves_in_dead_rows_reaches_no_sum(gated, monkeypatch,
+                                                          kept):
     """A grouped matmul answers for the rows its groups own; on the chip
     the others hold whatever was there. With every such row of every
     grouped matmul's output and of its transpose poisoned (NaN), value and
@@ -290,6 +300,46 @@ def test_what_a_kernel_leaves_in_dead_rows_reaches_no_sum(gated, monkeypatch):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         onp.testing.assert_allclose(g, w, rtol=2e-5,
                                     atol=2e-5 * float(jnp.abs(w).max()))
+
+
+def _largest_rows(fn, args, width):
+    """The most rows any (rows, width) array of fn's program has."""
+    return max(_row_counts(jax.make_jaxpr(fn)(*args).jaxpr, (width,)))
+
+
+def test_what_is_kept_follows_from_the_shapes(monkeypatch):
+    """Kept: 8 of 512 experts in a 1024-wide latent space, 2688 hidden
+    (65 536 rows x 3712 x 2 B = 486 MB); computed again: 8 of 320 SwiGLU
+    experts 1280 wide on 4096 (872 MB). Where nothing is kept the gradient's
+    program has no array of all the windows' rows."""
+    def shapes(d, h, gated):
+        tokens = jax.ShapeDtypeStruct((8192, d), jnp.bfloat16)
+        w_up = jax.ShapeDtypeStruct((8, d, h), jnp.bfloat16)
+        w_down = jax.ShapeDtypeStruct((8, h, d), jnp.bfloat16)
+        order = jax.ShapeDtypeStruct((65536,), jnp.int32)
+        return tokens, (w_up, w_up) if gated else (w_up,), w_down, order
+
+    assert moe._keeps(*shapes(1024, 2688, False))
+    assert not moe._keeps(*shapes(4096, 1280, True))
+    n_tokens, k, first, count, n_experts = 1024, 4, 8, 4, 64
+    rng = onp.random.default_rng(3)
+    top_idx = _routing("random", n_tokens, k, first, count, n_experts, rng)
+    tokens = jnp.zeros((n_tokens, D))
+    top_vals = jnp.ones((n_tokens, k))
+    w_up, w_down = jnp.zeros((count, D, H)), jnp.zeros((count, H, D))
+
+    def grads():
+        # a function of its own a reading: JAX keeps a function's trace
+        return lambda *args: jax.grad(lambda *a: jnp.sum(
+            moe.dropless_moe_held(a[0], a[1], top_idx, a[2], a[3],
+                                  jax.nn.relu, first, n_experts)),
+            (0, 1, 2, 3))(*args)
+
+    args = (tokens, top_vals, w_up, w_down)
+    assert _largest_rows(grads(), args, H) == n_tokens * k   # all windows'
+    monkeypatch.setattr(moe, "HELD_KEEP_BYTES", 0)
+    assert _largest_rows(grads(), args, H) == moe.held_window_rows(
+        n_tokens, k, count, n_experts)
 
 
 @pytest.mark.parametrize("gated,forward,both", [(False, 2, 8), (True, 3, 12)])
@@ -387,6 +437,57 @@ def test_layer_with_a_share_against_the_dense_form_and_routes_apart():
         parallel.MoELayer(E, D, H, held=(10, 4))
     with pytest.raises(ValueError):
         parallel.MoELayer(E, D, H, router="tanh")
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)])
+def test_the_balancing_rule_hands_out_the_bias_it_moves_to(held):
+    """`bias_rate`: forward gives (y, b + rate ln(even load / load)) over
+    the call's own choices, an expert nobody chose counted once; y is the
+    layer's without the rule."""
+    kw = dict(top_k=K, router="sigmoid_bias", activation="relu2", held=held)
+    plain = parallel.MoELayer(E, D, H, **kw)
+    ruled = parallel.MoELayer(E, D, H, bias_rate=0.05, **kw)
+    plain.initialize()
+    ruled.initialize()
+    rng = onp.random.default_rng(8)
+    bias = rng.uniform(-0.3, 0.3, (E,))
+    bias[7] = -10.0                                   # never chosen
+    for layer in (plain, ruled):
+        for name in layer._weight_names():
+            getattr(layer, name).set_data(getattr(plain, name).data())
+        layer.router_bias.set_data(nd.array(bias))
+    x = nd.array(rng.standard_normal((2, T // 2, D)).astype("float32"))
+    y, moved = ruled(x)
+    onp.testing.assert_array_equal(y.asnumpy(), plain(x).asnumpy())
+    _, _, _, idx = ruled.route(x._data.reshape(T, D),
+                               ruled.gate_weight.data()._data,
+                               ruled.router_bias.data()._data)
+    load = onp.bincount(onp.asarray(idx).reshape(-1), minlength=E)
+    assert load[7] == 0 and load.sum() == T * K
+    want = bias + 0.05 * onp.log((T * K / E) / onp.maximum(load, 1))
+    onp.testing.assert_allclose(moved.asnumpy(), want, rtol=1e-6, atol=1e-7)
+    assert str(moved.dtype) == "float32"
+    # the busiest expert's bias falls, the idle one's rises
+    assert moved.asnumpy()[load.argmax()] < bias[load.argmax()]
+    assert moved.asnumpy()[7] > bias[7]
+
+
+def test_the_bias_moves_in_training_only_and_only_a_sigmoid_router_has_one():
+    from incubator_mxnet_tpu import autograd
+    layer = parallel.MoELayer(E, D, H, top_k=K, router="sigmoid_bias",
+                              bias_rate=0.05)
+    layer.initialize()
+    x = nd.array(onp.random.default_rng(9).standard_normal(
+        (T, D)).astype("float32"))
+    _, moved = layer(x)
+    layer.move_bias(moved)
+    assert not layer.router_bias.data().asnumpy().any()
+    with autograd.record():
+        layer.move_bias(layer(x)[1])
+    onp.testing.assert_array_equal(layer.router_bias.data().asnumpy(),
+                                   moved.asnumpy())
+    with pytest.raises(ValueError):
+        parallel.MoELayer(E, D, H, bias_rate=0.05)
 
 
 def test_held_none_is_the_dispatch_it_was():
