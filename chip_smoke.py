@@ -81,7 +81,8 @@ TOY = {
                    SEL_R=4,
                    # (narrow heads ride the streamed kernels from 2048 on)
                    WIDE_H=1, WIDE_S=2048, WIDE_D=64, WIDE_WINDOW=512,
-                   DR_S=160, DR_H=2, DR_D=16, DR_Q=16),
+                   # (heads of 128: the narrowest the rule's kernels take)
+                   DR_S=160, DR_H=2, DR_D=128, DR_Q=16),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -397,20 +398,27 @@ def scan_alone(cfg):
 def delta_rule_alone(cfg, on_chip):
     """`ops.delta_rule.gated_delta_rule` alone at the Solar cell's shape
     (DR_S positions, DR_H heads of DR_D x DR_D, chunks of DR_Q), float32
-    inputs (the op runs every matmul at "highest" itself), against the
-    recurrence a position at a time; as `scan_alone`, the one comparison in
-    which the state's float32 shows. q and k have unit length (q scaled),
-    the decay is A in [1, 16] a head times a step about log-normal around
-    0.011 a CHANNEL, b = 2 sigmoid(.) reaches both its ends.
-    -> {"sound": the output's distance, "rounded": the recurrence's with
-    its state rounded to bfloat16 once a chunk, "gradients": the worst of
-    the five (q, k, v, g, b), each over its own largest entry,
-    "forward_ms", "both_ms": the op alone with a bfloat16 v (None off the
-    chip: a CPU time is no device number)}."""
+    inputs (the op runs every matmul at full precision itself), through the
+    one entry the cell runs and on the schedule it runs there (the Pallas
+    kernel pair wherever kernels run: compiled for the chip, one Mosaic
+    call forward and two for a gradient, BEFORE the first call; interpreted
+    in a rehearsal), against the recurrence a position at a time; as
+    `scan_alone`, the one comparison in which the state's float32 (and a
+    matmul's: Mosaic's default rounds float32 operands to bfloat16) shows.
+    q and k have unit length (q scaled), the decay is A in [1, 16] a head
+    times a step about log-normal around 0.011 a CHANNEL, b = 2 sigmoid(.)
+    reaches both its ends.
+    -> {"path": the schedule the calls took, "sound": the output's
+    distance, "rounded": the recurrence's with its state rounded to
+    bfloat16 once a chunk, "gradients": the worst of the five (q, k, v, g,
+    b), each over its own largest entry, "forward_ms", "both_ms": the op
+    alone with a bfloat16 v, "xla_ms": (forward, forward + backward) of the
+    XLA form at the same operands (None off the chip: a CPU time is no
+    device number)}."""
     import jax
     import jax.numpy as jnp
     import numpy as onp
-    from incubator_mxnet_tpu.ops.delta_rule import gated_delta_rule
+    from incubator_mxnet_tpu.ops import delta_rule as op
     s, h, d, q_ = cfg["DR_S"], cfg["DR_H"], cfg["DR_D"], cfg["DR_Q"]
     rng = onp.random.default_rng(0)
 
@@ -426,7 +434,30 @@ def delta_rule_alone(cfg, on_chip):
     args = tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta))
 
     def system(*a):
-        return gated_delta_rule(*a, chunk=q_)
+        return op.gated_delta_rule(*a, chunk=q_)
+
+    def both(fn):
+        return jax.jit(jax.grad(
+            lambda *t: jnp.sum(fn(*t).astype(jnp.float32)), (0, 1, 2, 3, 4)))
+
+    path = "pallas" if op._kernels_run_here() and op._kernel_takes(d, d, q_) \
+        else "xla"
+    calls = op._CALLS.value(path=path)
+    operands = args[:2] + (args[2].astype(jnp.bfloat16),) + args[3:]
+    if on_chip:
+        if path != "pallas":
+            raise RuntimeError("the delta rule's kernels refuse the cell's "
+                               "shape (%d x %d, chunks of %d)" % (d, d, q_))
+        for fn, kernels in ((jax.jit(system), ("delta_rule_fwd",)),
+                            (both(system), ("delta_rule_fwd",
+                                            "delta_rule_bwd"))):
+            lowered = fn.lower(*operands)
+            text = lowered.as_text()
+            if text.count("tpu_custom_call") != len(kernels) \
+                    or not all(k in text for k in kernels):
+                raise RuntimeError("the delta rule is not the Mosaic calls "
+                                   "%s" % (kernels,))
+            lowered.compile()
 
     def recurrence(q, k, v, g, beta, round_state=False):
         def step(state, at):
@@ -465,13 +496,23 @@ def delta_rule_alone(cfg, on_chip):
         out["gradients"] = max(
             distance(a, b) for a, b in zip(gradients(system, cot, *short),
                                            gradients(recurrence, cot, *short)))
-    out["forward_ms"] = out["both_ms"] = None
+    out["path"] = path
+    out["forward_ms"] = out["both_ms"] = out["xla_ms"] = None
     if on_chip:
-        operands = args[:2] + (args[2].astype(jnp.bfloat16),) + args[3:]
         out["forward_ms"] = median_ms(jax.jit(system), operands)
-        out["both_ms"] = median_ms(jax.jit(jax.grad(
-            lambda *t: jnp.sum(system(*t).astype(jnp.float32)),
-            (0, 1, 2, 3, 4))), operands)
+        out["both_ms"] = median_ms(both(system), operands)
+        # the other schedule at the same operands: steered from here, the
+        # op has no argument that chooses
+        run_here, op._kernels_run_here = op._kernels_run_here, lambda: False
+        try:
+            out["xla_ms"] = (
+                median_ms(jax.jit(lambda *a: system(*a)), operands),
+                median_ms(both(lambda *a: system(*a)), operands))
+        finally:
+            op._kernels_run_here = run_here
+    if op._CALLS.value(path=path) == calls:
+        raise RuntimeError("mxtpu_delta_rule_total{path=%r} did not count "
+                           "the calls" % path)
     return out
 
 
@@ -742,9 +783,9 @@ def phase_hybrid(cfg, on_chip, shared):
         "%.2g (%.2g), the eight gradients of one channel block %.2g%s; " \
         "attention with a value of its own width at (1, %d, %d, %d | %d) " \
         "against the two narrow calls it replaces: %s; gated delta rule " \
-        "alone at %d x %d x %d x %d in float32 %.2g of its largest output " \
-        "from the recurrence (a bfloat16 state a chunk %.2g), the five " \
-        "gradients %.2g%s" % (
+        "alone at %d x %d x %d x %d (%s) in float32 %.2g of its largest " \
+        "output from the recurrence (a bfloat16 state a chunk %.2g), the " \
+        "five gradients %.2g%s" % (
             cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
             cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
             cfg["SEL_N"], sel["sound"], sel["rounded"], sel["gradients"],
@@ -756,11 +797,12 @@ def phase_hybrid(cfg, on_chip, shared):
                     ", forward %.1f ms and forward + backward %.1f ms for "
                     "%.1f and %.1f" % (read["wide_ms"] + read["narrow_ms"]))
                 for name, read in wide.items()),
-            cfg["DR_S"], cfg["DR_H"], cfg["DR_D"], cfg["DR_D"],
+            cfg["DR_S"], cfg["DR_H"], cfg["DR_D"], cfg["DR_D"], rule["path"],
             rule["sound"], rule["rounded"], rule["gradients"],
             "" if rule["forward_ms"] is None else
-            ", forward %.1f ms, forward + backward %.1f ms" % (
-                rule["forward_ms"], rule["both_ms"]))
+            ", forward %.1f ms, forward + backward %.1f ms (the XLA form "
+            "%.1f / %.1f)" % ((rule["forward_ms"], rule["both_ms"])
+                              + rule["xla_ms"]))
 
 
 def build_resnet():

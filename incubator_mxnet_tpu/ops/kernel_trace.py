@@ -9,12 +9,13 @@ it takes go to ``mxtpu_kernel_trace_seconds_total{kernel}`` and one to
 ``mxtpu_kernel_traces_total{kernel}``, ``kernel`` the name the call carries.
 The equation it binds is the one ``pl.pallas_call`` binds: no jit, no scope
 and no argument of its own, so a caller under ``jax.jit(..., inline=True)``
-(``ops/selective_scan.py`` ``_traced_once``) still traces a shape once and
+(``traced_once`` below) still traces a shape once and
 the counter reads one. (Mosaic's lowering of the kernel happens later,
 inside the outer program's jaxpr -> MLIR: ``setup_phases`` books it there.)
 """
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
@@ -47,3 +48,13 @@ def pallas_call(kernel, operands, *, name, **params):
     finally:
         _SECONDS.inc(time.perf_counter() - t0, kernel=name)
         _TRACES.inc(kernel=name)
+
+
+def traced_once(*static):
+    """A kernel's Python body and its Mosaic lowering are paid by every
+    process before its cached executable loads (about 1 s a backward kernel
+    on the chip's host). Inlined jit: a call of shapes seen before re-binds
+    the SAME kernel jaxpr under the caller's scopes, so a model's layers
+    trace it once a process and lower it once a program (JAX caches an
+    equation's lowering by its parameters)."""
+    return functools.partial(jax.jit, static_argnames=static, inline=True)

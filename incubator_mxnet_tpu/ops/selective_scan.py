@@ -158,7 +158,7 @@ def _scan_block(x, low, w, bias, a, d, bm, cm):
 # kernels cost every process 40 s of tracing before its cached executable
 # loads (measured on the chip's host, PR 35: `setup_s` 61 -> 99 s). What is
 # left, 0.2 s a forward and 1.0 s a backward kernel there, is paid once a
-# program and not once a layer (`_traced_once`).
+# program and not once a layer (`kernel_trace.traced_once`).
 _SUB, _LANE = 8, 128
 _KERNEL_CHANNELS = _SUB * _LANE
 #: states the layout is laid out for (a state is a vreg carried through 8
@@ -467,17 +467,7 @@ def _params():
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _traced_once(*static):
-    """A kernel's Python body and its Mosaic lowering are paid by every
-    process before its cached executable loads (about 1 s a backward kernel
-    on the chip's host). Inlined jit: a call of shapes seen before re-binds
-    the SAME kernel jaxpr under the caller's scopes, so a model's layers
-    trace it once a process and lower it once a program (JAX caches an
-    equation's lowering by its parameters)."""
-    return functools.partial(jax.jit, static_argnames=static, inline=True)
-
-
-@_traced_once("q", "keep", "interpret")
+@kernel_trace.traced_once("q", "keep", "interpret")
 def _fwd_call(args, q, keep, interpret):
     """-> (y,) or (y, the state each chunk starts from (b, nb, chunks, n,
     8, 128) float32). s a multiple of q."""
@@ -505,7 +495,7 @@ def _fwd_call(args, q, keep, interpret):
         name="selective_scan_fwd")
 
 
-@_traced_once("q", "interpret")
+@kernel_trace.traced_once("q", "interpret")
 def _bwd_call(args, starts, dy, q, interpret):
     """-> the eight gradients, in the inputs' shapes and types."""
     from jax.experimental import pallas as pl

@@ -170,14 +170,24 @@ def test_the_scan_alone_reads_between_a_float32_and_a_bfloat16_state():
     assert rounded > chip_smoke.SCAN_ALONE_LIMIT * 5
 
 
-def test_the_delta_rule_alone_reads_between_a_float32_and_a_bfloat16_state():
-    """The same for the gated delta rule alone at the rehearsal shape: the
-    chunk form and its five gradients in float32 are under the hybrid
-    phase's limit (summation order: 3e-7 here), the recurrence with a
-    state rounded to bfloat16 once a chunk is over it (1.2e-3 here); off
-    the chip it reports no milliseconds."""
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_the_delta_rule_alone_reads_between_a_float32_and_a_bfloat16_state(
+        monkeypatch, path):
+    """The same for the gated delta rule alone at the rehearsal shape, on
+    the XLA form (nothing set: no kernel runs off the TPU) and on the
+    kernel pair interpreted (what `--rehearse` runs): the chunk form and
+    its five gradients in float32 are under the hybrid phase's limit
+    (summation order: 1e-6 here), the recurrence with a state rounded to
+    bfloat16 once a chunk is over it (1e-3 here); off the chip it reports
+    no milliseconds."""
+    if path == "pallas":
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
     read = chip_smoke.delta_rule_alone(chip_smoke.TOY["hybrid"], False)
+    assert read["path"] == path
     assert read["sound"] < chip_smoke.DELTA_RULE_ALONE_LIMIT / 10
     assert read["gradients"] < chip_smoke.DELTA_RULE_ALONE_LIMIT / 10
     assert read["rounded"] > chip_smoke.DELTA_RULE_ALONE_LIMIT * 5
-    assert read["forward_ms"] is None and read["both_ms"] is None
+    assert read["forward_ms"] is None and read["both_ms"] is None \
+        and read["xla_ms"] is None

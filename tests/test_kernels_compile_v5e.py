@@ -1,0 +1,65 @@
+"""Kernels of the main path compiled at their cells' real shapes for a v5e
+that is DESCRIBED, not attached (the TPU's compiler is installed here):
+what Mosaic refuses (a slice off the tiling, more VMEM than a kernel may
+use) is met at no chip time. Nothing runs, so nothing here is a result or
+a time. The topology is described inside a fixture and only here: one
+process loads the TPU's library at a time, and every xdist worker imports
+every test file."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from incubator_mxnet_tpu.ops import delta_rule
+
+#: the Solar cell's rule: 1 x 8192 positions, 8 heads of 128 x 128, chunks
+#: of 64 (perfbench/configs/solar-open2-250b.json)
+B, T, H, D, CHUNK = 1, 8192, 8, 128, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # (such a compile is written to the persistent cache and cannot be read
+    # back without a chip: off for these tests)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def rule_operands(one_chip):
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return (spec((B, T, H * D)), spec((B, T, H * D)),
+            spec((B, T, H * D), jnp.bfloat16), spec((B, T, H * D)),
+            spec((B, H, T, 1)))
+
+
+@pytest.mark.parametrize("keep", [False, True], ids=["o-only", "keeps-starts"])
+def test_the_delta_rule_forward_compiles_at_the_cells_shape(one_chip, keep):
+    compiled = jax.jit(lambda *a: delta_rule._fwd_call(
+        a, H, CHUNK, keep, False)).lower(*rule_operands(one_chip)).compile()
+    assert "delta_rule_fwd" in compiled.as_text()
+
+
+def test_the_delta_rule_backward_compiles_at_the_cells_shape(one_chip):
+    args = rule_operands(one_chip)
+    starts = jax.ShapeDtypeStruct((B, H, T // CHUNK, D, D), jnp.float32,
+                                  sharding=one_chip)
+    compiled = jax.jit(lambda starts, do, *a: delta_rule._bwd_call(
+        a, starts, do, H, CHUNK, False)).lower(starts, args[2], *args) \
+        .compile()
+    assert "delta_rule_bwd" in compiled.as_text()

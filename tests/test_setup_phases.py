@@ -4,7 +4,7 @@ compile-pipeline events, fed here through JAX's own recording calls so the
 cases are exact, land on the span open around them; a real tiny TrainStep's
 first call is covered by its children and its warm steps touch nothing;
 kernel traces are counted where Pallas makes them, once a shape under
-`_traced_once`."""
+`traced_once`."""
 import threading
 import time
 
@@ -112,7 +112,7 @@ def test_with_no_span_open_the_owner_is_other_and_no_span_is_made():
 
 
 def test_a_nested_trace_adds_its_events_but_not_its_seconds():
-    """An inner jax.jit traced inside an outer one (every `_traced_once`
+    """An inner jax.jit traced inside an outer one (every `traced_once`
     call): three events, the outer's 1.0 s once."""
     before = seconds("trace", "train:build"), events("trace", "train:build")
     with spans.span("train:build"):
@@ -348,7 +348,7 @@ def traces(kernel):
 
 
 def test_two_equal_shape_calls_of_the_scan_count_one_trace(interpreted):
-    """`_traced_once` still holds: a second layer of the same shapes
+    """`traced_once` still holds: a second layer of the same shapes
     re-binds the kernel jaxpr the first one traced."""
     from test_phi4flash import _chunked, _scan_inputs
     args = _scan_inputs(1, 1, 32, scan_mod._KERNEL_CHANNELS, 16)
@@ -404,9 +404,10 @@ def test_an_eager_kernel_call_is_no_trace(interpreted):
     assert (traces("flash_short_fwd"), traces("flash_fwd")) == before
 
 
-def test_every_kernel_call_goes_by_the_door_under_one_of_eight_names():
+def test_every_kernel_call_goes_by_the_door_under_one_of_ten_names():
     import inspect
-    for mod, calls in ((attention, 4), (scan_mod, 2)):
+    from incubator_mxnet_tpu.ops import delta_rule
+    for mod, calls in ((attention, 4), (scan_mod, 2), (delta_rule, 2)):
         src = inspect.getsource(mod)
         assert "pl.pallas_call(" not in src
         assert src.count("kernel_trace.pallas_call(") == calls
@@ -414,4 +415,5 @@ def test_every_kernel_call_goes_by_the_door_under_one_of_eight_names():
             kernel_trace._TRACES.series()} <= {
         "flash_fwd", "flash_bwd_dkvq", "flash_window_fwd",
         "flash_window_bwd", "flash_short_fwd", "flash_short_bwd",
-        "selective_scan_fwd", "selective_scan_bwd"}
+        "selective_scan_fwd", "selective_scan_bwd",
+        "delta_rule_fwd", "delta_rule_bwd"}
