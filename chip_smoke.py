@@ -61,7 +61,10 @@ FULL = {
                    # a differential layer's call there: (1, H, S, D | 2 D)
                    WIDE_H=20, WIDE_S=16384, WIDE_D=64, WIDE_WINDOW=512,
                    # the gated delta rule alone at the Solar cell's shape
-                   DR_S=8192, DR_H=8, DR_D=128, DR_Q=64),
+                   DR_S=8192, DR_H=8, DR_D=128, DR_Q=64,
+                   # sparse attention alone at the Keye cell's shape
+                   SA_S=16384, SA_H=32, SA_G=4, SA_D=128, SA_J=16, SA_DI=64,
+                   SA_K=2048),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -82,7 +85,9 @@ TOY = {
                    # (narrow heads ride the streamed kernels from 2048 on)
                    WIDE_H=1, WIDE_S=2048, WIDE_D=64, WIDE_WINDOW=512,
                    # (heads of 128: the narrowest the rule's kernels take)
-                   DR_S=160, DR_H=2, DR_D=128, DR_Q=16),
+                   DR_S=160, DR_H=2, DR_D=128, DR_Q=16,
+                   SA_S=256, SA_H=4, SA_G=2, SA_D=128, SA_J=2, SA_DI=64,
+                   SA_K=48),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -516,6 +521,134 @@ def delta_rule_alone(cfg, on_chip):
     return out
 
 
+#: sparse attention alone, float32 at "highest": its output may be this far
+#: (of the largest entry) from a masked softmax over `lax.top_k`'s keys, its
+#: six gradients `gradients` (the indexer's are differences of two
+#: probabilities, summed in another order); the same reference choosing on
+#: index scores rounded to bfloat16 must be further than `outputs`.
+#: Between the readings of one v5e (docs/PERF_KEYE_VL2.md section 2)
+SPARSE_ALONE_LIMITS = {"outputs": 1e-4, "gradients": 2e-3}
+
+
+def sparse_attention_alone(cfg, on_chip):
+    """`ops.sparse_attention.sparse_attention` alone at the Keye cell's shape
+    (SA_S positions, SA_H query heads on SA_G key-value heads of SA_D, SA_J
+    index heads of SA_DI, SA_K keys a query), float32 operands, through the
+    one entry the cell runs and on the schedule it runs there (the five
+    Pallas kernels wherever kernels run: in the lowered forward and
+    gradient BEFORE the first call; interpreted in a rehearsal), against a masked softmax over
+    the keys `lax.top_k` takes a row, in blocks of 128 queries: two
+    algorithms of selection, one set. The gradients on the first quarter of
+    the positions (still more than SA_K).
+    -> {"path", "sound": the output's distance, "kl": the KL's relative
+    distance, "rounded": the output's distance when the reference chooses
+    on scores rounded to bfloat16, "gradients": the worst of the six,
+    "forward_ms", "both_ms": the op alone in bfloat16 (None off the
+    chip)}."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import sparse_attention as op
+    s, h, g, d = cfg["SA_S"], cfg["SA_H"], cfg["SA_G"], cfg["SA_D"]
+    j, di, topk = cfg["SA_J"], cfg["SA_DI"], cfg["SA_K"]
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    args = tuple(
+        jax.random.normal(key, (1, s) + tail, jnp.float32) * scale
+        for key, tail, scale in zip(keys, (
+            (h, d), (g, d), (g, d), (j, di), (di,), (j,)),
+            (1, 1, 1, 1, 1, (j * di) ** -0.5)))
+
+    def system(*a):
+        return op.sparse_attention(*a, topk)
+
+    def plain(q, k, v, qi, ki, w, round_scores=False):
+        n, block = q.shape[1], 128
+
+        @jax.checkpoint
+        def one(at):
+            q_b, qi_b, w_b, start = at
+            scores = jnp.einsum("qj,qjs->qs", w_b, jax.nn.relu(
+                jnp.einsum("qjd,sd->qjs", qi_b, ki[0])))
+            if round_scores:
+                scores = jax.lax.reduce_precision(scores, 8, 7)
+            seen = jnp.arange(n)[None] <= (start + jnp.arange(block))[:, None]
+            scores = jnp.where(seen, jnp.where(scores == 0, 0.0, scores),
+                               -jnp.inf)
+            vals, idx = jax.lax.top_k(jax.lax.stop_gradient(scores),
+                                      min(topk, n))
+            kth = vals[:, -1:]
+            last = jnp.max(jnp.where(vals == kth, idx, -1), -1, keepdims=True)
+            mask = seen & ((scores > kth) | (
+                (scores == kth) & (jnp.arange(n)[None] <= last)))
+            att = jnp.einsum("qgrd,sgd->grqs", q_b.reshape(
+                block, g, h // g, d), k[0]) / math.sqrt(d)
+            a = jax.nn.softmax(jnp.where(mask, att, -jnp.inf), -1)
+            o = jnp.einsum("grqs,sgd->qgrd", a, v[0]).reshape(block, h, d)
+            p = jax.lax.stop_gradient(a.mean((0, 1)))
+            log_pi = jax.nn.log_softmax(jnp.where(mask, scores, -jnp.inf), -1)
+            kl = jnp.where(p > 0, p * (jnp.log(jnp.where(p > 0, p, 1.0))
+                                       - jnp.where(mask, log_pi, 0.0)), 0.0)
+            return o, kl.sum()
+
+        o, kl = jax.lax.map(one, (
+            q[0].reshape(n // block, block, h, d),
+            qi[0].reshape(n // block, block, j, di),
+            w[0].reshape(n // block, block, j), jnp.arange(0, n, block)))
+        return o.reshape(1, n, h, d), kl.sum(keepdims=True) / n
+
+    def both(fn):
+        return jax.jit(jax.grad(lambda cot, *t: jnp.sum(
+            fn(*t)[0].astype(jnp.float32) * cot) + jnp.sum(fn(*t)[1]),
+            (1, 2, 3, 4, 5, 6)))
+
+    path = op._strips_of()[0]
+    calls = op._ATTENTIONS.value(path=path)
+    low = tuple(x.astype(jnp.bfloat16) for x in args[:5]) + args[5:]
+    cot = jax.random.normal(keys[6], args[0].shape, jnp.float32)
+    if on_chip:
+        if path != "pallas_masked_strips":
+            raise RuntimeError("sparse attention took %r on the chip" % path)
+        # (a gradient alone never needs the KL's VALUE: JAX drops the
+        # forward's head-mean from it, and the backward kernel has its own)
+        for fn, operands, kernels in (
+                (jax.jit(system), low, ("sparse_index_fwd", "sparse_flash_fwd",
+                                        "sparse_head_mean")),
+                (both(system), (cot.astype(jnp.bfloat16),) + low,
+                 ("sparse_index_fwd", "sparse_index_bwd", "sparse_flash_fwd",
+                  "sparse_flash_bwd"))):
+            text = fn.lower(*operands).as_text()
+            if not all(k in text for k in kernels):
+                raise RuntimeError("sparse attention is not the Mosaic "
+                                   "calls %s" % (kernels,))
+
+    def distance(got, want):
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    with jax.default_matmul_precision("highest"):
+        o_want, kl_want = jax.jit(plain)(*args)
+        o_got, kl_got = jax.jit(system)(*args)
+        out = {"sound": distance(o_got, o_want),
+               "kl": distance(kl_got, kl_want),
+               "rounded": distance(jax.jit(functools.partial(
+                   plain, round_scores=True))(*args)[0], o_want)}
+        del o_want, o_got
+        some = min(s, -(-max(s // 4, 2 * topk) // 128) * 128)
+        short = tuple(t[:, :some] for t in args)
+        cot_s = cot[:, :some]
+        out["gradients"] = max(
+            distance(a, b) for a, b in zip(both(system)(cot_s, *short),
+                                           both(plain)(cot_s, *short)))
+    out["path"] = path
+    out["forward_ms"] = out["both_ms"] = None
+    if on_chip:
+        out["forward_ms"] = median_ms(jax.jit(system), low)
+        out["both_ms"] = median_ms(both(system),
+                                   (cot.astype(jnp.bfloat16),) + low)
+    if op._ATTENTIONS.value(path=path) == calls:
+        raise RuntimeError("mxtpu_sparse_attention_total{path=%r} did not "
+                           "count the calls" % path)
+    return out
+
+
 def median_ms(fn, operands):
     """Milliseconds of a jitted call, the median of five after the first."""
     import jax
@@ -773,6 +906,19 @@ def phase_hybrid(cfg, on_chip, shared):
             "(limit %g), a bfloat16 state a chunk %.3g" % (
                 rule["sound"], rule["gradients"], DELTA_RULE_ALONE_LIMIT,
                 rule["rounded"]))
+    sparse = sparse_attention_alone(cfg, on_chip)
+    if not (sparse["sound"] < SPARSE_ALONE_LIMITS["outputs"]
+            < sparse["rounded"]
+            and sparse["kl"] < SPARSE_ALONE_LIMITS["outputs"]
+            and sparse["gradients"] < SPARSE_ALONE_LIMITS["gradients"]):
+        raise RuntimeError(
+            "sparse attention alone, float32: outputs %.3g of the largest "
+            "entry from a masked softmax over top_k's keys and the KL %.3g "
+            "(limit %g), the six gradients %.3g (limit %g); choosing on "
+            "bfloat16 scores %.3g" % (
+                sparse["sound"], sparse["kl"], SPARSE_ALONE_LIMITS["outputs"],
+                sparse["gradients"], SPARSE_ALONE_LIMITS["gradients"],
+                sparse["rounded"]))
     alone = "" if sel["forward_ms"] is None else \
         "; forward %.1f ms, forward + backward %.1f ms in bfloat16 (the XLA " \
         "form 36-38 / 77-80, PR 34)" % (sel["forward_ms"], sel["both_ms"])
@@ -785,7 +931,11 @@ def phase_hybrid(cfg, on_chip, shared):
         "against the two narrow calls it replaces: %s; gated delta rule " \
         "alone at %d x %d x %d x %d (%s) in float32 %.2g of its largest " \
         "output from the recurrence (a bfloat16 state a chunk %.2g), the " \
-        "five gradients %.2g%s" % (
+        "five gradients %.2g%s; sparse attention alone at %d x %d | %d x " \
+        "%d, %d x %d index heads, %d keys a query (%s) in float32 %.2g of " \
+        "its largest output from a masked softmax over top_k's keys " \
+        "(choosing on bfloat16 scores %.2g), the KL %.2g, the six " \
+        "gradients %.2g%s" % (
             cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
             cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
             cfg["SEL_N"], sel["sound"], sel["rounded"], sel["gradients"],
@@ -802,7 +952,13 @@ def phase_hybrid(cfg, on_chip, shared):
             "" if rule["forward_ms"] is None else
             ", forward %.1f ms, forward + backward %.1f ms (the XLA form "
             "%.1f / %.1f)" % ((rule["forward_ms"], rule["both_ms"])
-                              + rule["xla_ms"]))
+                              + rule["xla_ms"]),
+            cfg["SA_S"], cfg["SA_H"], cfg["SA_G"], cfg["SA_D"], cfg["SA_J"],
+            cfg["SA_DI"], cfg["SA_K"], sparse["path"], sparse["sound"],
+            sparse["rounded"], sparse["kl"], sparse["gradients"],
+            "" if sparse["forward_ms"] is None else
+            ", forward %.1f ms, forward + backward %.1f ms in bfloat16" % (
+                sparse["forward_ms"], sparse["both_ms"]))
 
 
 def build_resnet():

@@ -68,10 +68,12 @@ def download(url, path=None, overwrite=False, sha1_hash=None, retries=5,
 
 
 
-def recompute(block, *args):
+def recompute(block, *args, policy=None):
     """``block(*args)`` with its forward recomputed in the backward pass
     (``jax.checkpoint`` around this one block): a gradient through it keeps
-    the block's inputs and none of what it computed. Around each layer of a
+    the block's inputs and none of what it computed (but what ``policy``, a
+    ``jax.checkpoint_policies`` rule, says to keep: a named result that is
+    dear to compute again). Around each layer of a
     stack, the activations held for the backward are one input a layer plus
     one layer's working set, where ``TrainStep(remat=True)`` checkpoints the
     whole forward at once and lowers nothing at the peak. A block that
@@ -113,4 +115,4 @@ def recompute(block, *args):
         return out._data
 
     import jax
-    return _apply(jax.checkpoint(pure), *args, *arrs)
+    return _apply(jax.checkpoint(pure, policy=policy), *args, *arrs)

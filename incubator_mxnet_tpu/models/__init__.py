@@ -15,6 +15,10 @@
               decay per channel; gated grouped-query attention without
               position embedding; sigmoid-routed SwiGLU experts beside a
               shared expert), whole or as one chip's share
+- keye_vl2:   Keye-VL 2.0's decoder (grouped-query attention over the keys a
+              lightning indexer picks, the indexer's own KL loss, rotary
+              positions from three streams, softmax-routed SwiGLU experts),
+              whole or as one chip's share of its experts
 """
 from .lenet import LeNet  # noqa
 from .bert import (BERTEncoder, BERTModel, TransformerEncoderLayer,  # noqa
@@ -30,6 +34,8 @@ from .phi4flash import (Phi4FlashModel, SambaYLayer, Mamba1Mixer,  # noqa
 from .solar_open2 import (SolarOpen2Model, SolarOpen2Layer,  # noqa
                           KimiDeltaAttention, GatedGroupedQueryAttention,
                           SharedExpertMoE)
+from .keye_vl2 import (KeyeVL2Model, KeyeVL2Layer,  # noqa
+                       SparseGroupedQueryAttention)
 from .lstm_lm import LSTMLanguageModel  # noqa
 from .ssd import SSD  # noqa
 from ..gluon.model_zoo.vision import get_model  # noqa

@@ -17,7 +17,10 @@ class ChunkedHeadLossBase:
     """Loss head fusing a (V, U) vocab projection with the CHUNKED
     softmax-CE (ops/lm_ce.py): the full (T, V) logits never materialize
     (what the chunking costs and saves on a v5e: PERF.md S6, PR 28). Pair
-    with ``FeaturesView(model)`` so TrainStep feeds the trunk activations."""
+    with ``FeaturesView(model)`` so TrainStep feeds the trunk activations.
+    A model whose layers have losses of their own (an indexer's KL, a
+    router's balancing loss) hands ``forward`` (hidden, their sum a sample
+    (B,)), and the sum is added to the head's loss."""
 
     def __init__(self, model, chunk=None):
         # chunk=None auto-routes (ops/lm_ce.py): dense below 128 MiB of
@@ -32,6 +35,9 @@ class ChunkedHeadLossBase:
     def forward(self, hidden, labels):
         from ..ops.lm_ce import chunked_lm_cross_entropy
         w, b = self._head_params()
+        own = None
+        if isinstance(hidden, (tuple, list)):
+            hidden, own = hidden
 
         def fn(h, w, y, b=None):
             losses = chunked_lm_cross_entropy(h, w, y, self._chunk,
@@ -40,8 +46,10 @@ class ChunkedHeadLossBase:
             return losses.reshape(losses.shape[0], -1).mean(axis=1)
 
         if b is None:
-            return _apply(fn, hidden, w, labels)
-        return _apply(lambda h, w, b, y: fn(h, w, y, b), hidden, w, b,
-                      labels)
+            loss = _apply(fn, hidden, w, labels)
+        else:
+            loss = _apply(lambda h, w, b, y: fn(h, w, y, b), hidden, w, b,
+                          labels)
+        return loss if own is None else loss + own
 
     __call__ = forward

@@ -191,3 +191,28 @@ def test_the_delta_rule_alone_reads_between_a_float32_and_a_bfloat16_state(
     assert read["rounded"] > chip_smoke.DELTA_RULE_ALONE_LIMIT * 5
     assert read["forward_ms"] is None and read["both_ms"] is None \
         and read["xla_ms"] is None
+
+
+@pytest.mark.parametrize("path", ["xla_masked_strips",
+                                  "pallas_masked_strips"])
+def test_sparse_attention_alone_reads_under_its_limits_and_a_rounded_choice_over(
+        monkeypatch, path):
+    """The same for sparse attention alone at the rehearsal shape, on the
+    plain strips (nothing set: no kernel runs off the TPU) and on the five
+    kernels interpreted (what `--rehearse` runs): outputs, the KL and the
+    six gradients in float32 are under the hybrid phase's limits
+    (summation order: 2e-7 and 8e-7 here), the reference choosing its keys
+    on scores rounded to bfloat16 is over the outputs' (8e-2 here); off the
+    chip it reports no milliseconds."""
+    if path.startswith("pallas"):
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    read = chip_smoke.sparse_attention_alone(chip_smoke.TOY["hybrid"], False)
+    limits = chip_smoke.SPARSE_ALONE_LIMITS
+    assert read["path"] == path
+    assert read["sound"] < limits["outputs"] / 10
+    assert read["kl"] < limits["outputs"] / 10
+    assert read["gradients"] < limits["gradients"] / 10
+    assert read["rounded"] > limits["outputs"] * 5
+    assert read["forward_ms"] is None and read["both_ms"] is None

@@ -308,3 +308,49 @@ def test_bert_chunked_mlm_loss_matches_dense_and_trains():
     assert l1 < l0
     after = bert.mlm_decoder.bias.data().asnumpy()
     assert onp.abs(after - before).max() > 1e-6  # bias got gradients
+
+
+@pytest.mark.parametrize("head", ["tied", "untied"])
+def test_a_models_own_loss_is_added_to_the_heads(head):
+    """A trunk that hands out (hidden, its layers' own loss a sample (B,)):
+    the head's loss is the cross-entropy of the hidden states plus that
+    term, sample by sample; through TrainStep the term's gradient reaches
+    the weights it depends on and the reported loss is the sum."""
+    mx.random.seed(0)
+    V, U, S, B = 64, 16, 32, 2
+    gpt = models.GPTModel(vocab_size=V, units=U, num_layers=1, num_heads=2,
+                          max_length=S, attention="dense")
+    gpt.initialize(mx.init.Xavier())
+    tokens = nd.array(onp.random.RandomState(1).randint(0, V, (B, S))
+                      .astype("int32"))
+    if head == "tied":
+        loss_fn = models.ChunkedLMLoss(gpt, chunk=16)
+    else:
+        gpt.lm_head = gluon.nn.Dense(V, flatten=False, in_units=U,
+                                     use_bias=False)
+        gpt.lm_head.initialize(mx.init.Xavier())
+        loss_fn = models.ChunkedUntiedLMLoss(gpt, chunk=16)
+    hidden = gpt.features(tokens)
+    plain = loss_fn(hidden, tokens).asnumpy()
+    own = nd.array(onp.array([0.25, 1.5], "float32"))
+    onp.testing.assert_allclose(loss_fn((hidden, own), tokens).asnumpy(),
+                                plain + own.asnumpy(), rtol=1e-6)
+
+    class WithOwnLoss(gluon.HybridBlock):
+        """hidden, and the mean square of the hidden states a sample"""
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.model = gpt
+
+        def forward(self, ids):
+            h = gpt.features(ids)
+            return h, (h * h).mean(axis=(1, 2))
+
+    view = WithOwnLoss()
+    h = gpt.features(tokens)
+    want = plain + (h * h).mean(axis=(1, 2)).asnumpy()
+    tr = gluon.Trainer(view.collect_params(), "sgd", {"learning_rate": 0.1})
+    step = jit.TrainStep(view, loss_fn, tr)
+    onp.testing.assert_allclose(step(tokens, tokens).asnumpy(), want,
+                                rtol=1e-5)

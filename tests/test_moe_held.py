@@ -439,6 +439,60 @@ def test_layer_with_a_share_against_the_dense_form_and_routes_apart():
         parallel.MoELayer(E, D, H, router="tanh")
 
 
+@pytest.mark.parametrize("norm", [True, False],
+                         ids=["renormalised", "as-scored"])
+@pytest.mark.parametrize("shares", [8, 2])
+def test_softmax_routed_shares_add_up_to_the_uncut_layer(shares, norm):
+    """`held=` under `router="softmax"` (the Keye share: SwiGLU experts,
+    weights renormalised over the k chosen): 16 experts over 8 chips, 2
+    each (and over 2, 8 each). Every share routes over all 16 alike and
+    computes its own experts' part; the parts add up to the uncut layer's
+    output (`held=None`, the dispatch OLMoE's cell runs) and each is the
+    dense form's same part. The renormalisation is over all k chosen,
+    wherever they are held."""
+    experts, k = 16, 4
+    count = experts // shares
+    whole = parallel.MoELayer(experts, D, H, top_k=k, router="softmax",
+                              activation="silu", gated=True,
+                              norm_topk_prob=norm)
+    whole.initialize()
+    x = jnp.asarray(onp.random.default_rng(8).standard_normal(
+        (2, T // 2, D)), jnp.float32)
+    names = ("w1", "w2", "w3")
+    full = {n: getattr(whole, n).data()._data for n in names}
+    gw = whole.gate_weight.data()._data
+    with jax.default_matmul_precision("highest"):
+        want = whole(nd.NDArray(x))._data
+        _, gates, vals, idx = whole.route(x.reshape(T, D), gw)
+        picked = jnp.take_along_axis(gates, idx, -1)
+        onp.testing.assert_allclose(
+            vals, picked / picked.sum(-1, keepdims=True) if norm else picked,
+            rtol=1e-6)
+        total, loads = 0, []
+        for first in range(0, experts, count):
+            share = parallel.MoELayer(
+                experts, D, H, top_k=k, router="softmax", activation="silu",
+                gated=True, norm_topk_prob=norm, held=(first, count))
+            share.initialize()
+            assert share.w1.shape == (count, D, H)
+            assert not hasattr(share, "router_bias") \
+                or "router_bias" not in share.collect_params()
+            share.gate_weight.set_data(nd.NDArray(gw))
+            for n in names:
+                getattr(share, n).set_data(
+                    nd.NDArray(full[n][first:first + count]))
+            got = share(nd.NDArray(x))._data
+            part = dense_moe(
+                x.reshape(T, D), _only(vals, idx, first, count), idx,
+                full["w3"], full["w2"], moe._ACTIVATIONS["silu"],
+                full["w1"]).reshape(x.shape)
+            onp.testing.assert_allclose(got, part, rtol=1e-5, atol=1e-5)
+            total = total + got
+            loads.append(int(((idx >= first) & (idx < first + count)).sum()))
+    onp.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-5)
+    assert sum(loads) == T * k and min(loads) > 0
+
+
 @pytest.mark.parametrize("held", [None, (4, 4)])
 def test_the_balancing_rule_hands_out_the_bias_it_moves_to(held):
     """`bias_rate`: forward gives (y, b + rate ln(even load / load)) over
