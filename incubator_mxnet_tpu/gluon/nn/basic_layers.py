@@ -81,7 +81,16 @@ class HybridSequential(HybridBlock):
 
 
 class Dense(HybridBlock):
-    """Fully-connected layer (ref basic_layers.py Dense → nn/fully_connected.cc)."""
+    """Fully-connected layer (ref basic_layers.py Dense → nn/fully_connected.cc).
+
+    ``flatten=False`` applies the layer to the last axis of an input of
+    any rank. On the TPU a (B, S, U) input at B > 1 makes the matmul, and
+    above all the weight gradient of a (U, 4 U) layer, a convolution with
+    the batch as a window dimension, at up to twice the time of the same
+    matmul over (B x S, U). A reshape in here alone does not cure it (XLA
+    moves it through the neighbouring element-wise ops until the pair
+    meets): the block that cares carries (tokens, channels) across its
+    whole MLP half, `models.bert.mlp_tokens` (PERF.md section 6, PR 42)."""
 
     def __init__(self, units, activation=None, use_bias=True, flatten=True,
                  dtype="float32", weight_initializer=None, bias_initializer="zeros",

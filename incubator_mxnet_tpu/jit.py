@@ -323,22 +323,14 @@ class TrainStep:
         VMEM budget, an HBM OOM) gets hidden."""
         step_fn, layout, trainable, t_arrs, f_arrs, aux_box = \
             self._build(n_inputs)
-        t_sh, f_sh, state_rules, data_sh, repl = layout
-        trainer = self.trainer
-        slots = [trainer._param2idx.get(p.name, i)
-                 for i, p in enumerate(trainable)]
+        data_sh, repl = layout[3:]
         # the lay-out writes, and the trace swaps tracers into, the live
         # param NDArrays (inner's _data swap) — hold the net's trace lock
         # for the whole window, exactly like the eval build
         with self._trace_lock:
             with spans.span("train:layout"):
-                state = ([a._data for a in t_arrs],
-                         [a._data for a in f_arrs],
-                         [_tree_to_data(trainer._states[idx])
-                          for idx in slots])
-                state_sh = t_sh + f_sh + [
-                    rule(leaf) for st, rule in zip(state[2], state_rules)
-                    for leaf in jax.tree_util.tree_leaves(st)]
+                slots, state, state_sh = self._state(
+                    layout, trainable, t_arrs, f_arrs)
                 specs = self._arg_specs(state, arrs, key, state_sh, data_sh,
                                         repl)
                 # rebound, so that nothing holds what was there before: the
@@ -355,6 +347,48 @@ class TrainStep:
         return compiled, (slots, t_arrs, f_arrs, aux_box, state_sh,
                           data_sh), None
 
+    def _state(self, layout, trainable, t_arrs, f_arrs):
+        """-> (each trainable parameter's slot in ``trainer._states``;
+        (parameters, frozen arrays, optimizer states) as one step_fn call
+        takes them; the layout's sharding of every leaf of those)."""
+        t_sh, f_sh, state_rules = layout[:3]
+        trainer = self.trainer
+        slots = [trainer._param2idx.get(p.name, i)
+                 for i, p in enumerate(trainable)]
+        state = ([a._data for a in t_arrs], [a._data for a in f_arrs],
+                 [_tree_to_data(trainer._states[idx]) for idx in slots])
+        return slots, state, t_sh + f_sh + [
+            rule(leaf) for st, rule in zip(state[2], state_rules)
+            for leaf in jax.tree_util.tree_leaves(st)]
+
+    def lower(self, *inputs, n_net_inputs=1, sharding=None):
+        """The step's program for ``inputs`` (NDArrays, or anything with
+        a shape and a dtype: `jax.ShapeDtypeStruct`s), lowered and not
+        compiled: nothing is laid out, cached or run, and ``.compile()``
+        gives the program a first call would build. ``sharding`` replaces
+        every sharding of the layout: the `SingleDeviceSharding` of a
+        DESCRIBED device (`jax.experimental.topologies`) compiles the
+        step for a chip that is not there
+        (tests/test_kernels_compile_v5e.py)."""
+        trainer = self.trainer
+        if not trainer._kv_initialized:
+            trainer._init_kvstore()
+        if not trainer._states_initialized:
+            trainer._init_states()
+        step_fn, layout, trainable, t_arrs, f_arrs, _ = self._build(
+            n_net_inputs)
+        _, state, state_sh = self._state(layout, trainable, t_arrs, f_arrs)
+        data_sh, repl = layout[3:]
+        if sharding is not None:
+            state_sh = [sharding] * len(state_sh)
+            data_sh = repl = sharding
+        specs = self._arg_specs(
+            state, inputs, jax.ShapeDtypeStruct((2,), jnp.uint32), state_sh,
+            data_sh, repl)
+        with self._trace_lock:
+            return jax.jit(step_fn,
+                           donate_argnums=_donate((0, 2))).lower(*specs)
+
     def _arg_specs(self, state, arrs, key, state_sh, data_sh, repl):
         """jax.ShapeDtypeStruct tree matching one step_fn call, every
         leaf with its sharding of the layout (None without a mesh) —
@@ -368,7 +402,8 @@ class TrainStep:
         t_specs, f_specs, opt_specs = _with_layout(sds, state, state_sh)
         vec = scalar((len(t_specs),), jnp.float32)
         return (t_specs, f_specs, opt_specs,
-                [sds(a._data, data_sh) for a in arrs], sds(key, repl),
+                [sds(getattr(a, "_data", a), data_sh) for a in arrs],
+                sds(key, repl),
                 vec, vec, scalar((), jnp.int32), scalar((), jnp.float32))
 
     def _write_back(self, p_arrs, slots, new_p, new_opt):

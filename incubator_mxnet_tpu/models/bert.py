@@ -16,10 +16,62 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from .. import ndarray as nd
+from .. import telemetry
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray import NDArray, _apply
 from .lm_head import ChunkedHeadLossBase
+
+
+_TRUNK_BLOCKS = telemetry.counter(
+    "mxtpu_trunk_block_total",
+    "Transformer blocks traced, by the form their MLP half's activations "
+    "have (tokens_2d: B > 1 sequences carried as (B x S, U) from the half's "
+    "entry to its exit, so its matmuls are the two-dimensional programs XLA "
+    "makes at B = 1; batch_1: one sequence, nothing to flatten; "
+    "batch_seq_3d: kept (B, S, U) because a mesh train step is tracing the "
+    "block. That is a rule on the MESH, whatever the step does with its "
+    "optimizer state: the one mesh step measured, dp 4 under ZeRO-1 on "
+    "four v5e chips, ran 1.75 % faster so; a mesh step without ZeRO is "
+    "held to it unmeasured).", ("form",))
+
+
+def mlp_tokens(x):
+    """The entry of a transformer block's MLP half: x (B, S, U) -> x as
+    the half carries it, (B x S, U) at B > 1. The caller reshapes the
+    half's output back to ``x.shape``.
+
+    At B > 1 XLA's TPU compiler makes a (B, S, U) matmul a convolution
+    with the batch as a window dimension and lays activations out with
+    the SEQUENCE minor-most; the (U, 4 U) weight gradients with their
+    Adam update fused behind, the forward matmuls with GELU, bias and the
+    norm's statistics inside, and the input gradients then take up to
+    twice the time of the same matmuls over (B x S, U) (PERF.md section 6,
+    PR 42). A reshape inside `nn.Dense` alone is undone (XLA moves it
+    through the element-wise ops until the pair meets), so the rank-2
+    form spans the half: fc1, the activation, fc2, their biases and all
+    their gradients see (tokens, channels). Rows keep their order and no
+    value changes. The ATTENTION half stays (B, S, U): its four (U, U)
+    weight gradients are the faster as they are (measured, same section),
+    and its heads need (B, S) apart anyway.
+
+    One thing a block can observe keeps the 3-D form at B > 1: a mesh
+    train step tracing it (`parallel.mesh.step_mesh()`). The rule is on
+    the mesh and not on what caused the reading: on four chips under
+    ZeRO-1, where the Adam update is not fused behind the weight
+    gradient, the 2-D form measured 1.75 % SLOWER; a mesh step without
+    ZeRO keeps the update in the fusion and may be the faster 2-D, and
+    is held to 3-D until someone measures it (ROADMAP, S9). Counts the
+    form, once a traced block (`mxtpu_trunk_block_total{form}`)."""
+    from ..parallel.mesh import step_mesh
+    if x.shape[0] == 1:
+        form = "batch_1"
+    elif step_mesh() is not None:
+        form = "batch_seq_3d"
+    else:
+        form = "tokens_2d"
+    _TRUNK_BLOCKS.inc(form=form)
+    return x.reshape((-1, x.shape[-1])) if form == "tokens_2d" else x
 
 
 class MultiHeadAttention(HybridBlock):
@@ -27,7 +79,15 @@ class MultiHeadAttention(HybridBlock):
     key-value head serves num_heads / num_kv_heads query heads);
     ``head_dim`` is the heads' size where it is not units / num_heads, so
     the q, k, v widths may differ from the input's. Both default to the
-    multi-head layout."""
+    multi-head layout.
+
+    Ranks: ``forward``, ``heads_output`` and ``project`` take x as
+    (B, S, U); ``forward`` returns (B, S, U), ``heads_output``
+    (B, S, H * D), ``project`` and ``split_heads`` (B, H, S, D). The
+    block around it flattens its MLP half to (B x S, U) (`mlp_tokens`)
+    and NOT this half: the heads need B and S apart, and the four
+    projections' weight gradients are (U, U), where XLA's batched form is
+    the faster one on a v5e (PERF.md section 6, PR 42)."""
 
     def __init__(self, units, num_heads, dropout=0.0, attention="dense",
                  sp_axis="sp", tp_axis=None, causal=False, use_bias=True,
@@ -144,6 +204,12 @@ class MultiHeadAttention(HybridBlock):
 
 
 class TransformerEncoderLayer(HybridBlock):
+    """Post-norm encoder block: ln(x + attn(x)); ln(x + ffn(x)).
+    (B, S, U) in, (B, S, U) out, ``mask`` as `MultiHeadAttention` takes
+    it. The attention half and both norms are (B, S, U); the MLP half
+    (ffn1, GELU, ffn2) is (B x S, U) inside at B > 1 (`mlp_tokens`, which
+    says why), reshaped back before the residual add."""
+
     def __init__(self, units, hidden_size, num_heads, dropout=0.1,
                  attention="dense", tp_axis=None, sp_axis="sp", **kwargs):
         super().__init__(**kwargs)
@@ -166,7 +232,9 @@ class TransformerEncoderLayer(HybridBlock):
             h = self.dropout_layer(h)
         x = self.ln1(x + h)
         with jax.named_scope("ffn"):
-            h = self.ffn2(nd.LeakyReLU(self.ffn1(x), act_type="gelu"))
+            h = mlp_tokens(x)
+            h = self.ffn2(nd.LeakyReLU(self.ffn1(h), act_type="gelu"))
+            h = h.reshape(x.shape)
         if self.dropout_layer:
             h = self.dropout_layer(h)
         return self.ln2(x + h)

@@ -19,14 +19,18 @@ from .. import ndarray as nd
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray import _apply
-from .bert import MultiHeadAttention
+from .bert import MultiHeadAttention, mlp_tokens
 from .lm_head import ChunkedHeadLossBase
 
 __all__ = ["GPTModel", "TransformerDecoderLayer"]
 
 
 class TransformerDecoderLayer(HybridBlock):
-    """Pre-norm decoder block: x + attn(ln(x)); x + ffn(ln(x))."""
+    """Pre-norm decoder block: x + attn(ln(x)); x + ffn(ln(x)).
+    (B, S, U) in, (B, S, U) out. The attention half and both norms are
+    (B, S, U); the MLP half (fc1, GELU, fc2) is (B x S, U) inside at
+    B > 1 (`models.bert.mlp_tokens`, which says why), reshaped back
+    before the residual add."""
 
     def __init__(self, units, hidden_size, num_heads, attention="flash",
                  tp_axis=None, sp_axis="sp", **kwargs):
@@ -49,7 +53,9 @@ class TransformerDecoderLayer(HybridBlock):
         x = x + self.attn(self.ln1(x))
         h = self.ln2(x)
         with jax.named_scope("ffn"):
+            h = mlp_tokens(h)
             h = self.fc2(nd.LeakyReLU(self.fc1(h), act_type="gelu"))
+            h = h.reshape(x.shape)
         return x + h
 
 

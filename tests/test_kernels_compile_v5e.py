@@ -6,6 +6,7 @@ a time. The topology is described inside a fixture and only here: one
 process loads the TPU's library at a time, and every xdist worker imports
 every test file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,3 +64,54 @@ def test_the_delta_rule_backward_compiles_at_the_cells_shape(one_chip):
         a, starts, do, H, CHUNK, False)).lower(starts, args[2], *args) \
         .compile()
     assert "delta_rule_bwd" in compiled.as_text()
+
+
+#: the Cerebras cells' widths (perfbench/configs/cerebras-gpt-1.3b.json)
+UNITS, INNER, HEADS = 2048, 8192, 16
+_UPDATE = re.compile(
+    r"= \(bf16\[(\d+),(\d+)\]\S*, f32\[\1,\2\]\S*, f32\[\1,\2\]\S*, "
+    r"f32\[\1,\2\]\S*\) fusion\(.*\"iteration_bounds\":\[([^\]]*)\]")
+
+
+@pytest.mark.parametrize("batch, seq", [(8, 2048), (1, 16384)],
+                         ids=["8x2048", "1x16384"])
+def test_a_decoder_blocks_mlp_weight_updates_are_2d_matmuls_at_any_batch(
+        one_chip, monkeypatch, batch, seq):
+    """Two `TransformerDecoderLayer`s through `jit.TrainStep` with
+    multi-precision Adam, the streamed kernels real: fc1's and fc2's
+    weight gradients with their Adam update fused behind are matmuls over
+    (tokens, channels), three `iteration_bounds`, at 8 x 2048 as at
+    1 x 16384 (with the batch a window dimension of a convolution there
+    were five), and nothing (8, 2048, 8192) exists. The attention half is
+    left as XLA makes it (PERF.md section 6, PR 42)."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import gluon, jit, models
+    from incubator_mxnet_tpu.gluon import nn
+    from incubator_mxnet_tpu.ops import attention
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    mx.random.seed(1)
+    net = nn.HybridSequential()
+    for _ in range(2):
+        net.add(models.TransformerDecoderLayer(UNITS, INNER, HEADS,
+                                               attention="flash"))
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    trainer = gluon.Trainer(net.collect_params(), "adam",
+                            {"learning_rate": 1e-4, "multi_precision": True})
+    step = jit.TrainStep(net, gluon.loss.L2Loss(), trainer)
+    # the attention asks where it runs: on the described chip
+    monkeypatch.setattr(attention, "_kernels_run_here", lambda: True)
+    x = jax.ShapeDtypeStruct((batch, seq, UNITS), jnp.bfloat16)
+    text = step.lower(x, x, sharding=one_chip).compile().as_text()
+    assert text.count("tpu_custom_call") >= 4
+    bounds = {}
+    for line in text.splitlines():
+        m = _UPDATE.search(line)
+        if m:
+            bounds.setdefault(m.group(1, 2), []).append(
+                len(m.group(3).split(",")))
+    # q, k, v, o and fc1, fc2 of two layers
+    assert {k: len(v) for k, v in bounds.items()} == {
+        ("2048", "2048"): 8, ("8192", "2048"): 2, ("2048", "8192"): 2}
+    assert bounds["8192", "2048"] + bounds["2048", "8192"] == [3] * 4
+    assert "[%d,%d,%d]" % (8, 2048, INNER) not in text
