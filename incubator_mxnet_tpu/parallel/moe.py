@@ -423,6 +423,37 @@ _ACTIVATIONS = {"relu": jax.nn.relu, "gelu": jax.nn.gelu,
 _ROUTERS = ("softmax", "sigmoid_bias")
 
 
+def _is_chosen(top_idx, num_experts):
+    """(T, k) expert ids -> (T, k, E) bool, True at [t, j, top_idx[t, j]]."""
+    return top_idx[..., None] == jnp.arange(num_experts, dtype=top_idx.dtype)
+
+
+# The chosen experts' scores, read out of `gates` by comparing expert ids:
+# what `jnp.take_along_axis(gates, top_idx, -1)` and its transpose, a
+# scatter-add into (T, E), give to the bit, because a row's ids are distinct
+# and so each reduction below has ONE term that counts. Elementwise passes
+# with a reduction, fused; XLA's gather and scatter step an element at a
+# time (1.6 ms for 8192 x 22 of 512, fifteen times a Nemotron step). The
+# value is a maximum and not a sum so that no sum over k behind it (the
+# renormalisation's) can be folded into it and take another order.
+@jax.custom_jvp
+def _chosen_scores(gates, top_idx):
+    """gates (T, E), top_idx (T, k) distinct a row -> (T, k):
+    gates[t, top_idx[t, j]]."""
+    return jnp.max(jnp.where(_is_chosen(top_idx, gates.shape[-1]),
+                             gates[:, None, :], -jnp.inf), -1)
+
+
+@_chosen_scores.defjvp
+def _chosen_scores_jvp(primals, tangents):
+    gates, top_idx = primals
+    # linear in d gates; its transpose, which autodiff derives, is
+    # d gates[t, e] = sum_j where(chosen, d top_vals[t, j], 0)
+    return _chosen_scores(gates, top_idx), jnp.sum(
+        jnp.where(_is_chosen(top_idx, gates.shape[-1]),
+                  tangents[0][:, None, :], 0.0), -1)
+
+
 class MoELayer(HybridBlock):
     """Top-k routed expert FFN, dropless: y = sum_k g_k * FFN_{e_k}(x).
 
@@ -542,7 +573,7 @@ class MoELayer(HybridBlock):
             gates = jax.nn.sigmoid(logits)
             _, top_idx = jax.lax.top_k(gates + bias.astype(jnp.float32),
                                        self.top_k)
-            top_vals = jnp.take_along_axis(gates, top_idx, -1)
+            top_vals = _chosen_scores(gates, top_idx)
             if self.norm_topk_prob:
                 top_vals = top_vals / (jnp.sum(top_vals, -1, keepdims=True)
                                        + 1e-20)

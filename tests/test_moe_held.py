@@ -2,6 +2,8 @@
 `dropless_moe_held`) and the sigmoid router with its selection bias,
 against the dense form (tests/moe_dense.py); and that `held=None` is the
 path OLMoE's cell runs, untouched."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -91,13 +93,19 @@ def test_window_rows_come_from_the_shapes(shape, rows):
     assert moe.held_window_rows(*shape) == rows
 
 
-def _eqns(jaxpr):
+def _scoped_eqns(jaxpr, outer=""):
     """Every equation of a jaxpr, those of its loops' and calls' bodies
-    among them."""
+    among them, each with the named scopes it was traced under (a body's
+    equations are under their call's scopes too)."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        path = outer + "/" + str(eqn.source_info.name_stack)
+        yield eqn, path
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
+            yield from _scoped_eqns(sub, path)
+
+
+def _eqns(jaxpr):
+    return (eqn for eqn, _ in _scoped_eqns(jaxpr))
 
 
 def _row_counts(jaxpr, widths):
@@ -582,3 +590,162 @@ def test_the_held_path_has_its_own_counter():
     # the rows of the buffers as traced: here the worst case is the window
     assert 'mxtpu_moe_held_rows{kind="window"} %d' % (T * 2) in text
     assert 'mxtpu_moe_held_rows{kind="worst_case"} %d' % (T * 2) in text
+
+
+# ---- the sigmoid router's chosen scores, read by comparison (PR 43) ----
+
+def _router_case(experts, dtype):
+    """A `sigmoid_bias` router over `experts` with a bias that changes the
+    choice: tokens, gate weight (both `dtype`) and the float32 bias."""
+    rng = onp.random.default_rng(11)
+    n_tokens, units = 64, 32
+    tokens = jnp.asarray(rng.standard_normal((n_tokens, units)), dtype)
+    gw = jnp.asarray(rng.standard_normal((experts, units)) / 4, dtype)
+    bias = jnp.asarray(rng.standard_normal(experts) / 2, jnp.float32)
+    return tokens, gw, bias
+
+
+def _gathered_route(k, norm, scale, tokens, gw, bias):
+    """`MoELayer.route`'s `sigmoid_bias` branch as it stood up to PR 42:
+    the chosen scores by `take_along_axis` (an XLA gather; its transpose
+    a scatter-add into the scores)."""
+    logits = jnp.einsum("td,ed->te", tokens, gw,
+                        preferred_element_type=jnp.float32)
+    gates = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(gates + bias.astype(jnp.float32), k)
+    top_vals = jnp.take_along_axis(gates, top_idx, -1)
+    if norm:
+        top_vals = top_vals / (jnp.sum(top_vals, -1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        top_vals = top_vals * scale
+    return logits, gates, top_vals, top_idx
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("norm", [True, False],
+                         ids=["renormalised", "as-scored"])
+@pytest.mark.parametrize("experts, k, scale", [(512, 22, 5.0), (320, 8, 1.0)],
+                         ids=["nemotron-512-top22", "solar-320-top8"])
+def test_chosen_scores_by_comparison_are_the_gathered_ones_to_the_bit(
+        experts, k, scale, norm, dtype):
+    """`route` reads the chosen experts' scores out of `gates` by comparing
+    expert ids. Against the gather written out above: the weights, the
+    indices, and the gradients of a scalar of the weights into the
+    router's two inputs are the same bits (one non-zero term a sum, forward
+    and transposed), compiled as the step compiles them."""
+    layer = parallel.MoELayer(experts, 32, H, top_k=k, router="sigmoid_bias",
+                              norm_topk_prob=norm, scale=scale)
+    tokens, gw, bias = _router_case(experts, dtype)
+    # each chosen score weighed differently, so a misplaced one shows
+    weigh = jnp.asarray(onp.random.default_rng(12).standard_normal(
+        (tokens.shape[0], k)), jnp.float32)
+
+    def scalar(route):
+        def value(tokens, gw):
+            _, _, top_vals, top_idx = route(tokens, gw, bias)
+            return jnp.sum(top_vals * weigh), (top_vals, top_idx)
+        return jax.jit(jax.value_and_grad(value, (0, 1), has_aux=True))
+
+    (got, (vals, idx)), grads = scalar(layer.route)(tokens, gw)
+    (want, (want_vals, want_idx)), want_grads = scalar(
+        lambda *a: _gathered_route(k, norm, scale, *a))(tokens, gw)
+    # the bias chooses: not the k largest scores
+    plain = layer.route(tokens, gw, jnp.zeros_like(bias))[3]
+    assert not onp.array_equal(idx, plain)
+    assert onp.array_equal(idx, want_idx)
+    assert vals.dtype == jnp.float32 and onp.array_equal(vals, want_vals)
+    assert onp.array_equal(got, want)
+    for g, w in zip(grads, want_grads):
+        assert g.dtype == w.dtype == dtype
+        assert float(jnp.max(jnp.abs(w.astype(jnp.float32)))) > 0
+        assert onp.array_equal(g, w)
+
+
+def _scoped_primitives(jaxpr, scope):
+    """The primitives of every equation traced under the named scope
+    `scope` (its transposes and recomputed copies keep the name)."""
+    return {eqn.primitive.name for eqn, path in _scoped_eqns(jaxpr)
+            if scope in path}
+
+
+def _held_sigmoid_layer():
+    """-> (value and gradient of a held `sigmoid_bias` layer with the
+    balancing rule, its arguments)."""
+    layer = parallel.MoELayer(32, D, H, top_k=4, router="sigmoid_bias",
+                              activation="relu2", scale=2.0, held=(8, 4),
+                              bias_rate=0.01)
+    layer.initialize()
+    names = ("x",) + layer._weight_names()
+    x = jnp.asarray(onp.random.default_rng(13).standard_normal((2, T, D)),
+                    jnp.float32)
+    weights = [w._data for w in layer._weights()]
+
+    def value(x, *weights):
+        out, moved = layer._fn(dict(zip(names, (x,) + weights)), False)
+        return jnp.sum(out * out), moved
+
+    trained = tuple(i for i, n in enumerate(names) if n != "router_bias")
+    return jax.value_and_grad(value, trained, has_aux=True), (x, *weights)
+
+
+def test_a_sigmoid_routed_step_holds_no_gather_or_scatter_of_the_routers(
+        monkeypatch):
+    """Value and gradient of a held `sigmoid_bias` layer with the balancing
+    rule: nothing traced under `router` is a gather or a scatter, in the
+    jaxpr and in the compiled module; the held dispatch's own moves between
+    tokens and rows stay, under `moe_dispatch` / `moe_combine`. With the
+    gather written back into `route` the same reading finds both, so it
+    can see what it says is gone."""
+    moves = {"gather", "scatter", "scatter-add"}
+    fn, args = _held_sigmoid_layer()
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    router = _scoped_primitives(jaxpr, "router")
+    assert {"dot_general", "logistic", "top_k", "eq"} <= router
+    assert not router & moves
+    held = _scoped_primitives(jaxpr, "moe_dispatch") \
+        | _scoped_primitives(jaxpr, "moe_combine")
+    assert {"gather", "scatter-add"} <= held
+
+    def router_lines(fn):
+        """The compiled program's gathers and scatters whose `op_name`
+        passes through `router`."""
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        return [line for line in text.splitlines()
+                if re.search(r" (gather|scatter)\(", line)
+                and "router" in re.search(r'op_name="([^"]*)"', line).group(1)]
+
+    assert router_lines(fn) == []
+    monkeypatch.setattr(
+        parallel.MoELayer, "route",
+        lambda self, tokens, gw, bias: _gathered_route(
+            self.top_k, self.norm_topk_prob, self._scale, tokens, gw, bias))
+    old, _ = _held_sigmoid_layer()
+    assert {"gather", "scatter-add"} <= _scoped_primitives(
+        jax.make_jaxpr(old)(*args).jaxpr, "router")
+    assert len(router_lines(old)) >= 2
+
+
+@pytest.mark.parametrize("norm", [True, False],
+                         ids=["renormalised", "as-scored"])
+def test_the_softmax_routers_weights_are_top_ks_own_values(norm):
+    """The `softmax` branch (OLMoE's and Keye's cells) is the parent's,
+    equation for equation: its weights are `top_k`'s first output, read by
+    nothing, so nothing of the comparison reaches it."""
+    layer = _layer(router="softmax", norm_topk_prob=norm, held=(4, 4))
+
+    def parent(tokens, gw):
+        logits = jnp.einsum("td,ed->te", tokens, gw,
+                            preferred_element_type=jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)
+        top_vals, top_idx = jax.lax.top_k(gates, K)
+        if norm:
+            top_vals = top_vals / jnp.sum(top_vals, -1, keepdims=True)
+        return logits, gates, top_vals, top_idx
+
+    tokens = jnp.ones((T, D), jnp.bfloat16)
+    gw = layer.gate_weight.data()._data.astype(jnp.bfloat16)
+    mine = jax.make_jaxpr(layer.route)(tokens, gw)
+    assert str(mine) == str(jax.make_jaxpr(parent)(tokens, gw))
+    names = {e.primitive.name for e in _eqns(mine.jaxpr)}
+    assert "top_k" in names and not names & {"eq", "iota", "gather"}
