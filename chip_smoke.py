@@ -1,7 +1,7 @@
 """chip_smoke.py — the quickest proof that HEAD still starts on the chip.
 
 One process, no arguments: drives the system's main paths once, through the
-public package, at the full width of the models bench.py times — the Pallas
+public package, at full width (the sizes in FULL below) — the Pallas
 flash-attention kernels against a reference, the dropless expert dispatch
 against its dense form, BERT and a long-context GPT
 through gluon.Trainer -> jit.TrainStep with those kernels, one tiny
@@ -9,7 +9,7 @@ Nemotron-H share (chunked Mamba-2 scan, held expert dispatch, grouped-query
 attention, each layer recomputed), ResNet-50 training, ResNet-50 behind the HTTP
 server, the generative engine, and (on a host with >= 4 chips) the dp and
 dp x sp mesh steps — and checks what comes out. Weights are random from a
-seed; depth is the bench's. It measures nothing: the compile and step
+seed. It measures nothing: the compile and step
 seconds it prints are for information.
 
 It fails (non-zero exit, no result line) when JAX's default backend is not
@@ -40,7 +40,7 @@ import urllib.request
 PHASES = ("kernels", "moe", "bert", "gpt", "hybrid", "resnet", "serve",
           "generate", "multichip")
 
-# the bench's widths (bench.py bench_transformer / bench_long_context / main)
+# the sizes a run on the chip drives (TOY below: what --rehearse drives)
 FULL = {
     # (B, H, S, D), causal: S picks the block (512 as BERT, 1024 as GPT);
     # D = 64 below S = 2048 takes the short family (BERT-large's shape, and

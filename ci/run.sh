@@ -78,14 +78,13 @@
 #             tools/profsum.py must diff identical summaries empty
 #             while the injected-2x-op-time canary fires (S001 naming
 #             the op class) — the gate can still fire; wall budget 120s
-#   loadgen - open-loop load harness + perf regression gate: three
-#             interleaved CPU soak repeats (tools/loadgen.py: Poisson
-#             ramp over a timer-bound servable, per-stage p50/95/99,
-#             X-Request-Id span join, detected saturation point), then
-#             tools/perfgate.py aggregates per-metric minima across the
-#             repeats and HARD-FAILS outside PERF_BASELINE.json's
-#             tolerance bands — plus the injected-2x-regression canary
-#             proving the gate can still fire (docs/LOADGEN.md)
+#   loadgen - open-loop load harness: three CPU soak repeats
+#             (tools/loadgen.py: Poisson ramp over a timer-bound
+#             servable, per-stage p50/95/99, X-Request-Id span join,
+#             detected saturation point); each must end with zero hard
+#             errors and a saturation point, and the span join must
+#             cover the OK responses. Control flow only: a CPU wall
+#             time is compared with nothing (docs/LOADGEN.md)
 #   slo     - SLO engine e2e (telemetry/slo.py + the tenant wiring): a
 #             tenant-mixed loadgen soak against a servable with an
 #             injectable failure window proves the fast-burn alert
@@ -122,10 +121,10 @@
 #             transport (the stdlib HTTP front-end tops out an order of
 #             magnitude below 8 replica workers, so HTTP would measure
 #             the web server, not serving), saturation detected on BOTH
-#             ramps, per-replica dispatch balance asserted, and the
-#             goodput scaling ratio perfgate-compared against the
-#             committed sharded_goodput_scaling baseline — the hard
-#             >=3x 1->8 contract of the replica router (docs/SERVING.md)
+#             ramps, zero hard errors, and per-replica dispatch
+#             balance asserted (every replica served, none hogged);
+#             the 1->8 goodput ratio is printed, compared with nothing
+#             (docs/SERVING.md)
 #   chaos   - self-healing serving gate (telemetry/faultlab.py +
 #             serving/resilience.py, docs/RESILIENCE.md): the chaos unit
 #             tier (tests/test_resilience.py — deterministic fault
@@ -178,7 +177,7 @@ has_stage() { local s; for s in "${STAGES[@]}"; do [ "$s" = "$1" ] && return 0; 
 
 if has_stage lint; then
   echo "=== lint: syntax walk + mxtpulint gate (two-phase) ==="
-  python -m compileall -q incubator_mxnet_tpu tests tools benchmark bench.py chip_smoke.py __graft_entry__.py
+  python -m compileall -q incubator_mxnet_tpu tests tools chip_smoke.py __graft_entry__.py
   # Per-file rules R001-R008 + R012-R013 over the runtime (tools/ and tests/ under
   # the relaxed R003/R005/R006 profile) + the whole-program passes
   # (R009-R011, interprocedural R001); exits nonzero on any finding that
@@ -834,13 +833,10 @@ EOF
 fi
 
 if has_stage loadgen; then
-  echo "=== loadgen: open-loop soak + noise-robust perf gate ==="
-  # Three interleaved soak repeats against a TIMER-bound servable (fixed
-  # 5 ms per dispatched batch), so capacity — and therefore the detected
-  # saturation stage and stage-0 latency — is set by clocks, not by host
-  # speed: the committed PERF_BASELINE.json holds across machines.
-  # Co-tenant noise only ever inflates a repeat, so perfgate's
-  # per-metric minima across the repeats recover the clean numbers.
+  echo "=== loadgen: open-loop soak ==="
+  # Three soak repeats against a TIMER-bound servable (fixed 5 ms per
+  # dispatched batch), so capacity — and therefore the stage the ramp
+  # saturates in — is set by clocks, not by host speed.
   lg_t0=$SECONDS
   LG_DIR=$(mktemp -d -t mxtpu_loadgen.XXXXXX)
   JAX_PLATFORMS=cpu python - "$LG_DIR" <<'EOF'
@@ -851,8 +847,8 @@ from incubator_mxnet_tpu.serving import ModelRegistry, ServingServer
 class SlowEcho:
     """Deterministic capacity: 5 ms per dispatched batch of <= 8, which
     with the 2 ms gather window and worker cycle overhead puts the knee
-    at ~550 rps goodput on every machine (timer-bound, not host-bound —
-    the PERF_BASELINE.json loadgen_saturation_goodput_rps anchor)."""
+    inside the 100 -> 400 -> 2000 rps ramp on every machine (timer-bound,
+    not host-bound)."""
     def predict_batch(self, x):
         time.sleep(0.005)
         return (x,)
@@ -861,6 +857,7 @@ out_dir = sys.argv[1]
 reg = ModelRegistry()
 reg.load("soak", SlowEcho(), max_batch_size=8, batch_timeout_ms=2.0,
          queue_size=16)
+coverage = []
 with ServingServer(reg, port=0) as srv:
     for rep in range(3):
         tr = loadgen.HttpTransport(srv.url, "soak", [0.0, 0.0, 0.0, 0.0])
@@ -884,27 +881,13 @@ with ServingServer(reg, port=0) as srv:
                  report["gate_metrics"]["metrics"]
                  ["loadgen_join_coverage"]))
         assert ci["ok"], json.dumps(ci, indent=1)
+        coverage.append(report["gate_metrics"]["metrics"]
+                        ["loadgen_join_coverage"])
+# the X-Request-Id join found the OK responses' spans (in the best repeat)
+assert max(coverage) >= 0.65, coverage
 print("loadgen OK: 3 reports in %s (schema %s)"
       % (out_dir, loadgen.REPORT_SCHEMA))
 EOF
-  # the gate proper: minima across the repeats vs the committed baseline
-  # (same one-parser JSON shape as mxtpulint/promcheck/loadgen)
-  python tools/perfgate.py --input "$LG_DIR"/report_*.json \
-      --only 'loadgen_*' --json > "$LG_DIR/perfgate.json" \
-    || { python tools/perfgate.py --input "$LG_DIR"/report_*.json \
-           --only 'loadgen_*' || true
-         exit 1; }
-  python -c "import json,sys; r=json.load(open(sys.argv[1])); \
-print('perfgate OK: gate artifact %s' % sys.argv[1])" "$LG_DIR/perfgate.json"
-  # seeded-regression canary: a synthetic 2x latency regression MUST
-  # fail the same baseline, or the gate has silently stopped firing
-  if python tools/perfgate.py --input "$LG_DIR"/report_*.json \
-      --only 'loadgen_*' --selftest-inject 2.0 --json \
-      > "$LG_DIR/perfgate_inject.json"; then
-    echo "perfgate canary FAILED: injected 2x regression passed the gate"
-    exit 1
-  fi
-  echo "perfgate canary OK: injected 2x regression fires"
   lg_dt=$(( SECONDS - lg_t0 ))
   echo "loadgen stage wall time: ${lg_dt}s (budget 120s)"
   [ "$lg_dt" -lt 120 ] || { echo "loadgen stage took ${lg_dt}s (budget 120s)"; exit 1; }
@@ -1288,21 +1271,19 @@ EOF
 fi
 
 if has_stage sharded; then
-  echo "=== sharded: 1-vs-8 replica goodput scaling gate (8-device CPU) ==="
+  echo "=== sharded: 1-vs-8 replica soaks (8-device CPU) ==="
   # Two interleaved repeats of (1 replica, 8 replicas) saturation soaks
   # against a TIMER-bound servable (20 ms per dispatched batch of <= 8):
-  # each replica's capacity is set by clocks (~395 rps), so 8 replicas
-  # land ~8x that and the committed scaling baseline holds across
-  # machines. Driven through loadgen's InProcessTransport — the serving
+  # each replica's capacity is set by clocks, so both ramps saturate on
+  # every host. Driven through loadgen's InProcessTransport — the serving
   # core (router -> replica queues -> workers), not the stdlib HTTP
-  # loop, is what this stage measures. perfgate aggregates maxima across
-  # the repeats and hard-fails below the sharded_goodput_scaling band
-  # (>= 3x); the injected canary proves the gate still fires.
+  # loop, is what this stage drives. It asserts control flow: a
+  # saturation point on both ramps, zero hard errors, every replica
+  # served. The goodput ratio is printed for the reader.
   sh_t0=$SECONDS
-  SH_DIR=$(mktemp -d -t mxtpu_sharded.XXXXXX)
   JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python - "$SH_DIR" <<'EOF'
-import json, sys, time
+    python - <<'EOF'
+import time
 import jax
 jax.config.update("jax_platforms", "cpu")
 from tools import loadgen
@@ -1313,7 +1294,7 @@ assert len(jax.devices()) == 8, jax.devices()
 
 class SlowEcho:
     """Deterministic per-replica capacity: 20 ms per dispatched batch of
-    <= 8 => ~395 rps per replica worker, timer-bound on every host."""
+    <= 8, timer-bound on every host."""
 
     def predict_batch(self, x):
         time.sleep(0.02)
@@ -1338,7 +1319,6 @@ def soak(tag, replicas, stages, seed):
     return sat["goodput_rps"], counts
 
 
-out_dir = sys.argv[1]
 RAMP1 = [{"rps": r, "duration_s": 1.0} for r in (100, 200, 400, 800)]
 RAMP8 = [{"rps": r, "duration_s": 1.0} for r in (800, 1600, 3200, 6400)]
 for rep in range(2):
@@ -1346,31 +1326,10 @@ for rep in range(2):
     g8, counts = soak("shard8-%d" % rep, 8, RAMP8, rep)
     # router balance at saturation: every replica worked, none hogged
     assert min(counts) > 0 and max(counts) <= 2 * min(counts), counts
-    scaling = g8 / g1
-    metrics = {"schema": loadgen.METRICS_SCHEMA,
-               "metrics": {"sharded_goodput_scaling": scaling,
-                           "sharded_goodput_1rep_rps": g1,
-                           "sharded_goodput_8rep_rps": g8}}
-    with open("%s/sharded_%d.json" % (out_dir, rep), "w") as f:
-        json.dump(metrics, f, indent=1)
     print("repeat %d: 1-rep %.0f rps -> 8-rep %.0f rps = %.2fx, "
-          "dispatch balance %s" % (rep, g1, g8, scaling, counts))
+          "dispatch balance %s" % (rep, g1, g8, g8 / g1, counts))
 print("sharded soaks OK")
 EOF
-  python tools/perfgate.py --input "$SH_DIR"/sharded_*.json \
-      --only 'sharded_*' --json > "$SH_DIR/perfgate.json" \
-    || { python tools/perfgate.py --input "$SH_DIR"/sharded_*.json \
-           --only 'sharded_*' || true
-         exit 1; }
-  echo "sharded perfgate OK: gate artifact $SH_DIR/perfgate.json"
-  # canary: a synthetic 3x scaling collapse MUST fail the same baseline
-  if python tools/perfgate.py --input "$SH_DIR"/sharded_*.json \
-      --only 'sharded_*' --selftest-inject 3.0 --json \
-      > "$SH_DIR/perfgate_inject.json"; then
-    echo "sharded canary FAILED: injected 3x collapse passed the gate"
-    exit 1
-  fi
-  echo "sharded canary OK: injected 3x collapse fires"
   sh_dt=$(( SECONDS - sh_t0 ))
   echo "sharded stage wall time: ${sh_dt}s (budget 120s)"
   [ "$sh_dt" -lt 120 ] || { echo "sharded stage took ${sh_dt}s (budget 120s)"; exit 1; }

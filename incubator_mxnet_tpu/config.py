@@ -605,19 +605,6 @@ ENV_VARS = {
         "unsent or queued client-side — client-side queueing would "
         "re-introduce the coordinated-omission bias the open-loop "
         "design exists to avoid. Read stdlib-side by tools/loadgen.py."),
-    "MXTPU_PERFGATE_REPEATS": (
-        int, 3,
-        "Default repeat count for tools/perfgate.py --cmd runs: repeats "
-        "interleave in time and the gate aggregates per-metric minima "
-        "(maxima for higher-is-better), so co-tenant noise — which only "
-        "ever inflates a latency or deflates a throughput — is absorbed "
-        "instead of widening tolerance bands (docs/LOADGEN.md)."),
-    "MXTPU_PERFGATE_TOLERANCE": (
-        float, 0.5,
-        "Default relative tolerance band for perfgate metrics whose "
-        "PERF_BASELINE.json entry doesn't pin its own: lower-is-better "
-        "fails past baseline*(1+tol), higher-is-better below "
-        "baseline*(1-tol). Read stdlib-side by tools/perfgate.py."),
     "MXTPU_SLO_TARGET": (
         float, 0.99,
         "Default availability objective for the per-model SLOs the serving "
@@ -742,7 +729,7 @@ def get_env(name):
 
 def place_compile_cache():
     """Give JAX's persistent compilation cache a home — the ONE place any
-    entry point (chip_smoke.py, bench.py, example/*, the server) gets it
+    entry point (chip_smoke.py, example/*, the server) gets it
     from, called at package import. It only sets config values; no
     backend is initialised. Returns the directory it set, or None.
 

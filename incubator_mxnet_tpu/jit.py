@@ -199,8 +199,8 @@ class TrainStep:
         self.zero = zero
         # device truth of the most recently dispatched program (aot entry
         # stats: flops / bytes_accessed / peak_bytes / output_bytes), or
-        # None pre-dispatch — what bench.py's cost-analysis-derived MFU
-        # reads
+        # None pre-dispatch; nothing in the package reads it, the tests
+        # do
         self._last_stats = None
         # watchdog bookkeeping: counts once this instance starts stepping
         self._hb_registered = False
@@ -711,7 +711,7 @@ class EvalStep:
         self._trace_lock = _net_trace_lock(net)
         self._pure = None       # (param_arrs, pure_fn): built once, no trace
         # device truth of the most recently dispatched program (aot entry
-        # stats), None pre-dispatch — bench.py's cost-analysis MFU source
+        # stats), None pre-dispatch
         self._last_stats = None
 
     def _ensure_pure(self):

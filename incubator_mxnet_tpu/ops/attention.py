@@ -53,7 +53,7 @@ shape off the TPU) takes the XLA composite, whose einsums carry any D_v.
 Used by models.bert MultiHeadAttention (attention='flash') and
 models.phi4flash DifferentialAttention. A Mosaic refusal of a routed shape surfaces as the
 compile error it is — nothing catches it to degrade. MXTPU_FLASH_INTERPRET=1 runs the kernels in
-Pallas interpret mode (CPU tests only; chip_smoke.py and bench.py refuse to
+Pallas interpret mode (CPU tests only; chip_smoke.py refuses to
 start with it set). Counters at /metrics, one increment per traced call:
 mxtpu_attention_route_total{route} (the backward follows the forward's
 route), mxtpu_attention_backward_total{kernel} (a streamed backward:

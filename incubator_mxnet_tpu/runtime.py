@@ -15,7 +15,7 @@ class Feature:
 
 def require_tpu():
     """The device gate of every entry point that reports on the chip
-    (chip_smoke.py, bench.py): JAX's default backend must be a TPU whose
+    (chip_smoke.py): JAX's default backend must be a TPU whose
     ``device_kind`` has a row in the peak table, and the Pallas kernels
     must not be interpreted. Returns ``(device, peaks)`` — the device as
     JAX reports it ``{"platform", "kind", "count"}`` and its

@@ -77,8 +77,8 @@ _LOG = logging.getLogger(__name__)
 #: int8 OP/s, peak HBM bytes/s) per chip. Source: Google Cloud TPU
 #: documentation, system-architecture pages per generation ("TPU v5e":
 #: 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s). Every rate the repo
-#: prints against a hardware peak (bench.py, chip_smoke.py,
-#: benchmark/roofline.py, the MFU gauges) divides by a row of this
+#: prints against a hardware peak (chip_smoke.py, the MFU gauges)
+#: divides by a row of this
 #: table; a device_kind with no row is an error for those callers
 #: (device_peaks), never a default.
 PEAK_TABLE = {
@@ -156,7 +156,7 @@ def _table_row(table, kind):
 def device_peaks(kind):
     """(peak bf16 FLOP/s, peak int8 OP/s, peak HBM bytes/s) of one chip
     of ``kind`` from PEAK_TABLE. Raises LookupError for a kind with no
-    row: whoever prints a rate against a peak (bench.py, chip_smoke.py)
+    row: whoever prints a rate against a peak (chip_smoke.py)
     must fail on an unknown device, not assume one."""
     row = _table_row(PEAK_TABLE, kind)
     if row is None:

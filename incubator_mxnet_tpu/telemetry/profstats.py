@@ -37,7 +37,7 @@ Event model (verified against the CPU and TPU backends' chrome traces):
 an XLA op execution is a ``ph == "X"`` event whose ``args`` carry
 ``hlo_op`` (op name, e.g. ``dot.4``) and ``hlo_module`` (program, e.g.
 ``jit_step``). Device-track events without args (TPU device lanes) fall
-back to the pid heuristic tools/profile_bench.py proved out: a pid whose
+back to a pid heuristic: a pid whose
 process_name mentions a device, with ``jit_*`` / all-digit umbrella
 events treated as containers, never leaves.
 """
@@ -182,7 +182,7 @@ def iter_trace_files(capture_dir):
 # ----------------------------------------------------- event aggregation
 def _device_pids(events):
     """pids whose process_name marks a device lane (the TPU/GPU track
-    heuristic folded in from tools/profile_bench.py)."""
+    heuristic)."""
     pids = set()
     for ev in events:
         if not isinstance(ev, dict) or ev.get("ph") != "M":
@@ -753,8 +753,8 @@ def running():
 
 # ------------------------------------------------------------ formatting
 def format_table(summary, top=40):
-    """The ranked-hotspot table both tools/profsum.py and
-    tools/profile_bench.py print (one renderer, one parser)."""
+    """The ranked-hotspot table tools/profsum.py prints (one renderer,
+    one parser)."""
     lines = []
     ops = summary.get("ops") or []
     lines.append("%4s  %12s  %6s  %8s  %-12s %s"

@@ -1,6 +1,5 @@
-"""Load harness + perf regression gate tier (tools/loadgen.py,
-tools/perfgate.py, bench.py --gate, and the serving-side saturation
-gauges they scrape) — docs/LOADGEN.md."""
+"""Load harness tier (tools/loadgen.py and the serving-side saturation
+gauges it scrapes) — docs/LOADGEN.md."""
 import json
 import threading
 import time
@@ -8,7 +7,7 @@ import time
 import numpy as onp
 import pytest
 
-from tools import loadgen, perfgate, promcheck
+from tools import loadgen, promcheck
 
 
 # ------------------------------------------------------------ fakes
@@ -211,120 +210,9 @@ def test_parse_prom_values_and_labels():
     assert loadgen._prom_sum(snap, "x") == 7.0
 
 
-# ------------------------------------------------------------- perfgate
-def test_perfgate_minima_aggregation_absorbs_noise():
-    runs = [{"a_ms": 10.0, "tput_rps": 100.0},
-            {"a_ms": 31.0, "tput_rps": 58.0},     # co-tenant-noised repeat
-            {"a_ms": 10.4, "tput_rps": 97.0}]
-    agg = perfgate.aggregate(runs)
-    assert agg == {"a_ms": 10.0, "tput_rps": 100.0}
-    assert perfgate.infer_direction("x_latency_weird") == "lower"
-    assert perfgate.infer_direction("goodput_frac") == "higher"
-
-
-def test_perfgate_roundtrip_and_injected_regression(tmp_path):
-    paths = []
-    for i, m in enumerate([{"a_ms": 10.0, "cov_frac": 1.0},
-                           {"a_ms": 24.0, "cov_frac": 0.9},
-                           {"a_ms": 10.2, "cov_frac": 0.98}]):
-        p = tmp_path / ("run%d.json" % i)
-        p.write_text(json.dumps({"schema": perfgate.METRICS_SCHEMA,
-                                 "metrics": m}))
-        paths.append(str(p))
-    bp = str(tmp_path / "base.json")
-    assert perfgate.main(["--input"] + paths + ["--baseline", bp,
-                                                "--update-baseline"]) == 0
-    base = json.load(open(bp))
-    assert base["schema"] == perfgate.BASELINE_SCHEMA
-    assert base["metrics"]["a_ms"]["value"] == 10.0        # the minimum
-    assert base["metrics"]["a_ms"]["direction"] == "lower"
-    assert base["metrics"]["cov_frac"]["direction"] == "higher"
-    # identical re-run passes: the noisy middle repeat is absorbed
-    assert perfgate.main(["--input"] + paths + ["--baseline", bp]) == 0
-    # documentation keys survive the documented update workflow
-    base["note"] = "reviewed methodology prose"
-    base["metrics"]["a_ms"]["tolerance"] = 0.9
-    with open(bp, "w") as f:
-        json.dump(base, f)
-    assert perfgate.main(["--input"] + paths + ["--baseline", bp,
-                                                "--update-baseline"]) == 0
-    rewritten = json.load(open(bp))
-    assert rewritten["note"] == "reviewed methodology prose"
-    assert rewritten["metrics"]["a_ms"]["tolerance"] == 0.9
-    # the canary: a synthetic 2x regression MUST fire the gate
-    assert perfgate.main(["--input"] + paths + ["--baseline", bp,
-                                                "--selftest-inject",
-                                                "2.0"]) == 1
-    # and a real 2x-regressed run fails without any injection flag
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"schema": perfgate.METRICS_SCHEMA,
-                               "metrics": {"a_ms": 20.0,
-                                           "cov_frac": 1.0}}))
-    assert perfgate.main(["--input", str(bad), "--baseline", bp]) == 1
-
-
-def test_perfgate_missing_baselined_metric_fails(tmp_path):
-    bp = tmp_path / "base.json"
-    bp.write_text(json.dumps({
-        "schema": perfgate.BASELINE_SCHEMA, "default_tolerance": 0.5,
-        "metrics": {"gone_ms": {"value": 5.0, "direction": "lower"}}}))
-    run = tmp_path / "run.json"
-    run.write_text(json.dumps({"schema": perfgate.METRICS_SCHEMA,
-                               "metrics": {"other_ms": 1.0}}))
-    findings = perfgate.compare(perfgate.load_metrics(str(run)),
-                                perfgate.load_baseline(str(bp)))
-    assert [f[0] for f in findings] == ["G002"]
-    assert perfgate.main(["--input", str(run), "--baseline", str(bp)]) == 1
-
-
-def test_perfgate_only_filter_scopes_gate_to_a_stage_subset(tmp_path):
-    # one committed baseline holds multiple CI stages' metrics; each
-    # stage gates its own glob without G002-failing on its siblings'
-    base = {"schema": perfgate.BASELINE_SCHEMA, "default_tolerance": 0.5,
-            "metrics": {
-                "loadgen_stage0_p50_ms": {"value": 10.0,
-                                          "direction": "lower"},
-                "sharded_goodput_scaling": {"value": 7.5,
-                                            "direction": "higher",
-                                            "tolerance": 0.6}}}
-    sharded_run = {"sharded_goodput_scaling": 7.9}
-    # unfiltered: the loadgen metric is missing from the run -> G002
-    assert [f[0] for f in perfgate.compare(sharded_run, base)] == ["G002"]
-    # scoped to the stage's glob: clean
-    assert perfgate.compare(sharded_run, base, only="sharded_*") == []
-    # the >=3x floor still fires inside the scope (7.5 * (1-0.6) = 3.0)
-    bad = perfgate.compare({"sharded_goodput_scaling": 2.9}, base,
-                           only="sharded_*")
-    assert [f[0] for f in bad] == ["G001"]
-    # --only on the CLI; combining with --update-baseline is refused
-    bp = tmp_path / "base.json"
-    bp.write_text(json.dumps(base))
-    run = tmp_path / "run.json"
-    run.write_text(json.dumps({"schema": perfgate.METRICS_SCHEMA,
-                               "metrics": sharded_run}))
-    assert perfgate.main(["--input", str(run), "--baseline", str(bp),
-                          "--only", "sharded_*"]) == 0
-    assert perfgate.main(["--input", str(run), "--baseline", str(bp)]) == 1
-    assert perfgate.main(["--input", str(run), "--baseline", str(bp),
-                          "--only", "sharded_*",
-                          "--update-baseline"]) == 2
-
-
-def test_perfgate_tolerance_bands_both_directions(tmp_path):
-    base = {"schema": perfgate.BASELINE_SCHEMA, "default_tolerance": 0.5,
-            "metrics": {"lat_ms": {"value": 10.0, "direction": "lower",
-                                   "tolerance": 0.2},
-                        "rate_rps": {"value": 100.0, "direction": "higher",
-                                     "tolerance": 0.1}}}
-    assert perfgate.compare({"lat_ms": 11.9, "rate_rps": 91.0}, base) == []
-    bad = perfgate.compare({"lat_ms": 12.1, "rate_rps": 89.0}, base)
-    assert sorted(f[1] for f in bad) == ["lat_ms", "rate_rps"]
-
-
 # ----------------------------------------------- one-parser CI report shape
 def test_ci_report_shape_parity_across_tools(tmp_path):
     prom_rep = promcheck.report("garbage line {", path="m.txt")
-    gate_rep = perfgate.report([("G001", "a_ms", "regressed")], "b.json")
     clock = FakeClock()
     lg = loadgen.LoadGen(FakeTransport(clock, {0: (500, 0.001)}),
                          [{"rps": 5, "duration_s": 1.0}],
@@ -332,12 +220,11 @@ def test_ci_report_shape_parity_across_tools(tmp_path):
                          run_id="t", seed=0)
     load_rep = loadgen.report_ci(lg.run(sync=True), "r.json")
     assert not load_rep["ok"]          # 500s are hard errors -> L001
-    for rep in (prom_rep, gate_rep, load_rep):
+    for rep in (prom_rep, load_rep):
         assert set(rep) == {"tool", "ok", "findings", "counts", "baselined"}
         for f in rep["findings"]:
             assert set(f) == {"path", "line", "rule", "message"}
     assert load_rep["findings"][0]["rule"] == "L001"
-    assert gate_rep["findings"][0]["rule"] == "G001"
 
 
 def test_require_saturation_finding():
@@ -353,11 +240,10 @@ def test_require_saturation_finding():
 
 def test_env_defaults_match_config_registry():
     from incubator_mxnet_tpu import config
-    for table in (loadgen.ENV_DEFAULTS, perfgate.ENV_DEFAULTS):
-        for name, default in table.items():
-            typ, cfg_default, _doc = config.ENV_VARS[name]
-            assert typ is type(default), name
-            assert cfg_default == default, name
+    for name, default in loadgen.ENV_DEFAULTS.items():
+        typ, cfg_default, _doc = config.ENV_VARS[name]
+        assert typ is type(default), name
+        assert cfg_default == default, name
 
 
 # --------------------------------------------------- serving-side gauges
@@ -451,7 +337,7 @@ def test_e2e_soak_report_joins_request_ids_to_spans():
     assert m["delta"]["mxtpu_serving_ok_total"] >= s0["ok"]
     assert "mxtpu_http_inflight_requests" in m["gauges"]
     assert "mxtpu_serving_bucket_queue_depth" in m["gauges"]
-    # gate bridge: the run reduces to perfgate-consumable metrics
+    # the run's flat summary
     gm = report["gate_metrics"]["metrics"]
     assert gm["loadgen_error_rate"] == 0.0
     assert gm["loadgen_saturation_detected"] == 1.0
@@ -459,17 +345,6 @@ def test_e2e_soak_report_joins_request_ids_to_spans():
     # inflight gauge balanced back to zero after the soak
     snap = loadgen.parse_prom(telemetry.export_text())
     assert loadgen._prom_sum(snap, "mxtpu_http_inflight_requests") == 0
-
-
-def test_bench_gate_emits_perfgate_schema(capsys):
-    import bench
-    out = bench.bench_gate(steps=3)
-    assert out["schema"] == perfgate.METRICS_SCHEMA
-    for name in ("bench_tiny_train_step_ms", "bench_tiny_eval_step_ms",
-                 "bench_tiny_serve_roundtrip_ms"):
-        assert out["metrics"][name] > 0
-    printed = capsys.readouterr().out.strip().splitlines()[-1]
-    assert json.loads(printed) == out
 
 
 def test_parse_stages_cli_grammar():

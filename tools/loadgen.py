@@ -60,9 +60,8 @@ disarmed (docs/RESILIENCE.md).
 ``ok`` / ``findings`` / ``counts`` / ``baselined`` — the same parser
 that reads ``python -m tools.mxtpulint --json`` and ``tools/promcheck.py
 --json`` reads this; violations carry rule id L001). The report's
-``gate_metrics`` section is in the perfgate metrics schema, so
-``tools/perfgate.py --input report.json`` gates a run directly
-(docs/LOADGEN.md).
+``gate_metrics`` section is the run's flat summary, a name and a number
+each (docs/LOADGEN.md).
 
 The module is import-light on purpose: driving a remote server must not
 require the framework (or jax) to be importable. The MXTPU_LOADGEN_*
@@ -1112,12 +1111,11 @@ class LoadGen:
         return report
 
 
-# ------------------------------------------------------------- gate bridging
+# ------------------------------------------------------------ the flat summary
 def gate_metrics(report):
-    """The run reduced to the flat perfgate metrics schema
-    (tools/perfgate.py): stage-0 (lowest-load) latency and conversion,
-    whole-run error rate and span-join coverage, and the saturation
-    verdict — the machine-comparable facts a perf PR is judged on."""
+    """The run reduced to a flat {"schema", "metrics": {name: number}}
+    summary: stage-0 (lowest-load) latency and conversion, whole-run
+    error rate and span-join coverage, and the saturation verdict."""
     stages = report["stages"]
     st0 = stages[0]
     covs = [s["server"]["join_coverage"] for s in stages
@@ -1170,7 +1168,7 @@ def gate_metrics(report):
 def report_ci(report, path="<report>", max_error_rate=0.0,
               require_saturation=False):
     """The shared CI report shape (one parser for mxtpulint / promcheck /
-    loadgen / perfgate): rule L001 per stage whose hard-error rate
+    loadgen): rule L001 per stage whose hard-error rate
     exceeds ``max_error_rate``, plus one L001 when ``require_saturation``
     and the ramp never saturated (a gate that can't find the knee isn't
     measuring capacity)."""

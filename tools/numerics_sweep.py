@@ -5,7 +5,7 @@ distilled into an op-table walk with per-dtype tolerances).
 Run on a TPU host: compares every table op CPU vs TPU at fp32/bf16/fp16.
 Prints a markdown table; nonzero exit if any MISMATCH/ERROR rows appear.
 
-Usage: python benchmark/numerics_sweep.py [--quick]
+Usage: python tools/numerics_sweep.py [--quick]
 """
 import sys
 

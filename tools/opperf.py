@@ -6,7 +6,7 @@ here the op registry is the nd namespace).
 Times eager forward and forward+backward for a representative op set (or
 --ops to pick), with warmup and sync, printing a table + one JSON line.
 
-Usage: python benchmark/opperf.py [--size 1024] [--runs 50] [--ops add,dot]
+Usage: python tools/opperf.py [--size 1024] [--runs 50] [--ops add,dot]
 """
 import argparse
 import json

@@ -7,14 +7,14 @@ The CLI face of telemetry/profstats.py (one trace parser in the repo):
     python tools/profsum.py diff <a> <b> [--threshold R] [--min-duty D]
                             [--json] [--inject-slowdown FACTOR]
 
-``summarize`` prints the same ranked-hotspot table tools/profile_bench.py
-ends with (profstats.format_table); ``--out`` writes the full summary
+``summarize`` prints a ranked-hotspot table
+(profstats.format_table); ``--out`` writes the full summary
 JSON, the artifact ``diff`` consumes. ``diff`` accepts summary JSON
 files or capture dirs/trace files directly, and reports per-op / per-
 category *duty* regressions (self-time normalized by the capture window,
 so two captures of different lengths compare honestly) in the shared
 mxtpulint/promcheck report shape {"tool", "ok", "findings", "counts",
-"baselined"} — a perfgate latency regression becomes attributable to a
+"baselined"} — a latency regression becomes attributable to a
 named op. ``--inject-slowdown`` doubles (or xN) the top op of ``b``
 before diffing: the CI canary proving the gate still fires.
 

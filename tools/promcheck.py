@@ -22,7 +22,7 @@ Usage::
     python tools/promcheck.py metrics.txt --json     # CI report shape
 
 ``--json`` emits the same report shape as ``python -m tools.mxtpulint
---json``, ``tools/loadgen.py --json`` and ``tools/perfgate.py --json``
+--json`` and ``tools/loadgen.py --json``
 (tool/ok/findings/counts/baselined), so CI aggregates every gate with
 one parser; format violations carry rule id ``P001``, metadata-hygiene
 violations carry ``P002``, naming-convention violations carry ``P003``

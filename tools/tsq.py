@@ -26,7 +26,7 @@ file canonically and verifies byte-stability (the CI proof that export
 and tool agree on one serialization). Every subcommand accepts
 ``--json`` and emits the shared CI report shape
 ({"tool": "tsq", "ok", "findings", "counts", "baselined"} — same
-one-parser aggregation as mxtpulint/promcheck/loadgen/perfgate) with
+one-parser aggregation as mxtpulint/promcheck/loadgen) with
 rules:
 
 - ``Q001`` — unreadable/malformed export (bad JSON line, wrong schema);
