@@ -36,6 +36,7 @@ import sys
 import threading
 import time
 import urllib.request
+from unittest import mock
 
 PHASES = ("kernels", "moe", "bert", "gpt", "hybrid", "resnet", "serve",
           "generate", "multichip")
@@ -64,7 +65,9 @@ FULL = {
                    DR_S=8192, DR_H=8, DR_D=128, DR_Q=64,
                    # sparse attention alone at the Keye cell's shape
                    SA_S=16384, SA_H=32, SA_G=4, SA_D=128, SA_J=16, SA_DI=64,
-                   SA_K=2048),
+                   SA_K=2048,
+                   # EVA attention alone at the EvaByte cell's shape
+                   EV_S=16384, EV_H=32, EV_D=128, EV_W=2048, EV_C=16),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -87,7 +90,9 @@ TOY = {
                    # (heads of 128: the narrowest the rule's kernels take)
                    DR_S=160, DR_H=2, DR_D=128, DR_Q=16,
                    SA_S=256, SA_H=4, SA_G=2, SA_D=128, SA_J=2, SA_DI=64,
-                   SA_K=48),
+                   SA_K=48,
+                   # (windows of 128 rows of 128: the streamed kernels' least)
+                   EV_S=512, EV_H=2, EV_D=128, EV_W=128, EV_C=16),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -648,6 +653,114 @@ def sparse_attention_alone(cfg, on_chip):
                            "count the calls" % path)
     return out
 
+EVA_ALONE_LIMITS = {"outputs": 1e-4, "gradients": 2e-3}
+
+
+def eva_attention_alone(cfg, on_chip):
+    """`ops.eva_attention.eva_attention` alone at the EvaByte cell's shape
+    (EV_S positions, EV_H heads of EV_D, windows of EV_W, chunks of EV_C),
+    float32 operands, through the one entry the cell runs (the exact part
+    on the streamed kernels wherever kernels run: in the lowered forward
+    and gradient BEFORE the first call; interpreted in a rehearsal), against
+    ONE masked softmax a block of 256 queries over [every key; every
+    summary]: the op splits the row and joins the parts by their
+    log-sum-exps, this does not. The gradients on the first two windows.
+    -> {"local": the exact part's path, "sound": the output's distance,
+    "no_remote": the output's distance when the plain form leaves the
+    summaries out, "gradients": the worst of the five (q, k, v, phi, mu),
+    "forward_ms", "both_ms": the op alone in bfloat16 (None off the
+    chip)}."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import eva_attention as op
+    s, h, d = cfg["EV_S"], cfg["EV_H"], cfg["EV_D"]
+    window, chunk = cfg["EV_W"], cfg["EV_C"]
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    args = tuple(jax.random.normal(key, shape, jnp.float32) * scale
+                 for key, shape, scale in zip(
+                     keys, ((1, h, s, d),) * 3 + ((h, d),) * 2,
+                     (1, 1, 1, 1, 0.25)))
+    cot = jax.random.normal(keys[5], args[0].shape, jnp.float32)
+
+    def system(*a):
+        return op.eva_attention(*a, window, chunk)
+
+    def plain(q, k, v, phi, mu, remote=True):
+        n, block, scale = q.shape[2], 256, 1.0 / math.sqrt(d)
+        kc = k.reshape(1, h, n // chunk, chunk, d)
+        vc = v.reshape(1, h, n // chunk, chunk, d)
+        a = jax.nn.softmax(scale * (kc * phi[None, :, None, None]).sum(-1),
+                           -1)[..., None]
+        kt, vt = (a * kc).sum(-2) + mu[None, :, None], (a * vc).sum(-2)
+        keys_all = jnp.concatenate([k, kt], 2)
+        values_all = jnp.concatenate([v, vt], 2)
+
+        @jax.checkpoint
+        def one(at):
+            q_b, start = at                                 # (1, h, block, d)
+            t = (start + jnp.arange(block))[:, None]
+            u, j = jnp.arange(n)[None], jnp.arange(n // chunk)[None]
+            seen = jnp.concatenate([
+                (u // window == t // window) & (u <= t),
+                (j < (window // chunk) * (t // window)) & remote], 1)
+            sc = jnp.einsum("bhqd,bhkd->bhqk", q_b, keys_all) * scale
+            return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(
+                jnp.where(seen, sc, -jnp.inf), -1), values_all)
+
+        o = jax.lax.map(one, (jnp.moveaxis(q.reshape(
+            1, h, n // block, block, d), 2, 0), jnp.arange(0, n, block)))
+        return jnp.moveaxis(o, 0, 2).reshape(1, h, n, d)
+
+    def both(fn):
+        return jax.jit(jax.grad(lambda cot, *t: jnp.sum(
+            fn(*t).astype(jnp.float32) * cot), (1, 2, 3, 4, 5)))
+
+    local = "streamed" if op.flash_attention_supported(
+        (1, h * (s // window), window, d)) else "dense"
+    calls = op._CALLS.value(local=local, remote="strips")
+    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
+    if on_chip:
+        if local != "streamed":
+            raise RuntimeError("EVA's exact part took %r on the chip" % local)
+        for fn, operands, kernels in (
+                (jax.jit(system), low, ("flash_fwd",)),
+                (both(system), (cot.astype(jnp.bfloat16),) + low,
+                 ("flash_fwd", "flash_bwd_dkvq"))):
+            text = fn.lower(*operands).as_text()
+            if not all(k in text for k in kernels):
+                raise RuntimeError("EVA's exact part is not the Mosaic "
+                                   "calls %s" % (kernels,))
+
+    def distance(got, want):
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    with jax.default_matmul_precision("highest"):
+        o_want = jax.jit(plain)(*args)
+        out = {"sound": distance(jax.jit(system)(*args), o_want),
+               "no_remote": distance(jax.jit(functools.partial(
+                   plain, remote=False))(*args), o_want)}
+        del o_want
+        short = tuple(t[:, :, :2 * window] for t in args[:3]) + args[3:]
+        cot_s = cot[:, :, :2 * window]
+        # float32 operands at full matmul precision: the streamed
+        # backward's tiles at blocks of 1024 exceed its scoped VMEM (30.5 MiB
+        # of 26 on a v5e, PR 45); blocks of 512 for this gradient alone
+        with mock.patch.dict(os.environ, {"MXTPU_FLASH_BLOCK_Q": "512",
+                                          "MXTPU_FLASH_BLOCK_K": "512"}):
+            got = both(system)(cot_s, *short)
+        out["gradients"] = max(distance(a, b) for a, b in zip(
+            got, both(plain)(cot_s, *short)))
+    out["local"] = local
+    out["forward_ms"] = out["both_ms"] = None
+    if on_chip:
+        out["forward_ms"] = median_ms(jax.jit(system), low)
+        out["both_ms"] = median_ms(both(system),
+                                   (cot.astype(jnp.bfloat16),) + low)
+    if op._CALLS.value(local=local, remote="strips") == calls:
+        raise RuntimeError("mxtpu_eva_attention_total{local=%r} did not "
+                           "count the calls" % local)
+    return out
+
 
 def median_ms(fn, operands):
     """Milliseconds of a jitted call, the median of five after the first."""
@@ -919,6 +1032,16 @@ def phase_hybrid(cfg, on_chip, shared):
                 sparse["sound"], sparse["kl"], SPARSE_ALONE_LIMITS["outputs"],
                 sparse["gradients"], SPARSE_ALONE_LIMITS["gradients"],
                 sparse["rounded"]))
+    eva = eva_attention_alone(cfg, on_chip)
+    if not (eva["sound"] < EVA_ALONE_LIMITS["outputs"] < eva["no_remote"]
+            and eva["gradients"] < EVA_ALONE_LIMITS["gradients"]):
+        raise RuntimeError(
+            "EVA attention alone, float32: outputs %.3g of the largest "
+            "entry from one masked softmax over keys and summaries (limit "
+            "%g), the five gradients %.3g (limit %g); without the summaries "
+            "%.3g" % (eva["sound"], EVA_ALONE_LIMITS["outputs"],
+                      eva["gradients"], EVA_ALONE_LIMITS["gradients"],
+                      eva["no_remote"]))
     alone = "" if sel["forward_ms"] is None else \
         "; forward %.1f ms, forward + backward %.1f ms in bfloat16 (the XLA " \
         "form 36-38 / 77-80, PR 34)" % (sel["forward_ms"], sel["both_ms"])
@@ -935,7 +1058,10 @@ def phase_hybrid(cfg, on_chip, shared):
         "%d, %d x %d index heads, %d keys a query (%s) in float32 %.2g of " \
         "its largest output from a masked softmax over top_k's keys " \
         "(choosing on bfloat16 scores %.2g), the KL %.2g, the six " \
-        "gradients %.2g%s" % (
+        "gradients %.2g%s; EVA attention alone at %d x %d x %d, windows of " \
+        "%d, chunks of %d (exact part: %s) in float32 %.2g of its largest " \
+        "output from one masked softmax over keys and summaries (without " \
+        "the summaries %.2g), the five gradients %.2g%s" % (
             cfg["P"], cfg["S"], losses[0], losses[-1], kernels,
             cfg["SCAN_S"], sound, rounded, cfg["SEL_S"], cfg["SEL_C"],
             cfg["SEL_N"], sel["sound"], sel["rounded"], sel["gradients"],
@@ -958,7 +1084,12 @@ def phase_hybrid(cfg, on_chip, shared):
             sparse["rounded"], sparse["kl"], sparse["gradients"],
             "" if sparse["forward_ms"] is None else
             ", forward %.1f ms, forward + backward %.1f ms in bfloat16" % (
-                sparse["forward_ms"], sparse["both_ms"]))
+                sparse["forward_ms"], sparse["both_ms"]),
+            cfg["EV_S"], cfg["EV_H"], cfg["EV_D"], cfg["EV_W"], cfg["EV_C"],
+            eva["local"], eva["sound"], eva["no_remote"], eva["gradients"],
+            "" if eva["forward_ms"] is None else
+            ", forward %.1f ms, forward + backward %.1f ms in bfloat16" % (
+                eva["forward_ms"], eva["both_ms"]))
 
 
 def build_resnet():

@@ -80,9 +80,16 @@ class Trainer:
             self._update_on_kvstore = update_on_kvstore
             if update_on_kvstore:
                 kv.set_optimizer(self._optimizer)
-            for i, param in enumerate(self._params):
-                if param.grad_req != "null":
-                    kv.init(i, param.data())
+                # the store's copy of a parameter is what a server-style
+                # update writes and `_update` pulls; every other flow
+                # (the local update, a compiled `jit.TrainStep`) never reads
+                # the store, and a copy there is 2 bytes a bfloat16
+                # parameter of device memory for nothing: 1.53 GiB beside
+                # EvaByte's 821 M, with which its step did not LOAD
+                # (PERF.md section 6, PR 45)
+                for i, param in enumerate(self._params):
+                    if param.grad_req != "null":
+                        kv.init(i, param.data())
         self._kv_initialized = True
 
     # ------------------------------------------------------------------

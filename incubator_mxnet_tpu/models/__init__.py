@@ -19,6 +19,10 @@
               lightning indexer picks, the indexer's own KL loss, rotary
               positions from three streams, softmax-routed SwiGLU experts),
               whole or as one chip's share of its experts
+- evabyte:    EvaByte (EVA attention: exact causal keys of a query's own
+              aligned window and one learned summary a chunk of every
+              earlier window under one softmax; a float32 residual stream;
+              a head that predicts the next eight bytes of every position)
 """
 from .lenet import LeNet  # noqa
 from .bert import (BERTEncoder, BERTModel, TransformerEncoderLayer,  # noqa
@@ -36,6 +40,8 @@ from .solar_open2 import (SolarOpen2Model, SolarOpen2Layer,  # noqa
                           SharedExpertMoE)
 from .keye_vl2 import (KeyeVL2Model, KeyeVL2Layer,  # noqa
                        SparseGroupedQueryAttention)
+from .evabyte import (EvaByteModel, EvaByteLayer, EvaAttention,  # noqa
+                      UnitOffsetRMSNorm, RowBlockedSwiGLU, MultiByteLoss)
 from .lstm_lm import LSTMLanguageModel  # noqa
 from .ssd import SSD  # noqa
 from ..gluon.model_zoo.vision import get_model  # noqa

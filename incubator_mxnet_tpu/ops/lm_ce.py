@@ -22,6 +22,12 @@ matmul at the 185 TFLOP/s a large matmul reaches. At 1024 rows they take
 
 Numerics: LSE in fp32 with max subtraction; identical to dense softmax-CE
 within bf16 matmul tolerance (tests/test_lm_ce.py pins parity and grads).
+
+``multibyte_cross_entropy(hidden, head_w, labels, heads)`` is the same trade
+for a head that predicts the next ``heads`` tokens of every position from
+ONE (heads x V, U) map (EvaByte's `num_pred_heads`): a block of rows' float32
+(rows, heads, V) logits at a time, head i of position t against
+labels[t + i], nothing past the end of a sequence.
 """
 from __future__ import annotations
 
@@ -31,7 +37,7 @@ from jax import lax
 
 from .. import telemetry
 
-__all__ = ["chunked_lm_cross_entropy"]
+__all__ = ["chunked_lm_cross_entropy", "multibyte_cross_entropy"]
 
 
 # Below DENSE_BYTES of float32 (T, V) logits: one chunk, which is the dense
@@ -131,3 +137,50 @@ def chunked_lm_cross_entropy(hidden, head_w, labels, chunk=None,
     if pad:
         losses = losses[:T]
     return losses.reshape(shape)
+
+
+# Rows a trip of the multi-head loss: at EvaByte's 8 x 320 columns a trip's
+# float32 logits are 21 MB and its matmuls (2048, U) x (U, 2560).
+_MULTIBYTE_ROWS = 2048
+
+
+def multibyte_cross_entropy(hidden, head_w, labels, heads, rows=None):
+    """hidden (B, S, U); head_w (heads x V, U), head i's rows
+    [V i, V (i + 1)); labels (B, S) int, labels[t] the token after position
+    t. Head i of position t is scored against labels[t + i]; the last i
+    positions of head i have no target. -> (the losses summed over a
+    position's heads (B, S) float32, zeros where there is no target; the
+    number of targets a sequence has, heads x S - heads (heads - 1) / 2).
+    Logits are float32 out of the matmul (operands keep their type). ``rows``
+    positions a trip (default 2048; one trip where it does not divide S),
+    each trip recomputed in the backward."""
+    b, s, u = hidden.shape
+    vocab = head_w.shape[0] // heads
+    rows = rows or _MULTIBYTE_ROWS
+    if s % rows:
+        rows = s
+    at = jnp.arange(s)[:, None] + jnp.arange(heads)[None, :]      # (S, P)
+    valid = at < s
+    targets = labels.astype(jnp.int32)[:, jnp.minimum(at, s - 1)]  # (B, S, P)
+    n = s // rows
+
+    def one(args):
+        hb, yb, ok = args              # (B, rows, U), (B, rows, P), (rows, P)
+        logits = jnp.einsum("bru,vu->brv", hb, head_w.astype(hb.dtype),
+                            preferred_element_type=jnp.float32) \
+            .reshape(b, rows, heads, vocab)
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        lse = (m + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1,
+                                   keepdims=True)))[..., 0]
+        lab = jnp.take_along_axis(logits, yb[..., None], axis=-1)[..., 0]
+        return jnp.where(ok, lse - lab, 0.0).sum(-1)              # (B, rows)
+
+    blocks = (jnp.moveaxis(hidden.reshape(b, n, rows, u), 1, 0),
+              jnp.moveaxis(targets.reshape(b, n, rows, heads), 1, 0),
+              valid.reshape(n, rows, heads))
+    if n == 1:
+        losses = one(tuple(x[0] for x in blocks))[None]
+    else:
+        losses = lax.map(jax.checkpoint(one), blocks)
+    count = heads * s - heads * (heads - 1) // 2
+    return jnp.moveaxis(losses, 0, 1).reshape(b, s), count

@@ -216,3 +216,25 @@ def test_sparse_attention_alone_reads_under_its_limits_and_a_rounded_choice_over
     assert read["gradients"] < limits["gradients"] / 10
     assert read["rounded"] > limits["outputs"] * 5
     assert read["forward_ms"] is None and read["both_ms"] is None
+
+
+@pytest.mark.parametrize("local", ["dense", "streamed"])
+def test_eva_attention_alone_reads_under_its_limits_and_no_summaries_over(
+        monkeypatch, local):
+    """The same for EVA attention alone at the rehearsal shape, the exact
+    part dense (nothing set: no kernel runs off the TPU) and on the
+    streamed kernels interpreted (what `--rehearse` runs): the output and
+    the five gradients in float32 are under the hybrid phase's limits
+    (summation order), the plain form without the summaries is over the
+    outputs'; off the chip it reports no milliseconds."""
+    if local == "streamed":
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    read = chip_smoke.eva_attention_alone(chip_smoke.TOY["hybrid"], False)
+    limits = chip_smoke.EVA_ALONE_LIMITS
+    assert read["local"] == local
+    assert read["sound"] < limits["outputs"] / 10
+    assert read["gradients"] < limits["gradients"] / 10
+    assert read["no_remote"] > limits["outputs"] * 5
+    assert read["forward_ms"] is None and read["both_ms"] is None

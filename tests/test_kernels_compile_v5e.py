@@ -115,3 +115,36 @@ def test_a_decoder_blocks_mlp_weight_updates_are_2d_matmuls_at_any_batch(
         ("2048", "2048"): 8, ("8192", "2048"): 2, ("2048", "8192"): 2}
     assert bounds["8192", "2048"] + bounds["2048", "8192"] == [3] * 4
     assert "[%d,%d,%d]" % (8, 2048, INNER) not in text
+
+
+def test_eva_attention_compiles_at_the_evabyte_cells_shape(one_chip,
+                                                           monkeypatch):
+    """`ops.eva_attention` forward and gradient at 1 x 32 x 16 384 x 128,
+    windows of 2048, chunks of 16, bfloat16 (perfbench/configs/evabyte.json):
+    the exact part is the streamed kernels over 256 windows a batch entry
+    each, `flash_fwd` and ONE `flash_bwd_dkvq` that takes the log-sum-exp's
+    cotangent; the seven strips and the merge are XLA's. The counter names
+    the path."""
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.ops import attention, eva_attention
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    monkeypatch.setattr(attention, "_kernels_run_here", lambda: True)
+    heads, seq, dim, window, chunk = 32, 16384, 128, 2048, 16
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = spec((1, heads, seq, dim), jnp.bfloat16)
+    vector = spec((heads, dim), jnp.float32)
+    calls = telemetry.REGISTRY.get("mxtpu_eva_attention_total")
+    before = calls.value(local="streamed", remote="strips")
+    grad = jax.jit(jax.grad(
+        lambda q, k, v, phi, mu: eva_attention.eva_attention(
+            q, k, v, phi, mu, window, chunk).astype(jnp.float32).sum(),
+        (0, 1, 2, 3, 4)))
+    text = grad.lower(wide, wide, wide, vector, vector).compile().as_text()
+    assert calls.value(local="streamed", remote="strips") == before + 1
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_fwd" in text and "flash_bwd_dkvq" in text
+    # no strip's scores are all of (S, S / 16): the widest is 2048 x 896
+    assert "[1,32,16384,1024]" not in text and "[32,16384,1024]" not in text

@@ -248,3 +248,27 @@ def test_trainstep_remat_matches_plain():
     onp.testing.assert_allclose(outs[0][0], outs[1][0], rtol=1e-6)
     for a, b in zip(outs[0][1], outs[1][1]):
         onp.testing.assert_allclose(a, b, rtol=1e-6)
+
+
+@pytest.mark.parametrize("on_store", [False, True])
+def test_a_trainer_copies_parameters_into_its_store_only_to_update_there(
+        on_store):
+    """The store's copy of a parameter is what a server-style update writes
+    and pulls. A local update and a compiled step never read the store, and
+    a copy there is a parameter's bytes of device memory for nothing (1.53
+    GiB beside EvaByte's 821 M parameters, with which its train step did
+    not load on a v5e: PERF.md section 6, PR 45). Both flows still train."""
+    mx.random.seed(0)
+    net = nn.Dense(4, in_units=8)
+    net.initialize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1},
+                            update_on_kvstore=on_store)
+    x = nd.array(onp.ones((2, 8), "float32"))
+    before = net.weight.data().asnumpy().copy()
+    with autograd.record():
+        loss = (net(x) ** 2).sum()
+    loss.backward()
+    trainer.step(2)
+    assert len(trainer._kvstore._data) == (2 if on_store else 0)
+    assert not onp.allclose(net.weight.data().asnumpy(), before)
