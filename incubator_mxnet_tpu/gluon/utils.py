@@ -4,10 +4,19 @@ from __future__ import annotations
 import math
 
 from .. import ndarray as nd
+from .. import telemetry
 from ..ndarray import NDArray
 
 __all__ = ["split_data", "split_and_load", "clip_global_norm", "check_sha1", "download",
            "recompute"]
+
+_RECOMPUTES = telemetry.counter(
+    "mxtpu_recompute_total",
+    "gluon.utils.recompute calls traced, by what the backward keeps of the "
+    "block's forward beside its inputs: none (everything is computed "
+    "again, Pallas kernels included) or given (a jax.checkpoint policy "
+    "names results to keep, e.g. a kernel's output and statistics).",
+    ("policy",))
 
 
 def split_data(data, num_slice, batch_axis=0, even_split=True):
@@ -73,7 +82,11 @@ def recompute(block, *args, policy=None):
     (``jax.checkpoint`` around this one block): a gradient through it keeps
     the block's inputs and none of what it computed (but what ``policy``, a
     ``jax.checkpoint_policies`` rule, says to keep: a named result that is
-    dear to compute again). Around each layer of a
+    dear to compute again: the Pallas kernels' forward rules name what they
+    hand their backward, ``ops.attention.ATTENDED_NAME``,
+    ``ops.selective_scan.SCANNED_NAME``, ``ops.delta_rule.RULED_NAME``,
+    ``ops.sparse_attention.ATTENDED_NAME``, and a layer that saves a name
+    runs that forward kernel once a step, not twice). Around each layer of a
     stack, the activations held for the backward are one input a layer plus
     one layer's working set, where ``TrainStep(remat=True)`` checkpoints the
     whole forward at once and lowers nothing at the peak. A block that
@@ -89,6 +102,7 @@ def recompute(block, *args, policy=None):
     from .. import autograd
     from ..ndarray import _apply
     from . import _functional
+    _RECOMPUTES.inc(policy="none" if policy is None else "given")
     arrs = [p.data() for p in block.collect_params().values()]
     n = len(args)
 

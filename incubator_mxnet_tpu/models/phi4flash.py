@@ -71,7 +71,8 @@ from .. import initializer
 from ..gluon import nn, utils
 from ..gluon.block import HybridBlock
 from ..ndarray import _apply
-from ..ops.selective_scan import selective_scan
+from ..ops.attention import ATTENDED_NAME
+from ..ops.selective_scan import SCANNED_NAME, selective_scan
 from .nemotron_h import _InverseSoftplusOfLogUniform
 
 __all__ = ["Phi4FlashModel", "SambaYLayer", "Mamba1Mixer",
@@ -83,6 +84,11 @@ _DT_INIT = (0.001, 0.1, 1e-4)
 #: the pattern's letters: Mamba-1, window attention, full attention (hands
 #: on k, v), gated memory unit, cross-decoder attention
 MIXERS = "MSFGC"
+#: what a recomputed layer keeps of its forward: what the attention and scan
+#: kernels wrote for their backward (o and lse a call, y and the chunks'
+#: states a scan; under 1 GB of the stage's step at 16k tokens)
+_KEPT = jax.checkpoint_policies.save_only_these_names(ATTENDED_NAME,
+                                                      SCANNED_NAME)
 
 
 def sambay_pattern(num_layers, mb_per_layer=2):
@@ -385,7 +391,8 @@ class Phi4FlashModel(HybridBlock):
     every `C` reads `F`'s k and v. ``mamba`` and ``attention`` are the
     keyword arguments of `Mamba1Mixer` and `DifferentialAttention` after
     ``units``. ``remat_layers``: each layer's forward is recomputed in the
-    backward (`gluon.utils.recompute`)."""
+    backward (`gluon.utils.recompute`) but for what its Pallas kernels
+    wrote (`_KEPT`), so each forward kernel runs once a step."""
 
     def __init__(self, vocab_size, units, hidden_size, pattern, mamba,
                  attention, window, epsilon=1e-5, remat_layers=False,
@@ -431,8 +438,8 @@ class Phi4FlashModel(HybridBlock):
         read = {"G": (), "C": ()}
         for i, (layer, letter) in enumerate(zip(self.layers, self.pattern)):
             args = (x,) + read.get(letter, ())
-            out = utils.recompute(layer, *args) if self._remat \
-                else layer(*args)
+            out = utils.recompute(layer, *args, policy=_KEPT) \
+                if self._remat else layer(*args)
             if i == self._memory_layer:
                 x, memory = out
                 read["G"] = (memory,)

@@ -52,7 +52,8 @@ from .. import initializer
 from ..gluon import nn, utils
 from ..gluon.block import HybridBlock
 from ..ndarray import _apply
-from ..ops.delta_rule import gated_delta_rule
+from ..ops.attention import ATTENDED_NAME
+from ..ops.delta_rule import RULED_NAME, gated_delta_rule
 from ..parallel.moe import MoELayer
 from .nemotron_h import (GroupedQueryAttention, _InverseSoftplusOfLogUniform,
                          _LogUniform)
@@ -63,6 +64,10 @@ __all__ = ["SolarOpen2Model", "SolarOpen2Layer", "KimiDeltaAttention",
 
 #: the pattern's letters: Kimi Delta Attention, gated grouped-query attention
 MIXERS = "KG"
+#: what a recomputed layer keeps of its forward: what the delta rule's and
+#: the `G` layer's attention kernels wrote for their backward
+_KEPT = jax.checkpoint_policies.save_only_these_names(RULED_NAME,
+                                                      ATTENDED_NAME)
 #: the decay's step sizes at initialisation, as Mamba's: log-uniform in
 #: [min, max], floored
 _DT_INIT = (0.001, 0.1, 1e-4)
@@ -256,7 +261,9 @@ class SolarOpen2Model(HybridBlock):
     layers' mixers (`K`, `G`); ``delta``, ``attention`` and ``moe`` are the
     keyword arguments of `KimiDeltaAttention`, `GatedGroupedQueryAttention`
     and `SharedExpertMoE` after ``units``. ``remat_layers``: each layer's
-    forward is recomputed in the backward (`gluon.utils.recompute`). With
+    forward is recomputed in the backward (`gluon.utils.recompute`) but for
+    what its Pallas kernels wrote (`_KEPT`), so each forward kernel runs
+    once a step. With
     ``moe["bias_rate"]`` every training step moves every router's selection
     bias by the balancing rule, here, outside the recomputed layers."""
 
@@ -287,7 +294,8 @@ class SolarOpen2Model(HybridBlock):
         ChunkedUntiedLMLoss so the (B*S, V) logits never materialise."""
         x = self.tok_embed(token_ids)
         for layer in self.layers:
-            x = utils.recompute(layer, x) if self._remat else layer(x)
+            x = utils.recompute(layer, x, policy=_KEPT) if self._remat \
+                else layer(x)
             if isinstance(x, tuple):
                 x, moved = x
                 layer.experts.moe.move_bias(moved)

@@ -88,6 +88,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
 from . import kernel_trace
@@ -95,7 +96,12 @@ from . import kernel_trace
 __all__ = ["flash_attention", "flash_attention_supported",
            "flash_attention_legal", "flash_attention_lse",
            "attention_with_lse", "flash_attention_on_mesh",
-           "attention_route"]
+           "attention_route", "ATTENDED_NAME"]
+
+#: what a forward kernel hands its backward, for a `jax.checkpoint` policy:
+#: the output and the log-sum-exp. A recomputed layer that saves the name
+#: runs the forward kernel once a step; q, k and v are made again by XLA.
+ATTENDED_NAME = "flash_attended"
 
 # The short family visits one head's whole (S, S) float32 tile per grid step.
 # 768 is the longest tile that compiles for a v5e within Mosaic's default
@@ -820,6 +826,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     return _fa_fwd(q, k, v, causal, scale, block_q, block_k, window)[0]
 
 
+def _attended(*written):
+    return tuple(checkpoint_name(t, ATTENDED_NAME) for t in written)
+
+
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -834,12 +844,14 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     if v.shape[-1] > k.shape[-1]:
         _WIDE_VALUES.inc(route=route)
     if route == "short":
-        out, lse = _short_call(q, k, v, causal, scale, _interpret())
+        out, lse = _attended(*_short_call(q, k, v, causal, scale,
+                                          _interpret()))
         return out, (q, k, v, None, lse)        # its backward needs no O
     if route == "streamed":
         block_q, block_k = _resolve_blocks(q.shape[2], block_q, block_k,
                                            window)
-        out, lse = _fa_call(q, k, v, causal, scale, block_q, block_k, window)
+        out, lse = _attended(*_fa_call(q, k, v, causal, scale, block_q,
+                                       block_k, window))
     else:
         out, lse = _blocked_reference(q, k, v, causal, scale, window), None
     return out, (q, k, v, out, lse)
@@ -920,7 +932,7 @@ def _fa_lse_fwd(q, k, v, causal, scale, block_q, block_k):
     block_q, block_k = _resolve_blocks(S, block_q, block_k)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    out, lse = _fa_call(q, k, v, causal, scale, block_q, block_k)
+    out, lse = _attended(*_fa_call(q, k, v, causal, scale, block_q, block_k))
     return (out, lse.reshape(B, H, S)), (q, k, v, out, lse)
 
 

@@ -87,12 +87,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
 from . import kernel_trace
 from .attention import _interpret, _kernels_run_here
 
-__all__ = ["gated_delta_rule"]
+__all__ = ["gated_delta_rule", "RULED_NAME"]
+
+#: what the forward kernel writes, for a `jax.checkpoint` policy: o and the
+#: chunks' starting states. A recomputed layer that saves the name runs the
+#: forward kernel once a step.
+RULED_NAME = "delta_ruled"
 
 _CALLS = telemetry.counter(
     "mxtpu_delta_rule_total",
@@ -581,7 +587,8 @@ def _rule_kernels(q, k, v, g, beta, h, chunk):
 
 def _rule_kernels_fwd(q, k, v, g, beta, h, chunk):
     args = (q, k, v, g, beta)
-    o, starts = _fwd_call(args, h, chunk, True, _interpret())
+    o, starts = (checkpoint_name(t, RULED_NAME)
+                 for t in _fwd_call(args, h, chunk, True, _interpret()))
     return o, (args, starts)
 
 

@@ -76,12 +76,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
 from . import kernel_trace
 from .attention import _interpret, _kernels_run_here
 
-__all__ = ["selective_scan"]
+__all__ = ["selective_scan", "SCANNED_NAME"]
+
+#: what the forward kernel writes, for a `jax.checkpoint` policy: y and the
+#: chunks' starting states. A recomputed layer that saves the name runs the
+#: forward kernel once a step.
+SCANNED_NAME = "selective_scanned"
 
 _SCANS = telemetry.counter(
     "mxtpu_selective_scan_total",
@@ -547,7 +553,8 @@ def _scan_kernels(x, low, A, B, C, D, w, bias, q):
 
 def _scan_kernels_fwd(x, low, A, B, C, D, w, bias, q):
     args = (x, low, A, B, C, D, w, bias)
-    y, starts = _fwd_call(args, q, True, _interpret())
+    y, starts = (checkpoint_name(t, SCANNED_NAME)
+                 for t in _fwd_call(args, q, True, _interpret()))
     return y, (args, starts)
 
 

@@ -14,6 +14,7 @@ import pytest
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import autograd, models, nd, telemetry
+from incubator_mxnet_tpu.gluon import utils as gutils
 from incubator_mxnet_tpu.models import evabyte
 from incubator_mxnet_tpu.ops import eva_attention as eva
 from incubator_mxnet_tpu.ops.lm_ce import multibyte_cross_entropy
@@ -202,13 +203,19 @@ CONFIG = {"hidden_size": 64, "intermediate_size": 96,
           "rms_norm_eps": 1e-5, "rope_theta": 100000}
 
 
-def tiny_model(seed=0, remat=False):
+#: heads of 128 in windows of 128: the exact part on the streamed kernels
+KERNEL_CONFIG = dict(CONFIG, hidden_size=256, num_attention_heads=2,
+                     window_size=128)
+
+
+def tiny_model(seed=0, remat=False, config=CONFIG):
     mx.random.seed(seed)
     net = models.EvaByteModel(
-        CONFIG["vocab_size"], CONFIG["hidden_size"],
-        CONFIG["intermediate_size"], CONFIG["num_layers"],
-        attention=dict(num_heads=CONFIG["num_attention_heads"], window=W,
-                       chunk=C, rope_theta=1e5),
+        config["vocab_size"], config["hidden_size"],
+        config["intermediate_size"], config["num_layers"],
+        attention=dict(num_heads=config["num_attention_heads"],
+                       window=config["window_size"], chunk=C,
+                       rope_theta=1e5),
         remat_layers=remat)
     net.initialize(mx.init.Xavier())
     for layer in net.layers:
@@ -232,14 +239,27 @@ def batch(seq_len, seed=1):
     return ids[:, :-1].astype("int32"), ids[:, 1:].astype("int32")
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
-def test_the_model_is_the_reference(reference, remat):
-    net = tiny_model(remat=remat)
-    tokens, labels = batch(3 * W)
+@pytest.mark.parametrize("remat,config", [
+    (False, CONFIG), (True, CONFIG), (True, KERNEL_CONFIG)],
+    ids=["kept", "recomputed", "recomputed_kernels"])
+def test_the_model_is_the_reference(monkeypatch, reference, remat, config):
+    """The last case: the exact windows on the streamed kernels
+    (interpreted), every layer recomputed. This model's layers keep
+    NOTHING of their forward (`recompute` gets no policy): at the cell's
+    size the 0.6 GB that the windows' o and lse would take are not there,
+    XLA recomputes other things to fit and the step gains nothing
+    (PERF.md section 6, PR 46)."""
+    if config is KERNEL_CONFIG:
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    window = config["window_size"]
+    calls = eva._CALLS.value(local="streamed", remote="strips")
+    policies = [gutils._RECOMPUTES.value(policy=p) for p in ("none", "given")]
+    net = tiny_model(remat=remat, config=config)
+    tokens, labels = batch(3 * window)
     params, _ = reference_params(net)
     loss_fn = models.MultiByteLoss(net)
     with jax.default_matmul_precision("highest"):
-        tail, ref_loss = reference.forward(params, CONFIG, tokens, labels, 16)
+        tail, ref_loss = reference.forward(params, config, tokens, labels, 16)
         with autograd.record():
             feats = net.features(nd.array(tokens))
             loss = loss_fn(feats, nd.array(labels))
@@ -247,11 +267,11 @@ def test_the_model_is_the_reference(reference, remat):
         np.testing.assert_allclose(feats.asnumpy()[:, -16:], tail, atol=2e-5)
         np.testing.assert_allclose(loss.asnumpy(), ref_loss, rtol=1e-5)
         z = net(nd.array(tokens))
-        assert z.shape == (B, 3 * W, 8, CONFIG["vocab_size"])
+        assert z.shape == (B, 3 * window, 8, config["vocab_size"])
         assert z.dtype == np.float32
         np.testing.assert_allclose(
-            z.asnumpy(), reference.logits(params, CONFIG, tokens), atol=2e-5)
-        grads = reference.checked_grads(params, CONFIG, tokens, labels)
+            z.asnumpy(), reference.logits(params, config, tokens), atol=2e-5)
+        grads = reference.checked_grads(params, config, tokens, labels)
     last = net.layers[-1]
     got = {"phi": last.attn.phi, "mu": last.attn.mu,
            "q": last.attn.query.weight, "k": last.attn.key.weight,
@@ -266,6 +286,12 @@ def test_the_model_is_the_reference(reference, remat):
     np.testing.assert_allclose(head[:20], grads["head_pred0"], atol=2e-5)
     np.testing.assert_allclose(head[-20:], grads["head_pred7"], atol=2e-5)
     assert np.any(grads["head_pred7"]) and np.any(grads["phi"])
+    assert (eva._CALLS.value(local="streamed", remote="strips") > calls) \
+        == (config is KERNEL_CONFIG)
+    # (the eager tape and the second forward each trace the stack once)
+    none, given = (gutils._RECOMPUTES.value(policy=p) - was
+                   for p, was in zip(("none", "given"), policies))
+    assert (none > 0, given) == (remat, 0)
 
 
 def test_the_residual_stream_stays_float32_under_a_bfloat16_cast():

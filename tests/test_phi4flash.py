@@ -277,10 +277,21 @@ def test_window_none_is_the_kernel_it_was(monkeypatch, shape, causal):
         return attention.flash_attention(q, k, v, causal, window=None) \
             .astype(jnp.float32).sum()
 
-    texts = [str(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(x, x, x))
-             for f in (absent, none)]
+    def traced():
+        return [str(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(x, x, x))
+                for f in (absent, none)]
+
+    texts = traced()
     assert texts[0] == texts[1]
-    assert hashlib.sha256(texts[0].encode()).hexdigest()[:16] \
+    # since PR 46 the forward rule names o and lse for checkpoint policies:
+    # two equations that lower to nothing; without them, the parent's text
+    named = " = name[name=%s]" % attention.ATTENDED_NAME
+    assert texts[0].count(named) == 2
+    with monkeypatch.context() as patch:
+        patch.setattr(attention, "checkpoint_name", lambda x, name: x)
+        plain = traced()[0]
+    assert len(plain.splitlines()) == len(texts[0].splitlines()) - 2
+    assert hashlib.sha256(plain.encode()).hexdigest()[:16] \
         == PARENT_JAXPR[shape, causal]
     assert "name=flash_fwd" in texts[0] and "name=flash_bwd_dkvq" in texts[0]
     assert "flash_window" not in texts[0]
@@ -471,33 +482,55 @@ def _grads(net, tokens, labels):
 KERNEL_CFG = dict(CFG, mamba_expand=16, mamba_d_state=4)
 
 
-@pytest.mark.parametrize("remat,cfg,path", [
-    (False, CFG, "chunked_xla"), (True, CFG, "chunked_xla"),
-    (True, KERNEL_CFG, "pallas")],
-    ids=["stored", "recomputed", "recomputed_scan_kernels"])
-def test_every_gradient_matches_the_reference(monkeypatch, remat, cfg, path):
+# heads of 128 at 128 positions: the streamed attention kernels (causal in
+# F and C, under the window in S), a value 256 wide on keys of 128
+ATTENTION_KERNEL_CFG = dict(CFG, hidden_size=256, num_attention_heads=2,
+                            num_key_value_heads=2, sliding_window=64,
+                            layer_pattern_run="MSMFGC")
+
+
+@pytest.mark.parametrize("remat,cfg,path,s", [
+    (False, CFG, "chunked_xla", S), (True, CFG, "chunked_xla", S),
+    (True, KERNEL_CFG, "pallas", S),
+    (True, ATTENTION_KERNEL_CFG, "chunked_xla", 128)],
+    ids=["stored", "recomputed", "recomputed_scan_kernels",
+         "recomputed_attention_kernels"])
+def test_every_gradient_matches_the_reference(monkeypatch, remat, cfg, path,
+                                              s):
     """EVERY parameter's gradient against autodiff of the reference,
     float32 at "highest", with and without per-layer recomputation (the
     tuples `gluon.utils.recompute` carries): 2e-4 of each gradient's
     largest entry. Two G and two C behind the F: F's projection holds dK
     and dV summed over itself and both readers, the memory's Mamba the sum
-    over both gates. The last case: the scans as their kernel pair
+    over both gates. The third case: the scans as their kernel pair
     (interpreted; three chunks of 64, the last one padded), forward,
-    recomputed with the chunks' start states kept, and backward."""
-    if path == "pallas":
+    recomputed with the chunks' start states kept, and backward: the
+    recomputation keeps what the kernels wrote (`phi4flash._KEPT`) and
+    runs none of them again. The last case, the same for the streamed
+    attention kernels (interpreted): o and lse of the first forward beside
+    q, k, v made again."""
+    kernels = path == "pallas" or cfg is ATTENTION_KERNEL_CFG
+    if kernels:
         monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     scans = scan_mod._SCANS.value(path=path)
+    streamed = attention._ROUTES.value(route="streamed")
+    given = gutils._RECOMPUTES.value(policy="given")
     net = build(cfg, remat=remat)
-    tokens, labels = batch()
+    tokens, labels = batch(cfg=cfg, s=s)
     with jax.default_matmul_precision("highest"):
         want = jax.grad(lambda p: reference.forward(
             p, cfg, jnp.asarray(tokens), jnp.asarray(labels), 1)[1].sum())(
                 reference._f32(builder.reference_params(net)))
         got = _grads(net, tokens, labels)
     assert scan_mod._SCANS.value(path=path) > scans
+    assert (attention._ROUTES.value(route="streamed") > streamed) \
+        == (cfg is ATTENTION_KERNEL_CFG)
+    assert gutils._RECOMPUTES.value(policy="given") - given \
+        == (len(cfg["layer_pattern_run"]) if remat else 0)
     flat_w, tree_w = jax.tree_util.tree_flatten_with_path(want)
     flat_g, tree_g = jax.tree_util.tree_flatten_with_path(got)
-    assert tree_w == tree_g and len(flat_w) > 90
+    assert tree_w == tree_g \
+        and len(flat_w) > (70 if cfg is ATTENTION_KERNEL_CFG else 90)
     for (path, w), (_, g) in zip(flat_w, flat_g):
         name = jax.tree_util.keystr(path)
         assert onp.abs(w).max() > 0, name
@@ -627,7 +660,11 @@ def test_one_train_step_lowers_once_and_keeps_the_scopes_under_recompute(
     for scope in ("selective_scan", "mamba_conv", "mamba_gate",
                   "diff_attention", "gmu", "cross_attention", "ffn"):
         paths = [l for l in text.splitlines() if "/" + scope + "/" in l]
-        assert any("rematted_computation" in l for l in paths), scope
+        # (the scan as its kernel pair is ALL that is under its scope, and
+        # a recomputed layer keeps what the forward kernel wrote: nothing
+        # of the scope is computed again)
+        assert any("rematted_computation" in l for l in paths) \
+            == ((scope, path) != ("selective_scan", "pallas")), scope
         assert any("transpose(" in l for l in paths), scope
         assert any("transpose(" not in l for l in paths), scope
     assert attention._WINDOWS.value(route="streamed") >= 1

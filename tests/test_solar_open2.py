@@ -19,7 +19,9 @@ import pytest
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import gluon, jit, models, nd
+from incubator_mxnet_tpu.gluon import utils as gutils
 from incubator_mxnet_tpu.ops import attention
+from incubator_mxnet_tpu.ops import delta_rule as rule_mod
 
 PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "perfbench")
@@ -349,22 +351,42 @@ def _loss_and_grads(net, tokens, labels):
     return dict(zip([p.name for p in params], grads))
 
 
-@pytest.mark.parametrize("remat", [False, True],
-                         ids=["stored", "recomputed"])
-def test_gradients_match_the_reference(remat):
+#: heads of 128 at 128 positions: the delta rule's kernel pair and, in the
+#: `G` layer, the streamed attention kernels
+KERNEL_CFG = dict(CFG, head_dim=128, hidden_size=128, linear_attn_config=dict(
+    CFG["linear_attn_config"], head_dim=128))
+
+
+@pytest.mark.parametrize("remat,cfg,s", [
+    (False, CFG, S), (True, CFG, S), (True, KERNEL_CFG, 128)],
+    ids=["stored", "recomputed", "recomputed_kernels"])
+def test_gradients_match_the_reference(monkeypatch, remat, cfg, s):
     """Every checked parameter's gradient (the last K layer's A_log,
     dt_bias, both rank -> heads x d maps and the in-projection by its rows;
     the G layer's gate; the last layer's router, shared expert and held
     experts) against the reference's, float32 at "highest", with and
     without per-layer recomputation: 1e-4 of each gradient's largest entry
-    (summation order through three layers and the head)."""
-    net = build(remat=remat)
-    tokens, labels = batch()
+    (summation order through three layers and the head). The last case:
+    both kinds of kernel (interpreted), every layer recomputed but for
+    what they wrote (`solar_open2._KEPT`): o and the chunks' states, o and
+    lse of the first forward beside operands made again."""
+    kernels = cfg is KERNEL_CFG
+    if kernels:
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    ran = (rule_mod._CALLS.value(path="pallas"),
+           attention._ROUTES.value(route="streamed"),
+           gutils._RECOMPUTES.value(policy="given"))
+    net = build(cfg, remat=remat, attention="flash" if kernels else "dense")
+    tokens, labels = batch(cfg=cfg, s=s)
     with jax.default_matmul_precision("highest"):
-        want = reference.checked_grads(builder.reference_params(net), CFG,
+        want = reference.checked_grads(builder.reference_params(net), cfg,
                                        jnp.asarray(tokens),
                                        jnp.asarray(labels))
         got = _loss_and_grads(net, tokens, labels)
+    assert (rule_mod._CALLS.value(path="pallas") - ran[0],
+            attention._ROUTES.value(route="streamed") > ran[1],
+            gutils._RECOMPUTES.value(policy="given") - ran[2]) \
+        == (2 * kernels, kernels, 3 * remat)
     k, e = net.layers[2].mixer, net.layers[2].experts
     rows = onp.split(onp.asarray(got[k.in_proj.weight.name]),
                      reference._in_proj_rows(
@@ -382,7 +404,7 @@ def test_gradients_match_the_reference(remat):
                  for n, p in (("w1", e.moe.w1), ("w2", e.moe.w2),
                               ("w3", e.moe.w3)) for i in range(HELD)})
     assert set(want) == set(mine)
-    assert onp.asarray(want["kda_beta"]).shape == (2, 64)
+    assert onp.asarray(want["kda_beta"]).shape == (2, cfg["hidden_size"])
     for name in want:
         w, g = onp.asarray(want[name]), onp.asarray(mine[name])
         assert onp.abs(g - w).max() < 1e-4 * onp.abs(w).max(), name
