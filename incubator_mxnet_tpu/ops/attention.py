@@ -36,8 +36,14 @@ shape alone:
   counts the blocks of a row's BAND instead of all of them (at S = 16k,
   window 512, blocks of 512: 63 live pairs a head where the causal grid
   visits 528), the index maps are clamped from both sides so a step past
-  the band moves nothing, and every live pair is masked. ``window=None``
-  traces exactly the calls there were before there was a window.
+  the band moves nothing, and every live pair is masked. **Which rows can
+  be empty:** in the causal and dense forward none (key 0 is in the first
+  tile of every q-block and every query sees it), so `_fa_kernel` masks
+  only the tiles the diagonal crosses (two bodies chosen from the program
+  ids: *interior* and *diagonal*) and guards no row; under a window a
+  row's first live tile may hold none of its keys, so `_fa_window_kernel`
+  alone keeps the -inf guards of the online softmax (the body both had up
+  to PR 46, value for value).
 * **short** (flash_short_fwd, flash_short_bwd): narrow heads that tile 128
   lanes (D of 32 or 64, whole blocks of H*D) below S = 2048 whose whole
   (S, S) float32 tile fits VMEM and is worth a visit (_SHORT_MIN_S <= S <=
@@ -60,8 +66,10 @@ route), mxtpu_attention_backward_total{kernel} (a streamed backward:
 one call, or segmented; flash_window_bwd under a window),
 mxtpu_attention_window_total{route} and
 mxtpu_attention_wide_value_total{route} (v wider than k); the gauge
-mxtpu_attention_live_block_pairs{kind="window"|"causal"} holds what the
-last traced windowed forward visits a head, and what the causal grid would.
+mxtpu_attention_live_block_pairs{kind} holds what the last traced forward
+visits a head: "window" and "causal" (what the causal grid would) of a
+windowed call, "interior" and "diagonal" of a causal or dense one (120 and
+16 at S = 16 384 and blocks of 1024: how often the unmasked body engages).
 
 Why two families (v5e, BERT-large's (16, 16, 512, 64) bf16, attention
 alone, forward + backward, a call; PERF.md §6, PR 26): the composite takes
@@ -80,6 +88,11 @@ padded to 128 in HBM), so each softmax map against [v_1; v_2] 128 wide in
 ONE call takes 14.2 ms forward and 37.7 with the backward where the two
 calls of one width it replaces take 28.3 and 74.6 (window 512: 4.0 / 8.9
 for 7.7 / 17.3), the output columns equal to the bit.
+Why the forward has two bodies, sub-tiles and lane-kept statistics (v5e,
+the kernel alone, ms a call; PERF.md §6, PR 47): (1, 16, 16384, 128) bf16
+causal 10.60 -> 7.62, (1, 20, 16384, 64 | 128) 13.37 -> 9.64, EvaByte's
+(256, 1, 2048, 128) 4.53 -> 3.45; the windowed call 3.27 both, every
+output and gradient equal to the bit.
 """
 from __future__ import annotations
 
@@ -140,8 +153,10 @@ _WIDE_VALUES = telemetry.counter(
 _LIVE_PAIRS = telemetry.gauge(
     "mxtpu_attention_live_block_pairs",
     "Block pairs a head's streamed forward visits, set when a call is "
-    "traced: under a window, and what the causal kernel would visit at "
-    "the same blocks.", ("kind",))
+    "traced: under a window (window), and what the causal kernel would "
+    "visit at the same blocks (causal); of a causal or dense call, the "
+    "pairs that are never masked (interior) and the others (diagonal).",
+    ("kind",))
 
 
 def _interpret():
@@ -193,6 +208,19 @@ def _band(n_blocks, first, last):
     with jax.ensure_compile_time_eval():      # they are jnp arithmetic
         counts = [int(last(i)) - int(first(i)) + 1 for i in range(n_blocks)]
     return max(counts), sum(counts)
+
+
+def _body_pairs(S, block_q, block_k, causal):
+    """(interior, diagonal): the block pairs of a head that each body of
+    the causal / dense forward kernel visits. Interior: the tile's last key
+    is not past the q-block's first query (never masked; every pair when
+    not causal); diagonal: the other live pairs."""
+    rows = range(S // block_q)
+    if not causal:
+        return len(rows) * (S // block_k), 0
+    interior = sum((i * block_q + 1) // block_k for i in rows)
+    live = sum(((i + 1) * block_q - 1) // block_k + 1 for i in rows)
+    return interior, live - interior
 
 
 def _seen(Sq, Sk, window):
@@ -298,8 +326,35 @@ def _last_q_block(kb, block_q, block_k, window, n_q):
     return jnp.minimum(((kb + 1) * block_k + window - 2) // block_q, n_q - 1)
 
 
+#: keys a sub-tile of the forward body: a kv-block that is a larger multiple
+#: of it is visited in sub-tiles, so that one sub-tile's Q K^T can run on the
+#: MXU while the vector unit is at the previous one's softmax. Measured on a
+#: v5e at (1, 16, 16384, 128), blocks of 1024 (PERF.md section 6, PR 47): 512
+#: keys 7.63 ms a call, 256 keys 8.07, the whole block 8.91.
+_SUB_K = 512
+
+
+def _sub_tile(block_k):
+    """(keys a sub-tile of the forward body, lanes its running max and sum
+    are kept in): 512 and 128 at blocks of 1024."""
+    width = _SUB_K if block_k % _SUB_K == 0 else block_k
+    return width, math.gcd(width, 128)
+
+
+def _lanes(x, width):
+    """A statistic kept in every lane of (rows, lanes), as wide as an
+    operand of ``width`` columns."""
+    from jax.experimental.pallas import tpu as pltpu
+    lanes = x.shape[1]
+    if width == lanes:
+        return x
+    if width % lanes == 0:
+        return pltpu.repeat(x, width // lanes, 1)
+    return x[:, :1]
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-               block_k, causal, scale, window=None):
+               block_k, causal, scale):
     """One (batch*head, q-block, k-block) program: K/V are STREAMED by the
     grid — VMEM holds only (block_q + 2*block_k) x D tiles (v's, the
     accumulator and the output D_v wide: the body reads every width off
@@ -308,17 +363,38 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     steps), so sequence length is bounded by HBM, not VMEM (S=32k+ on one
     chip).  Writes the per-row LSE (m + log l) the backward kernels consume.
 
-    Under a ``window`` (key j seen from i iff 0 <= i - j < window) the last
-    grid axis counts the kv-blocks of the q-block's BAND, not all of them:
-    step j is kv-block `_first_kv_block(qb) + j`, live while it is not past
-    the diagonal. A row may see no key of a live block (its window starts
-    further right): the carry's -inf guards, which the causal kernel needs
-    for no row, hold it at zero until its first key comes.
+    **A tile does its own work and no more** (PR 47). Which of two bodies
+    runs is read off the program ids: a live tile whose last key is not
+    past the q-block's first query is *interior* and is never masked (no
+    iota, no compare, no select: 120 of a head's 136 live pairs at S = 16k
+    and blocks of 1024, every tile when not causal); the others are
+    *diagonal* and take the mask by one select. **No row is ever empty
+    here, so nothing guards one:** key 0 is in the first sub-tile of
+    kv-block 0 and every query sees it, so after a q-block's first
+    sub-tile every running max is finite; before it, alpha = exp(-inf -
+    finite) = 0 scales sums that are 0, and a masked score is exp(-inf -
+    finite) = 0 without help. A row of a diagonal sub-tile that sees none
+    of its keys (block_q > block_k, or the later sub-tiles of a square
+    diagonal block) keeps the finite max it came with. Only a window can
+    start a row with no key: `_fa_window_kernel` keeps the guards.
+
+    Nothing a q-block owns is made again a kv step: q, k go to the MXU in
+    the type they came in (`_nt`, no transpose), the scale multiplies the
+    float32 scores as the backward's does (so the backward's recomputed P
+    is the forward's; q scaled once a q-block into a scratch measured 0.1
+    ms a call less at 16k and keeps the rounding of a scaled bf16 q:
+    PERF.md section 6, PR 47), P reaches the MXU as float32. The running max and sum live
+    in every lane of (block_q, lanes) scratch: the sum as a lane's PARTIAL
+    sum (adds of whole vregs; the one cross-lane sum is `_finish`'s), the
+    max the row's in every lane. The kv-block is visited in sub-tiles of
+    `_SUB_K` keys (`_sub_tile`), each with its own max / exp / P V step,
+    traced once and unrolled where it is lowered.
     """
     from jax.experimental import pallas as pl
 
     qb, kb = pl.program_id(1), pl.program_id(2)
     block_q = q_ref.shape[1]
+    width, lanes = _sub_tile(block_k)
 
     @pl.when(kb == 0)
     def _init():
@@ -326,26 +402,83 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    step = kb
-    if window is not None:
-        kb = _first_kv_block(qb, block_q, block_k, window) + step
-    # K/V blocks fully above the diagonal contribute nothing in causal mode
-    live = ((qb + 1) * block_q - 1 >= kb * block_k) if causal else (kb >= 0)
+    def _tile(masked):
+        q = q_ref[0]                                     # (block_q, D)
 
-    @pl.when(live)
+        def sub_tile(c, carry):
+            m, l, acc = carry
+            keys = pl.ds(pl.multiple_of(c * width, width), width)
+            s = _nt(q, k_ref[0, keys, :]) * scale        # (block_q, width)
+            if masked:
+                qi = qb * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, 1), 0)
+                ki = kb * block_k + c * width + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, width), 1)
+                s = jnp.where(qi >= ki, s, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, width))
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + functools.reduce(
+                jnp.add, [p[:, i:i + lanes] for i in range(0, width, lanes)])
+            acc = acc * _lanes(alpha, acc.shape[1]) \
+                + _mm(p, v_ref[0, keys, :].astype(jnp.float32))
+            return m_new, l, acc
+
+        m_s[...], l_s[...], acc_s[...] = jax.lax.fori_loop(
+            0, block_k // width, sub_tile, (m_s[...], l_s[...], acc_s[...]),
+            unroll=True)
+
+    if causal:
+        # K/V blocks fully above the diagonal contribute nothing
+        interior = (kb + 1) * block_k - 1 <= qb * block_q
+        live = (qb + 1) * block_q - 1 >= kb * block_k
+        pl.when(interior)(lambda: _tile(masked=False))
+        pl.when(live & jnp.logical_not(interior))(lambda: _tile(masked=True))
+    else:
+        _tile(masked=False)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finish():
+        l = jnp.maximum(jnp.sum(l_s[...], axis=-1, keepdims=True), 1e-37)
+        o_ref[0, :, :] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0, :] = (m_s[:, :1] + jnp.log(l))[:, 0]
+
+
+def _fa_window_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
+                      block_k, scale, window):
+    """`_fa_kernel`'s program under a ``window`` (key j seen from i iff
+    0 <= i - j < window): the last grid axis counts the kv-blocks of the
+    q-block's BAND, not all of them: step j is kv-block
+    `_first_kv_block(qb) + j`, live while it is not past the diagonal, and
+    every live tile is masked. A row may see no key of a live block (its
+    window starts further right): the carry's -inf guards, which the causal
+    kernel needs for no row, hold it at zero until its first key comes.
+    The body is the one both kernels had up to PR 46, value for value.
+    """
+    from jax.experimental import pallas as pl
+
+    qb, step = pl.program_id(1), pl.program_id(2)
+    block_q = q_ref.shape[1]
+
+    @pl.when(step == 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, -jnp.inf)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    kb = _first_kv_block(qb, block_q, block_k, window) + step
+
+    @pl.when((qb + 1) * block_q - 1 >= kb * block_k)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale        # (block_q, D)
         k_blk = k_ref[0].astype(jnp.float32)            # (block_k, D)
         v_blk = v_ref[0].astype(jnp.float32)
         s = q @ k_blk.T                                  # (block_q, block_k)
-        if causal:
-            qi = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            ki = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            seen = qi >= ki if window is None \
-                else (qi >= ki) & (qi - ki < window)
-            s = jnp.where(seen, s, -jnp.inf)
+        qi = qb * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        ki = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        s = jnp.where((qi >= ki) & (qi - ki < window), s, -jnp.inf)
         m, l, acc = m_s[...], l_s[...], acc_s[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -361,8 +494,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     def _finish():
         l = jnp.maximum(l_s[...], 1e-37)
         o_ref[0, :, :] = (acc_s[...] / l).astype(o_ref.dtype)
-        # rows with l=0 cannot occur (causal keeps the diagonal; dense
-        # keeps all)
+        # the diagonal is inside every window: no row ends with l = 0
         lse_ref[0, 0, :] = (m_s[...] + jnp.log(l))[:, 0]
 
 
@@ -377,8 +509,6 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
     kf = k.reshape(B * H, S, D)
     vf = v.reshape(B * H, S, Dv)
     grid = (B * H, S // block_q, S // block_k)
-    kernel = functools.partial(_fa_kernel, block_k=block_k, causal=causal,
-                               scale=scale)
     if window is not None:
         # the grid's last axis spans a q-block's band of kv-blocks; the
         # index map is clamped from BOTH sides, so a step past the diagonal
@@ -394,19 +524,26 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
         _LIVE_PAIRS.set(_band(S // block_q, lambda i: 0, last)[1],
                         kind="causal")
         grid = grid[:2] + (steps,)
-        kernel = functools.partial(kernel, window=window)
+        kernel = functools.partial(_fa_window_kernel, block_k=block_k,
+                                   scale=scale, window=window)
+        lanes = 1                 # the guarded body's columns
 
         def kv_idx(b, i, j):
             return (b, jnp.minimum(first(i) + j, last(i)), 0)
-    elif causal:
+    else:
+        kernel = functools.partial(_fa_kernel, block_k=block_k,
+                                   causal=causal, scale=scale)
+        lanes = _sub_tile(block_k)[1]
+        interior, diagonal = _body_pairs(S, block_q, block_k, causal)
+        _LIVE_PAIRS.set(interior, kind="interior")
+        _LIVE_PAIRS.set(diagonal, kind="diagonal")
+
         # dead blocks above the diagonal: clamp the index map so the grid
         # step re-uses the resident block instead of DMA-ing one it will
         # never read (compute is skipped by pl.when in the kernel)
         def kv_idx(b, i, j):
-            return (b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
-    else:
-        def kv_idx(b, i, j):
-            return (b, j, 0)
+            last = ((i + 1) * block_q - 1) // block_k
+            return (b, jnp.minimum(j, last) if causal else j, 0)
     out, lse = kernel_trace.pallas_call(
         kernel, (qf, kf, vf),
         out_shape=(jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
@@ -419,8 +556,8 @@ def _fa_call(q, k, v, causal, scale, block_q, block_k, window=None):
         ],
         out_specs=(pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32),
+                        pltpu.VMEM((block_q, lanes), jnp.float32),
                         pltpu.VMEM((block_q, Dv), jnp.float32)],
         interpret=_interpret(),
         name="flash_fwd" if window is None else "flash_window_fwd",

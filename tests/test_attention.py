@@ -54,14 +54,57 @@ def test_flash_head_dims(D):
         assert float(jnp.max(jnp.abs(x - y)) / jnp.max(jnp.abs(y))) < 1e-3
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_matches_composite(causal):
+def _case(shape=(1, 2, 256, 128), d_v=None, dtype="float32", causal=True,
+          window=None, scale=None, blocks=(None, None)):
+    return types.SimpleNamespace(**locals())
+
+
+# What the streamed forward must carry: the tile's two bodies (interior:
+# never masked; diagonal: one select), no guard of an empty row where none
+# can be empty, and the guarded body under a window.
+_FORWARD = {
+    "dense": _case(causal=False),
+    "causal": _case(),
+    # a diagonal tile holds rows that see none of its keys, both ways
+    "causal-256x128": _case(shape=(1, 2, 512, 128), blocks=(256, 128)),
+    "causal-128x256": _case(shape=(1, 2, 512, 128), blocks=(128, 256)),
+    "dense-128x256": _case(shape=(1, 2, 512, 128), causal=False,
+                           blocks=(128, 256)),
+    # blocks of 1024 in two sub-tiles of 512 keys, one interior pair and
+    # two diagonal; heads of 64 against a value 128 wide (the SambaY F, C)
+    "subtiles-64|128": _case(shape=(1, 1, 2048, 64), d_v=128),
+    "bf16": _case(dtype="bfloat16"),
+    "scale-0.3": _case(scale=0.3),                       # Ulysses's own
+    # rows 448.. of q-block 1 see no key of their first live tile (keys
+    # 128..255): the window body's guards carry them at zero
+    "window-empty-first-tile": _case(shape=(1, 2, 512, 128), window=64,
+                                     blocks=(256, 128)),
+}
+
+
+def _case_qkv(c, seed=0):
+    rng = onp.random.RandomState(seed)
+    wide = c.shape[:3] + (c.d_v or c.shape[3],)
+    return tuple(jnp.asarray(rng.randn(*s).astype("float32")).astype(c.dtype)
+                 for s in (c.shape, c.shape, wide))
+
+
+@pytest.mark.parametrize("case", list(_FORWARD))
+def test_flash_forward_matches_composite(case):
     from incubator_mxnet_tpu.ops import attention as A
-    q, k, v = _rand_qkv()
-    assert A.flash_attention_supported(q.shape)
-    out = A.flash_attention(q, k, v, causal)
-    ref = A._blocked_reference(q, k, v, causal, 1.0 / onp.sqrt(q.shape[-1]))
-    assert float(jnp.max(jnp.abs(out - ref))) < 2e-4
+    c = _FORWARD[case]
+    q, k, v = _case_qkv(c)
+    assert A.attention_route(q.shape, k.shape, v.shape, *c.blocks,
+                             c.window) == "streamed"
+    out = A.flash_attention(q, k, v, c.causal, c.scale, *c.blocks, c.window)
+    assert out.shape == v.shape and out.dtype == v.dtype
+    ref = A._blocked_reference(
+        *(x.astype(jnp.float32) for x in (q, k, v)), c.causal,
+        c.scale or 1.0 / onp.sqrt(q.shape[-1]), c.window)
+    assert bool(jnp.isfinite(out).all())
+    # bfloat16: the output's own rounding, 2^-9 of values up to ~4
+    tol = 2e-4 if c.dtype == "float32" else 2e-2
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref))) < tol
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -102,17 +145,46 @@ def test_flash_backward_never_materializes_scores():
                 f"(S,S) intermediate found: {eqn.primitive} -> {shape}"
 
 
-def test_flash_lse_saved_from_forward():
+@pytest.mark.parametrize("case", [
+    "dense", "causal", "causal-256x128", "causal-128x256", "dense-128x256",
+    "scale-0.3"])
+def test_flash_lse_saved_from_forward(case):
+    """The log-sum-exp the forward saves for its backward is the one
+    `flash_attention_lse` returns, and `_dense_with_lse`'s to 1e-5 (the
+    running sum is kept a lane, the scale multiplies float32 scores)."""
     from incubator_mxnet_tpu.ops import attention as A
-    q, k, v = _rand_qkv(S=256)
-    out, res = A._fa_fwd(q, k, v, False, None, 128, 128)
+    c = _FORWARD[case]
+    q, k, v = _case_qkv(c, seed=1)
+    B, H, S, D = q.shape
+    scale = c.scale or 1.0 / onp.sqrt(D)
+    blocks = tuple(b or 128 for b in c.blocks)
+    out, res = A._fa_fwd(q, k, v, c.causal, c.scale, *blocks)
     lse = res[4]
-    assert lse is not None and lse.shape == (q.shape[0] * q.shape[1], 1,
-                                             q.shape[2])
-    # LSE parity vs explicit logsumexp of the score matrix
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / onp.sqrt(q.shape[-1])
-    ref = jax.scipy.special.logsumexp(s, axis=-1).reshape(lse.shape)
-    assert float(jnp.max(jnp.abs(lse - ref))) < 2e-3
+    assert lse is not None and lse.shape == (B * H, 1, S)
+    out2, lse2 = A.flash_attention_lse(q, k, v, c.causal, scale, *blocks)
+    onp.testing.assert_array_equal(out, out2)
+    onp.testing.assert_array_equal(lse.reshape(B, H, S), lse2)
+    want_out, want_lse = A._dense_with_lse(q, k, v, c.causal, scale)
+    assert float(jnp.max(jnp.abs(lse2 - want_lse))) < 1e-5
+    assert float(jnp.max(jnp.abs(out2 - want_out))) < 2e-4
+
+
+@pytest.mark.parametrize("shape,causal,interior,diagonal", [
+    ((1, 16, 16384, 128), True, 120, 16),      # cerebras-gpt-1.3b.train-s16k
+    ((1, 16, 16384, 128), False, 256, 0),      # dense: never masked
+    ((256, 1, 2048, 128), True, 1, 2),         # EvaByte's windows of 2048
+])
+def test_live_pair_gauge_says_which_body_a_pair_takes(shape, causal,
+                                                      interior, diagonal):
+    """mxtpu_attention_live_block_pairs{kind="interior"|"diagonal"}, set
+    when a call is traced: how often the unmasked body engages."""
+    from incubator_mxnet_tpu.ops import attention as A
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: A._fa_call(
+        q, k, v, causal, 1.0 / math.sqrt(shape[-1]), 1024, 1024),
+        spec, spec, spec)
+    assert A._LIVE_PAIRS.value(kind="interior") == interior
+    assert A._LIVE_PAIRS.value(kind="diagonal") == diagonal
 
 
 @pytest.mark.parametrize("causal", [False, True])
