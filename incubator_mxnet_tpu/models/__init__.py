@@ -15,6 +15,12 @@
               decay per channel; gated grouped-query attention without
               position embedding; sigmoid-routed SwiGLU experts beside a
               shared expert), whole or as one chip's share
+- ling3:      Ling 3.0 (Kimi Delta Attention with full-rank maps and a
+              bounded decay, five layers to one of multi-head latent
+              attention: one latent and one rotary key a position for all
+              heads; a leading dense SwiGLU layer; sigmoid-routed experts
+              chosen under a group limit beside a shared expert), whole or
+              as one chip's share of its experts
 - keye_vl2:   Keye-VL 2.0's decoder (grouped-query attention over the keys a
               lightning indexer picks, the indexer's own KL loss, rotary
               positions from three streams, softmax-routed SwiGLU experts),
@@ -38,6 +44,7 @@ from .phi4flash import (Phi4FlashModel, SambaYLayer, Mamba1Mixer,  # noqa
 from .solar_open2 import (SolarOpen2Model, SolarOpen2Layer,  # noqa
                           KimiDeltaAttention, GatedGroupedQueryAttention,
                           SharedExpertMoE)
+from .ling3 import Ling3Model, MultiHeadLatentAttention  # noqa
 from .keye_vl2 import (KeyeVL2Model, KeyeVL2Layer,  # noqa
                        SparseGroupedQueryAttention)
 from .evabyte import (EvaByteModel, EvaByteLayer, EvaAttention,  # noqa

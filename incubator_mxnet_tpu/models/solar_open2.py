@@ -59,8 +59,9 @@ from .nemotron_h import (GroupedQueryAttention, _InverseSoftplusOfLogUniform,
                          _LogUniform)
 from .phi4flash import SwiGLU
 
-__all__ = ["SolarOpen2Model", "SolarOpen2Layer", "KimiDeltaAttention",
-           "GatedGroupedQueryAttention", "SharedExpertMoE"]
+__all__ = ["SolarOpen2Model", "SolarOpen2Layer", "MixerStackLM",
+           "KimiDeltaAttention", "GatedGroupedQueryAttention",
+           "SharedExpertMoE"]
 
 #: the pattern's letters: Kimi Delta Attention, gated grouped-query attention
 MIXERS = "KG"
@@ -86,32 +87,56 @@ class KimiDeltaAttention(HybridBlock):
     shard's heads' b. Scopes inside the block's own: `kda_conv` (the
     three short convolutions and the L2 norms), `kda_decay` (g and b),
     `delta_rule` (the op's), `kda_gate_norm`. ``A_log``, ``dt_bias`` and
-    the norm's gain stay float32 under ``cast``."""
+    the norm's gain stay float32 under ``cast``.
+
+    ``rank``: the decay's and the gate's maps are low-rank pairs units ->
+    rank -> heads x d (None: rank = head_dim), or with ``"full"`` ONE map
+    units -> heads x d each, two more blocks of ``in_proj``'s rows and no
+    ``decay_up`` / ``gate_up`` (Ling 3.0's `no_kda_lora`). ``decay``:
+    ``"softplus"`` is g = -exp(A_log) softplus(f + dt_bias), unbounded below;
+    ``("bounded", lower)`` is g = lower * sigmoid(exp(A_log) (f + dt_bias)),
+    in (lower, 0): a step's log-decay never under ``lower`` (the published
+    kernels' "safe gate", `kda_lower_bound`). ``neg_eigval`` False keeps b
+    in (0, 1). The defaults are Solar Open 2's block, its traced program
+    text for text (tests/test_ling3.py pins it). More than one head group
+    has run on the chip: 32 heads are 4 groups of 8 on the kernels' grid
+    (PERF.md section 6, PR 48 has the cost a group)."""
 
     def __init__(self, units, num_heads, head_dim, conv_kernel=4, rank=None,
-                 chunk=64, shards=1, neg_eigval=True, epsilon=1e-5, **kwargs):
+                 chunk=64, shards=1, neg_eigval=True, epsilon=1e-5,
+                 decay="softplus", **kwargs):
         super().__init__(**kwargs)
         if num_heads % shards:
             raise ValueError("%d heads do not divide into %d shards"
                              % (num_heads, shards))
+        if decay != "softplus" and not (
+                isinstance(decay, tuple) and len(decay) == 2
+                and decay[0] == "bounded" and decay[1] < 0):
+            raise ValueError("decay=%r: 'softplus' or ('bounded', a lower "
+                             "bound under 0)" % (decay,))
         self.heads, self.head_dim = num_heads // shards, head_dim
         self.inner = self.heads * head_dim
-        self.rank = rank or head_dim
+        self.rank = None if rank == "full" else rank or head_dim
         self._chunk, self._eps, self._k = chunk, epsilon, conv_kernel
         self._beta_max = 2.0 if neg_eigval else 1.0
+        self._lower = None if decay == "softplus" else float(decay[1])
+        # what the decay and the gate read of in_proj's output: rank wide,
+        # or a channel each
+        self._map_in = self.rank or self.inner
         with self.name_scope():
-            # rows: q, k, v (inner each), decay down, gate down (rank each),
-            # b (heads)
+            # rows: q, k, v (inner each), decay, gate (rank each going
+            # down, or inner each: the full-rank maps themselves), b (heads)
             self.in_proj = nn.Dense(
-                3 * self.inner + 2 * self.rank + self.heads, flatten=False,
+                3 * self.inner + 2 * self._map_in + self.heads, flatten=False,
                 in_units=units, use_bias=False)
             self.conv_weight = self.params.get(
                 "conv_weight", shape=(3 * self.inner, conv_kernel),
                 init=initializer.Uniform(1.0 / math.sqrt(conv_kernel)))
-            self.decay_up = self.params.get(
-                "decay_up", shape=(self.inner, self.rank), init="xavier")
-            self.gate_up = self.params.get(
-                "gate_up", shape=(self.inner, self.rank), init="xavier")
+            if self.rank:
+                self.decay_up = self.params.get(
+                    "decay_up", shape=(self.inner, self.rank), init="xavier")
+                self.gate_up = self.params.get(
+                    "gate_up", shape=(self.inner, self.rank), init="xavier")
             self.A_log = self.params.get("A_log", shape=(self.heads,),
                                          init=_LogUniform(1.0, 16.0))
             self.dt_bias = self.params.get(
@@ -149,18 +174,25 @@ class KimiDeltaAttention(HybridBlock):
             return unit(q) * self.head_dim ** -0.5, unit(k), \
                 v.astype(qkv.dtype)
 
-    def _mix(self, proj, conv_w, decay_up, gate_up, a_log, dt_bias, gamma):
+    def _mix(self, proj, conv_w, a_log, dt_bias, gamma, decay_up=None,
+             gate_up=None):
         b, s, _ = proj.shape
-        inner, r, h, d = self.inner, self.rank, self.heads, self.head_dim
+        inner, r, h, d = self.inner, self._map_in, self.heads, self.head_dim
         qkv, low_f, low_g, b_in = jnp.split(
             proj, [3 * inner, 3 * inner + r, 3 * inner + 2 * r], -1)
         q, k, v = self._conv(qkv, conv_w)
         with jax.named_scope("kda_decay"):
-            step = jax.nn.softplus(jnp.einsum(
-                "bsr,cr->bsc", low_f, decay_up,
-                preferred_element_type=jnp.float32)
-                + dt_bias.astype(jnp.float32)).reshape(b, s, h, d)
-            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * step
+            pre = low_f.astype(jnp.float32) if decay_up is None \
+                else jnp.einsum("bsr,cr->bsc", low_f, decay_up,
+                                preferred_element_type=jnp.float32)
+            pre = pre + dt_bias.astype(jnp.float32)
+            if self._lower is None:
+                step = jax.nn.softplus(pre).reshape(b, s, h, d)
+                g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * step
+            else:
+                g = self._lower * jax.nn.sigmoid(
+                    jnp.exp(a_log.astype(jnp.float32))[:, None]
+                    * pre.reshape(b, s, h, d))
             beta = self._beta_max * jax.nn.sigmoid(b_in.astype(jnp.float32))
         o = gated_delta_rule(q, k, v, g, beta, self._chunk)
 
@@ -170,17 +202,18 @@ class KimiDeltaAttention(HybridBlock):
                 o = o.astype(jnp.float32)
                 o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                                       + self._eps) * gamma.astype(jnp.float32)
-                gate = jax.nn.sigmoid(jnp.einsum(
-                    "bsr,cr->bsc", low, gate_up,
-                    preferred_element_type=jnp.float32))
+                gate = jax.nn.sigmoid(
+                    low.astype(jnp.float32) if gate_up is None
+                    else jnp.einsum("bsr,cr->bsc", low, gate_up,
+                                    preferred_element_type=jnp.float32))
                 return (o.reshape(b, s, inner) * gate).astype(proj.dtype)
 
         return gate_norm(o, low_g, gate_up, gamma)
 
     def forward(self, u):
-        y = _apply(self._mix, self.in_proj(u), *(p.data() for p in (
-            self.conv_weight, self.decay_up, self.gate_up, self.A_log,
-            self.dt_bias, self.norm_gamma)))
+        own = (self.conv_weight, self.A_log, self.dt_bias, self.norm_gamma) \
+            + ((self.decay_up, self.gate_up) if self.rank else ())
+        y = _apply(self._mix, self.in_proj(u), *(p.data() for p in own))
         return self.out_proj(y)
 
 
@@ -213,19 +246,21 @@ class SharedExpertMoE(HybridBlock):
     scores, a bias that chooses, renormalised weights times ``scale``) told
     which experts it holds; the router and the shared expert are whole on
     every rank. ``bias_rate``: the MoELayer's balancing rule; the block
-    then returns (y, the moved selection bias). Scopes: the MoELayer's own
+    then returns (y, the moved selection bias). ``n_group``, ``topk_group``:
+    the MoELayer's group-limited choice. Scopes: the MoELayer's own
     four under its block, the shared expert's `ffn` under its own."""
 
     def __init__(self, units, num_experts, ffn_hidden, top_k, shared_hidden,
                  scale=1.0, norm_topk_prob=True, held=None, bias_rate=None,
-                 **kwargs):
+                 n_group=None, topk_group=None, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.moe = MoELayer(num_experts, units, ffn_hidden, top_k=top_k,
                                 activation="silu", gated=True,
                                 norm_topk_prob=norm_topk_prob,
                                 router="sigmoid_bias", scale=scale, held=held,
-                                bias_rate=bias_rate)
+                                bias_rate=bias_rate, n_group=n_group,
+                                topk_group=topk_group)
             self.shared = SwiGLU(units, shared_hidden)
 
     def forward(self, u):
@@ -256,7 +291,31 @@ class SolarOpen2Layer(HybridBlock):
         return x + y
 
 
-class SolarOpen2Model(HybridBlock):
+class MixerStackLM(HybridBlock):
+    """What the decoders of this family share: ``tok_embed``, ``layers``
+    (each a `SolarOpen2Layer`), ``norm_f``, ``lm_head`` and ``_remat``, set
+    by the subclass, walked here. ``_remat``: each layer's forward is
+    recomputed in the backward (`gluon.utils.recompute`) but for what its
+    Pallas kernels wrote (`_KEPT`); a layer's moved selection bias is
+    booked here, outside the recomputed layers."""
+
+    def features(self, token_ids):
+        """The final norm's output (B, S, U): pair with
+        ChunkedUntiedLMLoss so the (B*S, V) logits never materialise."""
+        x = self.tok_embed(token_ids)
+        for layer in self.layers:
+            x = utils.recompute(layer, x, policy=_KEPT) if self._remat \
+                else layer(x)
+            if isinstance(x, tuple):
+                x, moved = x
+                layer.experts.moe.move_bias(moved)
+        return self.norm_f(x)
+
+    def forward(self, token_ids):
+        return self.lm_head(self.features(token_ids))
+
+
+class SolarOpen2Model(MixerStackLM):
     """tokens (B, S) int -> logits (B, S, vocab). ``pattern`` names the
     layers' mixers (`K`, `G`); ``delta``, ``attention`` and ``moe`` are the
     keyword arguments of `KimiDeltaAttention`, `GatedGroupedQueryAttention`
@@ -288,18 +347,3 @@ class SolarOpen2Model(HybridBlock):
             self.norm_f = nn.RMSNorm(in_channels=units, epsilon=epsilon)
             self.lm_head = nn.Dense(vocab_size, flatten=False,
                                     in_units=units, use_bias=False)
-
-    def features(self, token_ids):
-        """The final norm's output (B, S, U): pair with
-        ChunkedUntiedLMLoss so the (B*S, V) logits never materialise."""
-        x = self.tok_embed(token_ids)
-        for layer in self.layers:
-            x = utils.recompute(layer, x, policy=_KEPT) if self._remat \
-                else layer(x)
-            if isinstance(x, tuple):
-                x, moved = x
-                layer.experts.moe.move_bias(moved)
-        return self.norm_f(x)
-
-    def forward(self, token_ids):
-        return self.lm_head(self.features(token_ids))

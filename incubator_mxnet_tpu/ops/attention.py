@@ -32,7 +32,21 @@ shape alone:
   meet [v_1; v_2] (D 64, D_v 128) in ONE call this way, not two. The
   tiles' VMEM was sized at D = 128: D_v = 256 compiles for a v5e in
   bfloat16 and, at blocks of 1024, not in float32 (Mosaic refuses it, as
-  it refuses equal heads of 256). Under a window the grid's last axis
+  it refuses equal heads of 256). **A q.k width that is no whole lane
+  tile** (D = 192 | D_v = 128, latent attention's 128 beside a rotary 64;
+  compiled for a described v5e at (1, 32, 8192), PR 48,
+  tests/perfbench/test_ling3_compile_tpu.py): bfloat16 compiles at blocks
+  of 1024 and 512; float32 under `precision=HIGHEST` at 512 and 256 and is
+  refused at 1024 (the forward's scoped VMEM, as at D_v = 256): a float32
+  comparison of such a layer sets MXTPU_FLASH_BLOCK_Q/K=512. The operands
+  go in as they are: the 192 columns lie in 256 lanes in HBM and VMEM and
+  the MXU runs the half-empty second tile as a whole pass, which padding
+  with zeros outside the call cannot save (timings below). The dQ slab's
+  256 padded lanes are 2 * 8192 * 256 * 4 = 16.8 MB of _DQ_SLAB_BYTES: one
+  backward call up to S = 32 768 where D <= 128 has 65 536
+  (`_slab_bytes` rounds up, so `_dq_segments` knows). _BWD_TILE_BYTES
+  still knows neither the operands' type nor the matmul precision: 24 MiB
+  held the bf16 tiles at D = 192 as at 128. Under a window the grid's last axis
   counts the blocks of a row's BAND instead of all of them (at S = 16k,
   window 512, blocks of 512: 63 live pairs a head where the causal grid
   visits 528), the index maps are clamped from both sides so a step past
@@ -93,6 +107,13 @@ the kernel alone, ms a call; PERF.md §6, PR 47): (1, 16, 16384, 128) bf16
 causal 10.60 -> 7.62, (1, 20, 16384, 64 | 128) 13.37 -> 9.64, EvaByte's
 (256, 1, 2048, 128) 4.53 -> 3.45; the windowed call 3.27 both, every
 output and gradient equal to the bit.
+Why 192 goes in as it is (v5e, (1, 32, 8192, 192 | 128) bf16 causal, the
+kernels alone, ms a call forward / forward + backward; PERF.md §6, PR 48):
+as it is 6.69 / 23.18; q and k padded with zeros to 256 lanes 7.51 /
+23.99, the outputs equal to the bit; 128 | 128 for scale 4.29 / 14.48:
+1.6 times the time for 1.25 times the operations, the price of half a
+lane tile. In the Ling step's capture the two calls take 20.2 ms, 51.9 %
+of the roofline of the scores the model requires.
 """
 from __future__ import annotations
 

@@ -75,6 +75,12 @@ chooses, and a traced call holds one of them, never both;
           transpose above, the maps' among them (dq, dk, dv, dg as a
           reverse cumulative sum, db). Every dot says precision=HIGHEST:
           Mosaic's default rounds float32 operands to bfloat16.
+          More than one head group has run on the chip (PR 48: 32 heads
+          are 4 groups of 8 on the grid's second axis, 160 head-layers
+          a step of the Ling cell): a group of 8 heads at 8192 positions
+          costs 1.37 ms forward and 3.07 ms backward in the step's
+          capture, what Solar's one group costs, so the time is linear in
+          the groups; the chunk-start states are 268 MB a layer there.
   xla     Off the TPU, and for shapes the kernels do not take: A, P, W, U
           for all chunks at once (batched matmuls and one batched forward
           substitution, `lax.linalg.triangular_solve`), one `lax.scan` over

@@ -55,6 +55,10 @@ _DISPATCHES = telemetry.counter(
     "MoE expert dispatches traced, by path (dropless: the sort-based "
     "grouped matmul over every expert; dropless_held: the same over the "
     "experts this chip holds).", ("path",))
+_GROUP_LIMITED = telemetry.counter(
+    "mxtpu_moe_group_limited_total",
+    "Routers traced with a group limit (MoELayer(n_group=, topk_group=): "
+    "the experts chosen among the topk_group best of n_group groups).")
 _HELD_ROWS = telemetry.gauge(
     "mxtpu_moe_held_rows",
     "Rows of the held dispatch's buffers as last traced: one window of "
@@ -496,13 +500,26 @@ class MoELayer(HybridBlock):
     it to `move_bias`, which it does OUTSIDE any recomputed block. Without
     the rule Adam moves a router off its balance within tens of steps
     (PERF.md section 6, PR 38: a share's held experts lost every row).
+
+    ``n_group``, ``topk_group`` (``sigmoid_bias`` only; DeepSeek-V3's
+    `noaux_tc`): the experts are ``n_group`` groups of consecutive ones (a
+    host's, in an expert-parallel layout), a group's score is the sum of
+    its 2 largest s + b, and the k experts are the largest s + b of the
+    ``topk_group`` best groups only: a token's experts lie on at most that
+    many hosts. Ties go to the lower index, of groups as of experts. The
+    weights are as ever the chosen s. ``held=`` and ``bias_rate=`` compose
+    with it: the limit changes which k are chosen and nothing behind the
+    choice. None (the default) is no limit, the router it was. What the
+    node limit exists for, the exchange of rows between hosts, is not
+    written (ROADMAP 2a). Scope `router_groups` inside `router`.
     """
 
     def __init__(self, num_experts, hidden_size, ffn_hidden, top_k=2,
                  ep_axis="ep", activation="relu", gated=False,
                  norm_topk_prob=True, z_loss_coef=1e-3,
                  capacity_factor=None, router="softmax", scale=1.0,
-                 held=None, router_units=None, bias_rate=None, **kwargs):
+                 held=None, router_units=None, bias_rate=None, n_group=None,
+                 topk_group=None, **kwargs):
         super().__init__(**kwargs)
         if capacity_factor is not None:
             import warnings
@@ -519,6 +536,17 @@ class MoELayer(HybridBlock):
             raise ValueError("bias_rate moves the selection bias of "
                              "router='sigmoid_bias'; router=%r has none"
                              % router)
+        if (n_group is None) != (topk_group is None) or (
+                n_group is not None and (
+                    router != "sigmoid_bias" or num_experts % n_group
+                    or not 1 <= topk_group <= n_group
+                    or num_experts // n_group < 2
+                    or top_k > topk_group * (num_experts // n_group))):
+            raise ValueError(
+                "n_group=%r, topk_group=%r: router='sigmoid_bias', groups "
+                "of at least 2 that divide %d experts, and top_k=%d experts "
+                "within topk_group of them" % (n_group, topk_group,
+                                               num_experts, top_k))
         if held is not None and not (
                 0 <= held[0] and held[1] >= 1
                 and held[0] + held[1] <= num_experts):
@@ -533,6 +561,7 @@ class MoELayer(HybridBlock):
         self._router = router
         self._scale = scale
         self._bias_rate = bias_rate
+        self._groups = None if n_group is None else (n_group, topk_group)
         n_held = num_experts if held is None else held[1]
         stacked = {"w1": (n_held, hidden_size, ffn_hidden),
                    "w2": (n_held, ffn_hidden, hidden_size)}
@@ -558,6 +587,41 @@ class MoELayer(HybridBlock):
             # it is added to float32 scores and only chooses
             self.router_bias.cast("float32")
 
+    def _in_kept_groups(self, choosing):
+        """choosing (T, E) = s + b -> the same with -inf outside each
+        token's ``topk_group`` best groups. A group's score is the sum of
+        its two largest entries (two maxima, the first's place masked for
+        the second); a group is kept where fewer than ``topk_group`` others
+        beat it, an equal score of a lower index counting as beating: what
+        a stable top-k of the group scores keeps, without a sort."""
+        n_group, topk_group = self._groups
+        _GROUP_LIMITED.inc()
+        with jax.named_scope("router_groups"):
+            by_group = jax.lax.stop_gradient(choosing).reshape(
+                choosing.shape[0], n_group, -1)
+            place = jax.lax.broadcasted_iota(jnp.int32, by_group.shape, 2)
+            first = jnp.max(by_group, -1, keepdims=True)
+            at = jnp.min(jnp.where(by_group == first, place,
+                                   by_group.shape[-1]), -1, keepdims=True)
+            score = first[..., 0] + jnp.max(
+                jnp.where(place == at, -jnp.inf, by_group), -1)   # (T, G)
+            mine, other = score[:, :, None], score[:, None, :]
+            g = jnp.arange(n_group)
+            beaten_by = jnp.sum((other > mine) | (
+                (other == mine) & (g[None, :] < g[:, None])), -1)
+            kept = beaten_by < topk_group                         # (T, G)
+            return jnp.where(kept[..., None], by_group, -jnp.inf).reshape(
+                choosing.shape)
+
+    def choose(self, gates, bias):
+        """``sigmoid_bias``: gates (T, E) float32 -> the ids (T, k) of the
+        k largest gates + bias, under the group limit where there is one
+        (what a builder's balancing of the bias counts loads with)."""
+        choosing = gates + bias.astype(jnp.float32)
+        if self._groups is not None:
+            choosing = self._in_kept_groups(choosing)
+        return jax.lax.top_k(choosing, self.top_k)[1]
+
     def route(self, tokens, gw, bias=None):
         """tokens (T, D), gw (E, D) -> (logits, gates, top_vals, top_idx),
         all float32 but the indices: the matmul accumulates in float32 and
@@ -571,8 +635,7 @@ class MoELayer(HybridBlock):
                 top_vals = top_vals / jnp.sum(top_vals, -1, keepdims=True)
         else:
             gates = jax.nn.sigmoid(logits)
-            _, top_idx = jax.lax.top_k(gates + bias.astype(jnp.float32),
-                                       self.top_k)
+            top_idx = self.choose(gates, bias)
             top_vals = _chosen_scores(gates, top_idx)
             if self.norm_topk_prob:
                 top_vals = top_vals / (jnp.sum(top_vals, -1, keepdims=True)
