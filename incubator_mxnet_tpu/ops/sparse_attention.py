@@ -45,6 +45,26 @@ same strips in plain `jax.numpy`. Both are masked-dense: every causal
 of causal attention, not of `topk` keys a query; a kernel that gathers the
 chosen rows would do a quarter of it at 16k (ROADMAP 2a).
 
+**The forward kernel's online softmax (PR 49; `_flash_fwd_kernel` has the
+argument in full).** What it leans on: a row is never empty over its live
+key blocks (S_t holds min(topk, t + 1) >= 1 keys, all at or left of the
+diagonal), though it may be empty tile after tile before its first chosen
+key. So nothing guards an empty row: `_FLOOR`, the lowest finite float32,
+stands for `-inf` in the masked scores and in the running max's start, the
+first chosen key's alpha = exp(_FLOOR - real) = 0 wipes what an empty row
+gathered, and `o` and `lse` are the sums over the chosen keys alone. The
+running max and sum live in every lane of (R, block_q, 128) float32 scratch
+(the sum as lane partials, ONE cross-lane reduction a strip call), as
+`ops/attention.py`'s `_fa_kernel` keeps them.
+Measured on a v5e at the Keye cell's shape (a layer's 32 strip calls
+alone; PERF.md section 6, PR 49; the table in docs/PERF_NOTES.md): the
+statistics' layout is what paid, 30.8 -> 14.4 ms (in the step's capture
+29.8 -> 13.4); the guards, on columns or on lanes, 0.0-0.1 ms; an additive
+bias for the select -0.1 ms (not taken: exact only while |score| < 1e22);
+a select on P instead of on the scores +0.8 ms and a max over unchosen
+keys (not taken); a key block of 1024 in two sub-tiles for this kernel
+alone 0.0 ms, whole +1.0, sub-tiles of 256 +0.4 (not taken).
+
 Scopes: `indexer` (index scores, the head mean, the KL), `topk_select`,
 `sparse_attention`. Counters: `mxtpu_sparse_attention_total{path}`,
 `mxtpu_topk_select_total{path}`.
@@ -60,7 +80,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
 from . import kernel_trace
-from .attention import _interpret, _kernels_run_here, _nt, _tn, _mm
+from .attention import (_interpret, _kernels_run_here, _lanes, _nt, _tn,
+                        _mm)
 
 __all__ = ["sparse_attention", "index_scores", "select_thresholds",
            "chosen_strip", "TOPK_NAME", "ATTENDED_NAME"]
@@ -287,15 +308,56 @@ def _mask_strip(strip, scores, tau, cut):
 
 
 # ------------------------------------------------------ attention, a strip
+#: what a masked score reads and what a row's running max starts from: the
+#: lowest FINITE float32. No real score is below it, so a maximum taken over
+#: masked and chosen scores is the chosen ones' as soon as there is one, and
+#: `exp(x - max)` never sees `-inf - -inf`.
+_FLOOR = float(jnp.finfo(jnp.float32).min)
+
+
 def _flash_fwd_kernel(b_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                       m_s, l_s, acc_s, *, block_k, scale):
+    """One (key-value head, key block) program of a strip: the online
+    softmax of the head's R query heads over the tile's CHOSEN keys, the
+    key and value tile read once for all R.
+
+    **A tile does its own work and no more** (PR 49; `ops/attention.py`
+    `_fa_kernel` is the model, PR 47). The running max and sum live in every
+    lane of (R, block_q, lanes) float32 scratch, not in (block_q, 1)
+    columns: the max is the row's in every lane, the sum a lane's PARTIAL
+    sum (adds of whole vregs rescaled by the row's alpha); the one
+    cross-lane sum a strip call is the last key block's. That is what paid:
+    29.8 -> 13.4 ms a layer in the Keye step on a v5e, 86 % of the MXU's
+    floor over the scored pairs (PERF.md section 6, PR 49).
+
+    **No guard of an empty row.** A row CAN be empty in its first tiles (a
+    query past `topk` need not have chosen a key of block 0), but never
+    over all its live key blocks: `select_thresholds` gives every row
+    min(topk, t + 1) >= 1 keys at or left of its diagonal. So a masked
+    score reads `_FLOOR`, finite, and the max starts there. While a row has
+    met no chosen key its max stays `_FLOOR`, every p is exp(0) = 1 and the
+    row gathers finite sums of no meaning; its first chosen key makes the
+    max real and alpha = exp(_FLOOR - real) = 0 EXACTLY, which wipes them;
+    from then on a masked score is exp(_FLOOR - real) = 0 without a second
+    select. What a row ends with is the sum over its chosen keys alone, and
+    l >= 1 (the row's largest chosen score reads exp(0)). The mask's
+    compare is made once a tile for the R heads; each head pays one select.
+
+    The R heads are R independent bodies a grid step (Mosaic puts one
+    head's Q K^T under the last one's softmax on its own), traced once and
+    unrolled where the kernel is lowered. Scores, statistics and the
+    accumulator are float32; P goes to the MXU in v's type, as the
+    backward's does; `lse` = max + log(sum), which `sparse_head_mean` and
+    `sparse_flash_bwd` read.
+    """
     from jax.experimental import pallas as pl
     kb = pl.program_id(1)
     heads, block_q = q_ref.shape[1], q_ref.shape[2]
+    lanes = m_s.shape[-1]
 
     @pl.when(kb == 0)
     def _():
-        m_s[...] = jnp.full_like(m_s, -jnp.inf)
+        m_s[...] = jnp.full_like(m_s, _FLOOR)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
@@ -303,33 +365,46 @@ def _flash_fwd_kernel(b_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     def _():
         k, v = k_ref[0], v_ref[0]
         chosen = mask_ref[...].astype(jnp.float32) > 0
-        for r in range(heads):
-            s = jnp.where(chosen, _nt(q_ref[0, r], k) * scale, -jnp.inf)
+
+        def head(r, carry):
+            s = jnp.where(chosen, _nt(q_ref[0, r], k) * scale, _FLOOR)
             m = m_s[r]
             m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
-            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-            p = jnp.exp(s - m_safe)
-            alpha = jnp.exp(jnp.where(m == -jnp.inf, -jnp.inf, m - m_safe))
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            alpha = jnp.exp(m - m_new)
             m_s[r] = m_new
-            l_s[r] = l_s[r] * alpha + jnp.sum(p, -1, keepdims=True)
-            acc_s[r] = acc_s[r] * alpha + _mm(p.astype(v.dtype), v)
+            l_s[r] = l_s[r] * alpha + functools.reduce(
+                jnp.add, [p[:, i:i + lanes] for i in range(0, block_k, lanes)])
+            acc_s[r] = acc_s[r] * _lanes(alpha, acc_s.shape[-1]) \
+                + _mm(p.astype(v.dtype), v)
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0, unroll=True)
 
     @pl.when(kb == pl.num_programs(1) - 1)
     def _():
-        # every row chose its diagonal at least: l > 0
-        l = l_s[...]
+        l = jnp.sum(l_s[...], -1, keepdims=True)
         o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_s[...] + jnp.log(l)
+        lse_ref[0] = m_s[..., :1] + jnp.log(l)
 
 
 def _flash_fwd_strip_pallas(strip, q, k, v, mask, block_k, scale):
     """q (G, R, block_q, D), k, v (G, S, D), mask (block_q, S) int8 ->
     o like q, lse (G, R, block_q) float32."""
+    return _flash_fwd_call(strip, q, k, v, mask, block_k, scale,
+                           _interpret())
+
+
+@kernel_trace.traced_once("block_k", "scale", "interpret")
+def _flash_fwd_call(strip, q, k, v, mask, block_k, scale, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     groups, heads, block_q, d = q.shape
     seq = k.shape[1]
     last = functools.partial(_last_block, block_q=block_q, block_k=block_k)
+    # the statistics' lanes: a vreg's 128 wherever the key block is whole
+    # vregs (the tests' small blocks: the block itself)
+    lanes = math.gcd(block_k, 128)
 
     def kv_block(g, kb, b):
         return (g, jnp.minimum(kb, last(b[0])), 0)
@@ -353,13 +428,13 @@ def _flash_fwd_strip_pallas(strip, q, k, v, mask, block_k, scale):
                     0, jnp.minimum(kb, last(b[0]))))],
             out_specs=(pl.BlockSpec((1, heads, block_q, d), own),
                        pl.BlockSpec((1, heads, block_q, 1), own)),
-            scratch_shapes=[pltpu.VMEM((heads, block_q, 1), jnp.float32),
-                            pltpu.VMEM((heads, block_q, 1), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((heads, block_q, lanes), jnp.float32),
+                            pltpu.VMEM((heads, block_q, lanes), jnp.float32),
                             pltpu.VMEM((heads, block_q, d), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(), name="sparse_flash_fwd")
+        interpret=interpret, name="sparse_flash_fwd")
     return o, lse[..., 0]
 
 

@@ -148,3 +148,31 @@ def test_eva_attention_compiles_at_the_evabyte_cells_shape(one_chip,
     assert "flash_fwd" in text and "flash_bwd_dkvq" in text
     # no strip's scores are all of (S, S / 16): the widest is 2048 x 896
     assert "[1,32,16384,1024]" not in text and "[32,16384,1024]" not in text
+
+
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.bfloat16, "default"), (jnp.float32, "highest")],
+    ids=["bf16-as-the-cell", "f32-highest-as-the-chip-smoke"])
+def test_the_sparse_forward_compiles_at_the_keye_cells_shape(
+        one_chip, monkeypatch, dtype, precision):
+    """`ops.sparse_attention`'s forward kernel, one strip call of the Keye
+    cell (perfbench/configs/keye-vl-2.0-30b-a3b.json): 4 key-value heads x
+    8 query heads x 512 queries over 16 384 keys of 128, the mask a strip
+    wide in int8. Since PR 49 its running max and sum are (8, 512, 128)
+    float32 scratch each (2 MB where the columns were 16 KB), beside the
+    2 MB accumulator: a layout that interpret mode takes whatever its
+    size."""
+    from incubator_mxnet_tpu.ops import sparse_attention
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    groups, heads, block, seq, dim = 4, 8, 512, 16384, 128
+
+    def spec(shape, of=dtype):
+        return jax.ShapeDtypeStruct(shape, of, sharding=one_chip)
+
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(lambda *a: sparse_attention._flash_fwd_strip_pallas(
+            *a, block, dim ** -0.5)).lower(
+            spec((), jnp.int32), spec((groups, heads, block, dim)),
+            spec((groups, seq, dim)), spec((groups, seq, dim)),
+            spec((block, seq), jnp.int8)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "sparse_flash_fwd" in text
