@@ -204,7 +204,8 @@ def phase_moe(cfg, on_chip, shared):
                             (gate, up, down, weight.T))[0]
 
     def dropless(x, top_vals, gate, up, down):
-        return dropless_moe(x, top_vals, top_idx, up, down, jax.nn.silu, gate)
+        return dropless_moe(x, top_vals, top_idx, up, down, jax.nn.silu,
+                            gate)[0]
 
     def both(fn):
         def f(*args):
@@ -948,6 +949,58 @@ def wide_value_alone(cfg, on_chip):
     return out
 
 
+def watch_choices(net):
+    """Every MoELayer of `net` also hands its router's `top_idx` out of the
+    step, as a step counter beside the rows an expert it already publishes:
+    what the host counts the published rows against. -> the layers."""
+    from incubator_mxnet_tpu.gluon import _functional
+    from incubator_mxnet_tpu.parallel import MoELayer
+    layers = []
+
+    def watch(block):
+        if isinstance(block, MoELayer):
+            route = block.route
+
+            def watched(*args):
+                out = route(*args)
+                _functional.collect_step_counter(block.name + ":top_idx",
+                                                 out[3])
+                return out
+            block.route = watched
+            layers.append(block)
+    net.apply(watch)
+    return layers
+
+
+def published_rows_are_counts(layers):
+    """Each resolved step's published rows of each layer against a NumPy
+    count of the same step's `top_idx` over the layer's held experts. ->
+    (steps compared, the live rows a layer a step, smallest and largest)."""
+    import numpy as onp
+    from incubator_mxnet_tpu import jit
+    from incubator_mxnet_tpu.telemetry import spans
+    jit.flush_step_counters()
+    held = {layer.name: layer.held or (0, layer.num_experts)
+            for layer in layers}
+    records = [r for r in spans.snapshot() if r["name"] == "train:counters"]
+    live = []
+    for record in records:
+        by_name = {c["name"]: c for c in record["args"]["counters"]}
+        for name, (first, count) in held.items():
+            want = onp.bincount(by_name[name + ":top_idx"]["values"],
+                                minlength=first + count)[first:first + count]
+            if by_name[name]["values"] != want.tolist():
+                raise RuntimeError(
+                    "step %d, %s: the dispatch published %r rows an expert, "
+                    "a count of the step's top_idx gives %r" % (
+                        record["args"]["step"], name,
+                        by_name[name]["values"], want.tolist()))
+            live.append(int(want.sum()))
+    if not records or not live:
+        raise RuntimeError("no step published its experts' rows")
+    return len(records), min(live), max(live)
+
+
 def phase_hybrid(cfg, on_chip, shared):
     """One tiny Nemotron-H share through TrainStep: the chunked Mamba-2 scan
     with its backward, the held dispatch (experts 8..15 of 64) and the
@@ -971,10 +1024,15 @@ def phase_hybrid(cfg, on_chip, shared):
     tokens = fixed_tokens(cfg, 1)
     view = models.FeaturesView(net)
     trainer = adam_trainer(view)
+    routed = watch_choices(net)
     step = jit.TrainStep(view, models.ChunkedUntiedLMLoss(net), trainer)
     losses, compile_s, steady_s = run_steps(step, (tokens, tokens), 2, 2)
     if not losses[-1] < losses[0]:
         raise RuntimeError("loss did not fall: %r" % (losses,))
+    counted = published_rows_are_counts(routed)
+    log("held dispatch: the rows an expert that %d steps published (%d "
+        "layer(s), %d..%d live rows a layer a step) equal a count of the "
+        "same step's top_idx" % ((counted[0], len(routed)) + counted[1:]))
     (text,) = [t for model_id, t in jit.compiled_train_programs()
                if model_id == step._model_id]
     if "ssd_scan" not in text or "moe_dispatch" not in text:

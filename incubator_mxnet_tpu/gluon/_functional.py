@@ -8,6 +8,10 @@ In functional mode:
   as extra outputs, then written back after the compiled call.
 - Random ops draw from a per-call PRNG key argument instead of the global
   stateful key, so compiled programs get fresh randomness per step.
+- Step counters, small integer values a block computes anyway and a reader
+  on the host wants once a step (a MoELayer's rows an expert), are COLLECTED
+  and leave a compiled train step as one more output (jit.TrainStep); a
+  compiled forward drops them.
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ class _FnState(threading.local):
         self.active = False
         self.key = None           # traced PRNG key, split per use
         self.aux_updates = None   # list of (Parameter, traced_new_value)
+        self.step_counters = None  # list of (name, traced ints, publish, static)
 
 
 _STATE = _FnState()
@@ -44,19 +49,36 @@ def collect_aux_update(param_arr, new_value):
     _STATE.aux_updates.append((param_arr, new_value))
 
 
+def collect_step_counter(name, value, publish=None, **static):
+    """Record 'this step's `name` is `value`' (an integer array the block
+    computes anyway) with the facts of its shapes the host reads it by
+    (``static``: plain numbers and flags). A compiled train step hands the
+    values out beside its loss and books them to the step once they are
+    known (jit.TrainStep: the span ring's ``train:counters``), calling
+    ``publish(name, values, **static)`` there (``values`` a list of ints)
+    for the block's own series.
+    Outside functional mode nothing is recorded: an eager call traces what
+    it traced."""
+    if _STATE.active:
+        _STATE.step_counters.append((name, value, publish, static))
+
+
 class FunctionalScope:
     def __init__(self, key):
         self._key = key
 
     def __enter__(self):
-        self._prev = (_STATE.active, _STATE.key, _STATE.aux_updates)
+        self._prev = (_STATE.active, _STATE.key, _STATE.aux_updates,
+                      _STATE.step_counters)
         _STATE.active = True
         _STATE.key = self._key
         _STATE.aux_updates = []
+        _STATE.step_counters = []
         return _STATE
 
     def __exit__(self, *a):
-        _STATE.active, _STATE.key, _STATE.aux_updates = self._prev
+        (_STATE.active, _STATE.key, _STATE.aux_updates,
+         _STATE.step_counters) = self._prev
 
 
 def make_pure_fn(block, train_mode):
@@ -64,6 +86,8 @@ def make_pure_fn(block, train_mode):
 
     ``aux_box`` (returned alongside) is filled at trace time with the live aux
     NDArrays, in the same order as aux_new_values — stable for a fixed graph.
+    Step counters the blocks register are dropped with the scope: a forward
+    has no step to book them to.
     """
     params = list(block.collect_params().values())
     param_arrs = [p.data() for p in params]

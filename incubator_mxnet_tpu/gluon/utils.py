@@ -98,13 +98,18 @@ def recompute(block, *args, policy=None):
     compiled step its scope names (``Block.__call__``'s) stay on every op,
     forward and recomputed. A block that updates auxiliary state or draws
     random numbers in its forward (BatchNorm, Dropout) would do so twice:
-    refused."""
+    refused. Step counters the block registers
+    (``_functional.collect_step_counter``) are values of the checkpointed
+    trace: they leave it as extra outputs (integers: nothing flows back into
+    them, so they are the first forward's and no recomputation makes them
+    again) and are registered again here, outside, under the same names."""
     from .. import autograd
     from ..ndarray import _apply
     from . import _functional
     _RECOMPUTES.inc(policy="none" if policy is None else "given")
     arrs = [p.data() for p in block.collect_params().values()]
     n = len(args)
+    traced = {}       # what the trace found: the block's counters, a tuple?
 
     def pure(*datas):
         state = _functional._STATE
@@ -112,6 +117,7 @@ def recompute(block, *args, policy=None):
         for a, d in zip(arrs, datas[n:]):
             a._data = d
         key, n_aux = state.key, len(state.aux_updates or ())
+        n_counters = len(state.step_counters or ())
         try:
             # one op on the eager tape: nothing inside is recorded
             with autograd.pause(train_mode=autograd.is_training()):
@@ -124,9 +130,24 @@ def recompute(block, *args, policy=None):
                 "recompute(%s): the block updates auxiliary state or draws "
                 "random numbers in its forward, which a recomputation would "
                 "repeat" % block.name)
-        if isinstance(out, (tuple, list)):
-            return tuple(o._data for o in out)
-        return out._data
+        inside = (state.step_counters or [])[n_counters:]
+        if inside:
+            del state.step_counters[n_counters:]
+        traced["counters"] = [(name, publish, static)
+                              for name, _, publish, static in inside]
+        traced["tuple"] = isinstance(out, (tuple, list))
+        outs = tuple(o._data for o in out) if traced["tuple"] \
+            else (out._data,)
+        outs += tuple(value for _, value, _, _ in inside)
+        return outs if traced["tuple"] or inside else outs[0]
 
     import jax
-    return _apply(jax.checkpoint(pure, policy=policy), *args, *arrs)
+    out = _apply(jax.checkpoint(pure, policy=policy), *args, *arrs)
+    if not traced["counters"]:
+        return out
+    n_out = len(out) - len(traced["counters"])
+    for (name, publish, static), value in zip(traced["counters"],
+                                              out[n_out:]):
+        _functional.collect_step_counter(name, value._data, publish,
+                                         **static)
+    return out[:n_out] if traced["tuple"] else out[0]

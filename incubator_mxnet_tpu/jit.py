@@ -16,9 +16,11 @@ the batch axis of this same program.
 """
 from __future__ import annotations
 
+import collections
 import logging
 import threading as _threading
 import time as _time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +71,8 @@ def _net_trace_lock(net):
                 net._mxtpu_trace_lock = lock
     return lock
 
-__all__ = ["TrainStep", "EvalStep", "compiled_train_programs"]
+__all__ = ["TrainStep", "EvalStep", "compiled_train_programs",
+           "flush_step_counters"]
 
 # Compile observability: each shared-cache (aot.CACHE) miss that cannot be
 # satisfied by a persisted artifact is one model trace + XLA compile.
@@ -109,6 +112,34 @@ _EXAMPLES = telemetry.counter(
     "mxtpu_train_examples_total",
     "Examples consumed by TrainStep (batch-size sum); rate() of this is "
     "examples/sec.")
+
+
+# Step counters (gluon/_functional.collect_step_counter): small integer
+# values the blocks of a step compute anyway (a MoELayer's rows an expert)
+# leave the compiled step as ONE int32 vector beside the loss. The host
+# never waits for it: a TrainStep keeps (step number, the step's
+# train:dispatch span, the vector) of the steps it has not resolved and,
+# inside each later call, books those whose vector has arrived: the
+# retroactive record train:counters in the span ring, on that step's own
+# train:dispatch clock, and each counter's own series (its ``publish``).
+# flush_step_counters() resolves what is left, waiting.
+#: unresolved steps a TrainStep keeps; past it the oldest is dropped
+_COUNTERS_KEPT = 64
+_COUNTERS_DROPPED = telemetry.counter(
+    "mxtpu_step_counters_dropped_total",
+    "Steps whose counters were dropped unread: more than %d steps of one "
+    "TrainStep were waiting for their values." % _COUNTERS_KEPT)
+#: one resolver at a time: a step's own call, or a reader's flush
+_COUNTERS_LOCK = _threading.Lock()
+#: the live TrainSteps, for flush_step_counters
+_COUNTING = weakref.WeakSet()
+
+
+def flush_step_counters():
+    """Resolve every step counter a live TrainStep still holds, waiting for
+    the device where it must: for a reader after the loop (the steps' own
+    calls never wait). Returns the number of steps resolved."""
+    return sum(step._resolve_counters(wait=True) for step in list(_COUNTING))
 
 
 def _record_compile_span(name, dur_s):
@@ -204,6 +235,10 @@ class TrainStep:
         self._last_stats = None
         # watchdog bookkeeping: counts once this instance starts stepping
         self._hb_registered = False
+        # the steps whose counters the host does not know yet, oldest first:
+        # (step, its train:dispatch span, the vector, the vector's layout)
+        self._unresolved = collections.deque()
+        _COUNTING.add(self)
 
     # ------------------------------------------------------------------
     def _build(self, n_inputs):
@@ -215,6 +250,7 @@ class TrainStep:
         net, loss_fn = self.net, self.loss_fn
         optimizer = self.trainer._optimizer
         aux_box = []
+        counter_box = []   # (name, length, publish, static) of each counter
         # blocks whose kernels GSPMD cannot partition (models' flash
         # attention) ask which mesh they are being traced for (imported
         # here: parallel/ imports this module)
@@ -247,13 +283,17 @@ class TrainStep:
                     # rescale_grad (1/batch) then normalises — matches eager
                     loss_scalar = loss._data.sum()
                     aux_pairs = list(st.aux_updates)
+                    counters = list(st.step_counters)
             finally:
                 for a, s in zip(t_arrs, saved_t):
                     a._data = s
                 for a, s in zip(f_arrs, saved_f):
                     a._data = s
             aux_box[:] = [a for a, _ in aux_pairs]
-            return loss_scalar, (loss._data, [v for _, v in aux_pairs])
+            counter_box[:] = [(name, int(v.size), publish, static)
+                              for name, v, publish, static in counters]
+            return loss_scalar, (loss._data, [v for _, v in aux_pairs],
+                                 [v for _, v, _, _ in counters])
 
         fwd = jax.checkpoint(inner) if self.remat else inner
 
@@ -264,11 +304,13 @@ class TrainStep:
         grad_postprocess = self._grad_postprocess
         layout = self._layout(trainable, frozen)
         constrain_update = self._make_constrainer(layout)
+        replicated = layout[4]
 
         def step_fn(t_datas, f_datas, opt_states, input_datas, key, lrs, wds, t,
                     rescale):
-            (loss_scalar, (loss_full, aux_vals)), grads = jax.value_and_grad(
-                fwd, argnums=0, has_aux=True)(t_datas, f_datas, input_datas, key)
+            (loss_scalar, (loss_full, aux_vals, counted)), grads = \
+                jax.value_and_grad(fwd, argnums=0, has_aux=True)(
+                    t_datas, f_datas, input_datas, key)
             if grad_postprocess is not None:
                 grads = grad_postprocess(grads)
             new_t, new_opt = [], []
@@ -297,9 +339,19 @@ class TrainStep:
                         new_opt.append(_tree_to_data(new_state_nd))
             if constrain_update is not None:
                 new_t, new_opt = constrain_update(new_t, new_opt)
-            return loss_full, new_t, new_opt, aux_vals
+            if not counted:
+                # no block counts anything: the program it always was
+                return loss_full, new_t, new_opt, aux_vals
+            # one small transfer a step, every chip of a mesh its own copy
+            counted = jnp.concatenate(
+                [v.reshape(-1).astype(jnp.int32) for v in counted])
+            if replicated is not None:
+                counted = jax.lax.with_sharding_constraint(counted,
+                                                           replicated)
+            return loss_full, new_t, new_opt, aux_vals, counted
 
-        return step_fn, layout, trainable, t_arrs, f_arrs, aux_box
+        return step_fn, layout, trainable, t_arrs, f_arrs, aux_box, \
+            counter_box
 
     def _build_entry(self, n_inputs, arrs, key):
         """aot.compile_cached build hook: (compiled program, instance
@@ -321,7 +373,7 @@ class TrainStep:
         retry would compile the same program again, and swallowing the
         first error is how a compiler refusal (a Mosaic kernel over its
         VMEM budget, an HBM OOM) gets hidden."""
-        step_fn, layout, trainable, t_arrs, f_arrs, aux_box = \
+        step_fn, layout, trainable, t_arrs, f_arrs, aux_box, counter_box = \
             self._build(n_inputs)
         data_sh, repl = layout[3:]
         # the lay-out writes, and the trace swaps tracers into, the live
@@ -345,7 +397,7 @@ class TrainStep:
             compiled = jax.jit(
                 step_fn, donate_argnums=_donate((0, 2))).lower(*specs).compile()
         return compiled, (slots, t_arrs, f_arrs, aux_box, state_sh,
-                          data_sh), None
+                          data_sh, counter_box), None
 
     def _state(self, layout, trainable, t_arrs, f_arrs):
         """-> (each trainable parameter's slot in ``trainer._states``;
@@ -375,8 +427,8 @@ class TrainStep:
             trainer._init_kvstore()
         if not trainer._states_initialized:
             trainer._init_states()
-        step_fn, layout, trainable, t_arrs, f_arrs, _ = self._build(
-            n_net_inputs)
+        step_fn, layout, trainable, t_arrs, f_arrs = self._build(
+            n_net_inputs)[:5]
         _, state, state_sh = self._state(layout, trainable, t_arrs, f_arrs)
         data_sh, repl = layout[3:]
         if sharding is not None:
@@ -518,6 +570,10 @@ class TrainStep:
             # net's param arrays): release them instead of waiting for LRU
             for key in self._cache_keys:
                 aot.CACHE.discard(key)
+            # a loop that ends leaves its last steps' counters here: book
+            # those that have arrived, and let the rest go without a wait
+            self._resolve_counters(wait=False)
+            self._unresolved.clear()
         except Exception:
             pass          # interpreter-teardown __del__ must never raise
 
@@ -581,7 +637,8 @@ class TrainStep:
                     lambda: self._build_entry(n_net_inputs, arrs, key))
                 self._cache_keys.add(cache_key)
         self._last_stats = entry.stats
-        slots, t_arrs, f_arrs, aux_box, state_sh, data_sh = entry.extras
+        slots, t_arrs, f_arrs, aux_box, state_sh, data_sh, counter_box = \
+            entry.extras
 
         optimizer = trainer._optimizer
         # python-side schedule state (lr scheduler, update counts) advances
@@ -603,8 +660,8 @@ class TrainStep:
         # would capture tracers or lose the step's update to the trace's
         # finally-restore. Uncontended (the common case: nothing else
         # traces this net) the RLock costs sub-µs per step.
-        with spans.span("train:dispatch", compile=compile_miss), \
-                self._trace_lock:
+        with spans.span("train:dispatch", compile=compile_miss,
+                        step=self._step_count) as dispatch, self._trace_lock:
             dispatch_t0 = _time.perf_counter()
             # the state is passed as it is held: a leaf is put again only
             # where it left its layout between two steps (set_data,
@@ -612,7 +669,7 @@ class TrainStep:
             # program refuses; the inputs go onto the data sharding; the
             # scalars stay host arrays: on a mesh every chip gets its copy
             # from the host, none crosses from chip 0 between two programs
-            loss_full, new_t, new_opt, aux_vals = entry.fn(
+            loss_full, new_t, new_opt, aux_vals, *counted = entry.fn(
                 *_with_layout(_placed, (
                     [a._data for a in t_arrs], [a._data for a in f_arrs],
                     opt_states), state_sh),
@@ -636,6 +693,8 @@ class TrainStep:
             self._write_back(t_arrs, slots, new_t, new_opt)
             for a, v in zip(aux_box, aux_vals):
                 a._data = v
+        if counted:
+            self._count(dispatch, counted[0], counter_box)
         # numerics sentinel (stride-sampled, default off): on-device
         # stats taps over the per-sample loss and the updated parameter
         # tree — grads are fused inside the step program, so a NaN storm
@@ -659,6 +718,52 @@ class TrainStep:
         flightrec.record("step_end", step=self._step_count,
                          dur_s=round(step_dur, 6))
         return NDArray(loss_full)
+
+    # -- step counters ----------------------------------------------------
+    def _count(self, dispatch, vector, layout):
+        """Keep this step's counters for later and book the earlier steps'
+        that have arrived; never waits for the device."""
+        vector.copy_to_host_async()
+        self._unresolved.append((self._step_count, dispatch, vector, layout))
+        if len(self._unresolved) > _COUNTERS_KEPT:
+            self._unresolved.popleft()
+            _COUNTERS_DROPPED.inc()
+        self._resolve_counters(wait=False)
+
+    def _resolve_counters(self, wait):
+        """Book the unresolved steps in order, up to the first whose vector
+        has not arrived (``wait``: all of them, waiting). Per step one
+        retroactive record train:counters, a child of that step's
+        train:dispatch with its ``start_us`` and ``dur_us`` = how much later
+        the values were known, args ``step`` and ``counters`` (per counter
+        its name, values and static facts), and each counter's ``publish``.
+        Returns the number of steps booked."""
+        from . import profiler
+        done = 0
+        with _COUNTERS_LOCK:
+            while self._unresolved:
+                step, dispatch, vector, layout = self._unresolved[0]
+                if not (wait or vector.is_ready()):
+                    break
+                self._unresolved.popleft()
+                try:
+                    values = _onp.asarray(vector).tolist()
+                    counters, at = [], 0
+                    for name, length, publish, static in layout:
+                        mine = values[at:at + length]
+                        at += length
+                        counters.append(dict(static, name=name, values=mine))
+                        if publish is not None:
+                            publish(name, mine, **static)
+                    spans.record_span(
+                        "train:counters", dispatch.start_us,
+                        profiler.now_us() - dispatch.start_us,
+                        parent=dispatch, step=step, counters=counters)
+                except Exception:   # tracing must never fail the step
+                    _LOG.debug("step %d: its counters were not booked",
+                               step, exc_info=True)
+                done += 1
+        return done
 
 
 def compiled_train_programs():

@@ -59,11 +59,45 @@ _GROUP_LIMITED = telemetry.counter(
     "mxtpu_moe_group_limited_total",
     "Routers traced with a group limit (MoELayer(n_group=, topk_group=): "
     "the experts chosen among the topk_group best of n_group groups).")
-_HELD_ROWS = telemetry.gauge(
-    "mxtpu_moe_held_rows",
-    "Rows of the held dispatch's buffers as last traced: one window of "
-    "live rows (window), and the T x min(k, count) rows that every token "
-    "on every held expert would make (worst_case).", ("kind",))
+_WINDOW_ROWS = telemetry.gauge(
+    "mxtpu_moe_window_rows",
+    "W, the sorted rows one window of a layer's held dispatch handles "
+    "(held_window_rows: from shapes, set when the layer is traced).",
+    ("layer",))
+# What a compiled train step's experts got, step by step: a layer's
+# group_sizes leave the step as a step counter (MoELayer._fn) and
+# _publish_load books them here once the host knows them, a step or two
+# after the dispatch (jit.TrainStep). Labels are layers, never experts.
+_ROWS = telemetry.counter(
+    "mxtpu_moe_rows_total",
+    "Live rows of a layer's expert dispatch over the resolved train steps: "
+    "(token, expert) assignments its experts (the held ones of a held "
+    "layer) got; T x k a step where every expert is held.", ("layer",))
+_WINDOWS = telemetry.counter(
+    "mxtpu_moe_windows_total",
+    "Windows a held layer's dispatch ran a pass over the resolved train "
+    "steps, ceil(live rows / W) a step: more than one a step is a router "
+    "sending the held experts over twice their even share.", ("layer",))
+_EXPERT_ROWS = telemetry.gauge(
+    "mxtpu_moe_expert_rows",
+    "Rows of a layer's least and most loaded expert in the last resolved "
+    "train step (against the even load T x k / E).", ("layer", "stat"))
+_STARVED = telemetry.gauge(
+    "mxtpu_moe_starved_experts",
+    "Experts of a layer that got no row in the last resolved train step.",
+    ("layer",))
+
+
+def _publish_load(layer, rows, held, window_rows, even_rows):
+    """One resolved step's rows an expert of one layer into the registry
+    (`collect_step_counter`'s ``publish``; ``rows`` a list of ints)."""
+    live = sum(rows)
+    _ROWS.inc(live, layer=layer)
+    if held:
+        _WINDOWS.inc(-(-live // window_rows), layer=layer)
+    _EXPERT_ROWS.set(min(rows), layer=layer, stat="min")
+    _EXPERT_ROWS.set(max(rows), layer=layer, stat="max")
+    _STARVED.set(rows.count(0), layer=layer)
 
 
 def load_balancing_loss(gates, top_idx, num_experts):
@@ -134,7 +168,8 @@ def dropless_moe(tokens, top_vals, top_idx, w_up, w_down, act, w_gate=None):
     FFN_e(x) = act(x w_up[e]) w_down[e], or with ``w_gate`` (E, D, H) the
     gated form (act(x w_gate[e]) * (x w_up[e])) w_down[e] (SwiGLU when
     ``act`` is silu). Every op is under one of the scopes `moe_dispatch`,
-    `moe_experts`, `moe_combine`.
+    `moe_experts`, `moe_combine`. -> (y, the rows each expert got: int32
+    (E,), what the grouped matmuls ran on).
     """
     n_tokens, k = top_idx.shape
     num_experts = w_up.shape[0]
@@ -158,7 +193,7 @@ def dropless_moe(tokens, top_vals, top_idx, w_up, w_down, act, w_gate=None):
     with jax.named_scope("moe_combine"):
         y = _unsort(y, order, inverse).reshape(n_tokens, k, -1)
         out = jnp.sum(y.astype(jnp.float32) * top_vals[..., None], axis=1)
-    return out.astype(tokens.dtype)
+    return out.astype(tokens.dtype), group_sizes
 
 
 def held_window_rows(n_tokens, k, count, num_experts):
@@ -376,7 +411,8 @@ def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
     kept in buffers of all the windows' rows, which a window that runs
     writes its part of, or computed again where those buffers would pass
     `HELD_KEEP_BYTES`). Where W is the worst case there is no loop.
-    Scopes as in `dropless_moe`, inside `moe_window`.
+    Scopes as in `dropless_moe`, inside `moe_window`. -> (y, the rows each
+    held expert got: int32 (count,), what the windows ran on).
     """
     n_tokens, k = top_idx.shape
     count = w_up.shape[0]
@@ -384,8 +420,6 @@ def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
     window = held_window_rows(n_tokens, k, count, num_experts)
     n_windows = -(-worst // window)
     _DISPATCHES.inc(path="dropless_held")
-    _HELD_ROWS.set(window, kind="window")
-    _HELD_ROWS.set(worst, kind="worst_case")
     with jax.named_scope("moe_dispatch"):
         local = top_idx.astype(jnp.int32) - first                 # (T, k)
         key = jnp.where((local >= 0) & (local < count), local,
@@ -401,7 +435,7 @@ def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
     out = _held_sum(act, window, tokens, top_vals,
                     (w_up,) if w_gate is None else (w_gate, w_up), w_down,
                     order, group_sizes)
-    return out.astype(tokens.dtype)
+    return out.astype(tokens.dtype), group_sizes
 
 
 class _StackedXavier(initializer.Initializer):
@@ -662,12 +696,20 @@ class MoELayer(HybridBlock):
             else (None, arrays["w1"])
         act = _ACTIVATIONS[self._act]
         if self.held is None:
-            out = dropless_moe(tokens, top_vals, top_idx, w_up, arrays["w2"],
-                               act, w_gate)
+            window = top_idx.size       # all T x k rows, one pass over them
+            out, rows = dropless_moe(tokens, top_vals, top_idx, w_up,
+                                     arrays["w2"], act, w_gate)
         else:
-            out = dropless_moe_held(tokens, top_vals, top_idx, w_up,
-                                    arrays["w2"], act, self.held[0],
-                                    self.num_experts, w_gate)
+            window = held_window_rows(*top_idx.shape, self.held[1],
+                                      self.num_experts)
+            _WINDOW_ROWS.set(window, layer=self.name)
+            out, rows = dropless_moe_held(tokens, top_vals, top_idx, w_up,
+                                          arrays["w2"], act, self.held[0],
+                                          self.num_experts, w_gate)
+        # a compiled train step hands this step's rows out (jit.TrainStep)
+        _functional.collect_step_counter(
+            self.name, rows, _publish_load, held=self.held is not None,
+            window_rows=window, even_rows=top_idx.size / self.num_experts)
         out = out.reshape(shape)
         if compute_aux:
             with jax.named_scope("router"):

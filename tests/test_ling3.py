@@ -444,7 +444,11 @@ def test_the_routes_counted_at_build_are_the_train_steps(monkeypatch):
 #: and of str(jaxpr) of grad(MoELayer), taken on the tree of PR 47 before
 #: `KimiDeltaAttention` grew `rank="full"` / `decay=` and `MoELayer`
 #: `n_group=` / `topk_group=`: the defaults trace what they traced.
-PARENT = {"solar": "495dfe07f6103be8", "sigmoid_bias": "cd7561396b0bef12",
+#: "solar" re-taken at PR 50 (495dfe07f6103be8 before): the step hands out
+#: its three layers' rows an expert, ONE `stablehlo.concatenate` of three
+#: (4,) int32 and one more result; every other line is the parent's but
+#: for value numbers (tests/test_step_counters.py holds that).
+PARENT = {"solar": "d6c08dc62b8f5ead", "sigmoid_bias": "cd7561396b0bef12",
           "softmax": "8556de4088ad355e"}
 
 

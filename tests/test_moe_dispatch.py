@@ -17,6 +17,12 @@ from moe_dense import dense_moe
 T, D, H, E, K = 48, 16, 24, 8, 3
 
 
+def _dropless(*args):
+    """`dropless_moe`'s sum, the dense form's contract; the rows an expert
+    got, which it also hands back, are counted below."""
+    return moe.dropless_moe(*args)[0]
+
+
 def _uneven_case(seed=0):
     """Tokens, stacked weights and a routing in which expert 0 is every
     token's first choice, expert 1 nobody's, and the rest as they fall."""
@@ -44,7 +50,7 @@ def test_dropless_matches_dense_on_uneven_routing(gated):
         return fn(tokens, top_vals, top_idx, w["up"], w["down"], act,
                   w["gate"] if gated else None)
 
-    got = run(moe.dropless_moe, tokens, top_vals, w)
+    got = run(_dropless, tokens, top_vals, w)
     want = run(dense_moe, tokens, top_vals, w)
     onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
@@ -55,7 +61,7 @@ def test_dropless_matches_dense_on_uneven_routing(gated):
     def scalar(fn):
         return lambda *a: jnp.sum(run(fn, *a) * probe)
 
-    g_got = jax.grad(scalar(moe.dropless_moe), argnums=(0, 1, 2))(
+    g_got = jax.grad(scalar(_dropless), argnums=(0, 1, 2))(
         tokens, top_vals, w)
     g_want = jax.grad(scalar(dense_moe), argnums=(0, 1, 2))(
         tokens, top_vals, w)
@@ -149,3 +155,15 @@ def test_dispatch_counter_and_the_capacity_warning():
     assert any("dropless" in str(w.message) for w in rec)
     with pytest.raises(ValueError):
         parallel.MoELayer(num_experts=4, hidden_size=4, ffn_hidden=8, top_k=5)
+
+
+def test_dispatch_hands_back_the_rows_each_expert_got():
+    """Expert 0 every token's, expert 1 nobody's, T x K in all: the second
+    output is a count of `top_idx`, and nothing is dropped."""
+    tokens, w, top_vals, top_idx = _uneven_case()
+    rows = moe.dropless_moe(tokens, top_vals, top_idx, w["up"], w["down"],
+                            jax.nn.silu, w["gate"])[1]
+    assert rows.dtype == jnp.int32
+    onp.testing.assert_array_equal(
+        rows, onp.bincount(onp.asarray(top_idx).ravel(), minlength=E))
+    assert rows[0] == T and rows[1] == 0 and int(rows.sum()) == T * K

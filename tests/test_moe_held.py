@@ -17,6 +17,12 @@ from moe_dense import dense_moe
 T, D, H, E, K = 48, 16, 24, 12, 5
 
 
+def _held_sum(*args):
+    """`dropless_moe_held`'s sum; the rows it also hands back (what each
+    held expert got) have tests of their own at the end of this file."""
+    return moe.dropless_moe_held(*args)[0]
+
+
 def _case(seed, dtype=jnp.float32):
     rng = onp.random.default_rng(seed)
     tokens = jnp.asarray(rng.standard_normal((T, D)), dtype)
@@ -48,7 +54,7 @@ def test_held_dispatch_is_the_held_experts_part_of_the_dense_sum(
     act = moe._ACTIVATIONS["relu2"]
     sl = slice(first, first + count)
     with jax.default_matmul_precision("highest"):
-        got = moe.dropless_moe_held(
+        got = _held_sum(
             tokens, top_vals, top_idx, w_up[sl], w_down[sl], act, first, E,
             None if w_gate is None else w_gate[sl])
         want = dense_moe(tokens, _only(top_vals, top_idx, first, count),
@@ -63,7 +69,7 @@ def test_held_dispatch_gradients_are_the_dense_ones():
     act = moe._ACTIVATIONS["relu2"]
 
     def held(tokens, top_vals, w_up_s, w_down_s):
-        return jnp.sum(moe.dropless_moe_held(
+        return jnp.sum(_held_sum(
             tokens, top_vals, top_idx, w_up_s, w_down_s, act, first, E) ** 2)
 
     def dense(tokens, top_vals, w_up_s, w_down_s):
@@ -132,7 +138,7 @@ def test_no_op_reads_or_writes_more_rows_than_the_window(gated):
     w_gate = w_up if gated else None
 
     def value(tokens, top_vals, w_up, w_down, w_gate):
-        return jnp.sum(moe.dropless_moe_held(
+        return jnp.sum(_held_sum(
             tokens, top_vals, top_idx, w_up, w_down, moe.relu2, 0, n_experts,
             w_gate))
 
@@ -156,7 +162,7 @@ def test_holding_every_expert_is_one_window_and_no_loop():
     tokens, top_vals, top_idx, w_up, w_down = _case(2)
 
     def value(tokens, top_vals, w_up, w_down):
-        return jnp.sum(moe.dropless_moe_held(
+        return jnp.sum(_held_sum(
             tokens, top_vals, top_idx, w_up, w_down, jax.nn.relu, 0, E))
 
     args = (tokens, top_vals, w_up, w_down)
@@ -223,7 +229,7 @@ def test_windows_are_exact_at_any_load(kind, windows, gated, kept):
     out_weights = jnp.asarray(rng.standard_normal((n_tokens, D)), jnp.float32)
 
     def held(tokens, top_vals, w_up_s, w_down_s, w_gate_s):
-        return moe.dropless_moe_held(tokens, top_vals, top_idx, w_up_s,
+        return _held_sum(tokens, top_vals, top_idx, w_up_s,
                                      w_down_s, act, first, n_experts,
                                      w_gate_s)
 
@@ -287,7 +293,7 @@ def test_what_a_kernel_leaves_in_dead_rows_reaches_no_sum(gated, monkeypatch,
     act = moe._ACTIVATIONS["relu2"]
 
     def held(tokens, top_vals, w_up, w_down, w_gate):
-        return jnp.sum(moe.dropless_moe_held(
+        return jnp.sum(_held_sum(
             tokens, top_vals, top_idx, w_up, w_down, act, first, n_experts,
             w_gate) ** 2)
 
@@ -339,7 +345,7 @@ def test_what_is_kept_follows_from_the_shapes(monkeypatch):
     def grads():
         # a function of its own a reading: JAX keeps a function's trace
         return lambda *args: jax.grad(lambda *a: jnp.sum(
-            moe.dropless_moe_held(a[0], a[1], top_idx, a[2], a[3],
+            _held_sum(a[0], a[1], top_idx, a[2], a[3],
                                   jax.nn.relu, first, n_experts)),
             (0, 1, 2, 3))(*args)
 
@@ -566,7 +572,7 @@ def test_held_none_is_the_dispatch_it_was():
         gates = jax.nn.softmax(logits, axis=-1)
         top_vals, top_idx = jax.lax.top_k(gates, K)
         return moe.dropless_moe(tokens, top_vals, top_idx, w3, w2,
-                                jax.nn.silu, w1).reshape(xd.shape)
+                                jax.nn.silu, w1)[0].reshape(xd.shape)
 
     x = jnp.ones((2, T // 2, D), jnp.bfloat16)
     weights = [w._data.astype(jnp.bfloat16) for w in layer._weights()]
@@ -580,16 +586,23 @@ def test_held_none_is_the_dispatch_it_was():
 def test_the_held_path_has_its_own_counter():
     before = moe._DISPATCHES.value(path="dropless_held")
     tokens, top_vals, top_idx, w_up, w_down = _case(6)
-    f = jax.jit(lambda t: moe.dropless_moe_held(
+    f = jax.jit(lambda t: _held_sum(
         t, top_vals, top_idx, w_up[:2], w_down[:2], jax.nn.relu, 0, E))
     for _ in range(3):
         f(tokens)
     assert moe._DISPATCHES.value(path="dropless_held") - before == 1
     text = telemetry.REGISTRY.export_text()
     assert 'mxtpu_moe_dispatch_total{path="dropless_held"}' in text
-    # the rows of the buffers as traced: here the worst case is the window
-    assert 'mxtpu_moe_held_rows{kind="window"} %d' % (T * 2) in text
-    assert 'mxtpu_moe_held_rows{kind="worst_case"} %d' % (T * 2) in text
+    # W of each held layer as traced, by the layer's name: at these sizes
+    # the worst case, T x min(k, count) (one window, no loop)
+    for labels, _ in moe._WINDOW_ROWS.series():     # 64 label sets a family
+        moe._WINDOW_ROWS.remove(**labels)
+    for count, rows in ((2, T * 2), (6, T * K)):
+        layer = _layer(held=(0, count))
+        layer(nd.ones((T, D)))
+        text = telemetry.REGISTRY.export_text()
+        assert 'mxtpu_moe_window_rows{layer="%s"} %d' % (layer.name, rows) \
+            in text
 
 
 # ---- the sigmoid router's chosen scores, read by comparison (PR 43) ----
@@ -749,3 +762,25 @@ def test_the_softmax_routers_weights_are_top_ks_own_values(norm):
     assert str(mine) == str(jax.make_jaxpr(parent)(tokens, gw))
     names = {e.primitive.name for e in _eqns(mine.jaxpr)}
     assert "top_k" in names and not names & {"eq", "iota", "gather"}
+
+
+# ---- the rows each held expert got, handed back (PR 50) ----
+
+@pytest.mark.parametrize("first,count", [(0, 4), (2, 3), (8, 4), (7, 1),
+                                         (0, 12)])
+def test_held_dispatch_hands_back_the_rows_each_held_expert_got(first,
+                                                                count):
+    """The second output is a count of `top_idx` over the held experts
+    (expert 3 nearly every token's, expert 7 nobody's), under `jit` too."""
+    tokens, top_vals, top_idx, w_up, w_down = _case(9)
+    sl = slice(first, first + count)
+    want = onp.bincount(onp.asarray(top_idx).ravel(), minlength=E)[sl]
+    rows = jax.jit(lambda t: moe.dropless_moe_held(
+        t, top_vals, top_idx, w_up[sl], w_down[sl], jax.nn.relu, first,
+        E)[1])(tokens)
+    assert rows.dtype == jnp.int32
+    onp.testing.assert_array_equal(rows, want)
+    if first <= 7 < first + count:
+        assert rows[7 - first] == 0
+    if count == E:
+        assert int(rows.sum()) == T * K
