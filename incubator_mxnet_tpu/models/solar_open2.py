@@ -53,7 +53,7 @@ from ..gluon import nn, utils
 from ..gluon.block import HybridBlock
 from ..ndarray import _apply
 from ..ops.attention import ATTENDED_NAME
-from ..ops.delta_rule import RULED_NAME, gated_delta_rule
+from ..ops.delta_rule import RULED_NAME, gated_delta_rule_lanes
 from ..parallel.moe import MoELayer
 from .nemotron_h import (GroupedQueryAttention, _InverseSoftplusOfLogUniform,
                          _LogUniform)
@@ -100,7 +100,14 @@ class KimiDeltaAttention(HybridBlock):
     in (0, 1). The defaults are Solar Open 2's block, its traced program
     text for text (tests/test_ling3.py pins it). More than one head group
     has run on the chip: 32 heads are 4 groups of 8 on the kernels' grid
-    (PERF.md section 6, PR 48 has the cost a group)."""
+    (PERF.md section 6, PR 48 has the cost a group).
+
+    One form of the operands: q, k, v, g, o and their gradients are (b,
+    s, h d) from ``in_proj``'s output to ``out_proj``'s input, a head a
+    block of the last dimension, which is what the rule's kernels read
+    (`ops.delta_rule.gated_delta_rule_lanes`; the op alone chooses its
+    schedule, and splits the heads off itself where it runs the XLA
+    form)."""
 
     def __init__(self, units, num_heads, head_dim, conv_kernel=4, rank=None,
                  chunk=64, shards=1, neg_eigval=True, epsilon=1e-5,
@@ -152,31 +159,49 @@ class KimiDeltaAttention(HybridBlock):
         for p in (self.A_log, self.dt_bias, self.norm_gamma):
             p.cast("float32")
 
+    def _head_sums(self, t):
+        """The sum over each head's d channels of ``t`` (b, s, h d),
+        float32, in ``t``'s shape. It is taken on the view (b, s / 8, h, 8,
+        d) of (b, s, h d): the SAME bytes under the (8, 128) tiling (eight
+        positions of one head's 128 lanes are a tile of both), so the view
+        and the way back are bitcasts and the sum a reduction over lanes. A
+        view (b, s, h, d) is tiled by (h, d): each move between it and (b,
+        s, h d) is a pass over the array, 40 ms of the Ling cell's 620 ms
+        step up to PR 50. (Multiplying inside the view, the sums left (..,
+        8, 1), is the same block alone and 2.7 % of the Ling step slower:
+        PERF.md section 6, PR 51.)"""
+        b, s, _ = t.shape
+        pad = -s % 8
+        if pad:
+            t = jnp.pad(t, [(0, 0), (0, pad), (0, 0)])
+        tiles = t.reshape(b, -1, 8, self.heads, self.head_dim) \
+            .transpose(0, 1, 3, 2, 4)
+        sums = jnp.broadcast_to(jnp.sum(tiles, -1, keepdims=True),
+                                tiles.shape)
+        return sums.transpose(0, 1, 3, 2, 4).reshape(t.shape)[:, :s]
+
     @functools.partial(jax.checkpoint, static_argnums=0)
     def _conv(self, qkv, conv_w):
-        """(b, s, 3 inner) -> q, k (b, s, h, d) float32, normed; v in the
-        input's type. (checkpoint: the gradient keeps qkv, not the float32
-        sums.)"""
+        """(b, s, 3 inner) -> q, k (b, s, inner) float32, normed a head; v
+        in the input's type. (checkpoint: the gradient keeps qkv, not the
+        float32 sums.)"""
         with jax.named_scope("kda_conv"):
-            b, s, _ = qkv.shape
+            s = qkv.shape[1]
             padded = jnp.pad(qkv, [(0, 0), (self._k - 1, 0), (0, 0)])
             taps = conv_w.astype(jnp.float32)
             acc = 0.0
             for j in range(self._k):        # position t sees t-k+1 .. t
                 acc = acc + padded[:, j:j + s].astype(jnp.float32) * taps[:, j]
-            q, k, v = (t.reshape(b, s, self.heads, self.head_dim)
-                       for t in jnp.split(jax.nn.silu(acc), 3, -1))
+            q, k, v = jnp.split(jax.nn.silu(acc), 3, -1)
 
             def unit(t):
-                return t * jax.lax.rsqrt(
-                    jnp.sum(t * t, -1, keepdims=True) + _L2_EPS)
+                return t * jax.lax.rsqrt(self._head_sums(t * t) + _L2_EPS)
 
             return unit(q) * self.head_dim ** -0.5, unit(k), \
                 v.astype(qkv.dtype)
 
     def _mix(self, proj, conv_w, a_log, dt_bias, gamma, decay_up=None,
              gate_up=None):
-        b, s, _ = proj.shape
         inner, r, h, d = self.inner, self._map_in, self.heads, self.head_dim
         qkv, low_f, low_g, b_in = jnp.split(
             proj, [3 * inner, 3 * inner + r, 3 * inner + 2 * r], -1)
@@ -186,27 +211,25 @@ class KimiDeltaAttention(HybridBlock):
                 else jnp.einsum("bsr,cr->bsc", low_f, decay_up,
                                 preferred_element_type=jnp.float32)
             pre = pre + dt_bias.astype(jnp.float32)
-            if self._lower is None:
-                step = jax.nn.softplus(pre).reshape(b, s, h, d)
-                g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * step
-            else:
-                g = self._lower * jax.nn.sigmoid(
-                    jnp.exp(a_log.astype(jnp.float32))[:, None]
-                    * pre.reshape(b, s, h, d))
+            # exp(A_log) a head, on its d channels
+            rate = jnp.repeat(jnp.exp(a_log.astype(jnp.float32)), d)
+            g = -rate * jax.nn.softplus(pre) if self._lower is None \
+                else self._lower * jax.nn.sigmoid(rate * pre)
             beta = self._beta_max * jax.nn.sigmoid(b_in.astype(jnp.float32))
-        o = gated_delta_rule(q, k, v, g, beta, self._chunk)
+        o = gated_delta_rule_lanes(q, k, v, g, beta, h, self._chunk)
 
         @jax.checkpoint      # the gradient keeps o and the low-rank input
         def gate_norm(o, low, gate_up, gamma):
             with jax.named_scope("kda_gate_norm"):
                 o = o.astype(jnp.float32)
-                o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
-                                      + self._eps) * gamma.astype(jnp.float32)
+                o = o * jax.lax.rsqrt(self._head_sums(o * o) / d
+                                      + self._eps) \
+                    * jnp.tile(gamma.astype(jnp.float32), h)
                 gate = jax.nn.sigmoid(
                     low.astype(jnp.float32) if gate_up is None
                     else jnp.einsum("bsr,cr->bsc", low, gate_up,
                                     preferred_element_type=jnp.float32))
-                return (o.reshape(b, s, inner) * gate).astype(proj.dtype)
+                return (o * gate).astype(proj.dtype)
 
         return gate_norm(o, low_g, gate_up, gamma)
 

@@ -41,6 +41,15 @@ they round. What the gradient keeps of the states is the one at each
 chunk's start, (T / C, d_k, d_v) a head, never one a position. Every op
 and both kernels are under the scope `delta_rule`.
 
+**Two entries, one form each.** `gated_delta_rule` takes (b, t, h, d)
+operands, `gated_delta_rule_lanes` (b, t, h d) with h stated: the form the
+kernels read, a head's channels a block of whole 128-lane tiles of the last
+dimension. XLA tiles (b, t, h, d) by (h, d) and (b, t, h d) by (t, h d), so
+a reshape between the two is a pass over the array, not a view; a caller
+that makes its operands in lanes (`models.KimiDeltaAttention`) moves
+nothing but beta where the kernels run. Either entry serves either schedule
+(the heads merged or split off for the other form) and counts the call once.
+
 **One recurrence, two schedules**, chosen by what a call can see, the
 platform and its shape (`_kernels_run_here()`, the rule of ops/attention.py
 and ops/selective_scan.py: a TPU, or MXTPU_FLASH_INTERPRET=1 for the CPU
@@ -99,7 +108,7 @@ from .. import telemetry
 from . import kernel_trace
 from .attention import _interpret, _kernels_run_here
 
-__all__ = ["gated_delta_rule", "RULED_NAME"]
+__all__ = ["gated_delta_rule", "gated_delta_rule_lanes", "RULED_NAME"]
 
 #: what the forward kernel writes, for a `jax.checkpoint` policy: o and the
 #: chunks' starting states. A recomputed layer that saves the name runs the
@@ -607,20 +616,39 @@ def _rule_kernels_bwd(h, chunk, kept, do):
 _rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
 
 
-def _rule_pallas(q, k, v, g, beta, chunk):
-    """The operands as the kernels take them: t padded, a head's channels
-    a lane block of (b, t, h d), beta a column a head."""
-    b, t, h, dk = q.shape
+def _rule_pallas(q, k, v, g, beta, h, chunk):
+    """The lanes form as the kernels take it: t padded to whole chunks,
+    beta (b, t, h) a column a head."""
+    t = q.shape[1]
     pad = -t % chunk
     with jax.named_scope("delta_rule"):
-        def rows(x):
-            if pad:
-                x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-            return x.reshape(b, t + pad, -1)
+        def whole(x):
+            return jnp.pad(x, [(0, 0), (0, pad), (0, 0)]) if pad else x
 
-        o = _rule_kernels(rows(q), rows(k), rows(v), rows(g),
-                          jnp.swapaxes(rows(beta), 1, 2)[..., None], h, chunk)
-        return o.reshape(b, t + pad, h, -1)[:, :t]
+        o = _rule_kernels(whole(q), whole(k), whole(v), whole(g),
+                          jnp.swapaxes(whole(beta), 1, 2)[..., None], h, chunk)
+        return o[:, :t]
+
+
+def _on_kernels(dk, dv, chunk):
+    """Whether a call of these head widths and this chunk runs the kernel
+    pair here; counts the call under the schedule it takes."""
+    kernels = bool(_kernels_run_here()) and _kernel_takes(dk, dv, chunk)
+    _CALLS.inc(path="pallas" if kernels else "xla")
+    return kernels
+
+
+def gated_delta_rule_lanes(q, k, v, g, beta, heads, chunk=64):
+    """`gated_delta_rule` on the kernels' own form: q, k, g (b, t, h d_k)
+    and v (b, t, h d_v), a head's channels a block of the last dimension;
+    beta (b, t, h) -> o (b, t, h d_v) in v's type. Where the kernels run
+    nothing is moved but beta; elsewhere the heads are split off for the
+    XLA form."""
+    b, t, _ = q.shape
+    if _on_kernels(q.shape[-1] // heads, v.shape[-1] // heads, chunk):
+        return _rule_pallas(q, k, v, g, beta, heads, chunk)
+    q, k, v, g = (x.reshape(b, t, heads, -1) for x in (q, k, v, g))
+    return _rule_xla(q, k, v, g, beta, chunk).reshape(b, t, -1)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=64):
@@ -628,8 +656,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64):
     the decay, <= 0; beta (b, t, h) -> o (b, t, h, d_v) in v's type.
 
     t is padded on the right to a multiple of ``chunk`` with positions
-    that neither decay nor write (g = 0, beta = 0) and the pad cut off."""
-    kernels = _kernels_run_here() \
-        and _kernel_takes(q.shape[-1], v.shape[-1], chunk)
-    _CALLS.inc(path="pallas" if kernels else "xla")
-    return (_rule_pallas if kernels else _rule_xla)(q, k, v, g, beta, chunk)
+    that neither decay nor write (g = 0, beta = 0) and the pad cut off.
+    Where the kernels run the heads are merged into the lanes form for
+    them and split off again; a caller that can make its operands in that
+    form calls `gated_delta_rule_lanes` and moves nothing."""
+    b, t, h, _ = q.shape
+    if not _on_kernels(q.shape[-1], v.shape[-1], chunk):
+        return _rule_xla(q, k, v, g, beta, chunk)
+    q, k, v, g = (x.reshape(b, t, -1) for x in (q, k, v, g))
+    return _rule_pallas(q, k, v, g, beta, h, chunk).reshape(b, t, h, -1)

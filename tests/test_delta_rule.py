@@ -7,7 +7,8 @@ and in all five gradients; where a channel's decay is so strong that
 exp(-G) leaves float32 inside a chunk; at b near 2 (a negative eigenvalue)
 and near 0; what a bfloat16 state would read against the same limit; which
 schedule a call takes, that a refused shape traces the parent's program,
-and that three layers trace each kernel once."""
+that the lanes entry (b, t, h d) is the 4-D entry's to the bit, and that
+three layers trace each kernel once."""
 import contextlib
 import functools
 import hashlib
@@ -259,6 +260,34 @@ def test_the_counter_counts_traces(path):
     assert rule_mod._CALLS.value(path=path) - before == 1
     assert 'mxtpu_delta_rule_total{path="%s"}' % path \
         in telemetry.REGISTRY.export_text()
+
+
+def test_the_lanes_entry_is_the_4d_entrys_to_the_bit(path):
+    """`gated_delta_rule_lanes` on (b, t, h d) operands, t no multiple of
+    the chunk: values and all five gradients are the 4-D entry's bit for
+    bit on either schedule, and a call by either entry counts once."""
+    args = inputs(11, 100, path)
+    b, t, h, _ = args[0].shape
+    w = cotangent(args[2].shape)
+
+    def by_heads(*a):
+        return jnp.sum(gated_delta_rule(*a, chunk=16) * w)
+
+    def by_lanes(*a):
+        q, k, v, g = (x.reshape(b, t, -1) for x in a[:4])
+        o = rule_mod.gated_delta_rule_lanes(q, k, v, g, a[4], h, chunk=16)
+        assert o.shape == (b, t, h * w.shape[-1])
+        return jnp.sum(o.reshape(w.shape) * w)
+
+    before = rule_mod._CALLS.value(path=path)
+    want = jax.jit(jax.value_and_grad(by_heads, (0, 1, 2, 3, 4)))(*args)
+    assert rule_mod._CALLS.value(path=path) == before + 1
+    got = jax.jit(jax.value_and_grad(by_lanes, (0, 1, 2, 3, 4)))(*args)
+    assert rule_mod._CALLS.value(path=path) == before + 2
+    for mine, theirs in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+        onp.testing.assert_array_equal(onp.asarray(mine),
+                                       onp.asarray(theirs))
 
 
 def test_every_op_is_under_the_scope(path, monkeypatch):

@@ -66,6 +66,62 @@ def test_the_delta_rule_backward_compiles_at_the_cells_shape(one_chip):
     assert "delta_rule_bwd" in compiled.as_text()
 
 
+#: an entry-computation instruction that moves an array: its opcode and
+#: the array's type and dimensions
+_MOVE = re.compile(
+    r"^\s*(?:ROOT )?\S+ = ([a-z0-9]+\[[0-9,]*\])\S* (copy|reshape|transpose)\(",
+    re.M)
+
+
+@pytest.mark.parametrize("cell, heads, kwargs", [
+    ("ling", 32, dict(rank="full", decay=("bounded", -5.0),
+                      neg_eigval=False)),
+    ("solar", 8, {})])
+def test_a_k_block_moves_no_operand_of_the_rule_between_two_layouts(
+        one_chip, monkeypatch, cell, heads, kwargs):
+    """One `KimiDeltaAttention._mix`, forward + backward, at the Ling
+    cell's shape (32 heads of 128, full-rank maps, the bounded decay) and
+    at the Solar cell's (8 heads a shard, low-rank maps, softplus), 1 x
+    8192 in bfloat16: q, k, g, v, o and their gradients stay (1, 8192,
+    h 128) from in_proj's output to out_proj's input. Up to PR 50 the
+    entry computation held 9 `copy -> [1024,8,32,128]` and 7 `reshape ->
+    [1,8192,4096]` that were passes over the array, not bitcasts (Ling; at
+    Solar's 8 heads 8 + 6 and 2 `copy -> [1,8192,8,128]`): (b, s, h, d) is
+    tiled by (h, d), (b, t, h d) by (t, h d). The sums over a head are
+    taken on (b, s / 8, h, 8, d), the one view of (b, s, h d) that is the
+    same bytes. The two kernels are the ones they were."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import models
+    from incubator_mxnet_tpu.ops import attention
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    # the rule asks where it runs: on the described chip
+    monkeypatch.setattr(attention, "_kernels_run_here", lambda: True)
+    monkeypatch.setattr(delta_rule, "_kernels_run_here", lambda: True)
+    mx.random.seed(1)
+    block = models.KimiDeltaAttention(256, heads, D, chunk=CHUNK, **kwargs)
+    block.initialize(mx.init.Xavier())
+    block.cast("bfloat16")
+    own = (block.conv_weight, block.A_log, block.dt_bias, block.norm_gamma) \
+        + ((block.decay_up, block.gate_up) if block.rank else ())
+    specs = [jax.ShapeDtypeStruct((B, T, block.in_proj.weight.shape[0]),
+                                  jnp.bfloat16, sharding=one_chip)] \
+        + [jax.ShapeDtypeStruct(p.shape, p.data()._data.dtype,
+                                sharding=one_chip) for p in own]
+    text = jax.jit(jax.grad(
+        lambda *a: block._mix(*a).astype(jnp.float32).sum(),
+        tuple(range(len(specs))))).lower(*specs).compile().as_text()
+    moved = [m.group(0).strip() for m in _MOVE.finditer(
+        text[text.index("ENTRY"):])
+        if m.group(1).split("[")[1] in ("1024,8,%d,128]" % heads,
+                                        "1,8192,%d,128]" % heads,
+                                        "1,8192,%d]" % (heads * D))
+        # in_proj's bfloat16 output cut into its parts is the parent's too
+        and not (m.group(2) == "copy" and m.group(1).startswith("bf16[1,"))]
+    assert not moved, moved
+    assert text.count("tpu_custom_call") == 2
+    assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
+
+
 #: the Cerebras cells' widths (perfbench/configs/cerebras-gpt-1.3b.json)
 UNITS, INNER, HEADS = 2048, 8192, 16
 _UPDATE = re.compile(

@@ -162,11 +162,11 @@ def test_the_bounded_decay_stays_above_its_bound():
     """g = lower * sigmoid(.) in (lower, 0) however large the map's output;
     `decay="softplus"` passes it."""
     seen = {}
-    real = models.solar_open2.gated_delta_rule
+    real = models.solar_open2.gated_delta_rule_lanes
 
-    def spy(q, k, v, g, beta, chunk):
+    def spy(q, k, v, g, beta, heads, chunk):
         seen["g"], seen["beta"] = g, beta
-        return real(q, k, v, g, beta, chunk)
+        return real(q, k, v, g, beta, heads, chunk)
 
     x = nd.array(20.0 * inputs())
     for decay, low in ((("bounded", -5.0), -5.0), ("softplus", -1e9)):
@@ -175,11 +175,11 @@ def test_the_bounded_decay_stays_above_its_bound():
                                           decay=decay, neg_eigval=False)
         block.initialize(mx.init.Xavier())
         block.in_proj.weight.set_data(block.in_proj.weight.data() * 30.0)
-        models.solar_open2.gated_delta_rule = spy
+        models.solar_open2.gated_delta_rule_lanes = spy
         try:
             block(x)
         finally:
-            models.solar_open2.gated_delta_rule = real
+            models.solar_open2.gated_delta_rule_lanes = real
         g = onp.asarray(seen["g"])
         assert g.max() <= 0 and g.min() >= low
         assert (g.min() < -5.0) == (decay == "softplus")
@@ -448,7 +448,20 @@ def test_the_routes_counted_at_build_are_the_train_steps(monkeypatch):
 #: its three layers' rows an expert, ONE `stablehlo.concatenate` of three
 #: (4,) int32 and one more result; every other line is the parent's but
 #: for value numbers (tests/test_step_counters.py holds that).
-PARENT = {"solar": "d6c08dc62b8f5ead", "sigmoid_bias": "cd7561396b0bef12",
+#: "solar" RE-TAKEN at PR 51 (`d6c08dc62b8f5ead` before): `KimiDeltaAttention`
+#: has ONE form, every per-head stage on (b, s, h d) (the K block's
+#: reshapes to (b, s, h, d) were passes over the array on the chip, 40 ms
+#: of the Ling cell's step), on this CPU step too, where the rule's XLA
+#: form runs and the op splits the heads off itself. What moved in the
+#: text, two K layers forward, recomputed and backward: the sums over a
+#: head's channels are taken on the view (b, s / 8, h, 8, d) and broadcast
+#: back (8 613 -> 8 729 lines: `reshape` 464 -> 520, `transpose` 181 ->
+#: 229, `broadcast_in_dim` +8; s = 80 is whole tiles of 8, so no pad),
+#: exp(A_log) is repeated to a channel each and the norm's gain tiled to
+#: (h d) (`constant` +2, `reduce` +2); no other op's count moved. The values
+#: are held by tests/test_solar_open2.py (outputs and gradients against
+#: the block by heads as PR 50 had it, on both schedules).
+PARENT = {"solar": "b70ccc94ca220f29", "sigmoid_bias": "cd7561396b0bef12",
           "softmax": "8556de4088ad355e"}
 
 

@@ -4,9 +4,11 @@ float32 reference the benchmark uses
 gradient (the gradients: tests/test_gradients_solar_open2.py, a file of its
 own so that a second worker of the tier-1 run takes it); the shares of
 guide section 4 (8 head shards of each mixer, 40 expert shares with the
-shared expert counted once) against the uncut layer; what the reference hands out to be compared; the gate on the
-attention heads; the names the streamed kernels trace from the `G` layer;
-and the normal path (TrainStep, every layer recomputed) with its scopes.
+shared expert counted once) against the uncut layer; what the reference
+hands out to be compared; the K block (every per-head stage on (b, s, h d))
+against the block by heads as PR 50 had it; the gate on the attention
+heads; the names the streamed kernels trace from the `G` layer; and the
+normal path (TrainStep, every layer recomputed) with its scopes.
 """
 import importlib.util
 import os
@@ -385,6 +387,98 @@ def test_every_train_step_moves_every_routers_bias_by_the_rule(remat):
     for l, b in zip(plain.layers, before):
         onp.testing.assert_array_equal(
             l.experts.moe.router_bias.data().asnumpy(), b)
+
+
+# ------------------------------------- the K block against the block by heads
+def _mix_by_heads(block, proj, conv_w, a_log, dt_bias, gamma, decay_up=None,
+                  gate_up=None):
+    """`KimiDeltaAttention._mix` as PR 50 had it, the plain reference of
+    the block: every per-head stage on (b, s, h, d), the sums
+    over a head `jnp.sum(.., -1)`, the rule by its 4-D entry."""
+    from incubator_mxnet_tpu.models.solar_open2 import _L2_EPS
+    from incubator_mxnet_tpu.ops.delta_rule import gated_delta_rule
+    b, s, _ = proj.shape
+    inner, r, h, d = block.inner, block._map_in, block.heads, block.head_dim
+    f32 = jnp.float32
+    qkv, low_f, low_g, b_in = jnp.split(
+        proj, [3 * inner, 3 * inner + r, 3 * inner + 2 * r], -1)
+    padded = jnp.pad(qkv, [(0, 0), (block._k - 1, 0), (0, 0)])
+    acc = sum(padded[:, j:j + s].astype(f32) * conv_w.astype(f32)[:, j]
+              for j in range(block._k))
+    q, k, v = (t.reshape(b, s, h, d)
+               for t in jnp.split(jax.nn.silu(acc), 3, -1))
+
+    def unit(t):
+        return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + _L2_EPS)
+
+    pre = low_f.astype(f32) if decay_up is None else jnp.einsum(
+        "bsr,cr->bsc", low_f, decay_up, preferred_element_type=f32)
+    pre = (pre + dt_bias.astype(f32)).reshape(b, s, h, d)
+    rate = jnp.exp(a_log.astype(f32))[:, None]
+    g = -rate * jax.nn.softplus(pre) if block._lower is None \
+        else block._lower * jax.nn.sigmoid(rate * pre)
+    beta = block._beta_max * jax.nn.sigmoid(b_in.astype(f32))
+    o = gated_delta_rule(unit(q) * d ** -0.5, unit(k), v.astype(qkv.dtype),
+                         g, beta, block._chunk).astype(f32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + block._eps) \
+        * gamma.astype(f32)
+    gate = jax.nn.sigmoid(low_g.astype(f32) if gate_up is None else jnp.einsum(
+        "bsr,cr->bsc", low_g, gate_up, preferred_element_type=f32))
+    return (o.reshape(b, s, inner) * gate).astype(proj.dtype)
+
+
+KDA_FORMS = {"solar": {}, "ling": dict(rank="full", decay=("bounded", -5.0),
+                                       neg_eigval=False)}
+
+
+@pytest.mark.parametrize("kernels", [True, False], ids=["kernels", "xla"])
+@pytest.mark.parametrize("heads", [4, 32])
+@pytest.mark.parametrize("form", sorted(KDA_FORMS))
+def test_the_k_block_in_lanes_is_the_block_by_heads(monkeypatch, form, heads,
+                                                    kernels):
+    """`KimiDeltaAttention._mix` in float32 against `_mix_by_heads`, output
+    and every gradient (in_proj's output, taps, A_log, dt_bias, the norm's
+    gain, the low-rank maps), batch 2 and 44 positions (no whole chunks of
+    16, no whole tiles of 8): the block computes on (b, s, h d) and sums a
+    head's channels on the tiles' view, whichever schedule the rule takes
+    (the kernel pair interpreted, heads of 128; or the XLA form, for which
+    the op splits the heads off)."""
+    from incubator_mxnet_tpu.ops import delta_rule
+    if kernels:
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("MXTPU_FLASH_INTERPRET", raising=False)
+    mx.random.seed(heads)
+    block = models.KimiDeltaAttention(32, heads, 128, chunk=16,
+                                      **KDA_FORMS[form])
+    block.initialize(mx.init.Xavier())
+    rng = onp.random.default_rng(heads)
+    for p in (block.norm_gamma, block.A_log):   # not all ones, not all alike
+        p.set_data(p.data() * nd.array(rng.uniform(0.5, 1.5, p.shape)))
+    own = (block.conv_weight, block.A_log, block.dt_bias, block.norm_gamma) \
+        + ((block.decay_up, block.gate_up) if block.rank else ())
+    args = [jnp.asarray(rng.standard_normal(
+        (B, 44, block.in_proj.weight.shape[0])), jnp.float32)] \
+        + [p.data()._data for p in own]
+    w = jnp.asarray(rng.standard_normal((B, 44, block.inner)), jnp.float32)
+    every = tuple(range(len(args)))
+    counted = delta_rule._CALLS.value(path="pallas" if kernels else "xla")
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: (block._mix(*a) * w).sum(), every))(*args)
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: (_mix_by_heads(block, *a) * w).sum(), every))(*args)
+        out = jax.jit(block._mix)(*args)
+        assert out.shape == w.shape
+        assert rel_rms(out, jax.jit(
+            lambda *a: _mix_by_heads(block, *a))(*args)) < 1e-5
+    assert delta_rule._CALLS.value(
+        path="pallas" if kernels else "xla") == counted + 4
+    for mine, theirs in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+        assert mine.shape == theirs.shape
+        assert onp.abs(onp.asarray(theirs)).max() > 0
+        assert rel_rms(mine, theirs) < 1e-5
 
 
 def test_the_gate_is_on_the_heads_output_a_channel_at_a_time():
