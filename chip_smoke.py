@@ -38,8 +38,8 @@ import time
 import urllib.request
 from unittest import mock
 
-PHASES = ("kernels", "moe", "bert", "gpt", "hybrid", "resnet", "serve",
-          "generate", "multichip")
+PHASES = ("kernels", "moe", "bert", "gpt", "hybrid", "streams", "resnet",
+          "serve", "generate", "multichip")
 
 # the sizes a run on the chip drives (TOY below: what --rehearse drives)
 FULL = {
@@ -68,6 +68,11 @@ FULL = {
                    SA_K=2048,
                    # EVA attention alone at the EvaByte cell's shape
                    EV_S=16384, EV_H=32, EV_D=128, EV_W=2048, EV_C=16),
+    # one hyper-connected sublayer and one latent-attention block with a
+    # low-rank query at the Xing cell's widths (4 streams of 3584; 32 heads,
+    # 768 | 512 | 128 + 64 | 128, YaRN x 64 over 4096)
+    "streams": dict(S=8192, U=3584, N=4, H=32, QL=768, KL=512, DN=128, DR=64,
+                    DV=128, YARN=64, YARN_FROM=4096),
     "resnet": dict(B=256, HW=224),
     "serve": dict(HW=224, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=2048, V=32768, U=1024, L=2, H=8),
@@ -93,6 +98,8 @@ TOY = {
                    SA_K=48,
                    # (windows of 128 rows of 128: the streamed kernels' least)
                    EV_S=512, EV_H=2, EV_D=128, EV_W=128, EV_C=16),
+    "streams": dict(S=256, U=128, N=4, H=2, QL=48, KL=32, DN=16, DR=8,
+                    DV=16, YARN=64, YARN_FROM=64),
     "resnet": dict(B=16, HW=64),
     "serve": dict(HW=32, requests=16, clients=4, max_batch=8),
     "ring": dict(B=4, S=256, V=512, U=256, L=1, H=2),
@@ -1150,6 +1157,169 @@ def phase_hybrid(cfg, on_chip, shared):
                 eva["forward_ms"], eva["both_ms"]))
 
 
+#: the hyper-connection's maps in float32 against the plain form on the SAME
+#: bfloat16 stream (x^ P is a float32 matmul at full precision: a bfloat16
+#: pass of it reads 2e-3); its mixed streams and the latent block in bfloat16
+#: against themselves in float32 (bfloat16 rounding: 2^-8 an entry)
+STREAMS_LIMITS = {"maps": 1e-4, "bfloat16": 2.0 ** -5}
+
+
+def hyper_connection_alone(cfg, on_chip):
+    """One `HyperConnection` sublayer, X' = Hres X + Hpost^T silu(Hpre X),
+    at (1, S, N U) in bfloat16: the three maps against the plain form a
+    position at a time (20 Sinkhorn rounds written out) on the same
+    stream, X' against the plain form in float32 -> {"maps", "mixed":
+    the worst distance over the tensor's largest entry, "forward_ms",
+    "both_ms": None off the chip}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import models, nd
+    n, u, s = cfg["N"], cfg["U"], cfg["S"]
+    mx.random.seed(0)
+    hc = models.HyperConnection(u, n)
+    hc.initialize(mx.init.Xavier())
+    rng = onp.random.default_rng(0)
+    hc.weight.set_data(nd.array(rng.standard_normal(
+        hc.weight.shape, onp.float32) / onp.sqrt(n * u)))
+    bias = onp.zeros(2 * n + n * n, onp.float32)
+    bias[2 * n:] = 2.0 * onp.eye(n, dtype=onp.float32).reshape(-1)
+    hc.bias.set_data(nd.array(bias))
+    x = jnp.asarray(rng.standard_normal((1, s, n * u)), jnp.bfloat16)
+    cot = jnp.asarray(rng.standard_normal((1, s, n * u)), jnp.bfloat16)
+    params = tuple(p.data()._data for p in (hc.weight, hc.bias, hc.scale))
+
+    def plain_maps(one, w, b, a):
+        vec = one.reshape(-1)
+        raw = w @ (vec / jnp.sqrt((vec * vec).mean() + 1e-6))
+        h_pre = jax.nn.sigmoid(a[0] * raw[:n] + b[:n])
+        h_post = 2.0 * jax.nn.sigmoid(a[1] * raw[n:2 * n] + b[n:2 * n])
+        m = jnp.exp(jnp.clip((a[2] * raw[2 * n:] + b[2 * n:]).reshape(n, n),
+                             -30.0, 30.0))
+        for _ in range(20):
+            m = m / (m.sum(1, keepdims=True) + 1e-6)
+            m = m / (m.sum(0, keepdims=True) + 1e-6)
+        return h_pre, h_post, m
+
+    def plain(x, w, b, a):
+        with jax.default_matmul_precision("highest"):
+            xs = x.astype(jnp.float32).reshape(s, n, u)
+            h_pre, h_post, h_res = jax.vmap(
+                lambda one: plain_maps(one, w, b, a))(xs)
+            y = jax.nn.silu(jnp.einsum("sn,snc->sc", h_pre, xs))
+            mixed = jnp.einsum("sij,sjc->sic", h_res, xs) \
+                + h_post[..., None] * y[:, None]
+            return mixed.reshape(1, s, n * u), (h_pre.T, h_post.T,
+                                                h_res.transpose(1, 2, 0))
+
+    def system(x, w, b, a):
+        u_, h_post, h_res = hc._read(x, w, b, a)
+        y = jax.nn.silu(u_.astype(jnp.float32)).astype(x.dtype)
+        return hc._write(x, y, h_post, h_res)
+
+    def distance(got, want):
+        got, want = (t.astype(jnp.float32) for t in (got, want))
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    want, want_maps = jax.jit(plain)(x, *params)
+    got_maps = jax.jit(hc.maps)(x, *params)
+    out = {"maps": max(distance(g, w) for g, w in zip(got_maps, want_maps)),
+           "mixed": distance(jax.jit(system)(x, *params), want),
+           "forward_ms": None, "both_ms": None}
+    if on_chip:
+        def both(x, w, b, a):
+            y, back = jax.vjp(system, x, w, b, a)
+            return (y,) + back(cot)
+        out["forward_ms"] = median_ms(jax.jit(system), (x,) + params)
+        out["both_ms"] = median_ms(jax.jit(both), (x,) + params)
+    return out
+
+
+def latent_block_alone(cfg, on_chip):
+    """One `MultiHeadLatentAttention` block in the DeepSeek-V3 form (a
+    low-rank query with its norm, no QK-norm, no gate, a YaRN table, the
+    scale times m^2) at (1, S, U): bfloat16 against the same weights in
+    float32 at full matmul precision (the streamed kernels at blocks of
+    512 there: ops/attention.py), on the streamed kernels on the chip ->
+    {"outputs", "route", "forward_ms", "both_ms"}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import models, nd
+    from incubator_mxnet_tpu.models.ling3 import yarn_inv_freq, yarn_mscale
+    from incubator_mxnet_tpu.ops import attention as op
+    mx.random.seed(0)
+    block = models.MultiHeadLatentAttention(
+        cfg["U"], cfg["H"], cfg["KL"], cfg["DN"], cfg["DR"], cfg["DV"],
+        q_latent=cfg["QL"], qk_norm=False, head_gate=False,
+        inv_freq=yarn_inv_freq(cfg["DR"], 1e4, cfg["YARN"],
+                               cfg["YARN_FROM"]),
+        scale=yarn_mscale(cfg["YARN"]) ** 2
+        / (cfg["DN"] + cfg["DR"]) ** 0.5)
+    block.initialize(mx.init.Xavier())
+    x = onp.random.default_rng(1).standard_normal(
+        (1, cfg["S"], cfg["U"])).astype("float32")
+    routes = {r: op._ROUTES.value(route=r) for r in ("streamed", "composite")}
+    with mock.patch.dict(os.environ, {"MXTPU_FLASH_BLOCK_Q": "512",
+                                      "MXTPU_FLASH_BLOCK_K": "512"}), \
+            jax.default_matmul_precision("highest"):
+        want = block(nd.array(x))._data
+        jax.block_until_ready(want)
+    block.cast("bfloat16")
+    xb = jnp.asarray(x, jnp.bfloat16)
+
+    def system(xb):
+        return block(nd.NDArray(xb))._data
+
+    got = jax.jit(system)(xb).astype(jnp.float32)
+    route = [r for r, was in routes.items() if op._ROUTES.value(route=r) > was]
+    out = {"outputs": float(jnp.abs(got - want).max() / jnp.abs(want).max()),
+           "route": "+".join(route), "forward_ms": None, "both_ms": None}
+    if on_chip:
+        if route != ["streamed"]:
+            raise RuntimeError("the latent block's attention took %s at "
+                               "S=%d" % (route, cfg["S"]))
+        cot = jnp.asarray(x[::-1], jnp.bfloat16)
+        out["forward_ms"] = median_ms(jax.jit(system), (xb,))
+        out["both_ms"] = median_ms(jax.jit(
+            lambda xb: jax.vjp(system, xb)[1](cot)), (xb,))
+    return out
+
+
+def phase_streams(cfg, on_chip, shared):
+    """A residual path of several streams meets the device outside the
+    benchmark: one hyper-connected sublayer and one latent-attention block
+    with a low-rank query and YaRN frequencies, at the Xing cell's widths,
+    each against its float32 form."""
+    t0 = time.perf_counter()
+    hyper = hyper_connection_alone(cfg, on_chip)
+    latent = latent_block_alone(cfg, on_chip)
+    spent = time.perf_counter() - t0
+    if not (hyper["maps"] < STREAMS_LIMITS["maps"]
+            and hyper["mixed"] < STREAMS_LIMITS["bfloat16"]
+            and latent["outputs"] < STREAMS_LIMITS["bfloat16"]):
+        raise RuntimeError(
+            "streams: the maps read %.3g of their largest entry from the "
+            "plain form (limit %g), the mixed streams %.3g and the latent "
+            "block %.3g from their float32 forms (limit %g)" % (
+                hyper["maps"], STREAMS_LIMITS["maps"], hyper["mixed"],
+                latent["outputs"], STREAMS_LIMITS["bfloat16"]))
+    ms = "" if hyper["forward_ms"] is None else \
+        "; the sublayer's two mixes and maps forward %.2f ms, forward + " \
+        "backward %.2f ms; the block forward %.1f ms, forward + backward " \
+        "%.1f ms in bfloat16" % (hyper["forward_ms"], hyper["both_ms"],
+                                 latent["forward_ms"], latent["both_ms"])
+    return spent, 0.0, "a hyper-connection of %d streams of %d at S=%d: the " \
+        "maps %.2g of their largest entry from the plain form a position " \
+        "at a time, the mixed streams in bfloat16 %.2g from float32; a " \
+        "latent block of %d heads, query latent %d (%s) in bfloat16 %.2g " \
+        "from float32%s" % (cfg["N"], cfg["U"], cfg["S"], hyper["maps"],
+                            hyper["mixed"], cfg["H"], cfg["QL"],
+                            latent["route"], latent["outputs"], ms)
+
+
 def build_resnet():
     import incubator_mxnet_tpu as mx
     mx.random.seed(0)
@@ -1481,6 +1651,7 @@ def main():
              "bert": (phase_bert, cfgs["bert"]),
              "gpt": (phase_gpt, cfgs["gpt"]),
              "hybrid": (phase_hybrid, cfgs["hybrid"]),
+             "streams": (phase_streams, cfgs["streams"]),
              "resnet": (phase_resnet, cfgs["resnet"]),
              "serve": (phase_serve, cfgs["serve"]),
              "generate": (phase_generate, None),

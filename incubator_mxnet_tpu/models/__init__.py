@@ -21,6 +21,13 @@
               heads; a leading dense SwiGLU layer; sigmoid-routed experts
               chosen under a group limit beside a shared expert), whole or
               as one chip's share of its experts
+- xing4:      Xing 4.0 (a residual path of several streams a position,
+              mixed around every sublayer by per-token maps of which the
+              square one is made doubly stochastic by Sinkhorn rounds;
+              multi-head latent attention with a low-rank query and YaRN
+              frequencies in every layer; a leading dense SwiGLU layer;
+              sigmoid-routed experts beside a shared expert), whole or as
+              one chip's share of its experts
 - keye_vl2:   Keye-VL 2.0's decoder (grouped-query attention over the keys a
               lightning indexer picks, the indexer's own KL loss, rotary
               positions from three streams, softmax-routed SwiGLU experts),
@@ -45,6 +52,7 @@ from .solar_open2 import (SolarOpen2Model, SolarOpen2Layer,  # noqa
                           KimiDeltaAttention, GatedGroupedQueryAttention,
                           SharedExpertMoE)
 from .ling3 import Ling3Model, MultiHeadLatentAttention  # noqa
+from .xing4 import Xing4Model, Xing4Layer, HyperConnection  # noqa
 from .keye_vl2 import (KeyeVL2Model, KeyeVL2Layer,  # noqa
                        SparseGroupedQueryAttention)
 from .evabyte import (EvaByteModel, EvaByteLayer, EvaAttention,  # noqa

@@ -320,19 +320,29 @@ class MixerStackLM(HybridBlock):
     by the subclass, walked here. ``_remat``: each layer's forward is
     recomputed in the backward (`gluon.utils.recompute`) but for what its
     Pallas kernels wrote (`_KEPT`); a layer's moved selection bias is
-    booked here, outside the recomputed layers."""
+    booked here, outside the recomputed layers. A subclass whose layers
+    carry something else than (B, S, U) overrides `stream_in` /
+    `stream_out` (models/xing4.py's four streams)."""
+
+    def stream_in(self, x):
+        """The embeddings (B, S, U) -> what the first layer takes."""
+        return x
+
+    def stream_out(self, x):
+        """What the last layer hands out -> (B, S, U) for the final norm."""
+        return x
 
     def features(self, token_ids):
         """The final norm's output (B, S, U): pair with
         ChunkedUntiedLMLoss so the (B*S, V) logits never materialise."""
-        x = self.tok_embed(token_ids)
+        x = self.stream_in(self.tok_embed(token_ids))
         for layer in self.layers:
             x = utils.recompute(layer, x, policy=_KEPT) if self._remat \
                 else layer(x)
             if isinstance(x, tuple):
                 x, moved = x
                 layer.experts.moe.move_bias(moved)
-        return self.norm_f(x)
+        return self.norm_f(self.stream_out(x))
 
     def forward(self, token_ids):
         return self.lm_head(self.features(token_ids))

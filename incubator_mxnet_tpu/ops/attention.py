@@ -114,6 +114,12 @@ as it is 6.69 / 23.18; q and k padded with zeros to 256 lanes 7.51 /
 1.6 times the time for 1.25 times the operations, the price of half a
 lane tile. In the Ling step's capture the two calls take 20.2 ms, 51.9 %
 of the roofline of the scores the model requires.
+A ``scale`` of the caller's own at D 192 (v5e, PR 53: DeepSeek-V3's 192^-1/2
+times YaRN's m^2 = 2.0047, five layers a step in the Xing cell) showed
+nothing new: the scale is one scalar multiply in all three kernels' bodies
+whatever its value; the ten calls take 102 ms of a 493 ms step at the same
+51.8 % of the required scores' roofline as Ling's two, and a block alone
+reads 11.7 ms forward, 30.9 forward + backward with its five maps.
 """
 from __future__ import annotations
 

@@ -238,3 +238,20 @@ def test_eva_attention_alone_reads_under_its_limits_and_no_summaries_over(
     assert read["gradients"] < limits["gradients"] / 10
     assert read["no_remote"] > limits["outputs"] * 5
     assert read["forward_ms"] is None and read["both_ms"] is None
+
+
+def test_the_streams_phase_reads_float32_maps_and_bfloat16_blocks():
+    """The streams phase's own limits at the rehearsal shape: the
+    hyper-connection's maps are float32 (7e-7 of their largest entry from
+    the plain form a position at a time here; one bfloat16 pass of x^ P
+    would read 2e-3), the mixed streams and the latent block with a
+    low-rank query are a bfloat16 rounding from their float32 forms; off
+    the chip neither reports milliseconds."""
+    cfg = chip_smoke.TOY["streams"]
+    hyper = chip_smoke.hyper_connection_alone(cfg, False)
+    assert hyper["maps"] < chip_smoke.STREAMS_LIMITS["maps"] / 10
+    assert 1e-4 < hyper["mixed"] < chip_smoke.STREAMS_LIMITS["bfloat16"]
+    latent = chip_smoke.latent_block_alone(cfg, False)
+    assert 1e-4 < latent["outputs"] < chip_smoke.STREAMS_LIMITS["bfloat16"]
+    assert latent["route"] == "composite"
+    assert hyper["forward_ms"] is None and latent["both_ms"] is None
