@@ -3,7 +3,8 @@
 One process, no arguments: drives the system's main paths once, through the
 public package, at full width (the sizes in FULL below) — the Pallas
 flash-attention kernels against a reference, the dropless expert dispatch
-against its dense form, BERT and a long-context GPT
+against its dense form, the held dispatch's sum of a window's rows into
+their tokens alone in both its forms, BERT and a long-context GPT
 through gluon.Trainer -> jit.TrainStep with those kernels, one tiny
 Nemotron-H share (chunked Mamba-2 scan, held expert dispatch, grouped-query
 attention, each layer recomputed), ResNet-50 training, ResNet-50 behind the HTTP
@@ -38,7 +39,7 @@ import time
 import urllib.request
 from unittest import mock
 
-PHASES = ("kernels", "moe", "bert", "gpt", "hybrid", "streams", "resnet",
+PHASES = ("kernels", "moe", "combine", "bert", "gpt", "hybrid", "streams", "resnet",
           "serve", "generate", "multichip")
 
 # the sizes a run on the chip drives (TOY below: what --rehearse drives)
@@ -50,6 +51,14 @@ FULL = {
                 ((16, 16, 512, 64), False), ((8, 16, 768, 64), True)],
     # OLMoE's expert layer: 64 experts of 2048 -> 1024, 8 per token
     "moe": dict(T=4096, U=2048, I=1024, E=64, K=8),
+    # the held combine alone, a window's rows into their tokens, at the five
+    # held cells' (W rows, T tokens, D wide, at most `most` rows a token)
+    "combine": dict(shapes={"ling": (2048, 8192, 2560, 8),
+                            "xing": (8192, 8192, 3584, 4),
+                            "keye": (32768, 16384, 2048, 8),
+                            "solar": (4096, 8192, 4096, 8),
+                            "nemotron": (6144, 8192, 1024, 8)},
+                    live=(0.25, 0.5, 1.0), calls=20),
     "bert": dict(B=64, S=512, V=32768, U=1024, L=12, H=8),
     "gpt": dict(S=8192, V=32768, U=1024, L=4, H=8),
     # one Mamba-2, one expert and one attention layer of a Nemotron-H share:
@@ -83,6 +92,8 @@ TOY = {
     "kernels": [((1, 2, 128, 128), False), ((1, 2, 256, 128), True),
                 ((1, 2, 256, 64), False)],
     "moe": dict(T=64, U=32, I=16, E=8, K=2),
+    "combine": dict(shapes={"toy": (64, 48, 16, 4), "wide": (96, 32, 20, 3)},
+                    live=(0.25, 0.5, 1.0), calls=2),
     "bert": dict(B=4, S=128, V=512, U=256, L=1, H=2),
     "gpt": dict(S=256, V=512, U=256, L=1, H=2),
     "hybrid": dict(S=256, V=512, U=128, P="ME*", MH=16, MD=8, G=2, N=16,
@@ -254,6 +265,129 @@ def phase_moe(cfg, on_chip, shared):
             T, E, K, U, I, int(counts.min()), int(counts.max()), worst,
             "; %.1f TFLOP/s of required expert FLOPs over the whole call"
             % (flops / statistics.median(steady) / 1e12) if on_chip else "")
+
+
+def phase_combine(cfg, on_chip, shared):
+    """The held dispatch's sum of a window's rows into their tokens
+    (parallel.moe._rows_to_tokens) ALONE, at the held cells' shapes and at
+    a quarter, a half and all of the window live: both of its forms
+    whatever the rows' width would choose (XLA's float32 row scatter-add;
+    a sort of the window's token ids, two row gathers and shifted adds:
+    the same sum, the order of a token's at most `most` additions is all
+    that may differ), beside two forms that were not built: the runs'
+    first rows scattered by unique indices, and for windows up to 4096
+    rows a one-hot matmul. Each form runs `calls` times in one loop of one
+    program, on token ids that move with the iteration so that nothing
+    leaves the loop, and a line gives each form's milliseconds a call
+    (docs/PERF_NOTES.md, PR 54 has the chip's; `_sorts_the_window` quotes
+    them). bfloat16 rows are the forward's, float32 the backward's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from incubator_mxnet_tpu.parallel import moe
+    calls = cfg["calls"]
+    f32 = jnp.float32
+
+    def forms(n_tokens, most):
+        def systems(sorts):
+            def form(total, rows, token, live):
+                with mock.patch.object(moe, "_sorts_the_window",
+                                       lambda width: sorts):
+                    return moe._rows_to_tokens(total, rows, token, live,
+                                               most)
+            return form
+
+        def first_rows(total, rows, token, live):
+            # the sorted form's runs, and every row but a run's first sent
+            # past the end, each to a place of its own, and dropped
+            window = rows.shape[0]
+            key, at = jax.lax.sort_key_val(
+                jnp.where(live, token, n_tokens),
+                jnp.arange(window, dtype=jnp.int32))
+            key = jnp.pad(key, (0, most - 1), constant_values=-1)
+            rows = rows[jnp.pad(at, (0, most - 1))]
+            run = rows[:window].astype(f32)
+            for j in range(1, most):
+                run = run + jnp.where(
+                    (key[j:j + window] == key[:window])[:, None],
+                    rows[j:j + window].astype(f32), 0)
+            first = key[:window] != jnp.pad(key[:window - 1], (1, 0),
+                                            constant_values=-1)
+            return total.at[jnp.where(
+                first, key[:window],
+                n_tokens + jnp.arange(window, dtype=jnp.int32))].add(
+                    run, unique_indices=True, mode="drop")
+
+        def one_hot(total, rows, token, live):
+            hot = jnp.where(live, token, n_tokens)[None, :] \
+                == jnp.arange(n_tokens, dtype=jnp.int32)[:, None]
+            return total + jnp.dot(hot.astype(rows.dtype), rows,
+                                   preferred_element_type=f32)
+
+        return {"scatter-add": systems(False), "sorted": systems(True),
+                "first rows scattered": first_rows, "one-hot": one_hot}
+
+    def looped(form, n_tokens, width):
+        def many(rows, token, live):
+            return jax.lax.fori_loop(
+                0, calls, lambda i, total: form(
+                    total, rows, (token + i) % n_tokens, live),
+                jnp.zeros((n_tokens, width), f32))
+        return jax.jit(many)
+
+    rng = np.random.default_rng(54)
+    compile_s, steady, lines, worst = 0.0, [], [], 0.0
+    for cell, (window, n_tokens, width, most) in cfg["shapes"].items():
+        chosen = "sorted" if moe._sorts_the_window(width) else "scatter-add"
+        for share in cfg["live"]:
+            # `most` experts' rows, each expert's tokens distinct and in
+            # order, as a window of the sorted assignments holds them
+            each = int(window * share) // most
+            token = np.arange(window, dtype=np.int32) % n_tokens
+            token[:each * most] = np.concatenate([np.sort(rng.choice(
+                n_tokens, each, replace=False)) for _ in range(most)])
+            live = np.arange(window) < each * most
+            for dtype in (jnp.bfloat16, f32) if share == 0.5 \
+                    else (jnp.bfloat16,):
+                rows = jnp.asarray(
+                    rng.standard_normal((window, width)) * live[:, None],
+                    dtype)
+                args = (rows, jnp.asarray(token), jnp.asarray(live))
+                ms, want = {}, None
+                for name, form in forms(n_tokens, most).items():
+                    if name == "one-hot" and (
+                            window > 4096 or dtype != jnp.bfloat16):
+                        continue    # 2 W T D FLOPs; exact for bf16 rows
+                    fn = looped(form, n_tokens, width)
+                    t0 = time.perf_counter()
+                    got = jax.block_until_ready(fn(*args))
+                    compile_s += time.perf_counter() - t0
+                    if want is None:
+                        want = got
+                    err = float(jnp.abs(got - want).max()
+                                / jnp.abs(want).max())
+                    # the same float32 sum in another order
+                    if err <= 1e-5:
+                        ms[name] = "%.3f" % (median_ms(fn, args) / calls)
+                        worst = max(worst, err)
+                    elif name in ("scatter-add", "sorted"):
+                        raise RuntimeError(
+                            "combine %s, %s: %.3g of the scatter-add's max"
+                            % (cell, name, err))
+                    else:       # a form that was not built, and why not
+                        ms[name] = "WRONG by %.2g of the max" % err
+                steady.append(float(ms[chosen]) / 1e3)
+                lines.append("%s %s %d %% live: %s" % (
+                    cell, jnp.dtype(dtype).name, 100 * share, ", ".join(
+                        "%s %s" % item for item in ms.items())))
+                log("combine: (W %d, T %d, D %d, most %d; the width "
+                    "chooses %s) %s ms a call" % (
+                        window, n_tokens, width, most, chosen, lines[-1]))
+    return compile_s, statistics.median(steady), \
+        "%d shapes x loads of a window's rows into their tokens, every " \
+        "form within %.2g of the scatter-add's max%s" % (
+            len(lines), worst,
+            "; ms a call in the lines above" if on_chip else "")
 
 
 # ------------------------------------------------------------------ training
@@ -1648,6 +1782,7 @@ def main():
     shared = {}
     table = {"kernels": (phase_kernels, cfgs["kernels"]),
              "moe": (phase_moe, cfgs["moe"]),
+             "combine": (phase_combine, cfgs["combine"]),
              "bert": (phase_bert, cfgs["bert"]),
              "gpt": (phase_gpt, cfgs["gpt"]),
              "hybrid": (phase_hybrid, cfgs["hybrid"]),

@@ -54,7 +54,10 @@ _DISPATCHES = telemetry.counter(
     "mxtpu_moe_dispatch_total",
     "MoE expert dispatches traced, by path (dropless: the sort-based "
     "grouped matmul over every expert; dropless_held: the same over the "
-    "experts this chip holds).", ("path",))
+    "experts this chip holds) and by how the expert rows come back to "
+    "their tokens (unsort: the inverse permutation's gather; a held "
+    "window's by _rows_to_tokens, sort: sorted to their tokens and "
+    "gathered, scatter: XLA's row scatter-add).", ("path", "combine"))
 _GROUP_LIMITED = telemetry.counter(
     "mxtpu_moe_group_limited_total",
     "Routers traced with a group limit (MoELayer(n_group=, topk_group=): "
@@ -173,7 +176,7 @@ def dropless_moe(tokens, top_vals, top_idx, w_up, w_down, act, w_gate=None):
     """
     n_tokens, k = top_idx.shape
     num_experts = w_up.shape[0]
-    _DISPATCHES.inc(path="dropless")
+    _DISPATCHES.inc(path="dropless", combine="unsort")
     with jax.named_scope("moe_dispatch"):
         flat = top_idx.reshape(-1).astype(jnp.int32)              # (T*k,)
         slots = jnp.arange(flat.shape[0], dtype=jnp.int32)
@@ -227,8 +230,8 @@ def _window_of(w, window, tokens, top_vals, order, group_sizes):
     weight): the (token, slot) assignment of each row as an index into
     T x k and its token, whether a held expert owns the row, each held
     expert's rows inside the window, the rows' tokens (W, D) and router
-    weights. A dead row's token is its own position: it adds zero wherever
-    it lands, and no two dead rows land on one token."""
+    weights. A dead row's token is its own position: a row to gather, and
+    where its zeros are scattered no two dead rows land on one token."""
     with jax.named_scope("moe_dispatch"):
         lo = w * window
         iota = jnp.arange(window, dtype=jnp.int32)
@@ -241,6 +244,84 @@ def _window_of(w, window, tokens, top_vals, order, group_sizes):
             - jnp.clip(ends - group_sizes, lo, lo + window)
         return slot, token, live, sizes, tokens[token], \
             top_vals.reshape(-1)[slot]
+
+
+def _sorts_the_window(width):
+    """Whether `_rows_to_tokens` sorts a window's rows to their tokens or
+    leaves them to XLA's row scatter-add: from the rows' width alone, 2560
+    and 3584 (the Ling and Xing cells'; any width with a prime factor over
+    3) yes. On a v5e the scatter-add of W rows into (T, D) float32 takes
+    0.05-0.1 us a row and 1024 elements at D = 512 .. 4096 in steps of 512,
+    but 5.6 x that at 2560, 2 x at 3584 and 10 x at 5120, whatever the
+    tokens; the sorted form's passes over W + T rows cost by the byte, win
+    by little where the scatter-add is sound (and lose at D = 4096 and at
+    W = 2 T), and so run only where it is not. Milliseconds a call, half
+    the window live, bfloat16 | float32 rows, on one v5e (`chip_smoke.py
+    --phases combine`; docs/PERF_NOTES.md, PR 54, also for W 4096 into T
+    8192 at nine widths):
+
+        (W, T, D, most)               scatter-add    sorted
+        2048, 8192, 2560, 8 Ling      3.28 | 3.28    0.49 | 0.46
+        8192, 8192, 3584, 4 Xing      2.84 | 2.48    1.49 | 1.82
+        32768, 16384, 2048, 8 Keye    3.91 | 3.26    4.45 | 5.94
+        4096, 8192, 4096, 8 Solar     1.19 | 1.19    1.17 | 1.80
+        6144, 8192, 1024, 8 Nemotron  0.32 | 0.33    0.26 | 0.23
+    """
+    while width % 2 == 0:
+        width //= 2
+    while width % 3 == 0:
+        width //= 3
+    return width > 1
+
+
+def _rows_to_tokens(total, rows, token, live, most):
+    """total (T, D) float32 + zeros.at[token].add(rows) over the live rows
+    of a window, rows (W, D), where no token has more than `most` of them.
+    `rows` may be several (W, D) arrays whose float32 sum is meant (the
+    backward's gradients a projection).
+
+    One of two forms, by `_sorts_the_window`. XLA's row scatter-add, in
+    place; a dead row is zeros and its token its own position, so it adds
+    nothing and no two land on one token. Or one sort of the W token ids
+    and two row gathers: the live rows sorted by token (a dead row's key
+    is T: last, and in no token's run) lie as runs of at most `most`
+    neighbours; a run is summed onto its first row by `most` - 1 shifted
+    float32 adds under the mask "same key"; token t's run starts at the
+    number of keys below t, a count by comparison (a binary search, or a
+    gather or scatter of scalars, is serial on the TPU), and t reads that
+    row where a key equals t. Each of `rows` is gathered in its own type
+    and widened after. The same float32 sum either way: the order of a
+    token's at most `most` additions is all that differs."""
+    several = isinstance(rows, (tuple, list))
+    if not _sorts_the_window(total.shape[1]):
+        # (traced as up to PR 53: a pinned program stays the one it was)
+        return total.at[token].add(
+            sum(part.astype(jnp.float32) for part in rows) if several
+            else rows.astype(jnp.float32))
+    parts = rows if several else (rows,)
+    window, n_tokens = parts[0].shape[0], total.shape[0]
+    with jax.named_scope("rows_to_tokens"):
+        key, at = jax.lax.sort_key_val(
+            jnp.where(live, token, n_tokens),
+            jnp.arange(window, dtype=jnp.int32))
+        # most - 1 rows more, keyed as no token is, for the shifted reads
+        key = jnp.pad(key, (0, most - 1), constant_values=-1)
+        at = jnp.pad(at, (0, most - 1))
+        parts = [part[at] for part in parts]                      # (W+, D)
+
+        def shifted(j):
+            return sum(part[j:j + window].astype(jnp.float32)
+                       for part in parts)
+
+        run = shifted(0)
+        for j in range(1, most):
+            run = run + jnp.where(
+                (key[j:j + window] == key[:window])[:, None], shifted(j), 0)
+        upto = jnp.sum(key[:window, None] <= jnp.arange(
+            n_tokens, dtype=jnp.int32), axis=0, dtype=jnp.int32)   # (T,)
+        below = jnp.pad(upto[:-1], (1, 0))
+        return total + jnp.where((upto > below)[:, None],
+                                 run[jnp.minimum(below, window - 1)], 0)
 
 
 def _hidden(act, *pre):
@@ -282,6 +363,8 @@ def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
     """-> ((T, D) float32 sum, kept). With `keep` each window's
     pre-activations and expert rows are written into (windows x W)-row
     buffers for the backward; without, `kept` is empty."""
+    most = min(top_vals.shape[1], w_down.shape[0])   # a token's rows
+
     def body(w, carry):
         total, kept = carry
         _, token, live, sizes, rows, weight = _window_of(
@@ -289,8 +372,8 @@ def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
         pre, y = _expert_rows(act, rows, projections, w_down, sizes,
                               live)                               # (W, H)
         with jax.named_scope("moe_combine"):
-            total = total.at[token].add(
-                _weighted(y, weight).astype(jnp.float32))
+            total = _rows_to_tokens(total, _weighted(y, weight), token,
+                                    live, most)
         return total, jax.tree.map(
             lambda buf, x: jax.lax.dynamic_update_slice(
                 buf, x, (w * window, 0)), kept, (pre, y) if keep else ())
@@ -311,9 +394,12 @@ def _held_forward(act, window, keep, tokens, top_vals, projections, w_down,
 # buffers a window writes its own rows of; a window that does not run
 # costs those buffers' zeros and nothing else. Where those buffers would
 # pass HELD_KEEP_BYTES nothing is kept and the backward's body computes its
-# window's again. The moves between tokens
-# and rows are a gather one way and a float32 scatter-add the other, W rows
-# each.
+# window's again. The moves between tokens and rows are a gather one way
+# and `_rows_to_tokens` the other, W rows each: XLA's float32 row
+# scatter-add, which stood here alone up to PR 53, or where that is slow
+# (`_sorts_the_window`: the Ling and Xing cells' rows of 2560 and 3584) a
+# sort of the window's token ids and gathers of W + T whole rows. The
+# router weights' gradient is a scatter-add of W scalars at distinct slots.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _held_sum(act, window, tokens, top_vals, projections, w_down, order,
               group_sizes):
@@ -350,6 +436,8 @@ def _held_sum_fwd(act, window, *args):
 
 def _held_sum_bwd(act, window, res, g):
     tokens, top_vals, projections, w_down, order, group_sizes, kept = res
+    most = min(top_vals.shape[1], w_down.shape[0])
+
     def body(w, sums):
         d_tokens, d_vals, d_projections, d_w_down = sums
         slot, token, live, sizes, rows, weight = _window_of(
@@ -370,8 +458,8 @@ def _held_sum_bwd(act, window, res, g):
             d_rows, d_ms = zip(*(_grouped_t(rows, m, sizes, live, d)
                                  for m, d in zip(projections, pull(d_h))))
         with jax.named_scope("moe_dispatch"):
-            d_tokens = d_tokens.at[token].add(
-                sum(d.astype(jnp.float32) for d in d_rows))
+            d_tokens = _rows_to_tokens(d_tokens, d_rows, token, live,
+                                       most)
             d_vals = d_vals.at[slot].add(d_weight)
         return d_tokens, d_vals, tuple(
             total + d.astype(jnp.float32)
@@ -404,12 +492,16 @@ def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
     count, num_experts)` sorted rows: a window gathers its rows' tokens,
     runs the grouped matmuls with each held expert's rows inside it as
     `group_sizes`, weighs and rounds each row to the tokens' type and adds
-    it into its token's float32 sum. As many windows run as hold a live
-    row (`lax.while_loop`: one where the router is anywhere near balanced,
+    it into its token's float32 sum (`_rows_to_tokens`: by the rows' width
+    a sort of the window's token ids and two row gathers, or XLA's row
+    scatter-add; the backward's sum of the rows' gradients into the
+    tokens' is the same function). As many windows run as hold a live row
+    (`lax.while_loop`: one where the router is anywhere near balanced,
     T x min(k, count) / W at worst), so nothing is dropped at any load, and
-    every op works on W rows (what the backward reads of the forward is
-    kept in buffers of all the windows' rows, which a window that runs
-    writes its part of, or computed again where those buffers would pass
+    every op works on W rows, the sorted form's count of keys below a token
+    on W x T of their ids (what the backward reads of the forward is kept
+    in buffers of all the windows' rows, which a window that runs writes
+    its part of, or computed again where those buffers would pass
     `HELD_KEEP_BYTES`). Where W is the worst case there is no loop.
     Scopes as in `dropless_moe`, inside `moe_window`. -> (y, the rows each
     held expert got: int32 (count,), what the windows ran on).
@@ -419,7 +511,8 @@ def dropless_moe_held(tokens, top_vals, top_idx, w_up, w_down, act, first,
     worst = n_tokens * min(k, count)
     window = held_window_rows(n_tokens, k, count, num_experts)
     n_windows = -(-worst // window)
-    _DISPATCHES.inc(path="dropless_held")
+    _DISPATCHES.inc(path="dropless_held", combine="sort"
+                    if _sorts_the_window(tokens.shape[1]) else "scatter")
     with jax.named_scope("moe_dispatch"):
         local = top_idx.astype(jnp.int32) - first                 # (T, k)
         key = jnp.where((local >= 0) & (local < count), local,

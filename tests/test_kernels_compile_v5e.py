@@ -122,6 +122,51 @@ def test_a_k_block_moves_no_operand_of_the_rule_between_two_layouts(
     assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
 
 
+#: a scatter whose result is a float32 matrix, with its dimensions
+_ROW_SCATTER = re.compile(r"= f32\[(\d+),(\d+)\]\S* scatter\(")
+
+
+@pytest.mark.parametrize("cell, width, hidden, experts, k", [
+    ("ling", 2560, 768, 512, 8), ("xing", 3584, 1024, 64, 4)])
+def test_a_held_layers_rows_reach_their_tokens_by_no_row_scatter(
+        one_chip, monkeypatch, cell, width, hidden, experts, k):
+    """Value and gradient of one held expert layer's dispatch at the Ling
+    cell's shape (8192 tokens of 2560, 8 of 512 SwiGLU experts of 768 held,
+    8 a token: windows of 2048 rows) and at the Xing cell's (3584 wide, 8
+    of 64 experts of 1024, 4 a token: windows of 8192), bfloat16: the
+    compiled program holds no scatter into a float32 (8192, width), which
+    up to PR 53 was the forward's combine and the backward's token
+    gradient, 2.3 and 2.7 ms a call on the chip for 0.1-0.2 ms of bytes
+    (`parallel.moe._rows_to_tokens`, which sorts a window to its tokens at
+    these widths). Told to keep the scatter-add, as at other widths, the
+    same reading finds both, so it can see what it says is gone."""
+    from incubator_mxnet_tpu.parallel import moe
+    n_tokens, count = 8192, 8
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    specs = (spec((n_tokens, width)), spec((n_tokens, k), jnp.float32),
+             spec((count, width, hidden)), spec((count, width, hidden)),
+             spec((count, hidden, width)), spec((n_tokens, k), jnp.int32))
+
+    def value(tokens, top_vals, w_gate, w_up, w_down, top_idx):
+        return moe.dropless_moe_held(
+            tokens, top_vals, top_idx, w_up, w_down, jax.nn.silu, 0, experts,
+            w_gate)[0].astype(jnp.float32).sum()
+
+    def row_scatters():
+        text = jax.jit(jax.value_and_grad(value, (0, 1, 2, 3, 4))).lower(
+            *specs).compile().as_text()
+        assert "ragged-dot" in text
+        return [dims for dims in _ROW_SCATTER.findall(text)
+                if dims == (str(n_tokens), str(width))]
+
+    assert row_scatters() == []
+    monkeypatch.setattr(moe, "_sorts_the_window", lambda width: False)
+    assert len(row_scatters()) >= 2
+
+
 #: the Cerebras cells' widths (perfbench/configs/cerebras-gpt-1.3b.json)
 UNITS, INNER, HEADS = 2048, 8192, 16
 _UPDATE = re.compile(

@@ -139,14 +139,15 @@ def test_stacked_weights_are_scaled_per_expert():
 
 
 def test_dispatch_counter_and_the_capacity_warning():
-    before = moe._DISPATCHES.value(path="dropless")
+    labels = dict(path="dropless", combine="unsort")
+    before = moe._DISPATCHES.value(**labels)
     layer = parallel.MoELayer(num_experts=4, hidden_size=8, ffn_hidden=16)
     layer.initialize()
     f = jax.jit(lambda x: layer(nd.NDArray(x))._data)
     for _ in range(3):                       # traced once, run three times
         f(jnp.ones((2, 3, 8)))
-    assert moe._DISPATCHES.value(path="dropless") - before == 1
-    assert 'mxtpu_moe_dispatch_total{path="dropless"}' \
+    assert moe._DISPATCHES.value(**labels) - before == 1
+    assert 'mxtpu_moe_dispatch_total{path="dropless",combine="unsort"}' \
         in telemetry.REGISTRY.export_text()
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")

@@ -62,7 +62,11 @@ def test_held_dispatch_is_the_held_experts_part_of_the_dense_sum(
     onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_held_dispatch_gradients_are_the_dense_ones():
+@pytest.mark.parametrize("sorts", [True, False], ids=["sort", "scatter"])
+def test_held_dispatch_gradients_are_the_dense_ones(sorts, monkeypatch):
+    """Under both forms of `_rows_to_tokens` (the width chooses: told here
+    what to say)."""
+    monkeypatch.setattr(moe, "_sorts_the_window", lambda width: sorts)
     tokens, top_vals, top_idx, w_up, w_down = _case(1)
     first, count = 2, 4
     sl = slice(first, first + count)
@@ -119,15 +123,21 @@ def _row_counts(jaxpr, widths):
             if len(v.aval.shape) == 2 and v.aval.shape[1] in widths}
 
 
+@pytest.mark.parametrize("sorts", [True, False], ids=["sort", "scatter"])
 @pytest.mark.parametrize("gated", [False, True])
-def test_no_op_reads_or_writes_more_rows_than_the_window(gated):
+def test_no_op_reads_or_writes_more_rows_than_the_window(gated, sorts,
+                                                         monkeypatch):
     """At Nemotron's ratio (8 of 512 held, 22 a token) W is 6144 of the
     65 536 worst-case rows. The value's jaxpr holds no (n, D) or (n, H)
-    tensor with n > W but the tokens' own T rows. The gradient's holds the
+    tensor with n > W but the tokens' own T rows and, where
+    `_rows_to_tokens` sorts the window, its rows in token order, which
+    carry min(k, count) - 1 rows more for the shifted reads of a token's
+    run. The gradient's holds the
     kept buffers of 11 windows beside them, and the only ops that touch
     those are the zeros they start as, the loop that carries them and a
     window's slice in and out: no dead row is gathered, multiplied, squared
     or cast. The loop is a `while`."""
+    monkeypatch.setattr(moe, "_sorts_the_window", lambda width: sorts)
     n_tokens, k, count, n_experts = 8192, 22, 8, 512
     window = moe.held_window_rows(n_tokens, k, count, n_experts)
     assert window == 6144
@@ -145,10 +155,13 @@ def test_no_op_reads_or_writes_more_rows_than_the_window(gated):
     args = (tokens, top_vals, w_up, w_down, w_gate)
     jaxpr = jax.make_jaxpr(value)(*args).jaxpr
     assert any(e.primitive.name == "while" for e in _eqns(jaxpr))
-    assert _row_counts(jaxpr, (D, H)) - {D, H} == {window, n_tokens}
+    in_runs = {window + min(k, count) - 1} if sorts else set()
+    assert _row_counts(jaxpr, (D, H)) - {D, H} \
+        == {window, n_tokens} | in_runs
     jaxpr = jax.make_jaxpr(jax.grad(value, (0, 1, 2, 3)))(*args).jaxpr
     kept = 11 * window
-    assert _row_counts(jaxpr, (D, H)) - {D, H} == {window, n_tokens, kept}
+    assert _row_counts(jaxpr, (D, H)) - {D, H} \
+        == {window, n_tokens, kept} | in_runs
     touching = {e.primitive.name for e in _eqns(jaxpr)
                 if any(getattr(v.aval, "shape", ()) in ((kept, D), (kept, H))
                        for v in list(e.invars) + list(e.outvars))}
@@ -171,6 +184,96 @@ def test_holding_every_expert_is_one_window_and_no_loop():
         assert not any(e.primitive.name in ("while", "scan", "cond")
                        for e in _eqns(jaxpr))
         assert T * K in _row_counts(jaxpr, (D, H))
+
+
+def _window_case(kind, rng):
+    """-> (token (W,), live (W,), T, most) of one window: `kind` says how
+    many rows a token has and which rows are live."""
+    n_tokens, most = 40, 4
+    if kind in ("one", "two", "most"):          # every token that many rows
+        each = {"one": 1, "two": 2, "most": most}[kind]
+        token = rng.permutation(onp.repeat(
+            rng.choice(n_tokens, 24, replace=False), each))
+        live = onp.ones(token.shape, bool)
+    elif kind == "wider":                       # W = 2 T, Keye's ratio
+        n_tokens, most = 32, 8
+        token = rng.permutation(onp.repeat(onp.arange(n_tokens), most))[:64]
+        live = onp.arange(64) < 40
+    else:                       # 1 .. most rows a token, in any order
+        token = rng.permutation(onp.repeat(onp.arange(n_tokens), most))[:96]
+        live = {"none": onp.zeros(96, bool), "all": onp.ones(96, bool),
+                "between": rng.random(96) < 0.5,
+                "tail": onp.arange(96) < 31}[kind]
+    return token.astype(onp.int32), live, n_tokens, most
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("kind", ["one", "two", "most", "none", "all",
+                                  "between", "tail", "wider"])
+def test_a_windows_rows_reach_their_tokens_as_a_scatter_add_sums_them(
+        kind, dtype):
+    """`_rows_to_tokens`, the sorted form (rows 20 wide), against
+    `total.at[token].add(rows)` over the live rows, in float32: a token
+    with 1, 2 and `most` rows, no live row, every row live, dead rows
+    between live ones and behind them (a window's), more rows than tokens;
+    rows of the forward's type and of the backward's, one array and two to
+    be added. The order of a token's additions is all that may differ. A
+    dead row's token and contents reach no sum."""
+    rng = onp.random.default_rng(54)
+    token, live, n_tokens, most = _window_case(kind, rng)
+    width = 20
+    assert moe._sorts_the_window(width)
+    total = jnp.asarray(rng.standard_normal((n_tokens, width)), jnp.float32)
+    rows, more = (jnp.asarray(rng.standard_normal((token.size, width)),
+                              dtype) for _ in range(2))
+    fn = jax.jit(moe._rows_to_tokens, static_argnums=4)
+    for parts in (rows, (rows, more)):
+        added = rows if parts is rows else rows.astype(jnp.float32) + more
+        want = total.at[jnp.where(live, token, n_tokens)].add(
+            added.astype(jnp.float32), mode="drop")
+        got = fn(total, parts, token, live, most)
+        assert got.dtype == jnp.float32 and got.shape == total.shape
+        onp.testing.assert_allclose(got, want, rtol=1e-6, atol=2e-6)
+        untouched = onp.setdiff1d(onp.arange(n_tokens), token[live])
+        onp.testing.assert_array_equal(onp.asarray(got)[untouched],
+                                       onp.asarray(total)[untouched])
+    if kind == "none":
+        onp.testing.assert_array_equal(got, total)
+
+
+def test_the_rows_width_alone_chooses_between_the_two_forms():
+    """A width of no prime factor over 3 keeps XLA's row scatter-add, in
+    place (what the Keye, Solar and Nemotron cells' 2048, 4096 and 1024
+    trace: the program it was); any other (Ling's 2560, Xing's 3584)
+    sorts the window to its tokens, and its jaxpr holds a sort, two row
+    gathers and no scatter. Both are the same sum."""
+    for width, sorts in ((1024, False), (2048, False), (4096, False),
+                         (1536, False), (3072, False), (16, False),
+                         (2560, True), (3584, True), (5120, True)):
+        assert moe._sorts_the_window(width) is sorts
+    rng = onp.random.default_rng(5)
+    token, live, n_tokens, most = _window_case("tail", rng)
+    token = onp.where(live, token, onp.arange(token.size) % n_tokens)
+    names, sums = {}, {}
+    wide = jnp.asarray(rng.standard_normal((token.size, 40))
+                       * live[:, None], jnp.bfloat16)
+    for width in (32, 40):
+        total = jnp.zeros((n_tokens, width), jnp.float32)
+        rows = wide[:, :width]
+        jaxpr = jax.make_jaxpr(lambda *a: moe._rows_to_tokens(*a, most))(
+            total, rows, token, live).jaxpr
+        names[width] = [e.primitive.name for e in _eqns(jaxpr)
+                        if len(e.outvars[0].aval.shape) == 2
+                        or e.primitive.name == "sort"]
+        sums[width] = moe._rows_to_tokens(total, rows, token, live, most)
+    assert names[32].count("scatter-add") == 1
+    assert not {"sort", "gather"} & set(names[32])
+    assert names[40].count("sort") == 1
+    assert names[40].count("gather") == 2
+    assert not {"scatter", "scatter-add"} & set(names[40])
+    onp.testing.assert_allclose(sums[40][:, :32], sums[32],
+                                rtol=1e-6, atol=2e-6)
 
 
 def _routing(kind, n_tokens, k, first, count, n_experts, rng):
@@ -259,14 +362,18 @@ def test_windows_are_exact_at_any_load(kind, windows, gated, kept):
         assert not any(bool(jnp.any(g)) for g in got_grads)
 
 
+@pytest.mark.parametrize("sorts", [True, False], ids=["sort", "scatter"])
 @pytest.mark.parametrize("gated", [False, True])
 def test_what_a_kernel_leaves_in_dead_rows_reaches_no_sum(gated, monkeypatch,
-                                                          kept):
+                                                          kept, sorts):
     """A grouped matmul answers for the rows its groups own; on the chip
     the others hold whatever was there. With every such row of every
     grouped matmul's output and of its transpose poisoned (NaN), value and
     gradients are still the dense form's. Off the TPU those rows are
-    zeros, which is why nothing else here can see a missing mask."""
+    zeros, which is why nothing else here can see a missing mask. Under
+    both forms of `_rows_to_tokens`: the scatter-add ADDS its dead rows,
+    the sorted form reads none."""
+    monkeypatch.setattr(moe, "_sorts_the_window", lambda width: sorts)
     n_tokens, k, first, count, n_experts = 1024, 4, 8, 4, 64
     window = moe.held_window_rows(n_tokens, k, count, n_experts)
     rng = onp.random.default_rng(11)
@@ -584,15 +691,23 @@ def test_held_none_is_the_dispatch_it_was():
 
 
 def test_the_held_path_has_its_own_counter():
-    before = moe._DISPATCHES.value(path="dropless_held")
+    labels = dict(path="dropless_held", combine="scatter")  # rows of 16
+    before = moe._DISPATCHES.value(**labels)
     tokens, top_vals, top_idx, w_up, w_down = _case(6)
     f = jax.jit(lambda t: _held_sum(
         t, top_vals, top_idx, w_up[:2], w_down[:2], jax.nn.relu, 0, E))
     for _ in range(3):
         f(tokens)
-    assert moe._DISPATCHES.value(path="dropless_held") - before == 1
+    assert moe._DISPATCHES.value(**labels) - before == 1
     text = telemetry.REGISTRY.export_text()
-    assert 'mxtpu_moe_dispatch_total{path="dropless_held"}' in text
+    assert 'mxtpu_moe_dispatch_total{path="dropless_held",' \
+        'combine="scatter"}' in text
+    sorted_before = moe._DISPATCHES.value(path="dropless_held",
+                                          combine="sort")
+    _held_sum(jnp.ones((T, 20)), top_vals, top_idx, jnp.ones((2, 20, H)),
+              jnp.ones((2, H, 20)), jax.nn.relu, 0, E)
+    assert moe._DISPATCHES.value(
+        path="dropless_held", combine="sort") - sorted_before == 1
     # W of each held layer as traced, by the layer's name: at these sizes
     # the worst case, T x min(k, count) (one window, no loop)
     for labels, _ in moe._WINDOW_ROWS.series():     # 64 label sets a family
@@ -702,14 +817,19 @@ def _held_sigmoid_layer():
     return jax.value_and_grad(value, trained, has_aux=True), (x, *weights)
 
 
+@pytest.mark.parametrize("sorts", [True, False], ids=["sort", "scatter"])
 def test_a_sigmoid_routed_step_holds_no_gather_or_scatter_of_the_routers(
-        monkeypatch):
+        monkeypatch, sorts):
     """Value and gradient of a held `sigmoid_bias` layer with the balancing
     rule: nothing traced under `router` is a gather or a scatter, in the
     jaxpr and in the compiled module; the held dispatch's own moves between
-    tokens and rows stay, under `moe_dispatch` / `moe_combine`. With the
+    tokens and rows stay, under `moe_dispatch` / `moe_combine`: gathers of
+    whole rows, a scatter-add of W scalars at distinct slots (the router
+    weights' gradient), and by `_rows_to_tokens`' form either a sort and no
+    scatter of a row, or the two row scatter-adds of up to PR 53. With the
     gather written back into `route` the same reading finds both, so it
     can see what it says is gone."""
+    monkeypatch.setattr(moe, "_sorts_the_window", lambda width: sorts)
     moves = {"gather", "scatter", "scatter-add"}
     fn, args = _held_sigmoid_layer()
     jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
@@ -718,7 +838,13 @@ def test_a_sigmoid_routed_step_holds_no_gather_or_scatter_of_the_routers(
     assert not router & moves
     held = _scoped_primitives(jaxpr, "moe_dispatch") \
         | _scoped_primitives(jaxpr, "moe_combine")
-    assert {"gather", "scatter-add"} <= held
+    assert {"sort", "gather", "scatter-add"} <= held
+    assert not held & {"scatter"}
+    assert sorted(len(eqn.outvars[0].aval.shape)
+                  for eqn, path in _scoped_eqns(jaxpr)
+                  if eqn.primitive.name == "scatter-add"
+                  and ("moe_dispatch" in path or "moe_combine" in path)) \
+        == ([1] if sorts else [1, 2, 2])
 
     def router_lines(fn):
         """The compiled program's gathers and scatters whose `op_name`
